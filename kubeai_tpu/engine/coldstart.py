@@ -108,26 +108,39 @@ def mesh_signature(mesh) -> list:
     return []
 
 
-def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point JAX's persistent compilation cache at `cache_dir` with the
-    thresholds zeroed so every serving graph is cached (the defaults
-    skip fast compiles — exactly the ones a CPU-fallback test produces).
-    Best-effort: platforms without cache support boot normally."""
-    try:
-        import jax
+# Where the persistent compilation cache lives when the environment does
+# not say: one fixed, git-ignored path inside the checkout. The directory
+# is part of the cache key, so a path that moves (a temp dir, a pid, a
+# time) never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        with contextlib.suppress(Exception):
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0
-            )
-        with contextlib.suppress(Exception):
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return True
-    except Exception as e:  # noqa: BLE001 — never fail boot over the cache
-        logger.warning("persistent compilation cache unavailable: %s", e)
-        return False
+
+def enable_compilation_cache() -> str:
+    """The one rule for JAX's persistent compilation cache; every process
+    that compiles serving graphs (server main, bench.py, chip_smoke.py's
+    children) calls it before its first compile. Where
+    JAX_COMPILATION_CACHE_DIR is set JAX already points there and no
+    directory is set in code; where it is not, the cache goes to
+    DEFAULT_CACHE_DIR. The thresholds are zeroed so every serving graph
+    is kept (the defaults skip compiles under a second). Returns the
+    directory in effect."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
+
+
+def _cache_dir_in_effect() -> str | None:
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
 
 
 class ColdStartManager:
@@ -157,8 +170,10 @@ class ColdStartManager:
         self.publish = publish
         self.model = model_name
         self.tracker = ColdStartTracker(clock)
+        # Staging for the param tree and the fetched/published bundle only;
+        # the compilation cache stays wherever enable_compilation_cache put
+        # it, and is copied out of / into the bundle from there.
         self.work_dir = work_dir or tempfile.mkdtemp(prefix="kubeai-snap-")
-        self.cache_dir = os.path.join(self.work_dir, "xla_cache")
         self.params_dir = os.path.join(self.work_dir, "params")
         cfg = (
             dataclasses.asdict(engine_config)
@@ -180,11 +195,6 @@ class ColdStartManager:
         mismatched tree is never restored."""
         from kubeai_tpu.objstore import SnapshotMismatch
 
-        # The cache dir is configured up front: a restore fills it
-        # before the first compile, a full load populates it for the
-        # write-back.
-        if self.enabled:
-            enable_compilation_cache(self.cache_dir)
         manifest = None
         if self.enabled:
             try:
@@ -201,6 +211,8 @@ class ColdStartManager:
             else:
                 if manifest is None:
                     self.tracker.event("absent")
+                else:
+                    self._restore_compilation_cache()
         if manifest is not None:
             try:
                 from kubeai_tpu.engine.weights import load_native_checkpoint
@@ -222,6 +234,14 @@ class ColdStartManager:
         with self.tracker.phase("load"):
             return full_load()
 
+    def _restore_compilation_cache(self) -> None:
+        """Copy the snapshot's bundled cache entries into the directory in
+        effect, before the first compile, so the first jit is a read."""
+        fetched = os.path.join(self.work_dir, "xla_cache")
+        cache_dir = _cache_dir_in_effect()
+        if cache_dir and os.path.isdir(fetched):
+            shutil.copytree(fetched, cache_dir, dirs_exist_ok=True)
+
     def maybe_publish(self, params) -> bool:
         """Write-back on first boot, called AFTER warm-up so the bundled
         compilation cache holds the serving graphs. No-op when restore
@@ -235,10 +255,9 @@ class ColdStartManager:
             shutil.rmtree(stage, ignore_errors=True)
             os.makedirs(stage, exist_ok=True)
             save_native_checkpoint(os.path.join(stage, "params"), params)
-            if os.path.isdir(self.cache_dir) and os.listdir(self.cache_dir):
-                shutil.copytree(
-                    self.cache_dir, os.path.join(stage, "xla_cache")
-                )
+            cache_dir = _cache_dir_in_effect()
+            if cache_dir and os.path.isdir(cache_dir) and os.listdir(cache_dir):
+                shutil.copytree(cache_dir, os.path.join(stage, "xla_cache"))
             self.store.publish(
                 self.model,
                 self.fingerprint,

@@ -145,15 +145,14 @@ class LockstepEngine:
         # Overlapped stepping cannot run under lockstep: every host must
         # replay the SAME op/step sequence, and an unreaped chunk on host
         # 0 would reorder its broadcast schedule relative to the workers'.
-        # Explicit "on" (incl. the legacy pipeline bool) is a typed
-        # refusal; "auto" silently degrades to the synchronous loop.
+        # Explicit "on" is a typed refusal; "auto" silently degrades to
+        # the synchronous loop.
         # Defense in depth — server main() resolves this before the
         # worker engines are even built.
         explicit = inner.cfg.step_overlap
         if (
             explicit is True
             or str(explicit).strip().lower() == "on"
-            or inner.cfg.pipeline
         ):
             raise StepOverlapUnsupported(
                 "step_overlap='on' does not compose with lockstep "

@@ -87,21 +87,27 @@ class PagedKVCache:
         kv_heads: int,
         head_dim: int,
         dtype=jnp.bfloat16,
+        pool_sharding=None,  # a Sharding, or {"q8", "scale"} of them (int8)
+        table_sharding=None,
     ) -> "PagedKVCache":
+        """Buffers are created under their shardings (None = the default
+        device), never whole on one device and re-placed afterwards."""
         from kubeai_tpu.ops.kv_quant import make_quantized_pool
 
         max_pages = -(-max_seq_len // page_size)
         shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
         if dtype in (jnp.int8, "int8"):
-            k_pages = make_quantized_pool(shape)
-            v_pages = make_quantized_pool(shape)
+            k_pages = make_quantized_pool(shape, sharding=pool_sharding)
+            v_pages = make_quantized_pool(shape, sharding=pool_sharding)
         else:
-            k_pages = jnp.zeros(shape, dtype)
-            v_pages = jnp.zeros(shape, dtype)
+            k_pages = jnp.zeros(shape, dtype, device=pool_sharding)
+            v_pages = jnp.zeros(shape, dtype, device=pool_sharding)
         return PagedKVCache(
             k_pages=k_pages,
             v_pages=v_pages,
-            block_tables=jnp.full((num_slots, max_pages), -1, jnp.int32),
+            block_tables=jnp.full(
+                (num_slots, max_pages), -1, jnp.int32, device=table_sharding
+            ),
         )
 
 

@@ -25,13 +25,24 @@ import numpy as np
 QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
-def quantize_tensor(w: jnp.ndarray) -> dict:
-    """[..., in, out] -> {"w8": int8, "scale": f32[..., 1, out]}."""
-    w32 = np.asarray(w, np.float32)
+def quantize_tensor(w) -> dict:
+    """[..., in, out] -> {"w8": int8, "scale": f32[..., 1, out]}, computed
+    and left on the HOST: the engine shards the result straight onto its
+    mesh, so no unsharded copy of a weight ever sits on one device. A
+    stacked [NL, in, out] tensor goes one layer at a time, which bounds
+    the f32 working copy to one layer."""
+    w = np.asarray(w)
+    if w.ndim > 2:
+        parts = [quantize_tensor(w[i]) for i in range(w.shape[0])]
+        return {
+            "w8": np.stack([p["w8"] for p in parts]),
+            "scale": np.stack([p["scale"] for p in parts]),
+        }
+    w32 = w.astype(np.float32)
     amax = np.max(np.abs(w32), axis=-2, keepdims=True)  # per output channel
-    scale = np.maximum(amax / 127.0, 1e-8)
+    scale = np.maximum(amax / 127.0, 1e-8).astype(np.float32)
     w8 = np.clip(np.round(w32 / scale), -127, 127).astype(np.int8)
-    return {"w8": jnp.asarray(w8), "scale": jnp.asarray(scale, np.float32)}
+    return {"w8": w8, "scale": scale}
 
 
 def dequantize(leaf) -> jnp.ndarray:

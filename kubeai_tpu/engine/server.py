@@ -532,6 +532,7 @@ def engine_state_snapshot(engine) -> dict:
     kvu = getattr(inner, "kv_utilization", None)
     sched = getattr(inner, "scheduler", None)
     kv_info = getattr(inner, "kv_cache_info", None)
+    dev_info = getattr(inner, "device_info", None)
     return {
         "slots_active": engine.num_active,
         "requests_pending": engine.num_pending,
@@ -540,6 +541,10 @@ def engine_state_snapshot(engine) -> dict:
         # capacity factor here so the autoscaler and capacity planner
         # size against REAL capacity, not the bf16 assumption.
         "kv_cache": kv_info() if kv_info is not None else {},
+        # What the engine serves on, as JAX reports it: platform,
+        # device_kind, device count and mesh — so nobody has to guess
+        # whether a replica found its chips.
+        "device": dev_info() if dev_info is not None else {},
         "last_step": dict(getattr(inner, "last_step_stats", {}) or {}),
         "spec_stats": dict(getattr(inner, "spec_stats", {}) or {}),
         "prefix_stats": dict(getattr(inner, "prefix_stats", {}) or {}),
@@ -932,8 +937,8 @@ class EngineServer:
         """Detect a hung device step: work is active but the serve loop
         made no step progress for watchdog_timeout. A crashed loop
         already flips /health (_loop_dead); this catches the worse case
-        where step() never RETURNS — a wedged XLA dispatch or a dead
-        remote-chip tunnel — which no exception handler can see. On
+        where step() never RETURNS — a wedged XLA dispatch — which no
+        exception handler can see. On
         detection /health flips (the LB ejects long before the circuit
         breaker could accumulate response-header timeouts) and
         watchdog_action runs (production: exit nonzero → kubelet
@@ -958,8 +963,8 @@ class EngineServer:
             # progress — the device is computing and the host will reap
             # on the next step — but only within its own reap deadline
             # (the same watchdog budget). An in-flight chunk older than
-            # that means the reap itself is wedged (hung dispatch, dead
-            # tunnel) and must still trip the restart.
+            # that means the reap itself is wedged (a hung dispatch) and
+            # must still trip the restart.
             info_fn = getattr(self.engine, "inflight_info", None)
             if info_fn is not None:
                 try:
@@ -2317,10 +2322,8 @@ class EngineServer:
     @property
     def _embed_jit(self):
         if not hasattr(self, "_embed_jit_cached"):
-            import jax
-
             fam, mcfg = self.engine.family, self.engine.model_cfg
-            self._embed_jit_cached = jax.jit(
+            self._embed_jit_cached = self.engine.jit(
                 lambda params, tokens, lengths: fam.hidden_states(
                     params, mcfg, tokens, lengths
                 )
@@ -2462,11 +2465,6 @@ def main(argv=None) -> int:
         "(CRD kvCache.dtype)",
     )
     ap.add_argument(
-        "--pipeline", action="store_true",
-        help="legacy alias for --step-overlap on (overlap decode chunks "
-        "with host processing; direct PJRT targets)",
-    )
-    ap.add_argument(
         "--step-overlap", choices=["auto", "on", "off"], default="auto",
         help="overlapped step pipeline: dispatch decode chunk N+1 before "
         "reaping chunk N so readback/admission/detokenize/SSE hide "
@@ -2592,8 +2590,10 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--snapshot-dir", default="",
-        help="local staging dir for snapshot fetch/publish and the "
-        "persistent compilation cache (default: a fresh temp dir)",
+        help="local staging dir for snapshot fetch/publish (default: a "
+        "fresh temp dir). The persistent compilation cache is not kept "
+        "here: it lives where JAX_COMPILATION_CACHE_DIR says, else at one "
+        "fixed path in the checkout (engine/coldstart.py)",
     )
     ap.add_argument(
         "--snapshot-no-publish", action="store_true",
@@ -2614,7 +2614,7 @@ def main(argv=None) -> int:
         # worker hosts' engines resolve identically to host 0's.
         from kubeai_tpu.engine.engine import StepOverlapUnsupported
 
-        if args.step_overlap == "on" or args.pipeline:
+        if args.step_overlap == "on":
             raise StepOverlapUnsupported(
                 "--step-overlap on does not compose with lockstep "
                 "multihost (--num-processes > 1): the overlapped reap "
@@ -2639,13 +2639,29 @@ def main(argv=None) -> int:
             args.process_id, args.num_processes, args.dcn_coordinator,
         )
 
-    from kubeai_tpu.engine.weights import (
-        load_hf_config,
-        load_llama_params,
-        resolve_model_dir,
+    from kubeai_tpu.engine.coldstart import (
+        ColdStartManager,
+        enable_compilation_cache,
     )
+    from kubeai_tpu.engine.weights import load_hf_config, resolve_model_dir
     from kubeai_tpu.models.registry import get_model_family
-    from kubeai_tpu.parallel.mesh import mesh_from_topology, single_device_mesh
+    from kubeai_tpu.parallel.mesh import (
+        mesh_from_topology,
+        require_accelerator,
+        single_device_mesh,
+    )
+
+    # Before anything compiles: name the device (no carrying on after JAX
+    # fell back to the CPU) and place the compilation cache.
+    device = require_accelerator()
+    cache_dir = enable_compilation_cache()
+    import jax
+
+    log.info(
+        "serving on platform=%s device_kind=%s devices=%d; compilation "
+        "cache at %s",
+        device.platform, device.device_kind, len(jax.devices()), cache_dir,
+    )
 
     model_dir = resolve_model_dir(args.model_url, args.model_dir)
     hf_cfg = load_hf_config(model_dir)
@@ -2678,7 +2694,6 @@ def main(argv=None) -> int:
             tserver.stop()
         return 0
 
-    from kubeai_tpu.engine.coldstart import ColdStartManager
     from kubeai_tpu.engine.weights import load_params as _load_params
 
     # The mesh comes first now: its shape is part of the snapshot
@@ -2696,7 +2711,6 @@ def main(argv=None) -> int:
         # weights to every process (engine/multihost.py).
         max_adapters=args.max_adapters,
         decode_chunk=args.decode_chunk,
-        pipeline=args.pipeline,
         step_overlap=args.step_overlap,
         quantization=args.quantization,
         kv_dtype=args.kv_dtype,

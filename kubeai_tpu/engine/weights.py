@@ -48,7 +48,7 @@ def load_hf_config(model_dir: str) -> dict:
 _ST_DTYPES = {
     "F32": np.float32,
     "F16": np.float16,
-    "BF16": None,  # handled specially below
+    "BF16": jnp.bfloat16,  # the ml_dtypes scalar type numpy understands
     "I64": np.int64,
     "I32": np.int32,
     "U8": np.uint8,
@@ -56,10 +56,7 @@ _ST_DTYPES = {
 
 
 def _decode_raw(raw: bytes, dtype_s: str, shape, name: str) -> np.ndarray:
-    if dtype_s == "BF16":
-        u16 = np.frombuffer(raw, np.uint16)
-        u32 = u16.astype(np.uint32) << 16
-        return u32.view(np.float32).reshape(shape)
+    """The tensor in its STORED dtype (BF16 as ml_dtypes bfloat16)."""
     np_dtype = _ST_DTYPES.get(dtype_s)
     if np_dtype is None:
         raise WeightLoadError(f"unsupported dtype {dtype_s} for {name}")
@@ -123,9 +120,11 @@ class LazyTensors:
     def keys(self):
         return (self._eager or self._index).keys()
 
-    def __getitem__(self, name: str) -> np.ndarray:
-        """fp32 view of one tensor, freshly read (caller must not expect
-        the buffer to persist cheaply — copy into the target and drop)."""
+    def read(self, name: str) -> np.ndarray:
+        """One tensor, freshly read, in its stored dtype — a bf16
+        checkpoint loading into a bf16 tree never takes the fp32 detour
+        (caller must not expect the buffer to persist cheaply — copy into
+        the target and drop)."""
         if self._eager is not None:
             return self._eager[name]
         if name not in self._index:
@@ -134,29 +133,37 @@ class LazyTensors:
         with open(fpath, "rb") as f:
             f.seek(offset)
             raw = f.read(nbytes)
-        a = _decode_raw(raw, dtype_s, shape, name)
-        return np.asarray(a, np.float32)
+        return _decode_raw(raw, dtype_s, shape, name)
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        """fp32 view of one tensor (see read)."""
+        return np.asarray(self.read(name), np.float32)
 
 
 def _stream_helpers(model_dir: str, NL: int, dtype):
     """(tensors, get, stack, leaf): the shared streamed-assembly kit.
 
     `stack` fills a preallocated [NL, ...] TARGET-dtype buffer one layer
-    tensor at a time (numpy casts on assignment), so the fp32 view of
-    each tensor lives only for its own copy — peak host memory is the
-    target tree + one tensor, not an fp32 full model."""
+    tensor at a time (numpy casts on assignment), so each tensor lives
+    only for its own copy — peak host memory is the target tree + one
+    tensor, not an fp32 full model.
+
+    Leaves stay HOST numpy arrays: `shard_params` moves each one straight
+    to its shards on the engine's mesh, so a tensor is never whole on one
+    device (a 14.5 GB model would not fit on the first of four 16 GB
+    chips)."""
     t = LazyTensors(model_dir)
     target = np.dtype(dtype)
 
     def get(name: str) -> np.ndarray:
         if name not in t:
             raise WeightLoadError(f"missing tensor {name}")
-        return t[name]
+        return t.read(name)
 
-    def leaf(name: str) -> jnp.ndarray:
-        return jnp.asarray(get(name).astype(target))
+    def leaf(name: str) -> np.ndarray:
+        return get(name).astype(target)
 
-    def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
+    def stack(fmt: str, transpose: bool = True) -> np.ndarray:
         buf = None
         for i in range(NL):
             a = get(fmt.format(i=i))
@@ -164,8 +171,8 @@ def _stream_helpers(model_dir: str, NL: int, dtype):
                 a = a.T
             if buf is None:
                 buf = np.empty((NL, *a.shape), target)
-            buf[i] = a  # casts fp32 -> target in place
-        return jnp.asarray(buf)
+            buf[i] = a  # casts stored dtype -> target in place
+        return buf
 
     return t, get, stack, leaf
 
@@ -333,7 +340,7 @@ def load_mixtral_params(model_dir: str, cfg, dtype=jnp.bfloat16) -> dict:
                 if buf is None:
                     buf = np.empty((NL, X, *a.shape), target)
                 buf[i, e] = a
-        return jnp.asarray(buf)  # [NL, X, in, out]
+        return buf  # [NL, X, in, out]
 
     return {
         "embed": leaf("model.embed_tokens.weight"),

@@ -320,7 +320,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     """Paged decode (block tables). Attention layout per `attn_kernel`
     (None = env default — see llama.decode_step_paged for the layouts:
     "per_layer" scatter-then-attend with pools riding the scan is the
-    hardware-validated path; "fused" keeps pools outside the scan, the
+    default; "fused" keeps pools outside the scan, the
     new token rides as an extra attention column, and all layers' K/V
     write back in one batched scatter). The per-layer sliding window
     rides the scan, so Gemma-2's alternating local/global layers share

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kubeai_tpu.ops import dispatch
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.rope import (
     apply_rope,
@@ -41,13 +42,15 @@ from kubeai_tpu.parallel import sharding as sh
 
 
 def _prefill_attention(q, k, v):
-    """Pick the Pallas flash kernel on TPU for aligned long sequences; the
-    jnp reference path otherwise (CPU tests, short/unaligned shapes)."""
+    """Aligned buckets of 256 tokens and up take the Pallas flash kernel
+    wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
+    interpreter); the short and unaligned buckets keep the jnp path."""
     S = q.shape[1]
-    if jax.default_backend() == "tpu" and S >= 256 and S % 128 == 0:
+    mode = dispatch.kernel_mode()
+    if mode != "reference" and S >= 256 and S % 128 == 0:
         from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
-        return flash_causal_prefill(q, k, v)
+        return flash_causal_prefill(q, k, v, interpret=mode == "interpret")
     return causal_prefill_attention(q, k, v)
 
 
@@ -497,12 +500,11 @@ def decode_step_paged(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Decode step against the PAGED cache. Two attention layouts,
     selected by `attn_kernel` (None = $KUBEAI_TPU_DECODE_KERNEL, default
-    "per_layer"; see ops.paged_attention.default_decode_kernel):
+    "per_layer"; see ops.paged_attention.resolve_decode_kernel):
 
     "per_layer" — scatter-then-attend inside the layer scan: the stacked
     pools ride the scan as xs/ys and each layer runs the per-layer Pallas
-    kernel (paged_decode_attention). Hardware-validated: 1975.5 tok/s/chip
-    at bs=64 on the 1B proxy (round 2).
+    kernel (paged_decode_attention).
 
     "fused" — the stacked [NL, ...] page pools stay OUTSIDE the layer scan
     and are read by the fused Pallas kernel straight from HBM via a
@@ -512,10 +514,9 @@ def decode_step_paged(
     token's K/V is folded in as an extra attention column (it is NOT in
     the pool yet), collected per layer, and written back in ONE batched
     scatter after the scan — per-step cache write traffic is O(NL * B)
-    tokens, and read traffic is only each slot's resident pages.
-    Roofline-better, but not yet validated on real hardware (its first
-    on-chip dispatch hung) — it stays opt-in until a real-TPU A/B clears
-    it.
+    tokens, and read traffic is only each slot's resident pages. Both
+    kernels agree with their references on the chip; their speed has not
+    been compared (ROADMAP C3).
 
     Both layouts share _decode_layer_qkv/_decode_layer_finish, so the
     projection/LoRA/MLP math cannot drift between them."""

@@ -243,7 +243,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                       attn_kernel=None):
     """Paged decode (block tables). Attention layout per `attn_kernel`
     (None = env default — see llama.decode_step_paged: "per_layer"
-    scatter-then-attend with pools riding the scan, hardware-validated;
+    scatter-then-attend with pools riding the scan, the default;
     "fused" pools outside the scan, new token as an extra attention
     column, one batched scatter after), MoE FFN unchanged."""
     from kubeai_tpu.ops.paged_attention import (

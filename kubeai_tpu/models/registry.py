@@ -145,10 +145,7 @@ def _ensure_builtin() -> None:
             feature="SpeechToText",
         )
     )
-    # Further families (gemma, mixtral, …) self-register on import.
-    for mod in ("gemma", "mixtral"):
-        try:
-            __import__(f"kubeai_tpu.models.{mod}")
-        except ImportError:
-            pass
+    # Further families self-register on import.
+    from kubeai_tpu.models import gemma, mixtral  # noqa: F401
+
     _LOADED = True

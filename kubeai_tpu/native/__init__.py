@@ -1,8 +1,10 @@
 """ctypes bindings for the native C++ data-plane library.
 
-Builds on demand (g++ is a one-second compile) and caches the .so next to
-the sources; everything degrades to the pure-Python implementations when
-no compiler is available.
+Built from what git holds: `make` runs on first use and rebuilds the
+git-ignored .so whenever the source is newer (a one-second compile, a no-op
+when up to date), so a stale library left lying in the tree is never
+loaded. Everything degrades to the pure-Python implementations when the
+build cannot run.
 """
 
 from __future__ import annotations
@@ -46,7 +48,7 @@ def load_native():
         if _LIB is not None or _TRIED:
             return _LIB
         _TRIED = True
-        if not os.path.exists(_SO_PATH) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)

@@ -78,13 +78,15 @@ def kv_pool_nbytes(pool) -> int:
     return int(pool.nbytes)
 
 
-def make_quantized_pool(shape, scale_dtype=jnp.float32) -> dict:
+def make_quantized_pool(shape, scale_dtype=jnp.float32, sharding=None) -> dict:
     """Zeroed quantized pool: pages [..., page, KVH, D] int8 + scales
     [..., page, KVH] f32 (zero scale is fine — rows are written before
-    they are ever read, and masked junk dequantizes to 0)."""
+    they are ever read, and masked junk dequantizes to 0). `sharding` is
+    a {"q8", "scale"} dict of Shardings the leaves are created under."""
+    sharding = sharding or {"q8": None, "scale": None}
     return {
-        "q8": jnp.zeros(shape, jnp.int8),
-        "scale": jnp.zeros(shape[:-1], scale_dtype),
+        "q8": jnp.zeros(shape, jnp.int8, device=sharding["q8"]),
+        "scale": jnp.zeros(shape[:-1], scale_dtype, device=sharding["scale"]),
     }
 
 

@@ -5,7 +5,7 @@ regardless of true lengths; paging reads only the pages a sequence
 actually occupies. Two implementations with one contract:
 
   ref_paged_decode_attention — jnp gather-through-block-tables reference
-      (CPU/tests; also the fallback when kernel constraints aren't met).
+      (every backend but a TPU; see ops/dispatch.py for the one rule).
   paged_decode_attention     — Pallas TPU kernel. Grid (slots, max_pages);
       each DMA carries a full page across ALL kv heads (the block's last
       two dims are the full (KVH, D) — a Mosaic tiling requirement) and a
@@ -24,8 +24,9 @@ Int8 KV (ops/kv_quant.py): a pool passed as a {"q8", "scale"} dict is
 a quantized pool. The reference path gathers pages AND scales through
 the block tables and dequantizes in f32 before attention; the write
 helpers quantize each new token's rows on append. The Pallas kernels
-are bf16-only, so quantized pools always dispatch to the reference path
-(int8 KV buys capacity, not kernel speed — see kv_quant module docs).
+are bf16-only, so quantized pools always dispatch to the reference path,
+on a TPU too (int8 KV buys capacity, not kernel speed — see kv_quant
+module docs).
 
 The reference operator has no attention code — it runs vLLM images whose
 PagedAttention this replaces TPU-natively (reference:
@@ -41,24 +42,18 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu imports fine on CPU (needed for interpret-mode tests)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from kubeai_tpu.ops import dispatch
 
 NEG_INF = -1e30
 
 # Which decode-attention layout model families use when the caller doesn't
 # say. "per_layer" = scatter-then-attend inside the layer scan through
-# paged_decode_attention — the hardware-validated path (1975.5 tok/s/chip,
-# bs=64, 1B proxy, measured round 2). "fused" = stacked-pool kernel with a
-# deferred scatter (paged_decode_attention_fused) — roofline-better on
-# paper, but its first on-chip dispatch hung in round 3, so it stays
-# selectable-not-default until a real-TPU A/B validates it.
+# paged_decode_attention. "fused" = stacked-pool kernel with a deferred
+# scatter (paged_decode_attention_fused). Both compile and agree with their
+# references on a TPU v5 lite (PR 21); which is faster has not been
+# measured — that A/B, and the loser's removal, is ROADMAP C3.
 DECODE_KERNEL_ENV = "KUBEAI_TPU_DECODE_KERNEL"
 _DECODE_KERNELS = ("per_layer", "fused")
 
@@ -306,23 +301,28 @@ def _paged_pallas(
             pltpu.VMEM((kvh, g, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
     )(block_tables, lengths, window, q, k_pages, v_pages)
-    return out.reshape(b, kvh * g, d)
 
 
-def paged_supported(head_dim: int, page_size: int) -> bool:
-    """Kernel constraints. The k/v block is (1, page, KVH, D) — its last
-    two dims are the FULL array dims, so the BLOCK shape itself imposes
-    no divisibility rule; but in-kernel values still use `page` as a
-    sublane/lane dim ([page, D] loads, [rows, page] logits), so keep the
-    f32 sublane tile divisibility until odd sizes are validated on real
-    hardware (non-conforming pools use the jnp reference path)."""
-    return page_size % 8 == 0
+def _check_page_size(page_size: int) -> None:
+    """The k/v block is (1, page, KVH, D) — its last two dims are the FULL
+    array dims, so the block shape imposes no divisibility rule; but
+    in-kernel values use `page` as a sublane/lane dim ([page, D] loads,
+    [rows, page] logits), which wants the f32 sublane tile."""
+    if page_size % 8:
+        raise ValueError(
+            f"paged attention kernels need page_size % 8 == 0, got "
+            f"{page_size}"
+        )
+
+
+def _window_arg(window) -> jnp.ndarray:
+    return jnp.asarray([0 if window is None else window], jnp.int32).reshape(1)
 
 
 def paged_decode_attention(
@@ -335,42 +335,32 @@ def paged_decode_attention(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    use_pallas: bool | None = None,  # None = auto (TPU backend only)
-    interpret: bool = False,
+    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
-    """Paged decode attention with automatic kernel/reference dispatch.
-    Quantized {"q8", "scale"} pools always take the reference path (the
-    Pallas kernel is bf16-only)."""
+    """Paged decode attention: the Pallas kernel on a TPU, the reference
+    elsewhere, an int8 pool always on the reference (ops/dispatch.py)."""
     from kubeai_tpu.ops.kv_quant import is_quantized_kv
 
     b, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
-    if is_quantized_kv(k_pages):
+    mode = mode or dispatch.kernel_mode()
+    if mode == "reference" or is_quantized_kv(k_pages):
         return ref_paged_decode_attention(
             q, k_pages, v_pages, block_tables, lengths,
             scale=scale, logit_softcap=logit_softcap, window=window,
         )
+    _check_page_size(k_pages.shape[1])
     kvh = k_pages.shape[2]
-    if use_pallas is None:
-        use_pallas = (
-            _HAS_PLTPU
-            and not interpret
-            and jax.default_backend() not in ("cpu",)
-            and paged_supported(d, k_pages.shape[1])
-        )
-    if not use_pallas and not interpret:
-        return ref_paged_decode_attention(
-            q, k_pages, v_pages, block_tables, lengths,
-            scale=scale, logit_softcap=logit_softcap, window=window,
-        )
-    win_arr = jnp.asarray(
-        [0 if window is None else window], jnp.int32
-    ).reshape(1)
-    qg = q.reshape(b, kvh, h // kvh, d)
-    out = _paged_pallas(
-        qg, k_pages, v_pages, block_tables, lengths, win_arr,
-        scale=scale, logit_softcap=logit_softcap,
-        interpret=interpret,
+    call = dispatch.over_kv_heads(
+        functools.partial(
+            _paged_pallas, scale=scale, logit_softcap=logit_softcap,
+            interpret=mode == "interpret",
+        ),
+        kvh, (1, 2, 2, None, None, None),
+    )
+    out = call(
+        q.reshape(b, kvh, h // kvh, d), k_pages, v_pages, block_tables,
+        lengths, _window_arg(window),
     )
     return out.reshape(b, h, d)
 
@@ -516,9 +506,9 @@ def _paged_verify_pallas(
     block_tables,
     positions,  # [B]
     window,  # [1] int32
+    *,
     spec_k: int,
     group: int,
-    *,
     scale: float,
     logit_softcap: float | None,
     interpret: bool,
@@ -584,40 +574,36 @@ def paged_verify_attention(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
+    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
-    """Multi-query paged verify attention with kernel/reference dispatch
-    (speculative decoding's verify pass; see ref_paged_verify_attention
-    for semantics)."""
+    """Multi-query paged verify attention (speculative decoding's verify
+    pass; see ref_paged_verify_attention for semantics), dispatched like
+    paged_decode_attention."""
     b, spec_k, h, d = q.shape
     kvh = k_pages.shape[2]
     group = h // kvh
     scale = scale if scale is not None else d ** -0.5
-    if use_pallas is None:
-        use_pallas = (
-            _HAS_PLTPU
-            and not interpret
-            and jax.default_backend() not in ("cpu",)
-            and paged_supported(d, k_pages.shape[1])
-        )
-    if not use_pallas and not interpret:
+    mode = mode or dispatch.kernel_mode()
+    if mode == "reference":
         return ref_paged_verify_attention(
             q, k_pages, v_pages, block_tables, positions,
             scale=scale, logit_softcap=logit_softcap, window=window,
         )
-    win_arr = jnp.asarray(
-        [0 if window is None else window], jnp.int32
-    ).reshape(1)
+    _check_page_size(k_pages.shape[1])
     # [B, K, H, D] -> [B, KVH, K*G, D]: row r = query r//G, q-head-in-group
     # r%G, so the kernel's row//group recovers the query index.
     qk = jnp.moveaxis(
         q.reshape(b, spec_k, kvh, group, d), 1, 2
     ).reshape(b, kvh, spec_k * group, d)
-    out = _paged_verify_pallas(
-        qk, k_pages, v_pages, block_tables, positions, win_arr,
-        spec_k, group,
-        scale=scale, logit_softcap=logit_softcap, interpret=interpret,
+    call = dispatch.over_kv_heads(
+        functools.partial(
+            _paged_verify_pallas, spec_k=spec_k, group=group, scale=scale,
+            logit_softcap=logit_softcap, interpret=mode == "interpret",
+        ),
+        kvh, (1, 2, 2, None, None, None),
+    )
+    out = call(
+        qk, k_pages, v_pages, block_tables, positions, _window_arg(window)
     )
     out = jnp.moveaxis(
         out.reshape(b, kvh, spec_k, group, d), 2, 1
@@ -627,22 +613,21 @@ def paged_verify_attention(
 
 # ---- fused decode kernel (stacked pools, deferred scatter) -------------------
 #
-# The decode-step redesign that closes the roofline gap (ROADMAP round-3
-# item 1). Three wastes in the original scatter-then-attend layer loop:
-#   1. lax.scan sliced each layer's [P, page, KVH, D] pool out of the
-#      stacked array and re-stacked the updated slice — a full KV-pool
-#      round-trip through HBM every decode step (~2 GB at bs=64/1B) even
-#      though only B tokens/layer actually change.
+# A second decode-step layout. Three costs in the scatter-then-attend layer
+# loop, counted from shapes (none timed on the chip yet):
+#   1. lax.scan slices each layer's [P, page, KVH, D] pool out of the
+#      stacked array and re-stacks the updated slice — a full KV-pool
+#      round-trip through HBM every decode step even though only B
+#      tokens/layer change.
 #   2. pallas_call is opaque to XLA, so the sliced operand MATERIALIZES
 #      (no fusion into the kernel).
-#   3. Grid (slots, pages) ran one small page DMA per step — latency-
-#      bound, not bandwidth-bound.
-# The fused kernel fixes all three: it takes the FULL [NL, ...] pool plus
-# a scalar-prefetched layer index (the index map adds the layer offset —
-# no slicing, no materialization), attends the NEW token as an explicit
-# extra column merged at finalize (so the pool stays read-only and the
-# scatter defers to ONE batched write after the layer scan), and DMAs a
-# STRIP of pages per grid step with the slot dimension megacore-parallel.
+#   3. Grid (slots, pages) runs one small page DMA per step.
+# The fused kernel takes the FULL [NL, ...] pool plus a scalar-prefetched
+# layer index (the index map adds the layer offset — no slicing, no
+# materialization), attends the NEW token as an explicit extra column
+# merged at finalize (so the pool stays read-only and the scatter defers
+# to ONE batched write after the layer scan), and DMAs a STRIP of pages
+# per grid step with the slot dimension megacore-parallel.
 
 
 def _fused_attend_page(
@@ -922,39 +907,35 @@ def paged_decode_attention_fused(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    use_pallas: bool | None = None,
-    interpret: bool = False,
+    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
     """Fused paged decode attention: reads the layer's resident pages
     straight out of the STACKED pool (no per-layer slice materialization)
     and folds the not-yet-scattered new token in as an extra column, so
     the caller can defer all KV-cache writes to one batched scatter after
-    the layer scan. See module docstring for why this is the fast path."""
+    the layer scan. Dispatched like paged_decode_attention."""
     b, h, d = q.shape
     kvh = k_pages.shape[3]
     scale = scale if scale is not None else d ** -0.5
     layer_arr = jnp.asarray(layer, jnp.int32)
-    if use_pallas is None:
-        use_pallas = (
-            _HAS_PLTPU
-            and not interpret
-            and jax.default_backend() not in ("cpu",)
-            and paged_supported(d, k_pages.shape[2])
-        )
-    if not use_pallas and not interpret:
+    mode = mode or dispatch.kernel_mode()
+    if mode == "reference":
         return ref_paged_decode_attention_fused(
             q, k_pages, v_pages, k_new, v_new, block_tables, positions,
             layer_arr, scale=scale, logit_softcap=logit_softcap,
             window=window,
         )
-    win_arr = jnp.asarray(
-        [0 if window is None else window], jnp.int32
-    ).reshape(1)
-    qg = q.reshape(b, kvh, h // kvh, d)
-    out = _paged_fused_pallas(
-        qg, k_pages, v_pages, k_new, v_new, block_tables, positions,
-        win_arr, layer_arr.reshape(1),
-        scale=scale, logit_softcap=logit_softcap, interpret=interpret,
+    _check_page_size(k_pages.shape[2])
+    call = dispatch.over_kv_heads(
+        functools.partial(
+            _paged_fused_pallas, scale=scale, logit_softcap=logit_softcap,
+            interpret=mode == "interpret",
+        ),
+        kvh, (1, 3, 3, 1, 1, None, None, None, None),
+    )
+    out = call(
+        q.reshape(b, kvh, h // kvh, d), k_pages, v_pages, k_new, v_new,
+        block_tables, positions, _window_arg(window), layer_arr.reshape(1),
     )
     return out.reshape(b, h, d)
 

@@ -7,12 +7,14 @@ causal frontier (skipping fully-masked blocks entirely).
 
 GQA: the q-head grid axis maps each q head onto its kv head (h // group).
 
-Numerics: fp32 accumulation in VMEM scratch; bf16 in/out. Falls back to
-kubeai_tpu.ops.attention.causal_prefill_attention when shapes don't meet
-TPU tiling constraints (head_dim padded to 128 lanes; q/k blocks of 128).
+Numerics: fp32 accumulation in VMEM scratch; bf16 in/out. head_dim is
+padded to 128 lanes; q/k blocks are 128 rows, so the sequence must be a
+multiple of 128 (anything else raises — models.llama._prefill_attention
+routes the short and unaligned buckets to the jnp reference first).
 
 Usage: flash_causal_prefill(q, k, v) — same contract as the jnp reference;
-`interpret=True` runs on CPU for tests.
+`interpret=True` runs on CPU for tests. Under a mesh the pallas_call runs
+inside shard_map, KV heads split over tp (ops/dispatch.py).
 """
 
 from __future__ import annotations
@@ -23,15 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU for interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-from kubeai_tpu.ops.attention import causal_prefill_attention
+from kubeai_tpu.ops import dispatch
 
 NEG_INF = -1e30
 
@@ -123,10 +117,6 @@ def _flash_bhsd(
     )(q, k, v)
 
 
-def flash_supported(seq_len: int, head_dim: int, block: int = 128) -> bool:
-    return seq_len % block == 0 and seq_len >= block
-
-
 def flash_causal_prefill(
     q: jnp.ndarray,  # [B, S, H, D]
     k: jnp.ndarray,  # [B, S, KVH, D]
@@ -134,13 +124,15 @@ def flash_causal_prefill(
     *,
     block: int = 128,
     interpret: bool = False,
-    force: bool = False,
 ) -> jnp.ndarray:
     """Flash attention with the causal_prefill_attention contract."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
-    if not force and not flash_supported(S, D, block):
-        return causal_prefill_attention(q, k, v)
+    if S < block or S % block:
+        raise ValueError(
+            f"flash prefill needs a sequence that is a multiple of {block}, "
+            f"got {S}"
+        )
 
     group = H // KVH
     # [B, S, H, D] -> [B, H, S, D]. K/V keep their KVH heads — the kernel's
@@ -156,10 +148,13 @@ def flash_causal_prefill(
         pad = [(0, 0), (0, 0), (0, 0), (0, Dp - D)]
         qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
 
-    out = _flash_bhsd(
-        qt, kt, vt, block_q=block, block_k=block, interpret=interpret,
-        scale=D ** -0.5, group=group,
-    )
+    out = dispatch.over_kv_heads(
+        functools.partial(
+            _flash_bhsd, block_q=block, block_k=block, interpret=interpret,
+            scale=D ** -0.5, group=group,
+        ),
+        KVH, (1, 1, 1),
+    )(qt, kt, vt)
     if Dp != D:
         out = out[..., :D]
     return jnp.moveaxis(out, 1, 2)  # [B, S, H, D]

@@ -109,6 +109,28 @@ def build_mesh(
     return Mesh(arr, MESH_AXES)
 
 
+class NoAccelerator(RuntimeError):
+    pass
+
+
+def require_accelerator() -> jax.Device:
+    """The default backend's first device — unless JAX got there by
+    falling back. With no accelerator in reach JAX warns and carries on
+    on the CPU; a server or a benchmark that carried on with it would
+    answer from the wrong machine. The CPU is accepted only where it was
+    asked for by name (JAX_PLATFORMS / jax_platforms = cpu, as the tests
+    do)."""
+    device = jax.devices()[0]
+    if device.platform == "cpu" and "cpu" not in (
+        jax.config.jax_platforms or ""
+    ):
+        raise NoAccelerator(
+            "JAX found no accelerator and fell back to the CPU; set "
+            "JAX_PLATFORMS=cpu to run on the CPU on purpose"
+        )
+    return device
+
+
 def single_device_mesh(device: jax.Device | None = None) -> Mesh:
     if device is None:
         device = jax.devices()[0]

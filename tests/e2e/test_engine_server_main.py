@@ -37,13 +37,11 @@ def checkpoint(tmp_path_factory):
 def test_server_main_subprocess(checkpoint):
     port = 18477
     env = dict(os.environ)
-    # Engine pods on CPU nodes run the same entrypoint; force CPU so the
-    # subprocess doesn't contend for the (single) local chip.
-    env["KUBEAI_FORCE_CPU"] = "1"
+    # The same entrypoint, held to the CPU by name.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [
             sys.executable, "-c",
-            "import jax; jax.config.update('jax_platforms','cpu'); "
             "from kubeai_tpu.engine.server import main; import sys; "
             f"sys.exit(main(['--model-url', {checkpoint!r}, "
             f"'--served-model-name', 'tiny', '--port', '{port}', "
@@ -103,11 +101,10 @@ def test_server_main_draft_speculation(checkpoint):
     exactness is covered by the unit tier (test_draft_spec)."""
     port = 18478
     env = dict(os.environ)
-    env["KUBEAI_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [
             sys.executable, "-c",
-            "import jax; jax.config.update('jax_platforms','cpu'); "
             "from kubeai_tpu.engine.server import main; import sys; "
             f"sys.exit(main(['--model-url', {checkpoint!r}, "
             f"'--served-model-name', 'tiny', '--port', '{port}', "
@@ -155,11 +152,10 @@ def test_server_main_prefix_cache(checkpoint):
     (test_prefix_cache)."""
     port = 18479
     env = dict(os.environ)
-    env["KUBEAI_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [
             sys.executable, "-c",
-            "import jax; jax.config.update('jax_platforms','cpu'); "
             "from kubeai_tpu.engine.server import main; import sys; "
             f"sys.exit(main(['--model-url', {checkpoint!r}, "
             f"'--served-model-name', 'tiny', '--port', '{port}', "

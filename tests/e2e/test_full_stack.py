@@ -359,12 +359,11 @@ def test_draft_model_served_through_operator(tmp_path):
             "--model-dir", str(ckpt), "--draft-dir", str(ckpt),
         ]
         env = dict(os.environ)
-        env["KUBEAI_FORCE_CPU"] = "1"
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             [
                 sys.executable, "-c",
-                "import jax; jax.config.update('jax_platforms','cpu'); "
-                "from kubeai_tpu.engine.server import main; import sys; "
+                    "from kubeai_tpu.engine.server import main; import sys; "
                 f"sys.exit(main({boot!r}))",
             ],
             cwd=os.path.dirname(os.path.dirname(

@@ -154,6 +154,39 @@ def test_state_and_metrics_expose_boot_path(servers):
         assert "kubeai_coldstart_phase_seconds" in text
 
 
+def test_snapshot_carries_the_cache_dir_in_effect(tmp_path):
+    """The manager owns no cache directory: publish bundles whatever
+    directory JAX's persistent cache is set to, and restore copies the
+    bundle's entries into the directory in effect on THAT boot — so a
+    cache placed from outside (JAX_COMPILATION_CACHE_DIR) is the one a
+    snapshot fills."""
+    url = "file://" + str(tmp_path / "snaps")
+    mesh = single_device_mesh()
+    cache_a, cache_b = tmp_path / "cache_a", tmp_path / "cache_b"
+    cache_a.mkdir()
+    cache_b.mkdir()
+    (cache_a / "jit_step-abc-cache").write_bytes(b"compiled")
+    tree = {"w": np.arange(6, dtype=np.float32).reshape(2, 3)}
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(cache_a))
+        mgr1 = ColdStartManager(
+            url, "m", ECFG, mesh, work_dir=str(tmp_path / "boot1")
+        )
+        assert not hasattr(mgr1, "cache_dir")
+        assert mgr1.maybe_publish(mgr1.acquire_params(lambda: tree)) is True
+
+        jax.config.update("jax_compilation_cache_dir", str(cache_b))
+        mgr2 = ColdStartManager(
+            url, "m", ECFG, mesh, work_dir=str(tmp_path / "boot2")
+        )
+        restored = mgr2.acquire_params(lambda: 1 / 0)
+        assert mgr2.tracker.restored is True
+        np.testing.assert_array_equal(np.asarray(restored["w"]), tree["w"])
+        assert (cache_b / "jit_step-abc-cache").read_bytes() == b"compiled"
+    finally:
+        _reset_compilation_cache()
+
+
 # ---- orbax round-trip satellites ---------------------------------------------
 
 

@@ -131,18 +131,19 @@ def test_sharded_engine_tp_matches_single(devices8):
 
 @pytest.mark.slow
 def test_pipelined_stepping_equivalent():
-    """pipeline=True must emit the identical token stream, one chunk late."""
+    """step_overlap="on" must emit the identical token stream, one chunk
+    late."""
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     base = Engine(
         "llama", cfg, params,
         cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4,
-                         pipeline=False),
+                         step_overlap="off"),
     )
     piped = Engine(
         "llama", cfg, params,
         cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4,
-                         pipeline=True),
+                         step_overlap="on"),
     )
     prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [2, 2]]  # > slots: queueing
     want = base.generate(prompts, GREEDY)

@@ -58,7 +58,7 @@ def test_slot_paged_equivalence_seeded_sampling():
 def test_decode_kernel_selection_and_equivalence():
     """Both paged attention layouts are selectable (EngineConfig and env
     var) and emit identical greedy streams — the per-layer layout is the
-    hardware-validated default; the fused layout must match it exactly."""
+    default; the fused layout must match it exactly."""
     prompts = _prompts(5)
     sp = SamplingParams(temperature=0.0, max_tokens=12)
     eng_pl = _make("paged", decode_kernel="per_layer")

@@ -65,7 +65,7 @@ def test_reference_matches_dense_oracle():
 def test_kernel_matches_reference():
     q, _, _, kp, vp, bt, lengths = _setup([5, 17, 32])
     got = paged_decode_attention(
-        q, kp, vp, bt, lengths, use_pallas=True, interpret=True
+        q, kp, vp, bt, lengths, mode="interpret"
     )
     want = ref_paged_decode_attention(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(
@@ -78,7 +78,7 @@ def test_kernel_softcap_and_window():
     for cap, win in ((30.0, None), (None, 12), (50.0, 7)):
         got = paged_decode_attention(
             q, kp, vp, bt, lengths,
-            logit_softcap=cap, window=win, use_pallas=True, interpret=True,
+            logit_softcap=cap, window=win, mode="interpret",
         )
         want = ref_paged_decode_attention(
             q, kp, vp, bt, lengths, logit_softcap=cap, window=win
@@ -139,7 +139,7 @@ def test_fused_kernel_matches_reference():
     for layer in (0, 2):
         got = paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, layer,
-            use_pallas=True, interpret=True,
+            mode="interpret",
         )
         want = ref_paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, jnp.int32(layer)
@@ -155,7 +155,7 @@ def test_fused_kernel_softcap_and_window():
     for cap, win in ((30.0, None), (None, 12), (50.0, 7)):
         got = paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, 1,
-            logit_softcap=cap, window=win, use_pallas=True, interpret=True,
+            logit_softcap=cap, window=win, mode="interpret",
         )
         want = ref_paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, jnp.int32(1),
@@ -188,7 +188,7 @@ def test_fused_empty_slot_returns_value_of_new_token():
         np.asarray(out[0]), np.asarray(want0), atol=1e-5
     )
     got = paged_decode_attention_fused(
-        q, kp, vp, kn, vn, bt, pos, 0, use_pallas=True, interpret=True
+        q, kp, vp, kn, vn, bt, pos, 0, mode="interpret"
     )
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(out), atol=1e-4, rtol=1e-4
@@ -299,7 +299,7 @@ def test_verify_kernel_matches_reference():
         got = paged_verify_attention(
             q, kp, vp, bt, positions,
             logit_softcap=cap, window=win,
-            use_pallas=True, interpret=True,
+            mode="interpret",
         )
         want = ref_paged_verify_attention(
             q, kp, vp, bt, positions, logit_softcap=cap, window=win,

@@ -26,7 +26,7 @@ def _mk(B=1, S=256, H=4, KVH=2, D=64, seed=0):
 def test_flash_matches_reference_interpret():
     q, k, v = _mk()
     want = causal_prefill_attention(q, k, v)
-    got = flash_causal_prefill(q, k, v, interpret=True, force=True)
+    got = flash_causal_prefill(q, k, v, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
     )
@@ -36,16 +36,22 @@ def test_flash_gqa_and_padded_head_dim():
     # D=64 exercises the pad-to-128 path; KVH=1 the max-group GQA path.
     q, k, v = _mk(B=2, S=128, H=4, KVH=1, D=64, seed=1)
     want = causal_prefill_attention(q, k, v)
-    got = flash_causal_prefill(q, k, v, interpret=True, force=True)
+    got = flash_causal_prefill(q, k, v, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
     )
 
 
-def test_flash_fallback_on_unaligned_seq():
-    q, k, v = _mk(S=100)  # 100 % 128 != 0 -> jnp fallback
+def test_flash_refuses_unaligned_seq_and_dispatch_routes_it():
+    """The kernel raises on a sequence it cannot tile; the model-side
+    dispatch sends the short and unaligned buckets to the jnp path."""
+    from kubeai_tpu.models.llama import _prefill_attention
+
+    q, k, v = _mk(S=100)  # 100 % 128 != 0
+    with pytest.raises(ValueError, match="multiple of 128"):
+        flash_causal_prefill(q, k, v, interpret=True)
     want = causal_prefill_attention(q, k, v)
-    got = flash_causal_prefill(q, k, v)
+    got = _prefill_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
 
 

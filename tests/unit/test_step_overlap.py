@@ -342,8 +342,6 @@ def test_step_overlap_knob_parsing(tiny):
     assert _engine(tiny, "auto")._overlap is True  # default-on
     assert _engine(tiny, True)._overlap is True    # bool accepted
     assert _engine(tiny, False)._overlap is False
-    # Legacy pipeline bool is an alias for "on".
-    assert _engine(tiny, "auto", pipeline=True)._overlap is True
 
 
 # ---- over real HTTP ----------------------------------------------------------
