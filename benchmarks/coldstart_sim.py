@@ -433,15 +433,6 @@ def run_mismatch_scenario() -> dict:
         }
     finally:
         shutil.rmtree(root, ignore_errors=True)
-        # acquire_params pointed JAX's persistent compilation cache at
-        # the (now deleted) work dir; detach it so nothing later in the
-        # process tries to write there.
-        import contextlib
-
-        with contextlib.suppress(Exception):
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", None)
 
 
 # ---- governor stale-telemetry denial -----------------------------------------

@@ -116,8 +116,10 @@ def write_checkpoint(
     directory (one file per layer, one for the embeddings). Norm weights
     are ones. `lm_head` rows past the first 256 are zero: with no
     tokenizer files the server falls back to the ByteTokenizer, and this
-    keeps greedy decoding inside its alphabet, so the text the smoke
-    compares carries every generated token. Returns the bytes written."""
+    keeps greedy decoding inside its alphabet (and off its EOS, id 256).
+    The text is still mostly U+FFFD, because bytes >= 0x80 rarely form
+    valid UTF-8, so the smoke compares token ids where it compares greedy
+    output. Returns the bytes written."""
     os.makedirs(out_dir, exist_ok=True)
     for name in os.listdir(out_dir):
         if name.endswith(".safetensors"):
@@ -238,13 +240,19 @@ def leg_kernels(max_seq_len: int) -> int:
     import jax.numpy as jnp
 
     from kubeai_tpu.engine.coldstart import enable_compilation_cache
+    from kubeai_tpu.ops import dispatch
     from kubeai_tpu.ops import paged_attention as pa
     from kubeai_tpu.ops.attention import causal_prefill_attention
     from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
     enable_compilation_cache()
-    if jax.default_backend() != "tpu":
-        log(f"kernels leg needs a TPU, found {jax.default_backend()}")
+    # "compiled" is what the dispatches answer on a TPU and nowhere else:
+    # the Pallas kernel through Mosaic, never the interpreter or a reference.
+    if dispatch.kernel_mode() != "compiled":
+        log(
+            f"kernels leg needs a TPU, found {jax.default_backend()} "
+            f"(kernel mode {dispatch.kernel_mode()!r})"
+        )
         return 1
     rng = np.random.default_rng(SEED)
     D = MISTRAL_7B["head_dim"]
@@ -300,17 +308,13 @@ def leg_kernels(max_seq_len: int) -> int:
 
         def decode():
             q = bf16((B, H, D))
-            got = jax.jit(
-                lambda *a: pa.paged_decode_attention(*a, mode="compiled")
-            )(q, kp, vp, bt, lens)
+            got = jax.jit(pa.paged_decode_attention)(q, kp, vp, bt, lens)
             want = highest(pa.ref_paged_decode_attention, q, kp, vp, bt, lens)
             check(f"paged decode {tag}", got, want)
 
         def verify():
             q = bf16((B, spec_k, H, D))
-            got = jax.jit(
-                lambda *a: pa.paged_verify_attention(*a, mode="compiled")
-            )(q, kp, vp, bt, positions)
+            got = jax.jit(pa.paged_verify_attention)(q, kp, vp, bt, positions)
             want = highest(
                 pa.ref_paged_verify_attention, q, kp, vp, bt, positions
             )
@@ -431,18 +435,33 @@ def drive_server(base: str, device: dict, sizes: dict, proc) -> dict:
     first_text = r["choices"][0]["message"]["content"]
     log(f"short chat ok: {first_text!r}")
 
-    # 2. streamed.
-    raw = _chat(base, "Stream a few tokens.", 24, stream=True).decode()
-    events = [l[6:] for l in raw.splitlines() if l.startswith("data: ")]
-    need(events and events[-1] == "[DONE]", "SSE stream did not end in [DONE]")
-    chunks = [json.loads(e) for e in events[:-1]]
-    n_ids = sum(len(c.get("token_ids", ())) for c in chunks)
-    need(n_ids == 24, f"streamed chunks carried {n_ids} token ids, asked 24")
+    # 2. streamed. Every content chunk carries the ids of its tokens.
+    def streamed(text: str, max_tokens: int) -> list[int]:
+        raw = _chat(base, text, max_tokens, stream=True).decode()
+        events = [l[6:] for l in raw.splitlines() if l.startswith("data: ")]
+        need(
+            events and events[-1] == "[DONE]",
+            "SSE stream did not end in [DONE]",
+        )
+        chunks = [json.loads(e) for e in events[:-1]]
+        ids = [int(t) for c in chunks for t in c.get("token_ids", ())]
+        need(
+            len(ids) == max_tokens,
+            f"streamed chunks carried {len(ids)} token ids, asked {max_tokens}",
+        )
+        need(
+            chunks[-1]["choices"][0]["finish_reason"] == "length",
+            f"stream finish_reason: {chunks[-1]['choices'][0]}",
+        )
+        return ids
+
+    first_ids = streamed(first_prompt, 16)
     need(
-        chunks[-1]["choices"][0]["finish_reason"] == "length",
-        f"stream finish_reason: {chunks[-1]['choices'][0]}",
+        bytes(first_ids).decode("utf-8", errors="replace") == first_text,
+        f"streamed token ids {first_ids} do not decode to the text the same "
+        f"greedy request returned unstreamed, {first_text!r}",
     )
-    log(f"streamed chat ok: {len(chunks)} chunks, [DONE] seen")
+    log(f"streamed chat ok: [DONE] seen, token ids {first_ids}")
 
     # 3. concurrent: prompts of 300-1500 bytes land in the 512, 1024 and
     # 2048 buckets (flash prefill, batched admission); 96 new tokens cross
@@ -478,21 +497,35 @@ def drive_server(base: str, device: dict, sizes: dict, proc) -> dict:
 
     # 4. a bucket nothing has compiled yet (256): the compile happens inside
     # step() with work active, and must not trip the step watchdog.
+    t_cold = time.monotonic()
     r = json.loads(_chat(base, _prompt(200, 11), 8))
+    seen["cold_bucket_request_s"] = round(time.monotonic() - t_cold, 1)
     need(r["usage"]["completion_tokens"] == 8, f"cold bucket usage: {r['usage']}")
     need(
         _http("GET", base + "/health")[0] == 200,
         "server unhealthy after a compile inside step()",
     )
-    log("cold-bucket request ok, server still healthy")
+    log(
+        f"cold-bucket request ok in {seen['cold_bucket_request_s']}s, compile "
+        "included (smoke observation); server still healthy"
+    )
 
-    # 5. the first request again: greedy text must repeat.
+    # 5. the first request again, after the batch and the cold bucket have
+    # been through the pool: greedy output must repeat. Token ids decide
+    # it; the decoded text is mostly U+FFFD and different streams could
+    # compare equal.
+    again_ids = streamed(first_prompt, 16)
+    need(
+        again_ids == first_ids,
+        f"greedy token ids changed: {first_ids} -> {again_ids}",
+    )
     r = json.loads(_chat(base, first_prompt, 16))
     again = r["choices"][0]["message"]["content"]
     need(again == first_text, f"greedy text changed: {first_text!r} -> {again!r}")
+    log("greedy repeat ok: same 16 token ids, same text")
 
     metrics1 = _http("GET", base + "/metrics")[1].decode()
-    asked = 16 + 24 + 96 * len(plens) + 8 + 16
+    asked = 16 + 16 + 96 * len(plens) + 8 + 16 + 16
     gen = _metric(metrics1, "kubeai_engine_generated_tokens_total") - _metric(
         metrics0, "kubeai_engine_generated_tokens_total"
     )
@@ -552,13 +585,25 @@ def leg_server(device: dict, sizes: dict, deadline: float) -> dict:
                     pass
                 time.sleep(0.5)
             boot_s = time.monotonic() - t_boot
-            log(f"server Ready after {boot_s:.1f}s (smoke observation)")
+            # What the kernels leg and the boot left in the cache. Their
+            # graphs are the same every run; after Ready, how concurrent
+            # arrivals group into admission batches is a matter of timing,
+            # and a run may meet a batch shape the last one did not.
+            at_ready = cache_entries(device["cache_dir"])
+            log(
+                f"server Ready after {boot_s:.1f}s (smoke observation), "
+                f"{at_ready} compile-cache entries"
+            )
             seen = drive_server(base, device, sizes, proc)
             proc.send_signal(signal.SIGTERM)
             rc = proc.wait(timeout=max(5.0, deadline - time.monotonic()))
             need(rc == 0, f"server exited with {rc} after SIGTERM")
             log("SIGTERM: server drained and exited 0")
-            return {"boot_to_ready_s": round(boot_s, 1), **seen}
+            return {
+                "boot_to_ready_s": round(boot_s, 1),
+                "cache_entries_at_ready": at_ready,
+                **seen,
+            }
         except BaseException:
             logf.flush()
             with open(log_path, "rb") as lf:

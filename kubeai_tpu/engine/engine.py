@@ -478,15 +478,9 @@ class Engine:
         # GQA: when tp exceeds the KV-head count the cache can't shard on
         # heads — replicate it across tp (each shard attends with its local
         # q heads against the full KV; standard GQA-on-TPU fallback).
-        cache_rules = rules
-        tp_size = self.mesh.shape.get("tp", 1)
-        if model_cfg.num_kv_heads % max(tp_size, 1) != 0:
-            cache_rules = psh.ShardingRules(
-                rules=tuple(
-                    (name, None if name == psh.KV_HEADS else phys)
-                    for name, phys in rules.rules
-                )
-            )
+        cache_rules = psh.kv_cache_rules(
+            self.mesh, model_cfg.num_kv_heads, rules
+        )
 
         self.prefix_stats = {"lookups": 0, "hit_tokens": 0, "prompt_tokens": 0}
         # Disaggregation accounting (cumulative; the server converts
@@ -710,16 +704,9 @@ class Engine:
                     # back to replicated KV heads for BOTH the draft's
                     # params and its cache (the same GQA-on-TPU fallback
                     # the main cache uses).
-                    dc_rules = rules
-                    if dcfg.num_kv_heads % max(
-                        self.mesh.shape.get("tp", 1), 1
-                    ):
-                        dc_rules = psh.ShardingRules(
-                            rules=tuple(
-                                (n, None if n == psh.KV_HEADS else p)
-                                for n, p in rules.rules
-                            )
-                        )
+                    dc_rules = psh.kv_cache_rules(
+                        self.mesh, dcfg.num_kv_heads, rules
+                    )
                     self._draft_params = psh.shard_params(
                         dparams, self.family.param_specs(dcfg), self.mesh,
                         dc_rules,

@@ -46,11 +46,10 @@ def _prefill_attention(q, k, v):
     wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
     interpreter); the short and unaligned buckets keep the jnp path."""
     S = q.shape[1]
-    mode = dispatch.kernel_mode()
-    if mode != "reference" and S >= 256 and S % 128 == 0:
+    if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
         from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
-        return flash_causal_prefill(q, k, v, interpret=mode == "interpret")
+        return flash_causal_prefill(q, k, v)
     return causal_prefill_attention(q, k, v)
 
 

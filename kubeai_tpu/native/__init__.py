@@ -3,16 +3,21 @@
 Built from what git holds: `make` runs on first use and rebuilds the
 git-ignored .so whenever the source is newer (a one-second compile, a no-op
 when up to date), so a stale library left lying in the tree is never
-loaded. Everything degrades to the pure-Python implementations when the
-build cannot run.
+loaded. Where `make` cannot run (an image that ships the library without a
+toolchain), a library that is not older than its source is loaded as it
+is. Otherwise everything degrades to the pure-Python implementations, and
+says so once in the log.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
+
+log = logging.getLogger(__name__)
 
 _LIB = None
 _LOCK = threading.Lock()
@@ -26,9 +31,8 @@ _SO_PATH = os.path.join(_NATIVE_DIR, "libkubeai_native.so")
 
 
 def _build() -> bool:
+    """True when an up-to-date library is in place."""
     src = os.path.join(_NATIVE_DIR, "kubeai_native.cpp")
-    if not os.path.exists(src):
-        return False
     try:
         subprocess.run(
             ["make", "-C", _NATIVE_DIR],
@@ -37,8 +41,19 @@ def _build() -> bool:
             timeout=120,
         )
         return os.path.exists(_SO_PATH)
-    except (subprocess.SubprocessError, OSError):
-        return False
+    except (subprocess.SubprocessError, OSError) as e:
+        # No toolchain here. A library shipped prebuilt is still good
+        # unless the source has moved on since it was built.
+        current = os.path.exists(_SO_PATH) and (
+            not os.path.exists(src)
+            or os.path.getmtime(_SO_PATH) >= os.path.getmtime(src)
+        )
+        if not current:
+            log.warning(
+                "native library not built (%s) and no current %s; using the "
+                "pure-Python hash and ring", e, _SO_PATH,
+            )
+        return current
 
 
 def load_native():
@@ -52,7 +67,11 @@ def load_native():
             return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        except OSError as e:
+            log.warning(
+                "native library %s does not load (%s); using the "
+                "pure-Python hash and ring", _SO_PATH, e,
+            )
             return None
         lib.kubeai_xxhash64.restype = ctypes.c_uint64
         lib.kubeai_xxhash64.argtypes = [

@@ -81,6 +81,14 @@ class ModelConfig:
         v = self.limits.get("google.com/tpu") or self.requests.get("google.com/tpu")
         return int(v) if v else 0
 
+    @property
+    def requests_device(self) -> bool:
+        """Whether the Pod asks for any device. Devices are extended
+        resources, always domain-prefixed (google.com/tpu,
+        nvidia.com/gpu); cpu, memory and storage are not. A container
+        that requests none has none mounted."""
+        return any("/" in k for k in (*self.requests, *self.limits))
+
 
 def resolve_model_config(model: Model, cfg: System) -> ModelConfig:
     """Profile lookup+multiplication and engine-image selection

@@ -23,6 +23,11 @@ from kubeai_tpu.operator.engines.common import (
 )
 
 PORT = 8000
+# Where the engine keeps JAX's persistent compilation cache in a pod: an
+# emptyDir, so a restarted container compiles nothing twice and a
+# read-only image is no obstacle. The engine sets no cache directory of
+# its own where JAX_COMPILATION_CACHE_DIR is set (engine/coldstart.py).
+JAX_CACHE_DIR = "/var/cache/jax"
 
 
 def kubeai_tpu_pod(
@@ -138,6 +143,15 @@ def kubeai_tpu_pod(
 
     env.append({"name": "TPU_TOPOLOGY", "value": mcfg.tpu_topology or "1x1"})
     env.append({"name": "TPU_CHIPS", "value": str(mcfg.tpu_chips or 1)})
+    # The engine refuses to serve where JAX fell back to the CPU unasked
+    # (parallel/mesh.py:require_accelerator). A Pod whose profile requests
+    # no device (the `cpu` profile) is on the CPU on purpose, and says so
+    # by name.
+    if not mcfg.requests_device:
+        env.append({"name": "JAX_PLATFORMS", "value": "cpu"})
+    env.append({"name": "JAX_COMPILATION_CACHE_DIR", "value": JAX_CACHE_DIR})
+    volumes.append({"name": "jax-cache", "emptyDir": {}})
+    mounts.append({"name": "jax-cache", "mountPath": JAX_CACHE_DIR})
     env += model_env(model)
 
     container = {

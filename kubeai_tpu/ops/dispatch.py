@@ -18,12 +18,15 @@ whose padded length is under 256 or not a multiple of 128 (the short
 buckets), and a Gemma-2 prefill, whose softcap and sliding window the
 flash kernel does not carry.
 
+`kernel_mode()` is the only way to choose: no dispatch and no kernel takes
+a mode or an `interpret` argument.
+
 Mosaic kernels cannot be partitioned by GSPMD, so under a mesh each
 `pallas_call` runs inside `jax.shard_map`, manual over every mesh axis.
 The mesh is the context mesh (`jax.set_mesh`), which the engine enters
-around each of its jitted calls; KV heads split over `tp` exactly where
-the engine's cache does (`num_kv_heads % tp == 0`), otherwise the call
-runs replicated.
+around each of its jitted calls (`Engine.jit`, the only `jax.jit` in the
+engine); KV heads split by the rule the engine places its pools by
+(`parallel/sharding.py:kv_heads_axis`).
 """
 
 from __future__ import annotations
@@ -31,7 +34,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from kubeai_tpu.parallel.mesh import AXIS_TENSOR
+from kubeai_tpu.parallel.sharding import kv_heads_axis
 
 # Tests flip this to run the kernel path, interpreted, off-TPU.
 FORCE_INTERPRET = False
@@ -46,19 +49,18 @@ def kernel_mode() -> str:
 def over_kv_heads(fn, num_kv_heads: int, head_dims: tuple):
     """`fn` (one pallas_call) as it must run under the context mesh: inside
     a shard_map that is manual over every mesh axis, with argument i split
-    over `tp` along its `head_dims[i]`-th dimension (None: replicated) and
-    the result split like argument 0.
+    over the KV-heads axis along its `head_dims[i]`-th dimension (None:
+    replicated) and the result split like argument 0.
 
-    Heads split only where the engine splits its cache, `num_kv_heads % tp
-    == 0`; GQA with fewer KV heads than tp shards runs replicated, like the
-    cache. With no context mesh (a bare single-device call), or inside a
-    region that is already manual over every axis, `fn` is returned as is.
+    Where the engine replicates its cache (`kv_heads_axis` is None: GQA
+    with fewer KV heads than tp shards) the call runs replicated too. With
+    no context mesh (a bare single-device call), or inside a region that
+    is already manual over every axis, `fn` is returned as is.
     """
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or set(mesh.manual_axes) == set(mesh.axis_names):
         return fn
-    tp = mesh.shape.get(AXIS_TENSOR, 1)
-    axis = AXIS_TENSOR if tp > 1 and num_kv_heads % tp == 0 else None
+    axis = kv_heads_axis(mesh.shape, num_kv_heads)
     specs = tuple(
         P() if d is None else P(*[None] * d, axis) for d in head_dims
     )

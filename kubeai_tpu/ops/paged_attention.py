@@ -335,7 +335,6 @@ def paged_decode_attention(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
     """Paged decode attention: the Pallas kernel on a TPU, the reference
     elsewhere, an int8 pool always on the reference (ops/dispatch.py)."""
@@ -343,7 +342,7 @@ def paged_decode_attention(
 
     b, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
-    mode = mode or dispatch.kernel_mode()
+    mode = dispatch.kernel_mode()
     if mode == "reference" or is_quantized_kv(k_pages):
         return ref_paged_decode_attention(
             q, k_pages, v_pages, block_tables, lengths,
@@ -574,7 +573,6 @@ def paged_verify_attention(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
     """Multi-query paged verify attention (speculative decoding's verify
     pass; see ref_paged_verify_attention for semantics), dispatched like
@@ -583,7 +581,7 @@ def paged_verify_attention(
     kvh = k_pages.shape[2]
     group = h // kvh
     scale = scale if scale is not None else d ** -0.5
-    mode = mode or dispatch.kernel_mode()
+    mode = dispatch.kernel_mode()
     if mode == "reference":
         return ref_paged_verify_attention(
             q, k_pages, v_pages, block_tables, positions,
@@ -907,7 +905,6 @@ def paged_decode_attention_fused(
     scale: float | None = None,
     logit_softcap: float | None = None,
     window: jnp.ndarray | int | None = None,
-    mode: str | None = None,  # None = dispatch.kernel_mode()
 ) -> jnp.ndarray:
     """Fused paged decode attention: reads the layer's resident pages
     straight out of the STACKED pool (no per-layer slice materialization)
@@ -918,7 +915,7 @@ def paged_decode_attention_fused(
     kvh = k_pages.shape[3]
     scale = scale if scale is not None else d ** -0.5
     layer_arr = jnp.asarray(layer, jnp.int32)
-    mode = mode or dispatch.kernel_mode()
+    mode = dispatch.kernel_mode()
     if mode == "reference":
         return ref_paged_decode_attention_fused(
             q, k_pages, v_pages, k_new, v_new, block_tables, positions,

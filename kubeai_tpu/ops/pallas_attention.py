@@ -12,9 +12,10 @@ padded to 128 lanes; q/k blocks are 128 rows, so the sequence must be a
 multiple of 128 (anything else raises — models.llama._prefill_attention
 routes the short and unaligned buckets to the jnp reference first).
 
-Usage: flash_causal_prefill(q, k, v) — same contract as the jnp reference;
-`interpret=True` runs on CPU for tests. Under a mesh the pallas_call runs
-inside shard_map, KV heads split over tp (ops/dispatch.py).
+Usage: flash_causal_prefill(q, k, v) — same contract as the jnp reference.
+It is the kernel, not a dispatch: it runs compiled, or interpreted where
+tests force that (ops/dispatch.py), and off a TPU it raises otherwise. Under
+a mesh the pallas_call runs inside shard_map, KV heads split over tp.
 """
 
 from __future__ import annotations
@@ -123,7 +124,6 @@ def flash_causal_prefill(
     v: jnp.ndarray,
     *,
     block: int = 128,
-    interpret: bool = False,
 ) -> jnp.ndarray:
     """Flash attention with the causal_prefill_attention contract."""
     B, S, H, D = q.shape
@@ -150,7 +150,8 @@ def flash_causal_prefill(
 
     out = dispatch.over_kv_heads(
         functools.partial(
-            _flash_bhsd, block_q=block, block_k=block, interpret=interpret,
+            _flash_bhsd, block_q=block, block_k=block,
+            interpret=dispatch.kernel_mode() == "interpret",
             scale=D ** -0.5, group=group,
         ),
         KVH, (1, 1, 1),

@@ -79,6 +79,35 @@ class ShardingRules:
 DEFAULT_RULES = ShardingRules()
 
 
+def kv_heads_axis(
+    mesh_shape, num_kv_heads: int, rules: ShardingRules = DEFAULT_RULES
+) -> str | None:
+    """The mesh axis a KV cache of `num_kv_heads` heads is split over, or
+    None where it is replicated: GQA with a head count the axis does not
+    divide (each shard then attends its local q heads against the full
+    KV). The engine places its pools by this rule (`kv_cache_rules`) and
+    the attention kernels' shard_map splits by it (ops/dispatch.py), so
+    the two cannot disagree."""
+    axis = rules.physical(KV_HEADS)
+    if axis is None or num_kv_heads % mesh_shape.get(axis, 1):
+        return None
+    return axis
+
+
+def kv_cache_rules(
+    mesh: Mesh, num_kv_heads: int, rules: ShardingRules = DEFAULT_RULES
+) -> ShardingRules:
+    """`rules`, with KV heads replicated where `kv_heads_axis` says so."""
+    if kv_heads_axis(mesh.shape, num_kv_heads, rules) is not None:
+        return rules
+    return ShardingRules(
+        rules=tuple(
+            (name, None if name == KV_HEADS else phys)
+            for name, phys in rules.rules
+        )
+    )
+
+
 def logical_to_physical(
     logical_axes: tuple[str | None, ...],
     rules: ShardingRules = DEFAULT_RULES,

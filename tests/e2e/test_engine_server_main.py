@@ -5,13 +5,12 @@ its socket — exactly what runs inside a KubeAITPU engine Pod."""
 import json
 import os
 import signal
-import subprocess
 import sys
 import time
 
 import pytest
 
-from testutil import eventually, http_get, http_post
+from testutil import eventually, http_get, http_post, popen_logged
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -39,7 +38,7 @@ def test_server_main_subprocess(checkpoint):
     env = dict(os.environ)
     # The same entrypoint, held to the CPU by name.
     env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.Popen(
+    proc = popen_logged(
         [
             sys.executable, "-c",
             "from kubeai_tpu.engine.server import main; import sys; "
@@ -51,13 +50,11 @@ def test_server_main_subprocess(checkpoint):
         ],
         cwd=REPO,
         env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
     )
     try:
         def healthy():
             if proc.poll() is not None:
-                out = proc.stdout.read().decode(errors="replace")
+                out = proc.output()
                 raise AssertionError(f"server died:\n{out[-2000:]}")
             try:
                 return http_get(f"127.0.0.1:{port}", "/health", timeout=2)[0] == 200
@@ -102,7 +99,7 @@ def test_server_main_draft_speculation(checkpoint):
     port = 18478
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.Popen(
+    proc = popen_logged(
         [
             sys.executable, "-c",
             "from kubeai_tpu.engine.server import main; import sys; "
@@ -115,13 +112,11 @@ def test_server_main_draft_speculation(checkpoint):
         ],
         cwd=REPO,
         env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
     )
     try:
         def healthy():
             if proc.poll() is not None:
-                out = proc.stdout.read().decode(errors="replace")
+                out = proc.output()
                 raise AssertionError(f"server died:\n{out[-2000:]}")
             try:
                 return http_get(f"127.0.0.1:{port}", "/health", timeout=2)[0] == 200
@@ -153,7 +148,7 @@ def test_server_main_prefix_cache(checkpoint):
     port = 18479
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.Popen(
+    proc = popen_logged(
         [
             sys.executable, "-c",
             "from kubeai_tpu.engine.server import main; import sys; "
@@ -165,13 +160,11 @@ def test_server_main_prefix_cache(checkpoint):
         ],
         cwd=REPO,
         env=env,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
     )
     try:
         def healthy():
             if proc.poll() is not None:
-                out = proc.stdout.read().decode(errors="replace")
+                out = proc.output()
                 raise AssertionError(f"server died:\n{out[-2000:]}")
             try:
                 return http_get(f"127.0.0.1:{port}", "/health", timeout=2)[0] == 200

@@ -15,7 +15,7 @@ import jax
 import numpy as np
 import pytest
 
-from testutil import eventually, http_get, http_post
+from testutil import eventually, http_get, http_post, popen_logged
 
 from kubeai_tpu.config import System
 from kubeai_tpu.crd import metadata as md
@@ -293,7 +293,6 @@ def test_draft_model_served_through_operator(tmp_path):
     speculative path accepted proposals (target-as-draft ⇒ near-total
     acceptance)."""
     import signal
-    import subprocess
     import sys
 
     torch = pytest.importorskip("torch")
@@ -360,7 +359,7 @@ def test_draft_model_served_through_operator(tmp_path):
         ]
         env = dict(os.environ)
         env["JAX_PLATFORMS"] = "cpu"
-        proc = subprocess.Popen(
+        proc = popen_logged(
             [
                 sys.executable, "-c",
                     "from kubeai_tpu.engine.server import main; import sys; "
@@ -369,13 +368,11 @@ def test_draft_model_served_through_operator(tmp_path):
             cwd=os.path.dirname(os.path.dirname(
                 os.path.dirname(os.path.abspath(__file__)))),
             env=env,
-            stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT,
         )
 
         def healthy():
             if proc.poll() is not None:
-                out = proc.stdout.read().decode(errors="replace")
+                out = proc.output()
                 raise AssertionError(f"server died:\n{out[-2000:]}")
             # Mark the controller's pod Ready so the LB routes to the
             # (annotated) subprocess address.

@@ -5,6 +5,8 @@ kubelet — the httptest.Server / markAllModelPodsReady equivalents
 from __future__ import annotations
 
 import json
+import subprocess
+import tempfile
 import threading
 import time
 from contextlib import contextmanager
@@ -266,3 +268,22 @@ def eventually(fn, timeout=10, interval=0.05, msg="condition"):
             last = e
         time.sleep(interval)
     raise AssertionError(f"timed out waiting for {msg} (last error: {last})")
+
+
+def popen_logged(cmd, **kw) -> subprocess.Popen:
+    """Popen with stdout and stderr in a temp file; `proc.output()` reads
+    what was written so far. Not a pipe: a pipe nobody drains blocks the
+    child once 64 KiB sit in it, and XLA:CPU alone writes 2 KB for every
+    hit in the persistent compilation cache — a server boot on a warm
+    cache then stalls mid-step and reads as a hang (or dies by its own
+    step watchdog)."""
+    logf = tempfile.TemporaryFile()
+    proc = subprocess.Popen(cmd, stdout=logf, stderr=subprocess.STDOUT, **kw)
+
+    def output() -> str:
+        size = logf.seek(0, 2)
+        logf.seek(0)
+        return logf.read(size).decode(errors="replace")
+
+    proc.output = output
+    return proc

@@ -121,6 +121,47 @@ def test_kubeai_tpu_renderer_topology(cfg):
     env = env_dict(c)
     assert env["TPU_TOPOLOGY"] == "2x4" and env["TPU_CHIPS"] == "8"
     assert "--tpu-topology" in c["args"]
+    # A Pod that was given chips leaves the platform to JAX: the engine
+    # must fail at boot if the TPU is not there, not serve from the CPU.
+    assert "JAX_PLATFORMS" not in env
+
+
+def test_kubeai_tpu_renderer_cpu_profile_names_the_cpu(cfg):
+    """engine.server refuses a CPU it fell back to; a Pod rendered without
+    a device is on the CPU on purpose and has to say so, or it exits at
+    boot. Holds for the built-in `cpu` profile, for a config-file profile
+    that leaves imageName out (deploy/operator.yaml), and for no profile."""
+    from kubeai_tpu.config.system import ResourceProfile
+
+    cfg.resource_profiles["plain"] = ResourceProfile(
+        requests={"cpu": "1", "memory": "2Gi"},
+    )
+    for profile in ("cpu:1", "plain:2", ""):
+        m = mk("KubeAITPU", "hf://org/model", resource_profile=profile)
+        env = env_dict(container(render(cfg, m)))
+        assert env["JAX_PLATFORMS"] == "cpu", profile
+    # spec.env still has the last word (later entries win in a Pod).
+    m = mk("KubeAITPU", "hf://org/model", resource_profile="cpu:1",
+           env={"JAX_PLATFORMS": "cpu,tpu"})
+    names = [e["name"] for e in container(render(cfg, m))["env"]]
+    assert names.count("JAX_PLATFORMS") == 2
+    assert container(render(cfg, m))["env"][-1]["value"] == "cpu,tpu"
+
+
+def test_kubeai_tpu_renderer_compile_cache_on_writable_volume(cfg):
+    """Every engine Pod gets JAX_COMPILATION_CACHE_DIR on an emptyDir, so
+    enable_compilation_cache never creates a directory inside a (possibly
+    read-only) image and a restarted container finds its compiles."""
+    for profile in ("cpu:1", "google-tpu-v5e-2x2:4"):
+        m = mk("KubeAITPU", "hf://org/model", resource_profile=profile)
+        pod = render(cfg, m)
+        c = container(pod)
+        cache_dir = env_dict(c)["JAX_COMPILATION_CACHE_DIR"]
+        mount = [v for v in c["volumeMounts"] if v["mountPath"] == cache_dir]
+        assert len(mount) == 1 and not mount[0].get("readOnly")
+        vol = [v for v in pod["spec"]["volumes"]
+               if v["name"] == mount[0]["name"]]
+        assert vol == [{"name": mount[0]["name"], "emptyDir": {}}]
 
 
 def test_files_projected_via_configmap(cfg):

@@ -104,3 +104,31 @@ def test_kernel_call_is_manual_over_every_mesh_axis(
         out = jax.jit(pa.paged_decode_attention)(q, pool, pool, bt, lens)
     assert seen == {"manual": True, "kvh": kv_heads_per_shard}
     np.testing.assert_allclose(np.asarray(out), 1.0, atol=1e-5)
+
+
+def test_the_kernel_wrapper_splits_where_the_engine_places_the_pool():
+    """One rule (parallel/sharding.py:kv_heads_axis) feeds both the pool's
+    sharding and the kernels' shard_map specs."""
+    from kubeai_tpu.parallel import sharding as psh
+
+    assert psh.kv_heads_axis({"dp": 2, "tp": 4}, 8) == "tp"
+    assert psh.kv_heads_axis({"dp": 1, "tp": 8}, 4) is None
+    assert psh.kv_heads_axis({"tp": 1}, 3) == "tp"  # a size-1 axis divides all
+    mesh = build_mesh(MeshConfig(dp=1, tp=8), devices=jax.devices()[:8])
+    assert psh.kv_cache_rules(mesh, 8) is psh.DEFAULT_RULES
+    assert psh.kv_cache_rules(mesh, 4).physical(psh.KV_HEADS) is None
+    assert psh.kv_cache_rules(mesh, 4).physical(psh.HEADS) == "tp"
+
+
+def test_every_engine_jit_enters_the_mesh():
+    """The kernels find their mesh in the context `Engine.jit` enters. A
+    bare `jax.jit` on the serving path would trace its kernels with no
+    mesh, which lowers on one device and is refused by Mosaic only at
+    tp > 1 on the chip — so there is none besides `Engine.jit` itself."""
+    import inspect
+
+    from kubeai_tpu.engine import engine, server
+
+    assert inspect.getsource(engine).count("jax.jit(") == 1
+    assert "jax.jit(" in inspect.getsource(engine.Engine.jit)
+    assert "jax.jit(" not in inspect.getsource(server)

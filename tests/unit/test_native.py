@@ -59,3 +59,37 @@ def test_native_ring_bounded_load_displacement():
     loads = {"a:1": 100, "b:1": 0}
     for i in range(20):
         assert nat.get(f"k{i}", loads) == py.get(f"k{i}", loads)
+
+
+def test_prebuilt_library_loads_without_a_toolchain(monkeypatch, tmp_path, caplog):
+    """`make` missing: a library not older than its source is still good;
+    a stale or absent one is refused, and the downgrade is logged."""
+    import os
+    import shutil
+
+    from kubeai_tpu import native
+
+    def no_make(*a, **kw):
+        raise FileNotFoundError("make")
+
+    shutil.copy(native._SO_PATH, tmp_path / "libkubeai_native.so")
+    src = tmp_path / "kubeai_native.cpp"
+    src.write_text("// source")
+    so = str(tmp_path / "libkubeai_native.so")
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SO_PATH", so)
+    monkeypatch.setattr(native.subprocess, "run", no_make)
+
+    os.utime(src, (1000, 1000))
+    os.utime(so, (2000, 2000))
+    assert native._build() is True
+    src.unlink()  # an image that ships only the library
+    assert native._build() is True
+
+    src.write_text("// edited since the library was built")
+    os.utime(src, (3000, 3000))
+    with caplog.at_level("WARNING", logger=native.__name__):
+        assert native._build() is False
+        os.remove(so)
+        assert native._build() is False
+    assert caplog.text.count("pure-Python") == 2

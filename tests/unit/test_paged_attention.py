@@ -25,6 +25,15 @@ P = 1 + B * MP  # pool: scratch page 0 + full reservation
 L_MAX = MP * PAGE
 
 
+@pytest.fixture(autouse=True)
+def kernels_interpreted(monkeypatch):
+    """The dispatches take the Pallas kernels, interpreted (the `ref_*`
+    functions are called by name where a test wants the reference)."""
+    from kubeai_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "FORCE_INTERPRET", True)
+
+
 def _setup(lengths, seed=0):
     """Build equivalent dense [B, L, KVH, D] caches and paged pools."""
     rng = np.random.default_rng(seed)
@@ -64,9 +73,7 @@ def test_reference_matches_dense_oracle():
 
 def test_kernel_matches_reference():
     q, _, _, kp, vp, bt, lengths = _setup([5, 17, 32])
-    got = paged_decode_attention(
-        q, kp, vp, bt, lengths, mode="interpret"
-    )
+    got = paged_decode_attention(q, kp, vp, bt, lengths)
     want = ref_paged_decode_attention(q, kp, vp, bt, lengths)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4
@@ -78,7 +85,7 @@ def test_kernel_softcap_and_window():
     for cap, win in ((30.0, None), (None, 12), (50.0, 7)):
         got = paged_decode_attention(
             q, kp, vp, bt, lengths,
-            logit_softcap=cap, window=win, mode="interpret",
+            logit_softcap=cap, window=win,
         )
         want = ref_paged_decode_attention(
             q, kp, vp, bt, lengths, logit_softcap=cap, window=win
@@ -139,7 +146,6 @@ def test_fused_kernel_matches_reference():
     for layer in (0, 2):
         got = paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, layer,
-            mode="interpret",
         )
         want = ref_paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, jnp.int32(layer)
@@ -155,7 +161,7 @@ def test_fused_kernel_softcap_and_window():
     for cap, win in ((30.0, None), (None, 12), (50.0, 7)):
         got = paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, 1,
-            logit_softcap=cap, window=win, mode="interpret",
+            logit_softcap=cap, window=win,
         )
         want = ref_paged_decode_attention_fused(
             q, kp, vp, kn, vn, bt, pos, jnp.int32(1),
@@ -187,9 +193,7 @@ def test_fused_empty_slot_returns_value_of_new_token():
     np.testing.assert_allclose(
         np.asarray(out[0]), np.asarray(want0), atol=1e-5
     )
-    got = paged_decode_attention_fused(
-        q, kp, vp, kn, vn, bt, pos, 0, mode="interpret"
-    )
+    got = paged_decode_attention_fused(q, kp, vp, kn, vn, bt, pos, 0)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(out), atol=1e-4, rtol=1e-4
     )
@@ -299,7 +303,6 @@ def test_verify_kernel_matches_reference():
         got = paged_verify_attention(
             q, kp, vp, bt, positions,
             logit_softcap=cap, window=win,
-            mode="interpret",
         )
         want = ref_paged_verify_attention(
             q, kp, vp, bt, positions, logit_softcap=cap, window=win,

@@ -15,6 +15,13 @@ from kubeai_tpu.parallel.ring_attention import (
 )
 
 
+@pytest.fixture
+def kernels_interpreted(monkeypatch):
+    from kubeai_tpu.ops import dispatch
+
+    monkeypatch.setattr(dispatch, "FORCE_INTERPRET", True)
+
+
 def _mk(B=1, S=256, H=4, KVH=2, D=64, seed=0):
     rng = np.random.default_rng(seed)
     q = rng.standard_normal((B, S, H, D)).astype(np.float32)
@@ -23,20 +30,20 @@ def _mk(B=1, S=256, H=4, KVH=2, D=64, seed=0):
     return jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
 
 
-def test_flash_matches_reference_interpret():
+def test_flash_matches_reference_interpret(kernels_interpreted):
     q, k, v = _mk()
     want = causal_prefill_attention(q, k, v)
-    got = flash_causal_prefill(q, k, v, interpret=True)
+    got = flash_causal_prefill(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
     )
 
 
-def test_flash_gqa_and_padded_head_dim():
+def test_flash_gqa_and_padded_head_dim(kernels_interpreted):
     # D=64 exercises the pad-to-128 path; KVH=1 the max-group GQA path.
     q, k, v = _mk(B=2, S=128, H=4, KVH=1, D=64, seed=1)
     want = causal_prefill_attention(q, k, v)
-    got = flash_causal_prefill(q, k, v, interpret=True)
+    got = flash_causal_prefill(q, k, v)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-3, atol=2e-3
     )
@@ -49,7 +56,7 @@ def test_flash_refuses_unaligned_seq_and_dispatch_routes_it():
 
     q, k, v = _mk(S=100)  # 100 % 128 != 0
     with pytest.raises(ValueError, match="multiple of 128"):
-        flash_causal_prefill(q, k, v, interpret=True)
+        flash_causal_prefill(q, k, v)
     want = causal_prefill_attention(q, k, v)
     got = _prefill_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
