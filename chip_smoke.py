@@ -18,9 +18,11 @@ never imports JAX:
                 their jnp references.
   3. server   — boot, /health, /v1/state, then requests over HTTP.
 
-The last line of stdout is one JSON object; the exit code is 0 only when
-every leg passed. Times printed here are smoke observations, not benchmark
-metrics.
+The last line of stdout is one JSON object, `{"ok": true, "device":
+{"platform", "kind", "count"}}` and no other key; the `summary:` line before
+it carries what was served (mesh, depth, boot-to-Ready, cache entries). The
+exit code is 0 only when every leg passed, and a failed run prints no result
+object. Times printed here are smoke observations, not benchmark metrics.
 """
 
 from __future__ import annotations
@@ -678,13 +680,18 @@ def main() -> int:
     except Failed as e:
         log(f"FAILED: {e}")
         return 1
-    print(json.dumps({
+    result = {
         "ok": True,
         "device": {
             "platform": device["platform"],
             "kind": device["kind"],
             "count": device["count"],
         },
+    }
+    # What was served goes on its own labelled line: the driver reads the
+    # last line, which holds the keys "ok" and "device" and no other.
+    log("summary: " + json.dumps({
+        **result,
         "mesh": sizes["topology"] or "1",
         "depth": sizes["depth"],
         "num_slots": sizes["num_slots"],
@@ -695,7 +702,8 @@ def main() -> int:
         "cache_entries_after": cache_entries(device["cache_dir"]),
         "elapsed_s": round(time.monotonic() - t_start, 1),
         "claim": None,
-    }), flush=True)
+    }))
+    print(json.dumps(result), flush=True)
     return 0
 
 

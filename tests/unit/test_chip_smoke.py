@@ -3,6 +3,7 @@ TPU, its checkpoint writer produces what the server's loader reads, and the
 compile-cache rule it shares with the server holds."""
 
 import inspect
+import json
 import os
 import subprocess
 import sys
@@ -30,6 +31,30 @@ def test_default_invocation_fails_off_tpu_and_names_the_platform():
     # Stopped at the device leg: no kernels, no server, no result line.
     assert "starting:" not in out.stdout
     assert '"ok"' not in out.stdout
+
+
+def test_last_line_is_the_result_object_and_nothing_more(monkeypatch, capsys):
+    """The driver reads the last line of stdout: the keys "ok" and "device"
+    and no other. What was served is on the labelled line before it."""
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+              "cache_dir": "/nonexistent"}
+    monkeypatch.setattr(
+        chip_smoke, "run_leg", lambda args, deadline: dict(device)
+    )
+    monkeypatch.setattr(
+        chip_smoke, "leg_server",
+        lambda device, sizes, deadline: {"boot_to_ready_s": 1.0},
+    )
+    monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
+    assert chip_smoke.main() == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    summary = json.loads(lines[-2].removeprefix("chip_smoke: summary: "))
+    assert summary["depth"] == 16 and summary["mesh"] == "1"
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
 
 
 def test_parent_module_stays_off_jax():
