@@ -1,0 +1,9 @@
+"""The benchmark's own tests (part of the yardstick: listed in
+BENCHMARK.json `paths`). They import the harness as the package `perf`."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)

@@ -1,0 +1,59 @@
+"""Ahead-of-time compiles for a described v5e:2x2, at the cells' real sizes:
+they guard both configurations on every later PR at no chip time. Nothing
+runs; a compile that passes is not a chip run."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import pytest  # noqa: E402
+
+import aot  # noqa: E402  (tests/perf/aot.py)
+
+HBM = 15.75 * 2**30  # what the TPU compiler allows a v5e program
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        described = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # An entry written for a chip that is not attached cannot be read back.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", True)
+
+
+def test_mistral_one_chip_largest_graphs_fit(topo):
+    cfg = aot.load_config("mistral-7b-v5e1")
+    out = aot.compile_cell(topo, cfg, admit=8, bucket=2048)
+    for graph in ("weights", "decode", "prefill"):
+        assert aot.peak_bytes(out[graph]) < HBM, graph
+    # 24 slots x 2048 tokens x 64 KiB of pages are 3 GiB; the weights 7 GiB.
+    assert out["decode"].argument_size_in_bytes > 10 * 2**30
+    assert "tpu_custom_call" in out["decode_text"]  # the paged kernel is there
+
+
+def test_mistral_eight_more_slots_would_not_fit(topo):
+    cfg = aot.load_config("mistral-7b-v5e1")
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|memory"):
+        aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",),
+                         engine_overrides={"num_slots": 32})
+
+
+def test_mixtral_tp4_graphs_fit_and_carry_collectives(topo):
+    cfg = aot.load_config("mixtral-8x7b-v5e4")
+    out = aot.compile_cell(topo, cfg, admit=8, bucket=256)
+    for graph in ("weights", "decode", "prefill"):
+        assert aot.peak_bytes(out[graph]) < HBM, graph
+    # Per chip: a quarter of 46.7 GB of weights.
+    assert 10 * 2**30 < out["decode"].argument_size_in_bytes < 13.5 * 2**30
+    assert "all-reduce" in out["decode_text"]
+    assert "tpu_custom_call" in out["decode_text"]

@@ -1,0 +1,209 @@
+"""BENCHMARK.json and the data files it names: the contract's static rules,
+and that a later PR can add a configuration, a mix, a cell and a per-layer
+metric of an existing reader kind with new files and entries only."""
+
+import json
+import os
+import re
+import shutil
+
+import pytest
+
+from perf import readers, traffic
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def load(root=ROOT):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def check(root) -> list[str]:
+    """Every rule this file can check statically; returns what is wrong."""
+    b, bad = load(root), []
+    if set(b) != {"command", "paths", "run_seconds", "configs", "workloads",
+                  "end_to_end", "per_layer"}:
+        bad.append(f"top-level keys {sorted(b)}")
+    cfgs = {c["name"]: c for c in b["configs"]}
+    cells = {w["name"]: w for w in b["workloads"]}
+    e2e_names = {m["name"] for m in b["end_to_end"]}
+    for c in b["configs"]:
+        if set(c) != {"name", "source", "file", "reduced", "why"}:
+            bad.append(f"config keys {sorted(c)}")
+        if not NAME.match(c["name"]) or not all(NAME.match(k) for k in c["reduced"]):
+            bad.append(f"config name {c['name']!r}")
+        path = os.path.join(root, c["file"])
+        if not any(c["file"].startswith(p + "/") for p in b["paths"]):
+            bad.append(f"{c['file']} is outside paths")
+        try:
+            with open(path) as f:
+                body = json.load(f)
+            if "reference" not in body or not os.path.exists(os.path.join(
+                    root, "perf", "reference", body["reference"] + ".py")):
+                bad.append(f"{c['file']} names no reference")
+            if set(c["reduced"]) != set(body.get("reduced", {})):
+                bad.append(f"{c['name']}: reduced differs from its file's")
+        except (OSError, ValueError) as e:
+            bad.append(f"{c['file']}: {e}")
+    for w in b["workloads"]:
+        if set(w) != {"name", "config", "traffic", "chips", "why"}:
+            bad.append(f"workload keys {sorted(w)}")
+        for key in ("name", "config", "traffic"):
+            if not NAME.match(w[key]):
+                bad.append(f"workload {key} {w[key]!r}")
+        if w["config"] not in cfgs:
+            bad.append(f"{w['name']}: unknown config")
+        if w["chips"] not in (1, 4) or not 1 <= len(w["why"]) <= 200:
+            bad.append(f"{w['name']}: chips or why")
+        try:
+            traffic.load_mix(w["traffic"], os.path.join(root, "perf"))
+        except (OSError, ValueError) as e:
+            bad.append(f"{w['name']}: mix {e}")
+    if sum(1 for w in b["workloads"] if w["chips"] == 4) > max(1, len(cells) // 4):
+        bad.append("too many four-chip cells")
+    if {c["name"] for c in b["configs"]} != {w["config"] for w in b["workloads"]}:
+        bad.append("a configuration no cell uses")
+    for m in b["end_to_end"] + b["per_layer"]:
+        if not NAME.match(m["name"]) or not UNIT.match(m["unit"]):
+            bad.append(f"metric {m['name']!r} unit {m['unit']!r}")
+        if m["better"] not in ("lower", "higher") or m["source"] not in SOURCES:
+            bad.append(f"{m['name']}: better/source")
+        for w in m.get("workloads", []):
+            if w not in cells:
+                bad.append(f"{m['name']}: unknown cell {w}")
+    for m in b["end_to_end"]:
+        if set(m) - {"workloads"} != {"name", "unit", "better", "bound", "source"}:
+            bad.append(f"{m['name']}: keys")
+        if m["source"] not in ("host_clock", "device_trace"):
+            bad.append(f"{m['name']}: an end-to-end source")
+        if not 0.01 <= m["bound"] <= 0.1:
+            bad.append(f"{m['name']}: bound {m['bound']}")
+    if "setup_s" not in e2e_names:
+        bad.append("no setup_s")
+
+    def reports(cell):
+        return {m["name"] for m in b["end_to_end"]
+                if "workloads" not in m or cell in m["workloads"]}
+
+    for m in b["per_layer"]:
+        if set(m) - {"workloads"} != {"name", "unit", "better", "source",
+                                      "layer", "moves"}:
+            bad.append(f"{m['name']}: keys")
+        if m["moves"] not in e2e_names:
+            bad.append(f"{m['name']}: moves {m['moves']}")
+        for cell in m.get("workloads", cells):
+            if m["moves"] not in reports(cell):
+                bad.append(f"{m['name']}: {cell} does not report {m['moves']}")
+        path = os.path.join(root, "perf", "layer_metrics", m["name"] + ".json")
+        try:
+            with open(path) as f:
+                if json.load(f)["reader"] not in readers.READERS:
+                    bad.append(f"{m['name']}: unknown reader kind")
+        except (OSError, ValueError, KeyError) as e:
+            bad.append(f"{m['name']}: {e}")
+    for cell in cells:
+        if len(reports(cell)) < 2:
+            bad.append(f"{cell}: reports no end-to-end metric besides setup_s")
+        if not any("workloads" not in m or cell in m["workloads"]
+                   for m in b["per_layer"]):
+            bad.append(f"{cell}: reports no per-layer metric")
+    return bad
+
+
+def test_the_benchmark_as_committed_meets_the_static_rules():
+    assert check(ROOT) == []
+    b = load()
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    assert 1 <= b["run_seconds"] <= 51
+    assert all(os.path.isdir(os.path.join(ROOT, p)) for p in b["paths"])
+
+
+def test_command_names_no_file_outside_paths():
+    b = load()
+    for word in b["command"]:
+        assert not word.startswith("/") and ".." not in word
+        if "/" in word:
+            assert any(word.startswith(p + "/") for p in b["paths"])
+
+
+@pytest.mark.parametrize("name", sorted(
+    f[:-5] for f in os.listdir(os.path.join(ROOT, "perf", "configs"))))
+def test_configuration_files_state_their_engine_and_limits(name):
+    with open(os.path.join(ROOT, "perf", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    assert cfg["chips"] in (1, 4)
+    assert {"num_slots", "max_seq_len"} <= set(cfg["engine"])
+    assert {"max_gap", "mean_gap", "short"} == set(cfg["correct"])
+    assert cfg["mesh"]["tp"] == cfg["chips"]
+
+
+PUBLISHED = {  # the models' public config.json, widths and all
+    "mistral-7b-v5e1": dict(
+        hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
+        num_key_value_heads=8, vocab_size=32768, rope_theta=1e6,
+        rms_norm_eps=1e-5, max_position_embeddings=32768),
+    "mixtral-8x7b-v5e4": dict(
+        hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
+        num_key_value_heads=8, vocab_size=32000, rope_theta=1e6,
+        rms_norm_eps=1e-5, max_position_embeddings=32768,
+        num_local_experts=8, num_experts_per_tok=2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PUBLISHED))
+def test_only_depth_is_reduced_from_the_published_config(name):
+    with open(os.path.join(ROOT, "perf", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    for key, value in PUBLISHED[name].items():
+        assert cfg[key] == value, key
+    assert list(cfg["reduced"]) == ["num_hidden_layers"]
+    cut = cfg["reduced"]["num_hidden_layers"]
+    assert (cut["from"], cut["to"]) == (32, cfg["num_hidden_layers"])
+
+
+def test_a_later_pr_adds_a_config_mix_cell_and_metric_as_files_only(tmp_path):
+    root = tmp_path / "repo"
+    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf")
+    b = load()
+    before = {p: (root / "perf" / p).read_bytes()
+              for p in ("run.py", "readers.py", "traffic.py", "loadgen.py")}
+    with open(root / "perf" / "configs" / "mistral-7b-v5e1.json") as f:
+        cfg = json.load(f)
+    cfg["engine"]["num_slots"] = 16
+    (root / "perf" / "configs" / "mistral-7b-16slot.json").write_text(json.dumps(cfg))
+    (root / "perf" / "traffic" / "chat-fast.json").write_text(
+        json.dumps({"extends": "chat", "rate_rps": 9.5}))
+    (root / "perf" / "layer_metrics" / "itl_mean_ms.json").write_text(json.dumps(
+        {"reader": "histogram_mean", "scale": 1000.0,
+         "metric": "kubeai_engine_inter_token_latency_seconds"}))
+    b["configs"].append({**b["configs"][0], "name": "mistral-7b-16slot",
+                         "file": "perf/configs/mistral-7b-16slot.json"})
+    b["workloads"].append({"name": "mistral-7b-16slot.chat-fast", "chips": 1,
+                           "config": "mistral-7b-16slot", "traffic": "chat-fast",
+                           "why": "the same chat lengths at a higher rate"})
+    for m in b["end_to_end"]:
+        if m["name"] == "tpot_mean_ms":
+            m["workloads"].append("mistral-7b-16slot.chat-fast")
+    b["per_layer"].append({
+        "name": "itl_mean_ms", "unit": "ms", "better": "lower",
+        "source": "program_counter", "layer": "Decode step",
+        "moves": "tpot_mean_ms", "workloads": ["mistral-7b-16slot.chat-fast"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    assert check(str(root)) == []
+    mix = traffic.load_mix("chat-fast", str(root / "perf"))
+    assert mix["rate_rps"] == 9.5 and mix["loop"] == "open"
+    assert len(traffic.open_schedule(mix, 1, 10.0)) == round(9.5 * (10 + mix["preroll_s"]))
+    # The new metric reads through the existing reader kind.
+    edge = lambda s, c: readers.parse_prometheus(  # noqa: E731
+        f"kubeai_engine_inter_token_latency_seconds_sum {s}\n"
+        f"kubeai_engine_inter_token_latency_seconds_count {c}\n")
+    with open(root / "perf" / "layer_metrics" / "itl_mean_ms.json") as f:
+        spec = json.load(f)
+    assert readers.read(spec, {"metrics0": edge(1.0, 10), "metrics1": edge(3.0, 50)}) \
+        == pytest.approx(50.0)
+    for p, content in before.items():
+        assert (root / "perf" / p).read_bytes() == content
