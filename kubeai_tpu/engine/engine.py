@@ -332,16 +332,19 @@ class Engine:
         # running batch size, waiting-queue depth, tokens emitted, wall
         # duration.
         self.last_step_stats: dict[str, float] = {}
-        # Per-phase step profiler (kubeai_tpu/fleet/profiler): step()
-        # fills `_phase_scratch` with monotonic phase durations and
-        # closes each step into the profiler's ring; the serve loop
-        # drains it into the kubeai_engine_step_phase_seconds histogram
-        # and POST /v1/profile reads the ring. Plain float bookkeeping
-        # under the engine lock — no registry in the hot path.
+        # Per-phase step profiler (kubeai_tpu/fleet/profiler): every host
+        # interval of step() is a `profiler.span`, which adds `step.<phase>`
+        # durations to the open step and puts the interval on a device
+        # trace's clock through the TraceAnnotation handed over here. The
+        # serve loop drains the phases into a histogram and /v1/profile
+        # reads the ring: plain floats, no registry in the hot path.
         from kubeai_tpu.fleet.profiler import StepProfiler
 
-        self.profiler = StepProfiler()
-        self._phase_scratch: dict[str, float] | None = None
+        self.profiler = StepProfiler(annotate=jax.profiler.TraceAnnotation)
+        # Admission device calls made by step(), and the prompt tokens
+        # they computed against the tokens of the shapes they ran
+        # (padded - useful = padding); EngineMetrics folds the deltas in.
+        self.admit_stats = {"calls": 0, "useful_tokens": 0, "padded_tokens": 0}
 
         # Resolve the cache mode: paged needs family support; otherwise
         # fall back to the slot cache. Chunked prefill works in both modes
@@ -1728,136 +1731,157 @@ class Engine:
         A preempted request resumes by RECOMPUTE — re-prefill prompt +
         already-emitted tokens (minus the last, whose KV the next decode
         step writes) with its first token FORCED to the one already
-        emitted."""
-        from kubeai_tpu.engine.paged_cache import OutOfPages
+        emitted.
 
+        Each pass of the loop is one `step.admit` span: `admit.host` is
+        the pops, page grants, staging, uploads and dispatch (and, once
+        the tokens are back, the bookkeeping of the admitted),
+        `admit.wait` the time blocked on the sampled first tokens."""
         emitted: list[StepEvent] = []
+        span = self.profiler.span
         C = self.cfg.prefill_chunk
         while len(self._sched) and self._free_slots:
-            batch: list[
-                tuple[_Request, int, list[int], int, bool, list[bytes] | None]
-            ] = []
-            bucket = None
-            chunked = None  # long prompt diverted to the staged-chunk path
-            prefix_hit = None  # cached prefix diverted to the suffix path
-            while (
-                len(self._sched)
-                and self._free_slots
-                and len(batch) < max(1, self.cfg.max_admit_batch)
-            ):
-                req = self._sched.peek()
-                resumed = bool(req.out_tokens)
-                seq = (
-                    req.prompt + req.out_tokens[:-1] if resumed
-                    else req.prompt
+            with span("step.admit") as call:
+                with span("admit.host") as host:
+                    plan = self._plan_admission()
+                    if plan is None:
+                        break  # defer: nothing was popped, nothing is held
+                    kind, batch, bucket, cached_len = plan
+                    if kind == "batch":
+                        toks_dev = self._admit_paged_batch(batch, bucket)
+                        a_pad = toks_dev.shape[0]
+                        padded = a_pad * bucket
+                    else:
+                        req, slot, seq, plen = batch[0][:4]
+                        toks_dev = (
+                            self._admit_prefix_hit(
+                                req, slot, seq, plen, cached_len
+                            )
+                            if kind == "prefix"
+                            else self._admit_chunked_paged(
+                                req, slot, seq, plen, C
+                            )
+                        )
+                        a_pad = 1
+                        padded = -(-(plen - cached_len) // C) * C
+                with span("admit.wait") as wait:
+                    toks = np.asarray(toks_dev).reshape(-1)
+                with span("admit.host") as tail:
+                    for (req, slot, _seq, plen, resumed, hashes), tok in zip(
+                        batch, toks
+                    ):
+                        if not resumed:
+                            self._note_prefix_admission(
+                                req, slot, plen, cached_len, hashes
+                            )
+                        ev = self._finish_admission(
+                            req, slot, plen, int(tok), resumed
+                        )
+                        if ev is not None:
+                            emitted.append(ev)
+                useful = sum(entry[3] for entry in batch) - cached_len
+                call.note(
+                    kind=kind, bucket=bucket, batch=len(batch), a_pad=a_pad,
+                    useful_tokens=useful, padded_tokens=padded,
                 )
-                plen = len(seq)
-                hashes = None
-                if self._prefix_cache and not resumed:
-                    # Memoized per request: a head-of-line admission
-                    # deferred by OutOfPages would otherwise re-hash its
-                    # whole prompt every engine step. (Safe across steps:
-                    # adapter swaps refuse while a pending request
-                    # references the slot, so the generation in the seed
-                    # cannot change under a queued request.)
-                    hashes = getattr(req, "_apc_hashes", None)
-                    if hashes is None:
-                        hashes = self._prefix_hashes(seq, req.adapter_idx)
-                        req._apc_hashes = hashes
-                    # Cap the hit twice over: at least the final token
-                    # must compute (its logits seed the first sample),
-                    # and cached_len + prefill_chunk must fit inside the
-                    # staging buffer — a padded suffix chunk starting
-                    # past max_seq_len - C would have its
-                    # dynamic_update_slice start CLAMPED, silently
-                    # writing KV at the wrong offset and then scattering
-                    # it into shared pages.
-                    cap = min(
-                        (plen - 1) // self.cfg.page_size,
-                        max(
-                            0,
-                            (self.cfg.max_seq_len - C)
-                            // self.cfg.page_size,
-                        ),
-                    )
-                    hit = self._alloc.lookup(hashes[:cap])
-                    if hit:
-                        # One-at-a-time (staging buffer); flush any
-                        # batch first and take the hit next iteration.
-                        if not batch:
-                            prefix_hit = (req, seq, plen, hashes, hit)
-                        break
-                if C > 0 and plen > C:
-                    # Chunked admission is one-at-a-time (the staging
-                    # buffer holds one sequence); flush any batch first.
-                    if not batch:
-                        chunked = (req, seq, plen, resumed, hashes)
-                    break
-                b = self._bucket(plen)
-                if bucket is None:
-                    bucket = b
-                elif b != bucket:
-                    break  # same-bucket batching only (no pad blow-up)
-                slot = self._free_slots[-1]
-                try:
-                    pages = self._alloc.ensure(slot, plen)
-                except OutOfPages:
-                    break  # defer; ensure() rolled back
-                self._pop_pending()
-                self._free_slots.pop()
-                req.slot = slot
-                self._set_bt_row(slot, pages)
-                batch.append((req, slot, seq, plen, resumed, hashes))
-            if prefix_hit is not None:
-                req, seq, plen, hashes, hit = prefix_hit
-                slot = self._free_slots[-1]
-                self._alloc.adopt(slot, hit)
-                try:
-                    pages = self._alloc.ensure(slot, plen)
-                except OutOfPages:
-                    self._alloc.unadopt(slot)
-                    break  # defer; nothing held
-                self._pop_pending()
-                self._free_slots.pop()
-                req.slot = slot
-                self._set_bt_row(slot, pages)
-                cached_len = len(hit) * self.cfg.page_size
-                tok = self._admit_prefix_hit(req, slot, seq, plen, cached_len)
-                self._note_prefix_admission(req, slot, plen, cached_len, hashes)
-                ev = self._finish_admission(req, slot, plen, tok, False)
-                if ev is not None:
-                    emitted.append(ev)
-                continue
-            if chunked is not None:
-                req, seq, plen, resumed, hashes = chunked
-                slot = self._free_slots[-1]
-                try:
-                    pages = self._alloc.ensure(slot, plen)
-                except OutOfPages:
-                    break  # defer; ensure() rolled back
-                self._pop_pending()
-                self._free_slots.pop()
-                req.slot = slot
-                self._set_bt_row(slot, pages)
-                tok = self._admit_chunked_paged(req, slot, seq, plen, C)
-                if not resumed:
-                    self._note_prefix_admission(req, slot, plen, 0, hashes)
-                ev = self._finish_admission(req, slot, plen, tok, resumed)
-                if ev is not None:
-                    emitted.append(ev)
-                continue
-            if not batch:
-                break
-            toks = self._admit_paged_batch(batch, bucket)
-            for (req, slot, _seq, plen, resumed, hashes), tok in zip(
-                batch, toks
-            ):
-                if not resumed:
-                    self._note_prefix_admission(req, slot, plen, 0, hashes)
-                ev = self._finish_admission(req, slot, plen, int(tok), resumed)
-                if ev is not None:
-                    emitted.append(ev)
+            self.admit_stats["calls"] += 1
+            self.admit_stats["useful_tokens"] += useful
+            self.admit_stats["padded_tokens"] += padded
+            self._timing.append(("admit_host", host.seconds + tail.seconds))
+            self._timing.append(("admit_wait", wait.seconds))
         return emitted
+
+    def _plan_admission(self):
+        """Pop the next admission off the scheduler and grant its slots
+        and pages. Returns (kind, entries, bucket, cached_len): "batch"
+        is same-bucket prompts for one fused call, "chunked" one long
+        prompt for the staged-chunk path, "prefix" one prompt whose first
+        cached_len tokens are adopted from the prefix cache (the last two
+        are one-at-a-time: the staging buffer holds one sequence, so a
+        batch under construction is flushed first and they are taken by
+        the next call). None when nothing can be admitted now. An entry
+        is (req, slot, seq, plen, resumed, hashes)."""
+        C = self.cfg.prefill_chunk
+        batch: list[
+            tuple[_Request, int, list[int], int, bool, list[bytes] | None]
+        ] = []
+        bucket = None
+        while (
+            len(self._sched)
+            and self._free_slots
+            and len(batch) < max(1, self.cfg.max_admit_batch)
+        ):
+            req = self._sched.peek()
+            resumed = bool(req.out_tokens)
+            seq = req.prompt + req.out_tokens[:-1] if resumed else req.prompt
+            plen = len(seq)
+            hashes = None
+            hit = ()
+            if self._prefix_cache and not resumed:
+                # Memoized per request: a head-of-line admission
+                # deferred by OutOfPages would otherwise re-hash its
+                # whole prompt every engine step. (Safe across steps:
+                # adapter swaps refuse while a pending request
+                # references the slot, so the generation in the seed
+                # cannot change under a queued request.)
+                hashes = getattr(req, "_apc_hashes", None)
+                if hashes is None:
+                    hashes = self._prefix_hashes(seq, req.adapter_idx)
+                    req._apc_hashes = hashes
+                # Cap the hit twice over: at least the final token
+                # must compute (its logits seed the first sample),
+                # and cached_len + prefill_chunk must fit inside the
+                # staging buffer — a padded suffix chunk starting
+                # past max_seq_len - C would have its
+                # dynamic_update_slice start CLAMPED, silently
+                # writing KV at the wrong offset and then scattering
+                # it into shared pages.
+                cap = min(
+                    (plen - 1) // self.cfg.page_size,
+                    max(0, (self.cfg.max_seq_len - C) // self.cfg.page_size),
+                )
+                hit = self._alloc.lookup(hashes[:cap])
+            if hit or (C > 0 and plen > C):
+                if batch:
+                    break
+                if self._grant(req, plen, hit) is None:
+                    return None
+                return (
+                    "prefix" if hit else "chunked",
+                    [(req, req.slot, seq, plen, resumed, hashes)],
+                    C,
+                    len(hit) * self.cfg.page_size,
+                )
+            b = self._bucket(plen)
+            if bucket is None:
+                bucket = b
+            elif b != bucket:
+                break  # same-bucket batching only (no pad blow-up)
+            if self._grant(req, plen) is None:
+                break
+            batch.append((req, req.slot, seq, plen, resumed, hashes))
+        return ("batch", batch, bucket, 0) if batch else None
+
+    def _grant(self, req: _Request, plen: int, hit=()) -> int | None:
+        """Give the head of the queue the next free slot and the pages
+        for plen tokens, the first of them the cached pages `hit`. None,
+        with nothing popped and nothing held, when the pool is short."""
+        from kubeai_tpu.engine.paged_cache import OutOfPages
+
+        slot = self._free_slots[-1]
+        if hit:
+            self._alloc.adopt(slot, hit)
+        try:
+            pages = self._alloc.ensure(slot, plen)
+        except OutOfPages:  # ensure() rolled back
+            if hit:
+                self._alloc.unadopt(slot)
+            return None
+        self._pop_pending()
+        self._free_slots.pop()
+        req.slot = slot
+        self._set_bt_row(slot, pages)
+        return slot
 
     def _prefix_hashes(self, tokens: list[int], adapter_idx: int) -> list[bytes]:
         """Page-aligned content-hash chain over a prompt. Seeded with the
@@ -1906,7 +1930,7 @@ class Engine:
     def _admit_prefix_hit(
         self, req: _Request, slot: int, seq: list[int], plen: int,
         cached_len: int,
-    ) -> int:
+    ) -> jax.Array:
         """Admission with an adopted cached prefix: materialize the
         prefix pages into the staging buffers, then prefill ONLY the
         suffix through the staged-chunk path (the final chunk scatters
@@ -1955,7 +1979,7 @@ class Engine:
 
     def _admit_chunked_paged(
         self, req: _Request, slot: int, seq: list[int], plen: int, C: int
-    ) -> int:
+    ) -> jax.Array:
         """Chunked prefill in paged mode: chunks accumulate in the one-slot
         staging buffer; the final chunk scatters the whole staged sequence
         through the slot's freshly-allocated block-table row."""
@@ -1989,10 +2013,12 @@ class Engine:
 
     def _run_staged_chunks(
         self, req: _Request, slot: int, plen: int, mids, last
-    ) -> int:
+    ) -> jax.Array:
         """Run a staged-chunk schedule (mid chunks, then the scattering
         final chunk) — shared by chunked admission and prefix-cache-hit
-        suffix prefill so the two paths cannot drift."""
+        suffix prefill so the two paths cannot drift. Like
+        _admit_paged_batch it returns the sampled first token ON THE
+        DEVICE: the caller decides where the host blocks on it."""
         last_start, last_tokens = last
         for start, tokens in mids:
             self._stage_k, self._stage_v = self._stage_chunk_mid_jit(
@@ -2039,9 +2065,9 @@ class Engine:
             self._state,
             self._lora,
         )
-        return int(tok_dev)
+        return tok_dev
 
-    def _admit_paged_batch(self, batch, bucket: int) -> np.ndarray:
+    def _admit_paged_batch(self, batch, bucket: int) -> jax.Array:
         A = len(batch)
         a_pad = 1
         while a_pad < A:
@@ -2097,7 +2123,7 @@ class Engine:
                 self._dk,
                 self._dv,
             )
-        return np.asarray(toks_dev)[:A]
+        return toks_dev  # [a_pad]; rows past len(batch) are padding
 
     def _finish_admission(
         self, req: _Request, slot: int, plen: int, tok: int,
@@ -2477,7 +2503,9 @@ class Engine:
                 C = self.cfg.prefill_chunk
                 hashes = self._prefix_hashes(seq, adapter_idx)
                 if C > 0 and plen > C:
-                    tok = self._admit_chunked_paged(req, slot, seq, plen, C)
+                    tok = int(
+                        self._admit_chunked_paged(req, slot, seq, plen, C)
+                    )
                 else:
                     tok = int(
                         self._admit_paged_batch(
@@ -2491,17 +2519,15 @@ class Engine:
                 )
                 # Gather the sequence's pages to host IN TABLE ORDER: the
                 # packed-page blob is position-major by construction.
-                _kv_t0 = time.perf_counter()
-                idx = jnp.asarray(pages, jnp.int32)
-                k_host, k_scales = self._gather_pages_host(
-                    self.cache.k_pages, idx
-                )
-                v_host, v_scales = self._gather_pages_host(
-                    self.cache.v_pages, idx
-                )
-                self.profiler.observe(
-                    "kv_transfer", time.perf_counter() - _kv_t0
-                )
+                with self.profiler.span("kv.export", pages=len(pages)) as sp:
+                    idx = jnp.asarray(pages, jnp.int32)
+                    k_host, k_scales = self._gather_pages_host(
+                        self.cache.k_pages, idx
+                    )
+                    v_host, v_scales = self._gather_pages_host(
+                        self.cache.v_pages, idx
+                    )
+                self.profiler.observe("kv_transfer", sp.seconds)
                 if self._prefix_cache:
                     # Publish the prompt pages before release so they park
                     # in the idle LRU instead of returning to the free
@@ -2671,75 +2697,73 @@ class Engine:
             # zero-pad to max_seq_len (the scatter's static shape) and
             # push through the import graph. Values are copied bit-exact
             # (a dtype mismatch was refused above, never cast).
-            _kv_t0 = time.perf_counter()
-            k_seq, v_seq = handoff.contiguous_kv()
-            pad = np.zeros(
-                (nl, self.cfg.max_seq_len, kvh, d), dtype=k_seq.dtype
-            )
-            k_pad, v_pad = pad.copy(), pad
-            k_pad[:, :plen] = k_seq
-            v_pad[:, :plen] = v_seq
-            if self._kv_quant:
-                ks_seq, vs_seq = handoff.contiguous_scales()
-                spad = np.zeros(
-                    (nl, self.cfg.max_seq_len, kvh), np.float32
+            with self.profiler.span("kv.import", tokens=plen) as sp:
+                k_seq, v_seq = handoff.contiguous_kv()
+                pad = np.zeros(
+                    (nl, self.cfg.max_seq_len, kvh, d), dtype=k_seq.dtype
                 )
-                ks_pad, vs_pad = spad.copy(), spad
-                ks_pad[:, :plen] = ks_seq
-                vs_pad[:, :plen] = vs_seq
-            ints = jnp.asarray(
-                [
-                    plen,
-                    slot,
-                    int(np.uint32(handoff.seed & 0xFFFFFFFF).view(np.int32)),
-                    params.top_k,
-                    adapter_idx,
-                    int(handoff.first_token),
-                ],
-                jnp.int32,
-            )
-            floats = jnp.asarray(
-                [params.temperature, params.top_p], jnp.float32
-            )
-            if self._kv_quant:
-                (
-                    self.cache.k_pages,
-                    self.cache.v_pages,
-                    self.cache.block_tables,
-                    self._state,
-                ) = self._import_handoff_jit(
-                    jnp.asarray(k_pad, jnp.int8),
-                    jnp.asarray(ks_pad, jnp.float32),
-                    jnp.asarray(v_pad, jnp.int8),
-                    jnp.asarray(vs_pad, jnp.float32),
-                    ints,
-                    floats,
-                    jnp.asarray(self._bt_host[slot]),
-                    self.cache.k_pages,
-                    self.cache.v_pages,
-                    self.cache.block_tables,
-                    self._state,
+                k_pad, v_pad = pad.copy(), pad
+                k_pad[:, :plen] = k_seq
+                v_pad[:, :plen] = v_seq
+                if self._kv_quant:
+                    ks_seq, vs_seq = handoff.contiguous_scales()
+                    spad = np.zeros(
+                        (nl, self.cfg.max_seq_len, kvh), np.float32
+                    )
+                    ks_pad, vs_pad = spad.copy(), spad
+                    ks_pad[:, :plen] = ks_seq
+                    vs_pad[:, :plen] = vs_seq
+                ints = jnp.asarray(
+                    [
+                        plen,
+                        slot,
+                        int(np.uint32(handoff.seed & 0xFFFFFFFF).view(np.int32)),
+                        params.top_k,
+                        adapter_idx,
+                        int(handoff.first_token),
+                    ],
+                    jnp.int32,
                 )
-            else:
-                (
-                    self.cache.k_pages,
-                    self.cache.v_pages,
-                    self.cache.block_tables,
-                    self._state,
-                ) = self._import_handoff_jit(
-                    jnp.asarray(k_pad, self.cfg.cache_dtype),
-                    jnp.asarray(v_pad, self.cfg.cache_dtype),
-                    ints,
-                    floats,
-                    jnp.asarray(self._bt_host[slot]),
-                    self.cache.k_pages,
-                    self.cache.v_pages,
-                    self.cache.block_tables,
-                    self._state,
+                floats = jnp.asarray(
+                    [params.temperature, params.top_p], jnp.float32
                 )
-            self.profiler.observe(
-                "kv_transfer", time.perf_counter() - _kv_t0
-            )
+                if self._kv_quant:
+                    (
+                        self.cache.k_pages,
+                        self.cache.v_pages,
+                        self.cache.block_tables,
+                        self._state,
+                    ) = self._import_handoff_jit(
+                        jnp.asarray(k_pad, jnp.int8),
+                        jnp.asarray(ks_pad, jnp.float32),
+                        jnp.asarray(v_pad, jnp.int8),
+                        jnp.asarray(vs_pad, jnp.float32),
+                        ints,
+                        floats,
+                        jnp.asarray(self._bt_host[slot]),
+                        self.cache.k_pages,
+                        self.cache.v_pages,
+                        self.cache.block_tables,
+                        self._state,
+                    )
+                else:
+                    (
+                        self.cache.k_pages,
+                        self.cache.v_pages,
+                        self.cache.block_tables,
+                        self._state,
+                    ) = self._import_handoff_jit(
+                        jnp.asarray(k_pad, self.cfg.cache_dtype),
+                        jnp.asarray(v_pad, self.cfg.cache_dtype),
+                        ints,
+                        floats,
+                        jnp.asarray(self._bt_host[slot]),
+                        self.cache.k_pages,
+                        self.cache.v_pages,
+                        self.cache.block_tables,
+                        self._state,
+                    )
+            self.profiler.observe("kv_transfer", sp.seconds)
             # _set_bt_row marked the host mirror dirty; the import graph
             # also set the device row, so the next step's device_put is
             # redundant but harmless (and still needed if OTHER slots'
@@ -3046,15 +3070,20 @@ class Engine:
 
         Returns a list of StepEvents in emission order.
         """
-        with self._lock:
-            # Per-phase timeline for this step (fleet/profiler.py):
-            # prefill = admission pass, schedule = host bookkeeping
-            # before the decode dispatch, dispatch = block-table upload,
-            # decode = jit DISPATCH (async; the device wait lands in
-            # overlap_idle and the transfer in readback inside
-            # _process_chunk), sample = host token emission.
-            phases: dict[str, float] = {}
-            self._phase_scratch = phases
+        span = self.profiler.span
+        # The span opens before the lock: a handler thread adding or
+        # cancelling a request holds it, and that wait is the step's too.
+        with span(
+            "serve.step", step=self.profiler.steps_completed + 1,
+            batch=len(self._active), pending=len(self._sched),
+        ), self._lock:
+            # Per-phase timeline for this step (fleet/profiler.py), each a
+            # `step.<phase>` span: prefill = admission pass, schedule =
+            # host bookkeeping before the decode dispatch, dispatch =
+            # block-table upload, decode = jit DISPATCH (async; the device
+            # wait lands in overlap_idle and the transfer in readback
+            # inside _process_chunk), sample = host token emission.
+            phases = self.profiler.begin_step()
             emitted: list[StepEvent] = []
             if self._pending_events:
                 # Tokens reaped by an out-of-step barrier (cancel, drain,
@@ -3067,19 +3096,18 @@ class Engine:
             # so reap before admitting. Also reap before any speculation
             # window: prompt-lookup proposals read out_tokens.
             if self._inflight is not None and (len(self._sched) or self._spec):
-                emitted.extend(self._reap_inflight_locked())
-            _admit_t0 = time.perf_counter()
-            emitted.extend(self._admit_pending())
-            phases["prefill"] = (
-                phases.get("prefill", 0.0)
-                + (time.perf_counter() - _admit_t0)
-            )
+                emitted.extend(
+                    self._reap_inflight_locked(
+                        "admission" if len(self._sched) else "spec"
+                    )
+                )
+            with span("step.prefill"):
+                emitted.extend(self._admit_pending())
             prev = self._inflight
             self._inflight = None
             current = None
             decode_mode = None
             t0 = time.perf_counter()
-            _dec_t0 = t0
             if self._active and prev is not None:
                 # SEQ-CAP BARRIER: dispatching chunk N+1 before reaping N
                 # advances device positions by up to len(N) + chunk. If
@@ -3088,32 +3116,36 @@ class Engine:
                 # first — the dispatch below then overshoots by at most
                 # one chunk, exactly the envelope the synchronous loop
                 # already tolerates (surplus tokens are discarded).
-                horizon = prev[2] + self._decode_lookahead() + 1
-                if any(
-                    req.position + horizon >= self.cfg.max_seq_len
-                    for req in self._active.values()
-                ):
-                    emitted.extend(self._process_chunk(prev))
+                with span("step.schedule"):
+                    horizon = prev[2] + self._decode_lookahead() + 1
+                    at_cap = any(
+                        req.position + horizon >= self.cfg.max_seq_len
+                        for req in self._active.values()
+                    )
+                if at_cap:
+                    emitted.extend(self._process_chunk(prev, "seq_cap"))
                     prev = None
             if self._active:
                 if self.cache_mode == "paged":
-                    self._ensure_decode_pages(
-                        inflight_lag=prev[2] if prev is not None else 0
-                    )
+                    with span("step.schedule"):
+                        self._ensure_decode_pages(
+                            inflight_lag=prev[2] if prev is not None else 0
+                        )
                     if self._bt_dirty:
-                        _disp_t0 = time.perf_counter()
-                        self.cache.block_tables = jax.device_put(
-                            jnp.asarray(self._bt_host), self._bt_sharding
+                        with span("step.dispatch"):
+                            self.cache.block_tables = jax.device_put(
+                                jnp.asarray(self._bt_host), self._bt_sharding
+                            )
+                            self._bt_dirty = False
+                with span("step.decode"):
+                    if self.cache_mode != "paged":
+                        toks_seq, self.cache.k, self.cache.v, self._state = (
+                            self._decode_jit(
+                                self.params, self.cache.k, self.cache.v,
+                                self._state, self._lora,
+                            )
                         )
-                        self._bt_dirty = False
-                        self._note_phase(
-                            "dispatch", time.perf_counter() - _disp_t0
-                        )
-                    _dec_t0 = time.perf_counter()
-                    phases["schedule"] = (
-                        _dec_t0 - t0 - phases.get("dispatch", 0.0)
-                    )
-                    if self._spec and self._spec_pick():
+                    elif self._spec and self._spec_pick():
                         decode_mode = "spec"
                         if self._draft:
                             proposals, self._dk, self._dv = (
@@ -3174,18 +3206,6 @@ class Engine:
                                 self._draft_params, self._dk, self._dv,
                                 inputs, pre_positions,
                             )
-                else:
-                    _dec_t0 = time.perf_counter()
-                    toks_seq, self.cache.k, self.cache.v, self._state = (
-                        self._decode_jit(
-                            self.params, self.cache.k, self.cache.v,
-                            self._state, self._lora,
-                        )
-                    )
-                phases["decode"] = (
-                    phases.get("decode", 0.0)
-                    + (time.perf_counter() - _dec_t0)
-                )
                 self._steps += 1
                 is_spec = isinstance(toks_seq, tuple)
                 chunk_len = 0 if is_spec else int(toks_seq.shape[0])
@@ -3240,7 +3260,7 @@ class Engine:
                     "tokens": len(emitted),
                     "duration_s": step_s,
                 }
-            self._phase_scratch = None
+            self.profiler.end_step()
             # Record only steps that DID something — an idle poll's
             # all-zero timeline would just dilute the ring. A dispatch-
             # only step (overlap holding its first chunk) counts.
@@ -3258,17 +3278,20 @@ class Engine:
                 )
             return emitted
 
-    def _reap_inflight_locked(self) -> list[StepEvent]:
+    def _reap_inflight_locked(
+        self, barrier: str = "external"
+    ) -> list[StepEvent]:
         """Reap the dispatched-but-unreaped chunk NOW (caller holds the
         engine lock). The conservative barrier behind every mutation that
         must observe the chunk's tokens or slot frees: pending
         admissions, cancel, drain, handoff export/import, prefix-page
-        export/import, speculation windows. Returns the chunk's events."""
+        export/import, speculation windows. `barrier` names the cause on
+        the `step.reap` span. Returns the chunk's events."""
         inflight = self._inflight
         if inflight is None:
             return []
         self._inflight = None
-        return self._process_chunk(inflight)
+        return self._process_chunk(inflight, barrier)
 
     def _barrier_locked(self) -> None:
         """Barrier for callers OUTSIDE step() (cancel/drain/handoff/
@@ -3288,112 +3311,110 @@ class Engine:
             return None
         return {"dispatched_at": inflight[3]}
 
-    def _process_chunk(self, inflight: tuple) -> list[StepEvent]:
+    def _process_chunk(
+        self, inflight: tuple, barrier: str = "none"
+    ) -> list[StepEvent]:
+        """Reap one dispatched chunk: wait for it, read its tokens back
+        and emit them. `barrier` is what forced the reap ahead of the
+        next dispatch (admission | seq_cap | spec | external), "none" for
+        the ordinary reap behind it."""
         toks_seq, chunk_slots = inflight[0], inflight[1]
-        if isinstance(toks_seq, tuple) and toks_seq[0] == "spec":
-            return self._process_spec(toks_seq[1], toks_seq[2], chunk_slots)
+        span = self.profiler.span
         cols = [slot for slot, req in chunk_slots if not req.done]
-        if not cols:
-            return []  # every rider cancelled since dispatch — no transfer
-        col_of = None
-        if len(cols) < int(toks_seq.shape[1]):
-            # Slice to the ACTIVE rows on-device before the host
-            # transfer: the decode chunk is a padded [chunk, B] buffer
-            # and fetching dead columns ships chunk*(B-A) junk tokens
-            # per step. The gather is a dependent device op, so timing
-            # block_until_ready on its output still measures the chunk's
-            # compute wait.
-            toks_seq = jnp.take(
-                toks_seq, jnp.asarray(cols, jnp.int32), axis=1
-            )
-            col_of = {slot: i for i, slot in enumerate(cols)}
-        _wait_t0 = time.perf_counter()
-        toks_seq = jax.block_until_ready(toks_seq)
-        # Device compute the host could NOT hide: ~the whole device step
-        # in the synchronous loop, →0 under perfect overlap.
-        self._note_phase("overlap_idle", time.perf_counter() - _wait_t0)
-        _sync_t0 = time.perf_counter()
-        toks_seq = np.asarray(jax.device_get(toks_seq))  # [chunk, A]
-        self._note_phase("readback", time.perf_counter() - _sync_t0)
-        _sample_t0 = time.perf_counter()
-        emitted: list[StepEvent] = []
-        for k in range(toks_seq.shape[0]):
-            # One timestamp per fused decode step: its tokens became
-            # host-visible together, so intra-step ITL is genuinely ~0 and
-            # the first token after a chunk boundary carries the gap.
-            now = _now()
-            for slot, req in chunk_slots:
-                if req.done:
-                    continue  # surplus chunk tokens discarded
-                tok = int(
-                    toks_seq[k, slot if col_of is None else col_of[slot]]
+        with span(
+            "step.reap", rows=len(cols), chunk=inflight[2], barrier=barrier
+        ):
+            if isinstance(toks_seq, tuple) and toks_seq[0] == "spec":
+                return self._process_spec(
+                    toks_seq[1], toks_seq[2], chunk_slots
                 )
-                if req.t_prev_token:
-                    self._timing.append(
-                        ("itl", max(0.0, now - req.t_prev_token),
-                         f"rid-{req.rid}")
-                    )
-                req.t_prev_token = now
-                req.out_tokens.append(tok)
-                req.position += 1
-                req.last_token = tok
-                finished = self._check_stop(req)
-                emitted.append(
-                    StepEvent(req.rid, tok, finished, req.finish_reason)
+            if not cols:
+                return []  # every rider cancelled since dispatch — no transfer
+            col_of = None
+            if len(cols) < int(toks_seq.shape[1]):
+                # Slice to the ACTIVE rows on-device before the host
+                # transfer: the decode chunk is a padded [chunk, B] buffer
+                # and fetching dead columns ships chunk*(B-A) junk tokens
+                # per step. The gather is a dependent device op, so timing
+                # block_until_ready on its output still measures the
+                # chunk's compute wait.
+                toks_seq = jnp.take(
+                    toks_seq, jnp.asarray(cols, jnp.int32), axis=1
                 )
-                if finished:
-                    self._release(req)
-        self._note_phase("sample", time.perf_counter() - _sample_t0)
-        return emitted
+                col_of = {slot: i for i, slot in enumerate(cols)}
+            # Device compute the host could NOT hide: ~the whole device
+            # step in the synchronous loop, →0 under perfect overlap.
+            with span("step.overlap_idle"):
+                toks_seq = jax.block_until_ready(toks_seq)
+            with span("step.readback"):
+                toks_seq = np.asarray(jax.device_get(toks_seq))  # [chunk, A]
+            with span("step.sample"):
+                emitted: list[StepEvent] = []
+                for k in range(toks_seq.shape[0]):
+                    # One timestamp per fused decode step: its tokens
+                    # became host-visible together, so intra-step ITL is
+                    # genuinely ~0 and the first token after a chunk
+                    # boundary carries the gap.
+                    now = _now()
+                    for slot, req in chunk_slots:
+                        if req.done:
+                            continue  # surplus chunk tokens discarded
+                        self._emit_token(
+                            req,
+                            int(toks_seq[
+                                k, slot if col_of is None else col_of[slot]
+                            ]),
+                            now,
+                            emitted,
+                        )
+            return emitted
 
-    def _note_phase(self, phase: str, seconds: float) -> None:
-        """Accumulate a phase duration into the CURRENT step's timeline
-        (no-op outside step(); always under the engine lock)."""
-        ph = self._phase_scratch
-        if ph is not None:
-            ph[phase] = ph.get(phase, 0.0) + seconds
+    def _emit_token(
+        self, req: _Request, tok: int, now: float, emitted: list[StepEvent]
+    ) -> bool:
+        """Append one decoded token to its request and to `emitted`;
+        releases the slot and returns True when it finished the request."""
+        if req.t_prev_token:
+            self._timing.append(
+                ("itl", max(0.0, now - req.t_prev_token), f"rid-{req.rid}")
+            )
+        req.t_prev_token = now
+        req.out_tokens.append(tok)
+        req.position += 1
+        req.last_token = tok
+        finished = self._check_stop(req)
+        emitted.append(StepEvent(req.rid, tok, finished, req.finish_reason))
+        if finished:
+            self._release(req)
+        return finished
 
     def _process_spec(
         self, choices, n_emit, chunk_slots
     ) -> list[StepEvent]:
         """Emit each slot's accepted+corrected tokens (1..γ+1 per step).
         A stop mid-window discards the remainder, like chunk surplus."""
-        _sync_t0 = time.perf_counter()
+        span = self.profiler.span
         # ONE fused transfer for both outputs: two sequential device_get
         # calls would pay the host round trip twice per verify step and
         # charge readback for both (a profiler test pins this to one).
-        choices, n_emit = jax.device_get((choices, n_emit))
-        choices = np.asarray(choices)  # [B, γ+1]
-        n_emit = np.asarray(n_emit)  # [B]
-        self._note_phase("readback", time.perf_counter() - _sync_t0)
-        _sample_t0 = time.perf_counter()
-        emitted: list[StepEvent] = []
-        now = _now()  # one verify forward produced the whole window
-        for slot, req in chunk_slots:
-            if req.done:
-                continue
-            self.spec_stats["windows"] += 1
-            self.spec_stats["proposed"] += self._spec
-            self.spec_stats["accepted"] += int(n_emit[slot]) - 1
-            for j in range(int(n_emit[slot])):
-                tok = int(choices[slot, j])
-                if req.t_prev_token:
-                    self._timing.append(
-                        ("itl", max(0.0, now - req.t_prev_token),
-                         f"rid-{req.rid}")
-                    )
-                req.t_prev_token = now
-                req.out_tokens.append(tok)
-                req.position += 1
-                req.last_token = tok
-                finished = self._check_stop(req)
-                emitted.append(
-                    StepEvent(req.rid, tok, finished, req.finish_reason)
-                )
-                if finished:
-                    self._release(req)
-                    break
-        self._note_phase("sample", time.perf_counter() - _sample_t0)
+        with span("step.readback"):
+            choices, n_emit = jax.device_get((choices, n_emit))
+            choices = np.asarray(choices)  # [B, γ+1]
+            n_emit = np.asarray(n_emit)  # [B]
+        with span("step.sample"):
+            emitted: list[StepEvent] = []
+            now = _now()  # one verify forward produced the whole window
+            for slot, req in chunk_slots:
+                if req.done:
+                    continue
+                self.spec_stats["windows"] += 1
+                self.spec_stats["proposed"] += self._spec
+                self.spec_stats["accepted"] += int(n_emit[slot]) - 1
+                for j in range(int(n_emit[slot])):
+                    if self._emit_token(
+                        req, int(choices[slot, j]), now, emitted
+                    ):
+                        break
         return emitted
 
     def _build_proposals(self) -> np.ndarray:

@@ -47,6 +47,7 @@ class SamplingParams:
 MAX_TOP_K = 64
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jnp.ndarray,  # [B, V] float32
     seeds: jnp.ndarray,  # [B] uint32 per-request seeds

@@ -73,6 +73,22 @@ ITL_BUCKETS_S = (
 )
 
 
+class _EventQueue(queue.Queue):
+    """A stream's queue of StepEvents that remembers when each was handed
+    over: after `get()` returns an event, `handed_at` is the
+    `time.perf_counter()` of its `put()`. One consumer per queue (the
+    request's handler thread), which is who reads `handed_at`."""
+
+    handed_at = 0.0
+
+    def _put(self, ev):  # under the queue's mutex, like the base class's
+        self.queue.append((ev, time.perf_counter()))
+
+    def _get(self):
+        ev, self.handed_at = self.queue.popleft()
+        return ev
+
+
 class EngineMetrics:
     def __init__(self):
         self.registry = Registry()
@@ -226,12 +242,77 @@ class EngineMetrics:
             self.registry,
             buckets=REQUEST_LATENCY_BUCKETS_S,
         )
+        # -- admission, the serve loop's gaps and SSE emission: host time
+        # counted where it happens (the spans of the same names are on a
+        # /v1/profile trace; docs/concepts/observability.md) --------------
+        self.admit_host = Histogram(
+            "kubeai_engine_admit_host_seconds",
+            "Host time of one admission device call made by Engine.step "
+            "(the `admit.host` spans): scheduler pops, page grants, input "
+            "staging, uploads and dispatch, and the bookkeeping of the "
+            "admitted once their first tokens are back.",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
+        self.admit_wait = Histogram(
+            "kubeai_engine_admit_wait_seconds",
+            "Time one admission device call kept the engine thread "
+            "blocked on its sampled first tokens (`admit.wait`): whatever "
+            "the device still had queued, then the prefill itself.",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
+        self.admit_calls = Counter(
+            "kubeai_engine_admit_calls_total",
+            "Admission device calls made by Engine.step (one fused "
+            "same-bucket batch, one chunked prompt or one prefix-cache "
+            "hit each).",
+            self.registry,
+        )
+        self.prefill_tokens = Counter(
+            "kubeai_engine_prefill_tokens_total",
+            "Token positions computed by admission calls (label `kind`: "
+            "useful = prompt tokens that needed computing, pad = the rest "
+            "of the padded shape that ran: admit batch rounded up to a "
+            "power of two times the bucket, or whole prefill chunks).",
+            self.registry,
+        )
+        self.loop_gap = Histogram(
+            "kubeai_engine_loop_gap_seconds",
+            "Time the serve loop spends between two Engine.step calls on "
+            "the thread that drives the device, per pass that had work "
+            "(label `part`: fanout = handing the step's events to the "
+            "stream queues, sync = moving the engine's records into this "
+            "registry).",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
+        self.emit_busy = Histogram(
+            "kubeai_engine_emit_busy_seconds",
+            "Busy time of a request's handler thread per burst of events "
+            "(`http.emit`): from its queue handing it an event until the "
+            "queue is empty again — detokenize, stop strings, JSON, the "
+            "chunked write and its flush. Handler threads share the GIL "
+            "with the engine thread.",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
+        self.emit_lag = Histogram(
+            "kubeai_engine_emit_lag_seconds",
+            "From the serve loop handing an event to a stream's queue to "
+            "the handler having flushed (or, unary, decoded) what it "
+            "produced; one observation per event.",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
         self._timing_hist = {
             "queue_wait": self.queue_wait,
             "prefill": self.prefill,
             "ttft": self.ttft,
             "itl": self.itl,
             "e2e": self.e2e,
+            "admit_host": self.admit_host,
+            "admit_wait": self.admit_wait,
         }
         # -- per-decode-step engine-loop gauges ----------------------------
         self.batch_size = Gauge(
@@ -439,6 +520,16 @@ class EngineMetrics:
                 )
             )
         inner = getattr(engine, "inner", engine)  # LockstepEngine proxies
+        astats = getattr(inner, "admit_stats", None)
+        if astats:
+            useful = astats["useful_tokens"]
+            for counter, total, labels in (
+                (self.admit_calls, astats["calls"], {}),
+                (self.prefill_tokens, useful, {"kind": "useful"}),
+                (self.prefill_tokens, astats["padded_tokens"] - useful,
+                 {"kind": "pad"}),
+            ):
+                counter.inc(max(0.0, total - counter.get(**labels)), **labels)
         dstats = getattr(inner, "disagg_stats", None)
         if dstats:
             for direction, count_key, bytes_key in (
@@ -635,8 +726,15 @@ class EngineServer:
         self._adapter_sources: dict[str, str] = {}
         self.max_queue = max_queue
         self.request_timeout = request_timeout
-        self._subscribers: dict[int, queue.Queue] = {}
+        self._subscribers: dict[int, _EventQueue] = {}
         self._sub_lock = threading.Lock()
+        # The serve loop's and the handlers' host intervals go through the
+        # engine's span helper, so they land in the same /v1/profile trace
+        # as the step's (an engine stand-in without one gets an inert one).
+        from kubeai_tpu.fleet.profiler import StepProfiler
+
+        prof = getattr(getattr(engine, "inner", engine), "profiler", None)
+        self._span = (prof or StepProfiler()).span
         self._stop = threading.Event()
         self._work = threading.Event()
         # Graceful drain (SIGTERM / POST /v1/drain): refuse new work with
@@ -886,17 +984,26 @@ class EngineServer:
                     self._work.wait(timeout=0.01)
                     self._work.clear()
                     continue
-                for ev in self.engine.step():
-                    with self._sub_lock:
-                        q = self._subscribers.get(ev.rid)
-                    if q is not None:
-                        q.put(ev)
+                events = self.engine.step()
+                # What follows runs between two steps on the thread that
+                # drives the device: every second of it is a second the
+                # next dispatch waits. `events` is the tokens the step
+                # emitted, on the trace's clock.
+                with self._span("serve.fanout", events=len(events)) as fan:
+                    for ev in events:
+                        with self._sub_lock:
+                            q = self._subscribers.get(ev.rid)
+                        if q is not None:
+                            q.put(ev)
                 # Per-decode-step telemetry: drain the engine's latency
                 # records into histograms and refresh the occupancy/KV
                 # gauges while they are live (a scrape between steps then
                 # sees the batch as it ran, not as it idles).
-                self.metrics.sync_engine(self.engine)
-                self._last_progress = time.monotonic()
+                with self._span("serve.sync") as sync:
+                    self.metrics.sync_engine(self.engine)
+                    self.metrics.loop_gap.observe(fan.seconds, part="fanout")
+                    self._last_progress = time.monotonic()
+                self.metrics.loop_gap.observe(sync.seconds, part="sync")
             except Exception:
                 # A dead serving loop must flip /health so the liveness
                 # probe restarts the Pod (the blocking LB then stops
@@ -1352,10 +1459,10 @@ class EngineServer:
         # extra prefills are mostly free.
         import dataclasses as _dc
 
-        reqs: list[tuple[int, queue.Queue, SamplingParams]] = []
+        reqs: list[tuple[int, _EventQueue, SamplingParams]] = []
         try:
             for i in range(n):
-                sub_i: queue.Queue = queue.Queue()
+                sub_i = _EventQueue()
                 sp_i = (
                     sp if i == 0 or sp.seed is None
                     else _dc.replace(sp, seed=sp.seed + i)
@@ -1956,7 +2063,7 @@ class EngineServer:
             seed=handoff.seed,
             stop=tuple(handoff.stop),
         )
-        sub: queue.Queue = queue.Queue()
+        sub = _EventQueue()
 
         def register(rid: int) -> None:
             with self._sub_lock:
@@ -2052,9 +2159,11 @@ class EngineServer:
         else:
             emitted_len = 0
         finish = "length"
+        stopped = None  # the result, once a stop string ended the request
         if deadline is None:
             deadline = time.monotonic() + self.request_timeout
-        while True:
+        done = False
+        while not done:
             try:
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
@@ -2066,40 +2175,62 @@ class EngineServer:
                 self.engine.cancel(rid)
                 finish = "timeout"
                 break
-            if ev.token < 0:
-                # Drain-kill sentinel: the drain budget expired; end this
-                # stream cleanly with whatever was generated so far.
-                self.engine.cancel(rid)
-                finish = "timeout"
-                break
-            tokens.append(ev.token)
-            self.metrics.generated_tokens.inc()
-            text = self.tokenizer.decode(tokens)
-            # Stop strings act on detokenized text (engine core is
-            # token-space only; see sampling.SamplingParams docstring).
-            stop_hit = None
-            for s in sp.stop:
-                idx = text.find(s, max(0, emitted_len - len(s)))
-                if idx != -1:
-                    stop_hit = idx
-                    break
-            if stop_hit is not None:
-                if on_delta and stop_hit > emitted_len:
-                    on_delta(text[emitted_len:stop_hit],
-                             tokens[sent_tokens:])
-                    sent_tokens = len(tokens)
-                self.engine.cancel(rid)
-                return text[:stop_hit], "stop", len(tokens)
-            if on_delta and len(text) > emitted_len:
-                # Hold back a partial UTF-8 replacement char at the tail.
-                safe = text[:-1] if text.endswith("�") else text
-                if len(safe) > emitted_len:
-                    on_delta(safe[emitted_len:], tokens[sent_tokens:])
-                    sent_tokens = len(tokens)
-                    emitted_len = len(safe)
-            if ev.finished:
-                finish = ev.finish_reason or "stop"
-                break
+            # One burst: from the queue handing over an event until it is
+            # empty again (a decode chunk's tokens arrive together).
+            with self._span("http.emit", rid=rid) as emit:
+                n_events = 0
+                while True:
+                    n_events += 1
+                    if ev.token < 0:
+                        # Drain-kill sentinel: the drain budget expired;
+                        # end this stream cleanly with whatever was
+                        # generated so far.
+                        self.engine.cancel(rid)
+                        finish, done = "timeout", True
+                        break
+                    tokens.append(ev.token)
+                    self.metrics.generated_tokens.inc()
+                    text = self.tokenizer.decode(tokens)
+                    # Stop strings act on detokenized text (engine core is
+                    # token-space only; see sampling.SamplingParams
+                    # docstring).
+                    stop_hit = None
+                    for s in sp.stop:
+                        idx = text.find(s, max(0, emitted_len - len(s)))
+                        if idx != -1:
+                            stop_hit = idx
+                            break
+                    if stop_hit is not None:
+                        if on_delta and stop_hit > emitted_len:
+                            on_delta(text[emitted_len:stop_hit],
+                                     tokens[sent_tokens:])
+                            sent_tokens = len(tokens)
+                        self.engine.cancel(rid)
+                        stopped = (text[:stop_hit], "stop", len(tokens))
+                        done = True
+                    elif on_delta and len(text) > emitted_len:
+                        # Hold back a partial UTF-8 replacement char at
+                        # the tail.
+                        safe = text[:-1] if text.endswith("�") else text
+                        if len(safe) > emitted_len:
+                            on_delta(safe[emitted_len:], tokens[sent_tokens:])
+                            sent_tokens = len(tokens)
+                            emitted_len = len(safe)
+                    self.metrics.emit_lag.observe(
+                        time.perf_counter() - sub.handed_at
+                    )
+                    if ev.finished and not done:
+                        finish, done = ev.finish_reason or "stop", True
+                    if done:
+                        break
+                    try:
+                        ev = sub.get_nowait()
+                    except queue.Empty:
+                        break
+                emit.note(events=n_events)
+            self.metrics.emit_busy.observe(emit.seconds)
+        if stopped is not None:
+            return stopped
         text = self.tokenizer.decode(tokens)
         if on_delta and len(text) > emitted_len:
             on_delta(text[emitted_len:], tokens[sent_tokens:])

@@ -32,11 +32,20 @@ metrics registry from the hot path (same discipline as `Engine._timing`).
 The serve loop drains pending observations into the per-phase histogram
 (`kubeai_engine_step_phase_seconds`), and a bounded ring of recent step
 records backs `POST /v1/profile` on the engine server.
+
+`StepProfiler.span` is the one way a host interval is recorded. A span
+named `step.<phase>` adds its duration to that phase of the step that is
+open; every span also opens the annotation the profiler was built with
+(the engine hands it `jax.profiler.TraceAnnotation`, so this module needs
+no JAX), which puts the interval with its attributes on the clock of a
+device trace and is inert while no profiler session is open. Spans that
+are no phase (`serve.step`, `step.reap`, `step.admit`, `admit.host`,
+`admit.wait`, `serve.fanout`, `serve.sync`, `http.emit`, `kv.export`,
+`kv.import`) exist only in such a trace; docs/concepts/observability.md has the table.
 """
 
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from collections import deque
@@ -46,6 +55,41 @@ PHASES = (
     "schedule", "prefill", "decode", "dispatch", "overlap_idle",
     "readback", "sample", "kv_transfer",
 )
+_PHASE_OF_SPAN = {"step." + p: p for p in PHASES}
+
+
+class Span:
+    """One host interval: `with profiler.span(name, **attrs) as sp`.
+    `sp.seconds` holds the duration once the block has ended, also when
+    it ended in an exception."""
+
+    __slots__ = ("_prof", "_phase", "_ann", "_t0", "seconds")
+
+    def __init__(self, prof: "StepProfiler", name: str, attrs: dict):
+        self._prof = prof
+        self._phase = _PHASE_OF_SPAN.get(name)
+        self._ann = prof._annotate(name, **attrs) if prof._annotate else None
+        self.seconds = 0.0
+
+    def note(self, **attrs) -> None:
+        """Attributes known only inside the block (an admission's batch
+        is built after its span opens)."""
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        phases = self._prof._open_step
+        if self._phase is not None and phases is not None:
+            phases[self._phase] = phases.get(self._phase, 0.0) + self.seconds
 
 
 class StepProfiler:
@@ -53,26 +97,37 @@ class StepProfiler:
     (phase, seconds) observations for histogram export. Thread-safe; all
     methods are cheap enough for the engine lock's critical section."""
 
-    def __init__(self, maxlen: int = 256, wall=time.time):
+    def __init__(self, maxlen: int = 256, wall=time.time, annotate=None):
         self._cond = threading.Condition()
         self._ring: deque[dict] = deque(maxlen=maxlen)
         self._pending: list[tuple[str, float]] = []
         self._wall = wall
+        # `annotate(name, **attrs)` -> a context manager with
+        # `set_metadata(**attrs)`: jax.profiler.TraceAnnotation, or a fake.
+        self._annotate = annotate
+        # The phases of the step that is open, between begin_step and
+        # end_step. Only the thread inside Engine.step (under the
+        # engine lock) opens phase spans, so it needs no lock of its own.
+        self._open_step: dict[str, float] | None = None
         self.steps_completed = 0
+
+    def span(self, name: str, **attrs) -> Span:
+        return Span(self, name, attrs)
+
+    def begin_step(self) -> dict[str, float]:
+        """Open a step: until `end_step`, `step.<phase>` spans add to the
+        returned dict. Outside a step they are trace-only."""
+        self._open_step = {}
+        return self._open_step
+
+    def end_step(self) -> None:
+        self._open_step = None
 
     def observe(self, phase: str, seconds: float) -> None:
         """One standalone phase observation (e.g. a KV handoff transfer
         that happens outside the step loop)."""
         with self._cond:
             self._pending.append((phase, float(seconds)))
-
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.observe(name, time.perf_counter() - t0)
 
     def observe_step(
         self,

@@ -41,6 +41,7 @@ from kubeai_tpu.engine.quantization import dequantize as _w
 from kubeai_tpu.parallel import sharding as sh
 
 
+@jax.named_scope("prefill_attention")
 def _prefill_attention(q, k, v):
     """Aligned buckets of 256 tokens and up take the Pallas flash kernel
     wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
@@ -187,6 +188,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array | None = None) -> dict:
     return params
 
 
+@jax.named_scope("mlp")
 def _mlp(x, gate, up, down):
     return jnp.einsum(
         "bsm,me->bse", jax.nn.silu(jnp.einsum("bse,em->bsm", x, _w(gate)))
@@ -338,10 +340,11 @@ def prefill(
     idx = jnp.clip(lengths - 1, 0, S - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]  # [B, E]
     # bf16 matmul, fp32 accumulation: MXU-native, no fp32 weight copy.
-    logits = jnp.einsum(
-        "be,ve->bv", last, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "be,ve->bv", last, params["lm_head"],
+            preferred_element_type=jnp.float32,
+        )
     return logits, k_all, v_all
 
 
@@ -416,6 +419,7 @@ def decode_step(
     return logits, k_cache, v_cache
 
 
+@jax.named_scope("qkv")
 def _decode_layer_qkv(x, lp, lor, cfg, inv_freq, msc, pos1, lora_idx):
     """Shared decode-layer front half: norm, QKV projection (+bias/LoRA),
     rope. Returns (q [B,H,D], k [B,KVH,D], v [B,KVH,D], proj) where proj
@@ -445,6 +449,7 @@ def _decode_layer_qkv(x, lp, lor, cfg, inv_freq, msc, pos1, lora_idx):
     return q, k, v[:, 0], proj
 
 
+@jax.named_scope("layer_finish")
 def _decode_layer_finish(x, attn, lp, proj, cfg):
     """Shared decode-layer back half: output projection, residual, MLP."""
     B = x.shape[0]
@@ -478,8 +483,10 @@ def _paged_decode_layer(
     q, k, v, proj = _decode_layer_qkv(
         x, lp, lor, cfg, inv_freq, msc, positions[:, None], lora_idx
     )
-    kp, vp = scatter_decode_token(kp, vp, k, v, page_ids, offsets)
-    attn = paged_decode_attention(q, kp, vp, block_tables, lengths)
+    with jax.named_scope("kv_page_write"):
+        kp, vp = scatter_decode_token(kp, vp, k, v, page_ids, offsets)
+    with jax.named_scope("paged_attention"):
+        attn = paged_decode_attention(q, kp, vp, block_tables, lengths)
     x = _decode_layer_finish(x, attn, lp, proj, cfg)
     return x, (kp, vp)
 
@@ -583,10 +590,11 @@ def decode_step_paged(
         )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = jnp.einsum(
-        "be,ve->bv", x, params["lm_head"],
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "be,ve->bv", x, params["lm_head"],
+            preferred_element_type=jnp.float32,
+        )
     return logits, k_pages, v_pages
 
 

@@ -141,6 +141,7 @@ def init_params(cfg: MixtralConfig, key: jax.Array | None = None) -> dict:
     }
 
 
+@jax.named_scope("moe_ffn")
 def _moe_ffn(x, lp, cfg):
     """x: [B, S, E] (or [B, E] for decode via S=1 squeeze by caller).
 
@@ -148,16 +149,18 @@ def _moe_ffn(x, lp, cfg):
     all experts computed batched over the (sharded) expert axis, combine
     weighted by the routing probabilities.
     """
-    router_logits = jnp.einsum(
-        "bse,ex->bsx", x, lp["router"]
-    ).astype(jnp.float32)  # [B, S, X]
-    topv, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
-    probs = jax.nn.softmax(topv, axis=-1)  # normalize over selected only
-    # Scatter the top-k probabilities back to a dense [B, S, X] weight map.
-    weights = jnp.zeros_like(router_logits)
-    b_idx = jnp.arange(router_logits.shape[0])[:, None, None]
-    s_idx = jnp.arange(router_logits.shape[1])[None, :, None]
-    weights = weights.at[b_idx, s_idx, topi].set(probs)
+    with jax.named_scope("moe_router"):
+        router_logits = jnp.einsum(
+            "bse,ex->bsx", x, lp["router"]
+        ).astype(jnp.float32)  # [B, S, X]
+        topv, topi = jax.lax.top_k(router_logits, cfg.num_experts_per_tok)
+        probs = jax.nn.softmax(topv, axis=-1)  # normalize over selected only
+        # Scatter the top-k probabilities back to a dense [B, S, X] weight
+        # map.
+        weights = jnp.zeros_like(router_logits)
+        b_idx = jnp.arange(router_logits.shape[0])[:, None, None]
+        s_idx = jnp.arange(router_logits.shape[1])[None, :, None]
+        weights = weights.at[b_idx, s_idx, topi].set(probs)
 
     # All experts, batched einsum over the expert axis (sharded -> each
     # device computes its local experts; XLA psums the combine).
@@ -272,6 +275,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     page_ids, offsets = token_page_coords(block_tables, positions, page_size)
     lengths = positions + 1
 
+    @jax.named_scope("qkv")
     def layer_qkv(x, lp):
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
         q = jnp.einsum("be,eh->bh", h, lp["wq"]).reshape(B, 1, H, D)
@@ -281,6 +285,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
         k = apply_rope(k, pos1, inv_freq)[:, 0]
         return q, k, v[:, 0]
 
+    @jax.named_scope("layer_finish")
     def layer_finish(x, attn, lp):
         x = x + jnp.einsum("bh,he->be", attn.reshape(B, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
@@ -292,8 +297,12 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
             x, lp = carry, scanned["p"]
             kp, vp = scanned["kp"], scanned["vp"]
             q, k, v = layer_qkv(x, lp)
-            kp, vp = scatter_decode_token(kp, vp, k, v, page_ids, offsets)
-            attn = paged_decode_attention(q, kp, vp, block_tables, lengths)
+            with jax.named_scope("kv_page_write"):
+                kp, vp = scatter_decode_token(kp, vp, k, v, page_ids, offsets)
+            with jax.named_scope("paged_attention"):
+                attn = paged_decode_attention(
+                    q, kp, vp, block_tables, lengths
+                )
             return layer_finish(x, attn, lp), (kp, vp)
 
         x, (k_pages, v_pages) = jax.lax.scan(
@@ -323,9 +332,11 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
             page_ids[:, None], offsets[:, None],
         )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    logits = jnp.einsum(
-        "be,ve->bv", x, params["lm_head"], preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "be,ve->bv", x, params["lm_head"],
+            preferred_element_type=jnp.float32,
+        )
     return logits, k_pages, v_pages
 
 

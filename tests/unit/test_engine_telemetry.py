@@ -130,6 +130,90 @@ def test_metrics_exposition_has_nonzero_buckets_and_gauges(server):
         assert f"# TYPE {gauge} gauge" in text, gauge
 
 
+NEW_HISTOGRAMS = (
+    "kubeai_engine_admit_host_seconds",
+    "kubeai_engine_admit_wait_seconds",
+    "kubeai_engine_loop_gap_seconds",
+    "kubeai_engine_emit_busy_seconds",
+    "kubeai_engine_emit_lag_seconds",
+)
+NEW_COUNTERS = (
+    "kubeai_engine_admit_calls_total",
+    "kubeai_engine_prefill_tokens_total",
+)
+
+
+@pytest.fixture
+def streamed(server):
+    """One streamed request through the server, then a scrape."""
+    from kubeai_tpu.metrics.registry import parse_prometheus_text
+
+    n_tokens = 8
+    events = _stream_completion(
+        server.port,
+        {"model": "tiny", "prompt": "hello", "max_tokens": n_tokens,
+         "temperature": 0},
+    )
+    _, body = http_get(f"127.0.0.1:{server.port}", "/metrics")
+    return server, events, parse_prometheus_text(body.decode())
+
+
+@pytest.mark.parametrize("hist", NEW_HISTOGRAMS)
+def test_host_timeline_histograms_count_after_one_stream(streamed, hist):
+    server, _events, parsed = streamed
+    counts = {k: v for k, v in parsed.items() if k[0] == f"{hist}_count"}
+    assert counts and all(v > 0 for v in counts.values()), hist
+    sums = [v for k, v in parsed.items() if k[0] == f"{hist}_sum"]
+    assert all(v >= 0 for v in sums)
+    if hist == "kubeai_engine_loop_gap_seconds":
+        assert {dict(k[1])["part"] for k in counts} == {"fanout", "sync"}
+
+
+def test_host_timeline_counters_and_names_after_one_stream(streamed):
+    from kubeai_tpu.metrics.registry import lint_registry
+
+    server, events, parsed = streamed
+    m = server.metrics
+    assert parsed[("kubeai_engine_admit_calls_total", ())] == 1
+    useful = parsed[
+        ("kubeai_engine_prefill_tokens_total", (("kind", "useful"),))]
+    pad = parsed[("kubeai_engine_prefill_tokens_total", (("kind", "pad"),))]
+    assert useful == 5 and useful + pad == 16  # "hello" in the 16-bucket
+    # One wait and one host observation per admission call; they stay
+    # inside the step's prefill phase.
+    assert m.admit_wait.get() == m.admit_host.get() == 1
+    assert (m.admit_host.sum_for() + m.admit_wait.sum_for()
+            <= m.step_phase.sum_for(phase="prefill"))
+    # One lag observation per event the handler consumed, one busy
+    # observation per burst of them.
+    tokens = sum(len(e.get("token_ids", ())) for e in events)
+    assert m.emit_lag.get() == tokens == m.generated_tokens.get()
+    assert 1 <= m.emit_busy.get() <= tokens
+    assert m.emit_lag.sum_for() > 0
+    # The catalogue's rule: histograms end in _seconds, counters in _total.
+    assert lint_registry(m.registry) == []
+    names = {inst.name for inst in m.registry.metrics}
+    assert set(NEW_HISTOGRAMS + NEW_COUNTERS) <= names
+
+
+def test_event_queue_stamps_each_hand_over():
+    import time
+
+    from kubeai_tpu.engine.server import _EventQueue
+
+    q = _EventQueue()
+    t0 = time.perf_counter()
+    q.put("a")
+    q.put("b")
+    t1 = time.perf_counter()
+    assert not q.empty() and q.qsize() == 2
+    assert q.get(timeout=1) == "a"
+    first = q.handed_at
+    assert q.get_nowait() == "b"
+    assert t0 <= first <= q.handed_at <= t1
+    assert q.empty()
+
+
 def test_step_stats_and_kv_utilization_move_during_decode(server):
     """kv_utilization and last_step_stats reflect live decode state."""
     eng = server.engine

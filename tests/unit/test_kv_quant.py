@@ -477,7 +477,7 @@ def test_spec_verify_fuses_host_transfer(tiny, monkeypatch):
     calls = {"invocations": 0, "gets": 0, "syncs": 0, "depth": 0}
     orig_get = jax.device_get
     orig_spec = Engine._process_spec
-    orig_note = Engine._note_phase
+    orig_span = eng.profiler.span
 
     def counting_get(x):
         if calls["depth"]:
@@ -492,14 +492,14 @@ def test_spec_verify_fuses_host_transfer(tiny, monkeypatch):
         finally:
             calls["depth"] -= 1
 
-    def counting_note(self, phase, seconds):
-        if calls["depth"] and phase == "readback":
+    def counting_span(name, **attrs):
+        if calls["depth"] and name == "step.readback":
             calls["syncs"] += 1
-        return orig_note(self, phase, seconds)
+        return orig_span(name, **attrs)
 
     monkeypatch.setattr(jax, "device_get", counting_get)
     monkeypatch.setattr(Engine, "_process_spec", counting_spec)
-    monkeypatch.setattr(Engine, "_note_phase", counting_note)
+    monkeypatch.setattr(eng.profiler, "span", counting_span)
     # Repetitive prompt: prompt-lookup proposals get real acceptances.
     eng.add_request(
         TOK.encode("ab ab ab ab ab ab ab ab"),
