@@ -89,12 +89,6 @@ def _parse_args(argv=None):
         "only; slot = dense [slots, max_seq_len] reservation)",
     )
     ap.add_argument(
-        "--decode-kernel", default="", choices=["", "per_layer", "fused"],
-        help="paged decode attention layout ('' = auto: "
-        "$KUBEAI_TPU_DECODE_KERNEL, default per_layer; fused = "
-        "deferred-scatter kernel)",
-    )
-    ap.add_argument(
         "--uniform-prompts", action="store_true",
         help="all prompts exactly --prompt-len (default: mixed lengths in "
         "[prompt-len/4, prompt-len], the serving-realistic case where "
@@ -247,7 +241,6 @@ def main(argv=None) -> int:
             num_slots=args.slots,
             max_seq_len=args.max_seq_len,
             cache_mode=args.cache_mode,
-            decode_kernel=args.decode_kernel,
             speculate=args.speculate,
             spec_adaptive=args.spec_adaptive == "on",
             quantization=args.quantization,
@@ -473,7 +466,6 @@ def _measure_step_overlap(args, cfg, model_name) -> int:
                 num_slots=args.slots,
                 max_seq_len=args.max_seq_len,
                 cache_mode=args.cache_mode,
-                decode_kernel=args.decode_kernel,
                 quantization=args.quantization,
                 kv_dtype=args.kv_dtype,
                 decode_chunk=max(1, args.decode_chunk),
@@ -557,7 +549,7 @@ def _result_line(args, eng, model_name, toks_per_s):
         "metric": f"{model_name} decode throughput, continuous batching, "
         f"bs={args.slots}, {args.cache_mode} kv cache"
         + (
-            f" ({eng.decode_kernel} kernel)"
+            f" ({eng.kv_layout} layout)"
             if eng.cache_mode == "paged" else ""
         )
         + ", "

@@ -130,8 +130,8 @@ class EngineConfig:
     # (2D/(D+4), 1.94x at D=128) and halving every KV byte shipped by
     # disagg handoff, peer prefix fetch and objstore spill. Quantized
     # pools always use the reference attention path (the Pallas decode
-    # kernels are bf16-only) and do not compose with speculation, the
-    # fused decode kernel, or pipeline parallelism yet.
+    # kernels are bf16-only) inside the scatter-then-attend layout, and
+    # do not compose with speculation or pipeline parallelism yet.
     kv_dtype: str = ""
     # Decode steps fused into one device call (lax.scan). Amortizes host
     # dispatch. Tokens a request emits past its stop point within a chunk
@@ -141,11 +141,11 @@ class EngineConfig:
     # Weight-only quantization: "" (bf16) or "int8" (per-channel symmetric;
     # halves HBM weight traffic on the memory-bound decode path).
     quantization: str = ""
-    # Paged decode attention layout: "" = auto ($KUBEAI_TPU_DECODE_KERNEL,
-    # default "per_layer"), "per_layer" = scatter-then-attend inside the
-    # layer scan, "fused" = stacked-pool kernel with deferred scatter.
-    # Both compile and agree with their references on a TPU v5 lite
-    # (PR 21); which is faster is not measured (ROADMAP C3).
+    # Paged decode layout: "" = decided by the pool ("fused", the stacked
+    # pool read and written in place, for bf16; "per_layer",
+    # scatter-then-attend inside the layer scan, for int8). An explicit
+    # value is honoured: for tests and A/B runs, not for deployments
+    # (PERF.md section 6, PR 25; the field goes when ROADMAP C3 does).
     decode_kernel: str = ""
     # LoRA hot-swap: number of simultaneously loaded adapters (0 disables
     # the LoRA path entirely — no extra compute in the compiled graphs).
@@ -358,18 +358,18 @@ class Engine:
         elif cfg.cache_mode not in ("paged", "slot"):
             raise ValueError(f"unknown cache_mode {cfg.cache_mode!r}")
 
-        # Paged decode attention layout ("" = $KUBEAI_TPU_DECODE_KERNEL,
-        # default per_layer; "fused" is the deferred-scatter kernel).
-        from kubeai_tpu.ops.paged_attention import resolve_decode_kernel
-
-        self.decode_kernel = resolve_decode_kernel(cfg.decode_kernel)
-
         # KV quantization: validated here, materialized in the paged
         # branch below ({"q8", "scale"} pool leaves; ops/kv_quant.py).
         from kubeai_tpu.ops.kv_quant import resolve_kv_dtype
+        from kubeai_tpu.ops.paged_attention import resolve_decode_kernel
 
         self.kv_dtype = resolve_kv_dtype(cfg.kv_dtype)
         self._kv_quant = self.kv_dtype == "int8"
+        # Paged decode layout: decided by the pool's kind unless the
+        # config names one (int8 + "fused" is refused there).
+        self.decode_kernel = resolve_decode_kernel(
+            cfg.decode_kernel, quantized=self._kv_quant
+        )
         if self._kv_quant:
             if self.cache_mode != "paged":
                 raise ValueError(
@@ -380,12 +380,6 @@ class Engine:
                 raise ValueError(
                     "kv_dtype='int8' does not compose with speculative "
                     "decoding yet (the verify kernels read bf16 pools)"
-                )
-            if self.decode_kernel == "fused":
-                raise ValueError(
-                    "kv_dtype='int8' does not compose with "
-                    "decode_kernel='fused' (the fused kernel reads a "
-                    "stacked bf16 pool); use per_layer"
                 )
 
         # Pipeline parallelism: stage-local layers + KV over the pp mesh
@@ -428,6 +422,18 @@ class Engine:
                     f"pp_microbatches={m}"
                 )
             self._pp_microbatches = m
+
+        # The layout the compiled decode chunk has: "stacked" = the
+        # [NL, ...] pool read and written in place, "per_layer" = a
+        # layer's cache sliced out of the stack and written back (int8
+        # pools, pp stages, the slot cache). Fixed at compile time, so
+        # the step.decode span and /v1/state just name it.
+        self.kv_layout = (
+            "stacked"
+            if self.cache_mode == "paged" and self._pp == 1
+            and self.decode_kernel == "fused"
+            else "per_layer"
+        )
 
         # Overlapped stepping: resolve the tri-state knob against the
         # topology. pp > 1 already fills the device with microbatch ticks
@@ -2414,6 +2420,7 @@ class Engine:
             "quantized": self._kv_quant,
             "capacity_factor": factor,
             "slot_capacity": int(self.cfg.num_slots),
+            "kv_layout": self.kv_layout,
         }
         if self.cache_mode == "paged":
             info["num_pages"] = int(self._n_pages)
@@ -3137,7 +3144,7 @@ class Engine:
                                 jnp.asarray(self._bt_host), self._bt_sharding
                             )
                             self._bt_dirty = False
-                with span("step.decode"):
+                with span("step.decode", kv_layout=self.kv_layout):
                     if self.cache_mode != "paged":
                         toks_seq, self.cache.k, self.cache.v, self._state = (
                             self._decode_jit(

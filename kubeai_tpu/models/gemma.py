@@ -317,14 +317,14 @@ def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
 def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                       block_tables, lora=None, lora_idx=None, *,
                       attn_kernel=None):
-    """Paged decode (block tables). Attention layout per `attn_kernel`
-    (None = env default — see llama.decode_step_paged for the layouts:
-    "per_layer" scatter-then-attend with pools riding the scan is the
-    default; "fused" keeps pools outside the scan, the
-    new token rides as an extra attention column, and all layers' K/V
-    write back in one batched scatter). The per-layer sliding window
-    rides the scan, so Gemma-2's alternating local/global layers share
-    one compiled graph."""
+    """Paged decode (block tables). The attention layout follows the
+    pool as in llama.decode_step_paged: a bf16 pool stays stacked outside
+    the layer scan, is read in place by the layer-indexed kernel and
+    written by one batched scatter after it ("fused"); a quantized pool
+    takes scatter-then-attend inside the scan ("per_layer"). `attn_kernel`
+    names one explicitly. The per-layer sliding window rides the scan,
+    so Gemma-2's alternating local/global layers share one compiled
+    graph."""
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
         paged_decode_attention,
@@ -336,12 +336,9 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
 
     from kubeai_tpu.ops.kv_quant import is_quantized_kv, kv_pages_shape
 
-    attn_kernel = resolve_decode_kernel(attn_kernel)
-    if is_quantized_kv(k_pages) and attn_kernel != "per_layer":
-        raise ValueError(
-            "quantized KV pools require attn_kernel='per_layer' (the "
-            "fused kernel reads a raw bf16 pool)"
-        )
+    attn_kernel = resolve_decode_kernel(
+        attn_kernel, quantized=is_quantized_kv(k_pages)
+    )
     B = tokens.shape[0]
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     page_size = kv_pages_shape(k_pages)[2]

@@ -504,25 +504,24 @@ def decode_step_paged(
     *,
     attn_kernel: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Decode step against the PAGED cache. Two attention layouts,
-    selected by `attn_kernel` (None = $KUBEAI_TPU_DECODE_KERNEL, default
-    "per_layer"; see ops.paged_attention.resolve_decode_kernel):
+    """Decode step against the PAGED cache. The layout follows the pool
+    (ops.paged_attention.resolve_decode_kernel; `attn_kernel` names one
+    explicitly, for tests and A/B runs):
 
-    "per_layer" — scatter-then-attend inside the layer scan: the stacked
-    pools ride the scan as xs/ys and each layer runs the per-layer Pallas
-    kernel (paged_decode_attention).
+    "fused", every bf16 pool — the stacked [NL, ...] page pools stay
+    OUTSIDE the layer scan and are never sliced, copied or re-stacked:
+    the Pallas kernel reads a layer's resident pages straight from HBM
+    through a scalar-prefetched layer index, the new token's K/V is
+    folded in as an extra attention column (it is NOT in the pool yet),
+    and all layers' new rows are written in place by ONE batched scatter
+    after the scan. Per-step cache writes are O(NL * B) tokens and reads
+    only each slot's resident pages.
 
-    "fused" — the stacked [NL, ...] page pools stay OUTSIDE the layer scan
-    and are read by the fused Pallas kernel straight from HBM via a
-    scalar-prefetched layer index — the per-layer layout round-trips the
-    entire pool (GBs) through slice + re-stack every decode step and
-    materializes each slice to feed the opaque pallas_call. The new
-    token's K/V is folded in as an extra attention column (it is NOT in
-    the pool yet), collected per layer, and written back in ONE batched
-    scatter after the scan — per-step cache write traffic is O(NL * B)
-    tokens, and read traffic is only each slot's resident pages. Both
-    kernels agree with their references on the chip; their speed has not
-    been compared (ROADMAP C3).
+    "per_layer", a quantized pool — scatter-then-attend inside the layer
+    scan: the pools ride the scan as xs/ys (XLA slices a layer's pool out
+    of the stack and writes it back every layer, and copies the pool
+    once a step: PERF.md section 6, PR 25) and each layer attends through
+    paged_decode_attention, which has the only int8 path.
 
     Both layouts share _decode_layer_qkv/_decode_layer_finish, so the
     projection/LoRA/MLP math cannot drift between them."""
@@ -534,12 +533,9 @@ def decode_step_paged(
         token_page_coords,
     )
 
-    attn_kernel = resolve_decode_kernel(attn_kernel)
-    if is_quantized_kv(k_pages) and attn_kernel != "per_layer":
-        raise ValueError(
-            "quantized KV pools require attn_kernel='per_layer' (the "
-            "fused kernel reads a raw bf16 pool)"
-        )
+    attn_kernel = resolve_decode_kernel(
+        attn_kernel, quantized=is_quantized_kv(k_pages)
+    )
     inv_freq = jnp.asarray(
         rope_frequencies(
             cfg.head_size, cfg.rope_theta, cfg.rope_scaling,
@@ -574,20 +570,22 @@ def decode_step_paged(
             q, k, v, proj = _decode_layer_qkv(
                 x, lp, lor, cfg, inv_freq, msc, pos1, lora_idx
             )
-            attn = paged_decode_attention_fused(
-                q, k_pages, v_pages, k, v, block_tables, positions,
-                scanned["li"],
-            )
+            with jax.named_scope("paged_attention"):
+                attn = paged_decode_attention_fused(
+                    q, k_pages, v_pages, k, v, block_tables, positions,
+                    scanned["li"],
+                )
             x = _decode_layer_finish(x, attn, lp, proj, cfg)
             return x, (k, v)
 
         xs["li"] = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         x, (k_all, v_all) = jax.lax.scan(layer, x, xs)
         # One batched write for every layer's new token ([NL, B, KVH, D]).
-        k_pages, v_pages = batched_scatter_sequence(
-            k_pages, v_pages, k_all[:, :, None], v_all[:, :, None],
-            page_ids[:, None], offsets[:, None],
-        )
+        with jax.named_scope("kv_page_write"):
+            k_pages, v_pages = batched_scatter_sequence(
+                k_pages, v_pages, k_all[:, :, None], v_all[:, :, None],
+                page_ids[:, None], offsets[:, None],
+            )
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     with jax.named_scope("lm_head"):

@@ -244,11 +244,12 @@ def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
 def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                       block_tables, lora=None, lora_idx=None, *,
                       attn_kernel=None):
-    """Paged decode (block tables). Attention layout per `attn_kernel`
-    (None = env default — see llama.decode_step_paged: "per_layer"
-    scatter-then-attend with pools riding the scan, the default;
-    "fused" pools outside the scan, new token as an extra attention
-    column, one batched scatter after), MoE FFN unchanged."""
+    """Paged decode (block tables). The attention layout follows the
+    pool as in llama.decode_step_paged: a bf16 pool stays stacked outside
+    the layer scan, is read in place by the layer-indexed kernel and
+    written by one batched scatter after it ("fused"); a quantized pool
+    takes scatter-then-attend inside the scan ("per_layer"). `attn_kernel`
+    names one explicitly. MoE FFN unchanged."""
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
         paged_decode_attention,
@@ -260,12 +261,9 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
 
     from kubeai_tpu.ops.kv_quant import is_quantized_kv, kv_pages_shape
 
-    attn_kernel = resolve_decode_kernel(attn_kernel)
-    if is_quantized_kv(k_pages) and attn_kernel != "per_layer":
-        raise ValueError(
-            "quantized KV pools require attn_kernel='per_layer' (the "
-            "fused kernel reads a raw bf16 pool)"
-        )
+    attn_kernel = resolve_decode_kernel(
+        attn_kernel, quantized=is_quantized_kv(k_pages)
+    )
     B = tokens.shape[0]
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     page_size = kv_pages_shape(k_pages)[2]
@@ -314,10 +312,11 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
         def layer(carry, scanned):
             x, lp = carry, scanned["p"]
             q, k, v = layer_qkv(x, lp)
-            attn = paged_decode_attention_fused(
-                q, k_pages, v_pages, k, v, block_tables, positions,
-                scanned["li"],
-            )
+            with jax.named_scope("paged_attention"):
+                attn = paged_decode_attention_fused(
+                    q, k_pages, v_pages, k, v, block_tables, positions,
+                    scanned["li"],
+                )
             return layer_finish(x, attn, lp), (k, v)
 
         x, (k_all, v_all) = jax.lax.scan(
@@ -327,10 +326,11 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                 "li": jnp.arange(cfg.num_layers, dtype=jnp.int32),
             },
         )
-        k_pages, v_pages = batched_scatter_sequence(
-            k_pages, v_pages, k_all[:, :, None], v_all[:, :, None],
-            page_ids[:, None], offsets[:, None],
-        )
+        with jax.named_scope("kv_page_write"):
+            k_pages, v_pages = batched_scatter_sequence(
+                k_pages, v_pages, k_all[:, :, None], v_all[:, :, None],
+                page_ids[:, None], offsets[:, None],
+            )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     with jax.named_scope("lm_head"):
         logits = jnp.einsum(

@@ -37,7 +37,6 @@ live in the external image; charts/kubeai/values.yaml:45).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,28 +47,33 @@ from kubeai_tpu.ops import dispatch
 
 NEG_INF = -1e30
 
-# Which decode-attention layout model families use when the caller doesn't
-# say. "per_layer" = scatter-then-attend inside the layer scan through
-# paged_decode_attention. "fused" = stacked-pool kernel with a deferred
-# scatter (paged_decode_attention_fused). Both compile and agree with their
-# references on a TPU v5 lite (PR 21); which is faster has not been
-# measured — that A/B, and the loser's removal, is ROADMAP C3.
-DECODE_KERNEL_ENV = "KUBEAI_TPU_DECODE_KERNEL"
+# The two decode layouts of a paged pool (measured against each other on
+# the chip in PR 25: PERF.md section 6). "fused": the stacked [NL, ...] pool
+# is read in place by a layer-indexed kernel and written once after the layer
+# scan (paged_decode_attention_fused); what every bf16 pool takes.
+# "per_layer": scatter-then-attend on one layer's pool
+# (paged_decode_attention); what a quantized pool takes, since the Pallas
+# kernels read bf16 only.
 _DECODE_KERNELS = ("per_layer", "fused")
 
 
-def default_decode_kernel() -> str:
-    mode = os.environ.get(DECODE_KERNEL_ENV, "").strip().lower()
-    return mode if mode in _DECODE_KERNELS else "per_layer"
-
-
-def resolve_decode_kernel(requested: str | None) -> str:
-    """Validate an explicit kernel choice; None/"" defers to the env var."""
+def resolve_decode_kernel(
+    requested: str | None, *, quantized: bool = False
+) -> str:
+    """The decode layout for a pool: None/"" decides by the pool's kind
+    (`quantized` = a {"q8", "scale"} pool), an explicit choice is
+    validated and honoured."""
     if not requested:
-        return default_decode_kernel()
+        return "per_layer" if quantized else "fused"
     if requested not in _DECODE_KERNELS:
         raise ValueError(
             f"decode kernel {requested!r} not in {_DECODE_KERNELS}"
+        )
+    if quantized and requested == "fused":
+        raise ValueError(
+            "a quantized (int8) KV pool does not compose with "
+            "decode_kernel='fused' (the stacked kernel reads a raw bf16 "
+            "pool); leave it unset or use per_layer"
         )
     return requested
 
@@ -609,23 +613,25 @@ def paged_verify_attention(
     return out.reshape(b, spec_k, h, d)
 
 
-# ---- fused decode kernel (stacked pools, deferred scatter) -------------------
+# ---- stacked-pool decode kernel (pool read in place, deferred scatter) --------
 #
-# A second decode-step layout. Three costs in the scatter-then-attend layer
-# loop, counted from shapes (none timed on the chip yet):
+# The decode layout of every bf16 pool. Scatter-then-attend inside the layer
+# scan moves the pool instead of reading it (timed on a v5e in PR 25, PERF.md
+# section 6: more than half of the decode chunk's device time):
 #   1. lax.scan slices each layer's [P, page, KVH, D] pool out of the
-#      stacked array and re-stacks the updated slice — a full KV-pool
+#      stacked array and writes the updated slice back — a full KV-pool
 #      round-trip through HBM every decode step even though only B
-#      tokens/layer change.
+#      tokens/layer change — and the chunk scan that carries the pools
+#      copies each whole pool once a step.
 #   2. pallas_call is opaque to XLA, so the sliced operand MATERIALIZES
 #      (no fusion into the kernel).
-#   3. Grid (slots, pages) runs one small page DMA per step.
-# The fused kernel takes the FULL [NL, ...] pool plus a scalar-prefetched
-# layer index (the index map adds the layer offset — no slicing, no
+# This kernel takes the FULL [NL, ...] pool plus a scalar-prefetched layer
+# index (the index map adds the layer offset — no slicing, no
 # materialization), attends the NEW token as an explicit extra column
 # merged at finalize (so the pool stays read-only and the scatter defers
 # to ONE batched write after the layer scan), and DMAs a STRIP of pages
-# per grid step with the slot dimension megacore-parallel.
+# per grid step. Its jitted wrapper is named `_paged_pallas...` because the
+# benchmark's `paged_attn_ms` finds the custom call by that prefix.
 
 
 def _fused_attend_page(
@@ -747,9 +753,10 @@ def _fused_page_index(
     return layer_ref[0], page_id, 0, 0, 0
 
 
-# Pages fetched per grid step. 4 × 64-token pages ≈ 512 KB of K+V per
-# step at KVH=8/D=64/bf16 — enough DMA in flight to be bandwidth-bound
-# instead of latency-bound, without blowing VMEM.
+# Pages fetched per grid step: 4 x 64-token pages are 1 MB of K+V per step
+# at KVH=8/D=128/bf16. Not tuned on the chip: at 24 slots of short caches
+# the kernel's time goes to grid steps that find no live page (PERF.md
+# section 6, PR 25).
 FUSED_STRIP = 4
 
 
@@ -757,7 +764,7 @@ FUSED_STRIP = 4
     jax.jit,
     static_argnames=("scale", "logit_softcap", "interpret"),
 )
-def _paged_fused_pallas(
+def _paged_pallas_stacked(
     q,  # [B, KVH, G, D]
     k_pages,  # [NL, P, page, KVH, D] FULL stacked pool
     v_pages,
@@ -823,7 +830,7 @@ def _paged_fused_pallas(
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # Slots are independent (scratch re-inits at s == 0 per slot):
-            # split them across the two TensorCores.
+            # a chip with two TensorCores may split them (a v5e has one).
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -925,7 +932,7 @@ def paged_decode_attention_fused(
     _check_page_size(k_pages.shape[2])
     call = dispatch.over_kv_heads(
         functools.partial(
-            _paged_fused_pallas, scale=scale, logit_softcap=logit_softcap,
+            _paged_pallas_stacked, scale=scale, logit_softcap=logit_softcap,
             interpret=mode == "interpret",
         ),
         kvh, (1, 3, 3, 1, 1, None, None, None, None),

@@ -19,10 +19,10 @@ def _make(mode, **kw):
     return Engine("llama", CFG, PARAMS, cfg=EngineConfig(cache_mode=mode, **defaults))
 
 
-def _prompts(n, rng=None):
+def _prompts(n, rng=None, vocab=CFG.vocab_size):
     rng = rng or np.random.default_rng(42)
     return [
-        rng.integers(1, CFG.vocab_size, rng.integers(3, 40)).tolist()
+        rng.integers(1, vocab, rng.integers(3, 40)).tolist()
         for _ in range(n)
     ]
 
@@ -54,28 +54,76 @@ def test_slot_paged_equivalence_seeded_sampling():
     assert out_slot == out_paged
 
 
-@pytest.mark.slow
-def test_decode_kernel_selection_and_equivalence():
-    """Both paged attention layouts are selectable (EngineConfig and env
-    var) and emit identical greedy streams — the per-layer layout is the
-    default; the fused layout must match it exactly."""
-    prompts = _prompts(5)
-    sp = SamplingParams(temperature=0.0, max_tokens=12)
-    eng_pl = _make("paged", decode_kernel="per_layer")
-    eng_fused = _make("paged", decode_kernel="fused")
-    assert eng_pl.decode_kernel == "per_layer"
-    assert eng_fused.decode_kernel == "fused"
-    assert _make("paged").decode_kernel == "per_layer"  # auto default
-    assert eng_pl.generate(prompts, sp) == eng_fused.generate(prompts, sp)
+def _family_world(name):
+    """(family, tiny config, params): llama, mixtral (MoE FFN) and gemma-2
+    (sliding window on every other layer, attention and final softcap)."""
+    import dataclasses
+
+    from kubeai_tpu.models import gemma, mixtral
+
+    if name == "llama":
+        return "llama", CFG, PARAMS
+    if name == "mixtral":
+        cfg = mixtral.MixtralConfig.tiny()
+        return "mixtral", cfg, mixtral.init_params(cfg, jax.random.PRNGKey(1))
+    cfg = dataclasses.replace(
+        gemma.GemmaConfig.tiny(), sandwich_norms=True,
+        attn_logit_softcapping=50.0, final_logit_softcapping=30.0,
+        query_pre_attn_scalar=16.0, sliding_window=8,
+    )
+    return "gemma", cfg, gemma.init_params(cfg, jax.random.PRNGKey(2))
 
 
-def test_decode_kernel_env_override(monkeypatch):
-    monkeypatch.setenv("KUBEAI_TPU_DECODE_KERNEL", "fused")
-    assert _make("paged").decode_kernel == "fused"
-    monkeypatch.setenv("KUBEAI_TPU_DECODE_KERNEL", "bogus")
-    assert _make("paged").decode_kernel == "per_layer"
+@pytest.fixture(scope="module", params=["llama", "mixtral", "gemma2"])
+def layouts(request):
+    """One family's engines over the same weights: nothing set (the pool's
+    kind decides), and each layout named."""
+    family, cfg, params = _family_world(request.param)
+
+    def mk(**kw):
+        return Engine(family, cfg, params, cfg=EngineConfig(
+            num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4, **kw))
+
+    return {"unset": mk(), "per_layer": mk(decode_kernel="per_layer"),
+            "int8": mk(kv_dtype="int8"), "mk": mk,
+            "vocab": cfg.vocab_size}
+
+
+def test_layout_follows_the_pool(layouts):
+    """An unset option resolves by the pool's kind: a bf16 pool is read
+    and written in place (`stacked`), an int8 pool takes
+    scatter-then-attend; /v1/state's `kv_cache` block names it."""
+    unset, int8 = layouts["unset"], layouts["int8"]
+    assert (unset.decode_kernel, unset.kv_layout) == ("fused", "stacked")
+    assert (int8.decode_kernel, int8.kv_layout) == ("per_layer", "per_layer")
+    assert layouts["per_layer"].kv_layout == "per_layer"
+    assert unset.kv_cache_info()["kv_layout"] == "stacked"
+    assert int8.kv_cache_info()["kv_layout"] == "per_layer"
     with pytest.raises(ValueError):
-        _make("paged", decode_kernel="bogus")
+        layouts["mk"](decode_kernel="bogus")
+
+
+@pytest.mark.parametrize(
+    "sp",
+    [
+        SamplingParams(temperature=0.0, max_tokens=12),
+        SamplingParams(temperature=0.9, top_k=20, max_tokens=10, seed=123),
+    ],
+    ids=["greedy", "seeded-sampling"],
+)
+def test_stacked_layout_equals_scatter_then_attend(layouts, sp):
+    """Token for token, across chunk boundaries (decode_chunk=4) and, for
+    gemma-2, past the sliding window of 8."""
+    prompts = _prompts(5, vocab=layouts["vocab"])
+    assert layouts["unset"].generate(prompts, sp) == (
+        layouts["per_layer"].generate(prompts, sp))
+
+
+def test_int8_pool_with_nothing_set_boots_and_generates(layouts):
+    prompts = _prompts(3, np.random.default_rng(7), layouts["vocab"])
+    out = layouts["int8"].generate(
+        prompts, SamplingParams(temperature=0.0, max_tokens=8))
+    assert [len(o) for o in out] == [8, 8, 8]
 
 
 @pytest.mark.slow

@@ -151,6 +151,22 @@ def _count_calls(monkeypatch, eng, *names):
     return calls
 
 
+@pytest.mark.parametrize(
+    "kw, layout",
+    [({}, "stacked"), ({"kv_dtype": "int8"}, "per_layer"),
+     ({"cache_mode": "slot"}, "per_layer")],
+    ids=["bf16-pool", "int8-pool", "slot-cache"],
+)
+def test_the_decode_span_names_the_kv_layout(tiny, kw, layout):
+    """A compile-time choice: every `step.decode` span of an engine carries
+    the same word, the one `/v1/state` gives under `kv_cache`."""
+    eng, rec = _engine(tiny, **kw)
+    _drive(eng, [[1, 2, 3], [4, 5, 6, 7]])
+    spans = rec.named("step.decode")
+    assert spans and {s["attrs"]["kv_layout"] for s in spans} == {layout}
+    assert eng.kv_cache_info()["kv_layout"] == layout
+
+
 def test_admission_counters_with_mixed_admissions(tiny, monkeypatch):
     """Batches of several buckets, a chunked prompt and a prefix-cache hit:
     useful + pad is the tokens of the shapes that ran, every device call is

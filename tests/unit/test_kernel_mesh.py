@@ -69,6 +69,7 @@ def test_tp_sharded_serving_runs_the_kernels_under_shard_map(
     assert got == want
 
 
+@pytest.mark.parametrize("kernel", ["per_layer", "stacked"])
 @pytest.mark.parametrize(
     "mesh_cfg, kv_heads_per_shard",
     [
@@ -80,28 +81,37 @@ def test_tp_sharded_serving_runs_the_kernels_under_shard_map(
     ids=["kv-heads-on-tp", "gqa-replicated"],
 )
 def test_kernel_call_is_manual_over_every_mesh_axis(
-    devices8, forced_kernels, monkeypatch, mesh_cfg, kv_heads_per_shard
+    devices8, forced_kernels, monkeypatch, mesh_cfg, kv_heads_per_shard,
+    kernel,
 ):
     """What Mosaic demands on the chip: inside the wrapper no mesh axis is
-    left to GSPMD, and each shard sees its own KV heads."""
+    left to GSPMD, and each shard sees its own KV heads: of one layer's
+    pool, and of the stacked pool the decode step reads in place."""
     from kubeai_tpu.ops import paged_attention as pa
 
     seen = {}
-    real = pa._paged_pallas
+    name = "_paged_pallas" if kernel == "per_layer" else "_paged_pallas_stacked"
+    real = getattr(pa, name)
 
     def probe(q, k_pages, *rest, **kw):
         am = jax.sharding.get_abstract_mesh()
         seen["manual"] = set(am.manual_axes) == set(am.axis_names)
-        seen["kvh"] = k_pages.shape[2]
+        seen["kvh"] = k_pages.shape[-2]
         return real(q, k_pages, *rest, **kw)
 
-    monkeypatch.setattr(pa, "_paged_pallas", probe)
+    monkeypatch.setattr(pa, name, probe)
     q = jnp.ones((2, 8, 16), jnp.float32)
     pool = jnp.ones((3, 16, 4, 16), jnp.float32)
     bt = jnp.asarray([[1, -1], [2, -1]], jnp.int32)
     lens = jnp.asarray([5, 9], jnp.int32)
     with jax.set_mesh(build_mesh(mesh_cfg, devices=devices8)):
-        out = jax.jit(pa.paged_decode_attention)(q, pool, pool, bt, lens)
+        if kernel == "per_layer":
+            out = jax.jit(pa.paged_decode_attention)(q, pool, pool, bt, lens)
+        else:
+            stacked = jnp.ones((2,) + pool.shape, jnp.float32)
+            new = jnp.ones((2, 4, 16), jnp.float32)
+            out = jax.jit(pa.paged_decode_attention_fused)(
+                q, stacked, stacked, new, new, bt, lens - 1, 1)
     assert seen == {"manual": True, "kvh": kv_heads_per_shard}
     np.testing.assert_allclose(np.asarray(out), 1.0, atol=1e-5)
 

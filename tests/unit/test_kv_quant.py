@@ -641,6 +641,10 @@ def test_http_state_and_metrics_expose_quantization(qfleet):
     kv = state["kv_cache"]
     assert kv["dtype"] == "int8" and kv["quantized"]
     assert kv["capacity_factor"] == pytest.approx(kv_capacity_factor(16))
+    # Which decode layout was compiled follows the pool's kind.
+    assert kv["kv_layout"] == "per_layer"
+    st, body = http_get(_addr(qfleet["bf16"]), "/v1/state")
+    assert json.loads(body)["kv_cache"]["kv_layout"] == "stacked"
     st, body = http_get(_addr(qfleet["a8"]), "/metrics")
     text = body.decode()
     assert "kubeai_engine_kv_quant_enabled 1" in text

@@ -462,9 +462,13 @@ def test_mixed_priority_clients_single_slot_ordering(server):
     blocker.start()
     assert _wait(lambda: _state(server)["slots_active"] == 1)
 
+    # The batch request is long enough to end well after the realtime one
+    # when it is served second: at 4 tokens the two ended 2-3 ms apart, and
+    # two client threads on a loaded machine read that in either order.
     batch = threading.Thread(
         target=_completion, args=(server, results, "batch"),
-        kwargs={"headers": {"X-Priority": "batch", "X-Client-Id": "b"}},
+        kwargs={"headers": {"X-Priority": "batch", "X-Client-Id": "b"},
+                "max_tokens": 100},
     )
     batch.start()
     assert _wait(lambda: _state(server)["requests_pending"] == 1)
