@@ -21,6 +21,9 @@ rare. Compared, each beside its limit from the configuration's file:
 The control (a study, not part of a run) is the same reference computed in a
 lower precision: at each position of the same prompts and tokens, the gap of
 the token that precision puts first.
+
+`compared` in what is returned holds each number that decided `correct`
+beside its limit (a number that could not be read is `None`, and over).
 """
 
 from __future__ import annotations
@@ -34,19 +37,22 @@ SAMPLE_REQUESTS = 6
 SAMPLE_MIN_TOKENS = 600
 
 
+def device_arrays(*owners) -> list:
+    """Every device array that the attributes of `owners` hold, found by
+    walking them and not by name: a cache with one latent pool, or with state
+    beside its pages, is covered like the two page pools."""
+    import jax
+
+    return [leaf for o in owners if o is not None
+            for leaf in jax.tree.leaves(dict(vars(o)))
+            if isinstance(leaf, jax.Array) and not leaf.is_deleted()]
+
+
 def free_engine(engine) -> None:
     """Give the device back before the reference runs, so the peak that is
     reported stays the program's and the reference fits."""
-    import jax
-
-    trees = [engine.params, engine._state, getattr(engine, "_lora", None)]
-    cache = engine.cache
-    for name in ("k_pages", "v_pages", "block_tables", "k", "v"):
-        trees.append(getattr(cache, name, None))
-    trees += [getattr(engine, "_stage_k", None), getattr(engine, "_stage_v", None)]
-    for leaf in jax.tree.leaves(trees):
-        if hasattr(leaf, "delete") and not leaf.is_deleted():
-            leaf.delete()
+    for leaf in device_arrays(engine, engine.cache):
+        leaf.delete()
     gc.collect()
 
 
@@ -131,27 +137,32 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
             control[q]["flips"] += int((lq.argmax(axis=-1) != first).sum())
     n = len(gaps)
     readings = {
-        "max_gap": max(gaps) if gaps else float("inf"),
-        "mean_gap": sum(gaps) / n if n else float("inf"),
-        "flip_share": flips / n if n else 1.0,
+        "max_gap": max(gaps) if gaps else None,
+        "mean_gap": sum(gaps) / n if n else None,
+        "flip_share": flips / n if n else None,
         "short": short,
     }
     ok = bool(picked)
+    compared = {}
     for name, value in readings.items():
         if name not in limits:
-            log(f"correct: {name} = {value:.6g}  (read, not compared)")
+            log(f"correct: {name} = {_fmt(value)}  (read, not compared)")
             continue
         limit = limits[name]
-        within = value <= limit
+        within = value is not None and value <= limit
         ok = ok and within
-        log(f"correct: {name} = {value:.6g}  limit {limit}  "
+        compared[name] = [value, limit]
+        log(f"correct: {name} = {_fmt(value)}  limit {limit}  "
             f"{'ok' if within else 'OVER'}")
     log(f"correct: sample of {len(picked)} requests, {n} served tokens")
+    control = {q: {"max_gap": max(c["gaps"]), "mean_gap": sum(c["gaps"]) / n,
+                   "flip_share": c["flips"] / n}
+               for q, c in control.items()} if n else {}
     for q, c in control.items():
-        log(f"control {q}: max_gap = {max(c['gaps']):.6g}  flip_share = "
-            f"{c['flips'] / n:.6g}  mean gap {sum(c['gaps']) / n:.6g}")
-    return {"correct": ok, **readings, "tokens": n,
-            "control": {q: {"max_gap": max(c["gaps"]),
-                            "mean_gap": sum(c["gaps"]) / n,
-                            "flip_share": c["flips"] / n}
-                        for q, c in control.items()} if n else {}}
+        log(f"control {q}: " + "  ".join(f"{k} = {_fmt(v)}" for k, v in c.items()))
+    return {"correct": ok, **readings, "tokens": n, "compared": compared,
+            "control": control}
+
+
+def _fmt(value) -> str:
+    return "unread" if value is None else f"{value:.6g}"

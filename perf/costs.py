@@ -5,11 +5,23 @@ per chip, resident KV once, padding does no useful work, a mixture of
 experts counts the experts a token is routed to and not all of them. A
 tier-1 test pins each count against a hand-worked case. `hf` is a
 configuration file's dict (the published `config.json` keys).
+
+The counts below are of the llama and mixtral shapes: heads of one width, a
+cache of keys and values. An architecture they do not fit (heads of two
+widths, a latent cache, layers of several kinds) brings its own counts as
+functions of the same names in its reference module, and a reader asks for a
+count through `of(reference, name)`.
 """
 
 from __future__ import annotations
 
 BF16 = 2
+
+
+def of(reference, name: str):
+    """The cost function `name`: the configuration's reference module's own
+    where it has one, this file's otherwise."""
+    return getattr(reference, name, None) or globals()[name]
 
 
 def _dims(hf):

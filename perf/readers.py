@@ -1,21 +1,28 @@
 """The reader kinds. A per-layer metric is a data file,
 `layer_metrics/<name>.json`, that names one of these and gives it its
 parameters; a reader that finds nothing to read returns None and the harness
-leaves the metric out of the line. A new KIND of reader is code, and so a
-`tracing` or `benchmark` PR's; a new metric of an existing kind is a file.
+leaves the metric out of the line. A new metric of an existing kind is a
+file; a new KIND is a file too, `reader_kinds/<kind>.py` with a function
+`read(spec, obs)`, found by its name when a metric first asks for it (the
+kinds below come first: a file cannot replace one of them).
 
 `obs` is what one run observed: `metrics0`/`metrics1` (the server's /metrics
 text parsed at the window's two edges), `steps0`/`steps1`, `polled` (series
 sampled in-process at 5 Hz), `records` (the load generator's), `trace` (the
 summary of perf/trace_reduce.py, traced runs only), and the cell's `hf`, `engine`,
-`chips`, `peaks`, `seconds`.
+`chips`, `peaks`, `seconds`, and `reference`, the configuration's reference
+module, which is asked for a cost (bytes, FLOPs) before perf/costs.py is.
 """
 
 from __future__ import annotations
 
+import importlib.util
+import os
 import re
 
 from perf import costs, e2e, trace_reduce
+
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 _LINE = re.compile(r"^([a-zA-Z_:][\w:]*)(?:\{(.*)\})?\s+(\S+)$")
 _LABEL = re.compile(r'(\w+)="((?:[^"\\]|\\.)*)"')
@@ -156,7 +163,7 @@ def decode_hbm_share(spec, obs):
     if mean is None or not used:
         return None
     step_s = mean / obs["engine"]["decode_chunk"]
-    need = costs.decode_step_bytes_per_chip(
+    need = costs.of(obs.get("reference"), "decode_step_bytes_per_chip")(
         obs["hf"], sum(used) / len(used), obs["chips"])
     return 100.0 * need / obs["peaks"]["hbm_bytes_per_s"] / step_s
 
@@ -169,5 +176,19 @@ READERS = {f.__name__: f for f in (
 )}
 
 
-def read(spec: dict, obs: dict):
-    return READERS[spec["reader"]](spec, obs)
+def kind(name: str, root: str = HERE):
+    """The reader of that kind: one of `READERS`, or `read` of
+    `reader_kinds/<name>.py`; None where there is neither."""
+    if name in READERS:
+        return READERS[name]
+    path = os.path.join(root, "reader_kinds", name + ".py")
+    if not re.fullmatch(r"[A-Za-z0-9_]+", name) or not os.path.exists(path):
+        return None
+    spec = importlib.util.spec_from_file_location("perf.reader_kinds." + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+def read(spec: dict, obs: dict, root: str = HERE):
+    return kind(spec["reader"], root)(spec, obs)

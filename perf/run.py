@@ -12,8 +12,10 @@ holds a seeded sample of what was served against the plain reference.
 
 The last line of stdout is one JSON object with exactly the keys `correct`,
 `attempted`, `failed`, `metrics`, `device` (and `breakdown` with
-`--trace 1`). Off a TPU it prints no result and exits non-zero; `--rehearse`
-runs the tiny CPU presets of perf/rehearse.json, and its line says platform
+`--trace 1`), and last of all `compared`: each number `correct` was decided
+on, beside its limit (also the last lines of stderr). Off a TPU it prints no
+result and exits non-zero; `--rehearse` runs the tiny CPU presets of
+perf/rehearse.json and perf/rehearse.d/*.json, and its line says platform
 `cpu`, which nothing may record as a chip result.
 """
 
@@ -24,6 +26,7 @@ import time
 T_START = time.time()  # set-up is counted from here
 
 import argparse  # noqa: E402
+import glob  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -42,6 +45,7 @@ from perf.tokenizer import BenchTokenizer  # noqa: E402
 WINDOW_LEAD_S = 1.5  # the child's start-up, before the pre-roll begins
 TRACE_SLICE_S = 3.0  # the profiler's slice, in the middle of the window
 POLL_HZ = 5.0
+POOL_MIN_BYTES = 1 << 20  # a cache's array of this size or more is a pool
 
 
 def log(msg: str) -> None:
@@ -54,9 +58,13 @@ def load_cell(name: str, rehearse: bool) -> tuple[dict, dict, dict]:
         bench = json.load(f)
     cells, configs = bench["workloads"], bench["configs"]
     if rehearse:
-        with open(os.path.join(HERE, "rehearse.json")) as f:
-            extra = json.load(f)
-        cells, configs = extra["workloads"], extra["configs"]
+        cells, configs = [], []
+        for path in [os.path.join(HERE, "rehearse.json"), *sorted(
+                glob.glob(os.path.join(HERE, "rehearse.d", "*.json")))]:
+            with open(path) as f:
+                extra = json.load(f)
+            cells += extra["workloads"]
+            configs += extra["configs"]
     cell = next((w for w in cells if w["name"] == name), None)
     if cell is None:
         raise SystemExit(f"perf: no workload {name!r}; known: "
@@ -190,6 +198,7 @@ class Bench:
         from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
 
         self.jax, self.bench = jax, bench
+        self._pools_like: dict = {}  # what `release` took, for `reseed`
         self.cell, self.cfg, self.mix = cell, cfg, mix
         self.chips = int(cell["chips"])
         self.cache_dir = enable_compilation_cache()
@@ -250,35 +259,44 @@ class Bench:
 
     def reseed(self, seed: int) -> None:
         """Study mode: new weights from another seed, in place, and the
-        page pool again if `release` took it."""
+        cache's pools again if `release` took them."""
+        import jax.numpy as jnp
+
         jax, engine = self.jax, self.engine
         for leaf in jax.tree.leaves(engine.params):
             if not leaf.is_deleted():
                 leaf.delete()
         self.key = seed_key(jax, seed)
         engine.params = jax.block_until_ready(self._make_params(self.key))
-        if self._pool_like is not None:
-            import jax.numpy as jnp
-
-            shape, dtype, sharding = self._pool_like
-            engine.cache.k_pages = jnp.zeros(shape, dtype, device=sharding)
-            engine.cache.v_pages = jnp.zeros(shape, dtype, device=sharding)
-            self._pool_like = None
-
-    _pool_like = None
+        for name, tree in self._pools_like.items():
+            setattr(engine.cache, name, jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding), tree))
+        self._pools_like = {}
 
     def release(self) -> None:
         """Study mode: once the engine is idle, give the weights and the
-        page pool back, so the reference has room beside what stays."""
+        cache's pools back, so the reference has room beside what stays. A
+        pool is any attribute of the cache that holds device arrays over
+        `POOL_MIN_BYTES`, whatever its name: two page pools, one latent
+        pool, state beside pages. An idle engine's pools hold nothing that
+        is read again, so `reseed` re-makes them as zeros; the small tables
+        beside them (block tables) stay."""
         deadline = time.time() + 60.0
         while self.engine.has_work() and time.time() < deadline:
             time.sleep(0.05)
         time.sleep(0.5)  # let the serve loop finish the step it is in
-        cache = self.engine.cache
-        self._pool_like = (cache.k_pages.shape, cache.k_pages.dtype,
-                           cache.k_pages.sharding)
-        for leaf in self.jax.tree.leaves(
-                [self.engine.params, cache.k_pages, cache.v_pages]):
+        jax, cache = self.jax, self.engine.cache
+        self._pools_like = {}
+        for name, value in vars(cache).items():
+            leaves = jax.tree.leaves(value)
+            if leaves and all(isinstance(a, jax.Array) for a in leaves) and (
+                    sum(a.nbytes for a in leaves) >= POOL_MIN_BYTES):
+                self._pools_like[name] = jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype, sharding=a.sharding), value)
+        for leaf in jax.tree.leaves(
+                [self.engine.params]
+                + [getattr(cache, name) for name in self._pools_like]):
             leaf.delete()
 
     def window(self, mix: dict, seed: int, seconds: float, trace: bool) -> dict:
@@ -350,7 +368,8 @@ class Bench:
                 f.write(out)
         obs.update(records=json.loads(out)["records"], loop=mix["loop"],
                    seconds=seconds, drain_s=drain_s, hf=self.cfg,
-                   engine=self.engine_cfg, chips=self.chips, peaks=self.peaks)
+                   engine=self.engine_cfg, chips=self.chips, peaks=self.peaks,
+                   reference=self.reference)
         return obs
 
     def end_to_end(self, obs: dict) -> dict:
@@ -473,6 +492,12 @@ def main(argv=None) -> int:
     log(f"setup_s {obs['setup_s']:.3f}; compiles in the window "
         f"{obs['compiles_in_window']}; requests recorded "
         f"{len(obs['records'])}; whole run {time.time() - T_START:.1f} s")
+    # Each number `correct` was decided on, beside its limit: last in the
+    # line, and the last lines of stderr.
+    line["compared"] = {**v["compared"], "failed": [failed, 0]}
+    for name, (value, limit) in line["compared"].items():
+        print(f"perf: compared: {name} = {value}  limit {limit}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -33,7 +33,6 @@ def compile_cell(topo, cfg: dict, *, admit: int, bucket: int,
     from kubeai_tpu.engine.engine import Engine, EngineConfig
     from kubeai_tpu.models.registry import get_model_family
     from kubeai_tpu.ops import dispatch
-    from kubeai_tpu.ops.paged_attention import resolve_decode_kernel
     from kubeai_tpu.parallel import sharding as psh
     from kubeai_tpu.parallel.mesh import MESH_AXES, MeshConfig
 
@@ -53,7 +52,12 @@ def compile_cell(topo, cfg: dict, *, admit: int, bucket: int,
     eng.family, eng.model_cfg, eng.cfg, eng.mesh = family, mcfg, ecfg, mesh
     eng._pp, eng._pp_microbatches, eng._spec, eng._draft = 1, 0, 0, None
     eng._kv_quant, eng.cache_mode = False, "paged"
-    eng.decode_kernel = resolve_decode_kernel(ecfg.decode_kernel)
+    # Nothing set: the model takes the decode layout from the pool's kind,
+    # which is what the engine runs. An override that names a layout is
+    # handed on (tests/unit/test_decode_pool_in_place.py compiles the
+    # per-layer layout to show its guard is not blind); once the program
+    # drops the field, nothing here has to change.
+    eng.decode_kernel = getattr(ecfg, "decode_kernel", "") or None
     eng._bt_sharding = psh.named_sharding(mesh, (None, None), cache_rules)
     eng._chunk_fn = None
     eng.jit = lambda fn, **kw: jax.jit(fn, **kw)

@@ -3,6 +3,7 @@ they guard both configurations on every later PR at no chip time. Nothing
 runs; a compile that passes is not a chip run."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -41,11 +42,24 @@ def test_mistral_one_chip_largest_graphs_fit(topo):
     assert "tpu_custom_call" in out["decode_text"]  # the paged kernel is there
 
 
-def test_mistral_eight_more_slots_would_not_fit(topo):
+def test_mistral_slots_fit_in_eights_up_to_64_and_72_are_refused(topo):
+    """Decode reads and writes the pool in place (PR 25), so what a slot
+    costs is its pages: 8 slots x 2048 tokens x 64 KiB are 1 GiB. Compile
+    upward in eights and find the first count the compiler refuses."""
     cfg = aot.load_config("mistral-7b-v5e1")
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|memory"):
-        aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",),
-                         engine_overrides={"num_slots": 32})
+    fits = {}
+    for slots in range(32, 129, 8):
+        try:
+            out = aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",),
+                                   engine_overrides={"num_slots": slots})
+        except Exception as e:
+            assert re.search("RESOURCE_EXHAUSTED|memory", str(e)), e
+            break
+        fits[slots] = aot.peak_bytes(out["decode"])
+    assert slots == 72 and sorted(fits) == [32, 40, 48, 56, 64]
+    assert 11.5 * 2**30 < fits[32] < 11.8 * 2**30  # 11.63 GiB (AOT, PR 25)
+    assert all(fits[n + 8] - fits[n] == pytest.approx(2**30, rel=0.01)
+               for n in (32, 40, 48, 56))
 
 
 def test_mixtral_tp4_graphs_fit_and_carry_collectives(topo):

@@ -1,15 +1,19 @@
 """BENCHMARK.json and the data files it names: the contract's static rules,
 and that a later PR can add a configuration, a mix, a cell and a per-layer
-metric of an existing reader kind with new files and entries only."""
+metric, and a whole architecture (reference, reader kind, cost function, a
+cache of its own shape, a rehearsal cell), with new files and entries only."""
 
+import importlib.util
 import json
 import os
 import re
 import shutil
+import types
 
 import pytest
 
-from perf import readers, traffic
+from perf import check as perf_check
+from perf import costs, readers, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -101,7 +105,8 @@ def check(root) -> list[str]:
         path = os.path.join(root, "perf", "layer_metrics", m["name"] + ".json")
         try:
             with open(path) as f:
-                if json.load(f)["reader"] not in readers.READERS:
+                if readers.kind(json.load(f)["reader"],
+                                os.path.join(root, "perf")) is None:
                     bad.append(f"{m['name']}: unknown reader kind")
         except (OSError, ValueError, KeyError) as e:
             bad.append(f"{m['name']}: {e}")
@@ -165,12 +170,46 @@ def test_only_depth_is_reduced_from_the_published_config(name):
     assert (cut["from"], cut["to"]) == (32, cfg["num_hidden_layers"])
 
 
+HARNESS = ("run.py", "readers.py", "check.py", "costs.py", "traffic.py", "loadgen.py")
+
+STUB_REFERENCE = '''"""A stub architecture: a latent cache of 576 numbers a token a layer."""
+from perf.reference.mistral import forward, served_params  # noqa: F401
+
+
+def kv_bytes_per_token(hf):
+    return hf["num_hidden_layers"] * (hf["kv_lora_rank"] + hf["qk_rope_head_dim"]) * 2
+
+
+def decode_step_bytes_per_chip(hf, resident_tokens, chips):
+    return (1000 + resident_tokens * kv_bytes_per_token(hf)) / chips
+'''
+
+STUB_KIND = '''"""A reader kind of the stub's own: latent bytes resident, in MiB."""
+from perf import costs
+
+
+def read(spec, obs):
+    used = obs["polled"].get("kv_tokens") or []
+    if not used:
+        return None
+    per_token = costs.of(obs.get("reference"), "kv_bytes_per_token")(obs["hf"])
+    return sum(used) / len(used) * per_token / 2**20 * spec.get("scale", 1.0)
+'''
+
+
+def load_file(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def test_a_later_pr_adds_a_config_mix_cell_and_metric_as_files_only(tmp_path):
     root = tmp_path / "repo"
-    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf")
+    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     b = load()
-    before = {p: (root / "perf" / p).read_bytes()
-              for p in ("run.py", "readers.py", "traffic.py", "loadgen.py")}
+    before = {p: (root / "perf" / p).read_bytes() for p in HARNESS}
     with open(root / "perf" / "configs" / "mistral-7b-v5e1.json") as f:
         cfg = json.load(f)
     cfg["engine"]["num_slots"] = 16
@@ -192,8 +231,38 @@ def test_a_later_pr_adds_a_config_mix_cell_and_metric_as_files_only(tmp_path):
         "name": "itl_mean_ms", "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": "Decode step",
         "moves": "tpot_mean_ms", "workloads": ["mistral-7b-16slot.chat-fast"]})
+
+    # A whole architecture, as files: its reference (with cost functions of
+    # its own), a reader kind, a configuration, a cell that reports a metric
+    # of that kind, and a rehearsal cell.
+    (root / "perf" / "reference" / "stub_latent.py").write_text(STUB_REFERENCE)
+    (root / "perf" / "reader_kinds").mkdir(exist_ok=True)
+    (root / "perf" / "reader_kinds" / "latent_resident_mib.py").write_text(STUB_KIND)
+    stub = {**cfg, "reference": "stub_latent", "kv_lora_rank": 512, "qk_rope_head_dim": 64}
+    (root / "perf" / "configs" / "stub-latent.json").write_text(json.dumps(stub))
+    (root / "perf" / "layer_metrics" / "latent_resident_mib.json").write_text(
+        json.dumps({"reader": "latent_resident_mib"}))
+    (root / "perf" / "rehearse.d").mkdir(exist_ok=True)
+    (root / "perf" / "rehearse.d" / "stub.json").write_text(json.dumps({
+        "configs": [{"name": "stub-latent", "file": "perf/configs/stub-latent.json"}],
+        "workloads": [{"name": "stub-latent.closed", "config": "stub-latent",
+                       "traffic": "tiny-closed", "chips": 1,
+                       "as": "stub-latent.decode-sat"}]}))
+    b["configs"].append({**b["configs"][0], "name": "stub-latent",
+                         "file": "perf/configs/stub-latent.json"})
+    b["workloads"].append({"name": "stub-latent.decode-sat", "chips": 1,
+                           "config": "stub-latent", "traffic": "decode-sat",
+                           "why": "a latent cache under the saturated closed loop"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if m["name"] in ("out_tok_s", "decode_hbm_share"):
+            m["workloads"].append("stub-latent.decode-sat")
+    b["per_layer"].append({
+        "name": "latent_resident_mib", "unit": "MiB", "better": "higher",
+        "source": "program_counter", "layer": "KV pool",
+        "moves": "out_tok_s", "workloads": ["stub-latent.decode-sat"]})
     (root / "BENCHMARK.json").write_text(json.dumps(b))
     assert check(str(root)) == []
+
     mix = traffic.load_mix("chat-fast", str(root / "perf"))
     assert mix["rate_rps"] == 9.5 and mix["loop"] == "open"
     assert len(traffic.open_schedule(mix, 1, 10.0)) == round(9.5 * (10 + mix["preroll_s"]))
@@ -205,5 +274,75 @@ def test_a_later_pr_adds_a_config_mix_cell_and_metric_as_files_only(tmp_path):
         spec = json.load(f)
     assert readers.read(spec, {"metrics0": edge(1.0, 10), "metrics1": edge(3.0, 50)}) \
         == pytest.approx(50.0)
+
+    # The stub's reader kind is found by its name, asks the stub's reference
+    # for its cost first, and `decode_hbm_share` does too; a cost the stub
+    # does not bring is perf/costs.py's.
+    reference = load_file(root / "perf" / "reference" / "stub_latent.py", "stub_latent")
+    obs = {"polled": {"kv_tokens": [1000.0, 3000.0]}, "hf": stub, "chips": 1,
+           "reference": reference, "engine": {"decode_chunk": 8},
+           "peaks": {"hbm_bytes_per_s": 819e9},
+           "trace": {"modules": {"jit__decode_chunk(1)": {"count": 2, "total_s": 0.016}}}}
+    per_token = 16 * 576 * 2
+    assert readers.read({"reader": "latent_resident_mib"}, obs, str(root / "perf")) \
+        == pytest.approx(2000 * per_token / 2**20)
+    assert readers.kind("latent_resident_mib") is None  # not a kind of this tree
+    assert readers.kind("histogram_mean", str(root / "perf")) is readers.histogram_mean
+    assert costs.of(reference, "decode_step_bytes_per_chip")(stub, 2000, 1) \
+        == 1000 + 2000 * per_token
+    assert costs.of(reference, "prefill_flops_per_token") is costs.prefill_flops_per_token
+    share = readers.read({"reader": "decode_hbm_share", "module": "^jit__decode_chunk"},
+                         obs, str(root / "perf"))
+    assert share == pytest.approx(100.0 * (1000 + 2000 * per_token) / 819e9 / 0.001)
+
+    # Its rehearsal cell is found in rehearse.d/ by the copy's own run.py.
+    run = load_file(root / "perf" / "run.py", "perf_run_copy")
+    _, cell, body = run.load_cell("stub-latent.closed", True)
+    assert cell["as"] == "stub-latent.decode-sat" and body["reference"] == "stub_latent"
+    assert [m["name"] for m in run.metrics_for(b, "per_layer", cell)
+            if m["name"].startswith("latent")] == ["latent_resident_mib"]
+    assert run.load_cell("tiny-mistral.closed", True)[1]["config"] == "tiny-mistral"
+
     for p, content in before.items():
         assert (root / "perf" / p).read_bytes() == content
+
+
+class OnePoolCache:
+    """A cache that is neither `k_pages` nor `v_pages`: one latent pool, a
+    state beside it, and the small block tables."""
+
+    def __init__(self, jnp):
+        self.latent_pages = jnp.ones((4, 300, 16, 64), jnp.float32)  # 4.7 MiB
+        self.state = {"conv": jnp.ones((512, 1024), jnp.float32)}  # 2 MiB
+        self.block_tables = jnp.full((4, 8), -1, jnp.int32)
+        self.page_size = 16
+
+
+def test_a_cache_of_another_shape_is_freed_and_remade_by_walking_it():
+    import jax
+    import jax.numpy as jnp
+
+    from perf import run
+
+    engine = types.SimpleNamespace(
+        params={"w": jnp.ones((8, 8))}, cache=OnePoolCache(jnp),
+        _state={"tokens": jnp.zeros((4,), jnp.int32)}, has_work=lambda: False)
+    bench = run.Bench.__new__(run.Bench)
+    bench.jax, bench.engine, bench._pools_like = jax, engine, {}
+    bench._make_params = lambda key: {"w": jnp.full((8, 8), 2.0)}
+    tables = engine.cache.block_tables
+    bench.release()
+    assert engine.cache.latent_pages.is_deleted()
+    assert engine.cache.state["conv"].is_deleted() and engine.params["w"].is_deleted()
+    assert not tables.is_deleted() and not engine._state["tokens"].is_deleted()
+    bench.reseed(5)
+    assert engine.cache.latent_pages.shape == (4, 300, 16, 64)
+    assert float(engine.cache.latent_pages.sum()) == 0.0  # an idle pool holds nothing
+    assert engine.cache.state["conv"].shape == (512, 1024)
+    assert float(engine.params["w"][0, 0]) == 2.0 and engine.cache.block_tables is tables
+    assert bench._pools_like == {}
+    # Before the reference runs, everything goes, whatever it is called.
+    held = perf_check.device_arrays(engine, engine.cache)
+    assert len(held) == 5
+    perf_check.free_engine(engine)
+    assert all(a.is_deleted() for a in held) and tables.is_deleted()

@@ -1,6 +1,7 @@
 """The tiny CPU rehearsals of perf/run.py, end to end: the last line's keys
-are exactly the contract's; a broken timed path and a lower precision both
-come out as not correct; off a TPU nothing is printed."""
+are exactly the contract's, with what was compared last; a broken timed path
+and a lower precision both come out as not correct; off a TPU nothing is
+printed."""
 
 import json
 import os
@@ -11,7 +12,7 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
 def run(tmp_path, *args, rehearse=True):
@@ -24,6 +25,11 @@ def run(tmp_path, *args, rehearse=True):
              *(["--rehearse"] if rehearse else [])],
             stdout=fo, stderr=fe, cwd=ROOT, env=env, timeout=600).returncode
     return rc, out.read_text().splitlines(), err.read_text()
+
+
+def load_config(cell):
+    with open(os.path.join(ROOT, "perf", "configs", cell.split(".")[0] + ".json")) as f:
+        return json.load(f)
 
 
 def load_benchmark():
@@ -42,7 +48,7 @@ def test_rehearsal_prints_the_contracts_last_line(tmp_path, cell, stands_for, tr
                          "--seconds", "2", "--trace", str(trace))
     assert rc == 0, err[-2000:]
     line = json.loads(lines[-1])
-    assert set(line) == KEYS
+    assert list(line) == KEYS  # what was compared comes last
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"  # never a chip result
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
@@ -65,6 +71,15 @@ def test_rehearsal_prints_the_contracts_last_line(tmp_path, cell, stands_for, tr
         assert "setup_s" in line["metrics"] and len(line["metrics"]) >= 2
     compared = [l for l in lines if l.startswith("perf: correct: ") and "limit" in l]
     assert len(compared) == 3  # each number printed beside its limit
+    # ... and again in the line, and as the last lines of stderr.
+    assert set(line["compared"]) == {"max_gap", "mean_gap", "short", "failed"}
+    limits = load_config(cell)["correct"]
+    for name, (value, limit) in line["compared"].items():
+        assert limit == limits.get(name, 0) and value <= limit
+    tail = [l for l in err.splitlines() if l.startswith("perf: compared: ")]
+    assert err.rstrip().splitlines()[-1] == tail[-1] and len(tail) == 4
+    assert tail[0] == (f"perf: compared: max_gap = {line['compared']['max_gap'][0]}"
+                       f"  limit {limits['max_gap']}")
 
 
 def test_a_broken_timed_path_comes_out_not_correct(tmp_path):
@@ -84,12 +99,11 @@ def test_the_lower_precision_control_fails_the_limits(tmp_path):
     rc, lines, err = run(tmp_path, "--workload", "tiny-mistral.closed", "--seed", "6",
                          "--seconds", "2", "--trace", "0", "--control", "fp8")
     assert rc == 0, err[-2000:]
-    with open(os.path.join(ROOT, "perf", "configs", "tiny-mistral.json")) as f:
-        limits = json.load(f)["correct"]
+    limits = load_config("tiny-mistral.closed")["correct"]
     control = next(l for l in lines if l.startswith("perf: control fp8:"))
-    nums = dict(re.findall(r"(max_gap|mean gap) =? ?([0-9.e+-]+)", control))
+    nums = dict(re.findall(r"(max_gap|mean_gap) = ([0-9.e+-]+)", control))
     assert float(nums["max_gap"]) > 3 * limits["max_gap"]
-    assert float(nums["mean gap"]) > 3 * limits["mean_gap"]
+    assert float(nums["mean_gap"]) > 3 * limits["mean_gap"]
     assert json.loads(lines[-1])["correct"] is True  # the program itself is sound
 
 
