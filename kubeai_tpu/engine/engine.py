@@ -199,12 +199,25 @@ class StepOverlapUnsupported(ValueError):
 
 class StepEvent(NamedTuple):
     """One emitted token. `finish_reason` is "" while the request is live,
-    else "stop" | "length" | "cancelled" (OpenAI finish_reason semantics)."""
+    else "stop" | "length" | "cancelled" (OpenAI finish_reason semantics).
+
+    `routes` is None unless the request asked for its expert routes
+    (`add_request(routes=True)` on an engine whose `moe["routes"]` is
+    true): then a tuple of blocks `(start, rows)`, `rows` a
+    `[n, routed layers, k]` array of global expert ids, row j the sets
+    taken when the program computed position `start + j` of prompt plus
+    served tokens. The first token's event carries the prompt's rows
+    (from the first computed position, if a prefix was reused); the
+    event of token i >= 2 carries row P + i - 2, the position whose
+    forward produced it; rows recomputed after a preemption or for a
+    resume prefix ride in front of the next token's row, under their
+    positions (docs/concepts/expert-routes.md)."""
 
     rid: int
     token: int
     finished: bool
     finish_reason: str = ""
+    routes: tuple | None = None
 
 
 @dataclasses.dataclass
@@ -240,6 +253,10 @@ class _Request:
     t_enqueue: float = 0.0
     t_admit_start: float = 0.0
     t_prev_token: float = 0.0
+    # None unless the request asked for its expert routes: then the
+    # blocks computed while it emitted nothing (a resumed admission's
+    # recompute), which ride on its next event.
+    route_backlog: list | None = None
 
 
 class EngineDraining(RuntimeError):
@@ -304,7 +321,8 @@ class Engine:
         self._free_slots = list(range(cfg.num_slots))
         # In-flight decode chunk (overlapped stepping): (token futures,
         # snapshot of the slot->request map the chunk was dispatched
-        # with, chunk length in model steps, monotonic dispatch time).
+        # with, chunk length in model steps, monotonic dispatch time,
+        # a routed family's expert-set futures or None).
         # The dispatch timestamp feeds the server watchdog: a dispatched
         # chunk counts as progress until its own reap deadline ages out.
         self._inflight: tuple | None = None
@@ -755,7 +773,63 @@ class Engine:
                 "draft model provided but cfg.speculate == 0"
             )
 
+        # Expert routes (see `_routes` below): what /v1/state says of a
+        # family with a router, None for a dense one.
+        self.moe: dict | None = None
+        if self.family.routes:
+            experts, k, layers = self.family.route_dims(model_cfg)
+            self.moe = {
+                "experts": int(experts),
+                "k": int(k),
+                "routed_layers": int(layers),
+                "routes": self._routes,
+            }
+        # Cumulative, plain host values (EngineMetrics folds the deltas
+        # in): token-layer assignments per global expert id, rows whose
+        # tokens were kept by the forward that computed them, rows handed
+        # to requests that asked, and such requests.
+        self.route_stats = {
+            "expert_tokens": np.zeros(
+                self.moe["experts"] if self.moe else 0, np.int64
+            ),
+            "rows_prefill": 0,
+            "rows_decode": 0,
+            "rows_sent": 0,
+            "requests": 0,
+        }
+
         self._build_jits(cache_sharding)
+
+    # ---- expert routes ---------------------------------------------------------
+
+    @property
+    def routes_unsupported(self) -> str:
+        """Why a request that asks for its expert routes is refused ("" =
+        it is served; by a dense family without any). Not a setting: the
+        pp stage forwards, the verify forwards and the slot cache's
+        admission hand no routes over."""
+        if self._pp > 1:
+            return "pipeline-parallel stage forwards hand no expert routes over"
+        if self._spec:
+            return (
+                "speculative decoding's verify forwards hand no expert "
+                "routes over"
+            )
+        if self.family.routes and self.cache_mode != "paged":
+            return (
+                "the slot cache's admission hands no expert routes over "
+                "(cache_mode='slot')"
+            )
+        return ""
+
+    @property
+    def _routes(self) -> bool:
+        """Whether the compiled programs return the expert sets they took.
+        A routed family's always do where they can (the sets feed the load
+        counters; a request that asks gets its own rows), so the programs
+        are the same whether or not anybody asks; a dense family's are
+        what they were."""
+        return self.family.routes and not self.routes_unsupported
 
     # ---- compiled functions -------------------------------------------------
 
@@ -777,13 +851,16 @@ class Engine:
     def jit(self, fn, **kw):
         """jax.jit whose calls run with this engine's mesh as the context
         mesh — where the attention kernels find the mesh their pallas_call
-        must be shard_mapped over (ops/dispatch.py)."""
+        must be shard_mapped over (ops/dispatch.py). `.lower` is the
+        jitted function's, for whoever wants the program and not a run
+        (call it under `jax.set_mesh(engine.mesh)`)."""
         jitted = jax.jit(fn, **kw)
 
         def call(*args):
             with jax.set_mesh(self.mesh):
                 return jitted(*args)
 
+        call.lower = jitted.lower
         return call
 
     def device_info(self) -> dict:
@@ -975,6 +1052,11 @@ class Engine:
         max_len = self.cfg.max_seq_len
         chunk = max(1, self.cfg.decode_chunk)
         page = self.cfg.page_size
+        # A routed family's forwards append their expert sets; each jit
+        # below then returns them beside the tokens the host already
+        # reads back. A dense family's programs are what they were.
+        routed = self._routes
+        route_kw = {"routes": True} if routed else {}
         if self._pp > 1:
             from functools import partial as _partial
 
@@ -987,7 +1069,8 @@ class Engine:
             from functools import partial as _partial
 
             decode_paged = _partial(
-                fam.decode_step_paged, attn_kernel=self.decode_kernel
+                fam.decode_step_paged, attn_kernel=self.decode_kernel,
+                **route_kw,
             )
 
         def _prefill_admit(
@@ -1014,11 +1097,13 @@ class Engine:
             forced = ints[:, 5]
             temp, topp = floats[:, 0], floats[:, 1]
             if lora is None:
-                logits, k_all, v_all = prefill_fn(params, mcfg, tokens, lengths)
+                logits, k_all, v_all, *routes = prefill_fn(
+                    params, mcfg, tokens, lengths, **route_kw
+                )
             else:
-                logits, k_all, v_all = prefill_fn(
+                logits, k_all, v_all, *routes = prefill_fn(
                     params, mcfg, tokens, lengths,
-                    lora=lora, lora_idx=adapters,
+                    lora=lora, lora_idx=adapters, **route_kw,
                 )
             # Per-row page coordinates: [A, S] ids/offsets; padded tails
             # (and padding rows) land in reserved scratch page 0.
@@ -1045,7 +1130,10 @@ class Engine:
                 topp=state["topp"].at[slots].set(topp),
                 lora_idx=state["lora_idx"].at[slots].set(adapters),
             )
-            return toks, kp, vp, bt, state
+            # Routed: the prompts' expert sets [A, S, routed layers, k]
+            # ride with the first tokens the admission blocks on.
+            head = (toks, routes[0]) if routed else toks
+            return head, kp, vp, bt, state
 
         self._prefill_admit_jit = self.jit(
             _prefill_admit,
@@ -1066,17 +1154,20 @@ class Engine:
             def body(carry, _):
                 tokens, positions, kp, vp = carry
                 if lora is None:
-                    logits, kp, vp = decode_paged(
+                    logits, kp, vp, *routes = decode_paged(
                         params, mcfg, tokens, positions, kp, vp, bt
                     )
                 else:
-                    logits, kp, vp = decode_paged(
+                    logits, kp, vp, *routes = decode_paged(
                         params, mcfg, tokens, positions, kp, vp, bt,
                         lora=lora, lora_idx=state["lora_idx"],
                     )
                 toks = sample(logits, seeds, positions + 1, temp, topk, topp)
                 next_pos = jnp.minimum(positions + 1, max_len - 1)
-                return (toks, next_pos, kp, vp), toks
+                # Routed: (tokens [B], expert sets [B, routed layers, k])
+                # of each step, stacked over the chunk by the scan.
+                out = (toks, routes[0]) if routed else toks
+                return (toks, next_pos, kp, vp), out
 
             (tokens, positions, kp, vp), toks_seq = jax.lax.scan(
                 body,
@@ -1376,18 +1467,20 @@ class Engine:
                 """One non-final chunk into the staging buffer. `ints`
                 packs [start, length, adapter]."""
                 start, length, adapter = ints[0], ints[1], ints[2]
-                _, ks, vs = chunk_fn(
+                _, ks, vs, *routes = chunk_fn(
                     params, mcfg, tokens, start, length, ks, vs,
                     want_logits=False,
                     lora=lora,
                     lora_idx=None if lora is None else adapter[None],
+                    **route_kw,
                 )
-                return ks, vs
+                return (ks, vs, *routes)  # routed: + [C, routed layers, k]
 
             self._stage_chunk_mid_jit = self.jit(
                 _stage_mid,
                 donate_argnums=(3, 4),
-                out_shardings=(stage_sharding, stage_sharding),
+                out_shardings=(stage_sharding, stage_sharding)
+                + ((None,) if routed else ()),
             )
 
             def _stage_last(
@@ -1404,11 +1497,12 @@ class Engine:
                 adapter, seed = ints[3], ints[4]
                 topk, forced = ints[5], ints[6]
                 temp, topp = floats[0], floats[1]
-                logits, ks, vs = chunk_fn(
+                logits, ks, vs, *routes = chunk_fn(
                     params, mcfg, tokens, start, length, ks, vs,
                     want_logits=True,
                     lora=lora,
                     lora_idx=None if lora is None else adapter[None],
+                    **route_kw,
                 )
                 page_ids, offsets = sequence_page_coords(
                     bt_row, length, max_len, page
@@ -1433,7 +1527,8 @@ class Engine:
                     topp=state["topp"].at[slot].set(topp),
                     lora_idx=state["lora_idx"].at[slot].set(adapter),
                 )
-                return tok, ks, vs, kp, vp, bt, state
+                head = (tok, routes[0]) if routed else tok
+                return head, ks, vs, kp, vp, bt, state
 
             self._stage_chunk_last_jit = self.jit(
                 _stage_last,
@@ -1501,6 +1596,7 @@ class Engine:
         client: str = "",
         deadline_ms: float | None = None,
         resume_tokens: list[int] | None = None,
+        routes: bool = False,
     ) -> int:
         """Queue a request. `on_admit(rid)` runs under the engine lock
         before the request becomes visible to `step()` — callers use it to
@@ -1522,7 +1618,16 @@ class Engine:
         Because the sampler is seeded and position-folded (stateless
         given (seed, position)), a seeded or greedy continuation is
         token-identical to the uninterrupted stream; unseeded sampling
-        resumes with this replica's entropy and stays merely plausible."""
+        resumes with this replica's entropy and stays merely plausible.
+
+        `routes=True` asks for the request's expert routes on its events
+        (`StepEvent.routes`). A dense family serves it without any; an
+        engine whose forwards hand none over (`routes_unsupported`)
+        refuses with ValueError."""
+        if routes and self.routes_unsupported:
+            raise ValueError(
+                f"expert routes are not available: {self.routes_unsupported}"
+            )
         params = params or SamplingParams()
         resume = [int(t) for t in (resume_tokens or [])]
         if resume:
@@ -1576,6 +1681,7 @@ class Engine:
                 out_tokens=resume,
                 stop_token_ids=self.eos_token_ids,
                 t_enqueue=_now(),
+                route_backlog=[] if routes and self._routes else None,
             )
             self._requests[rid] = req
             if on_admit is not None:
@@ -1596,6 +1702,8 @@ class Engine:
                 # scheduling args: the request never becomes visible.
                 del self._requests[rid]
                 raise
+            if req.route_backlog is not None:
+                self.route_stats["requests"] += 1
             return rid
 
     def begin_drain(self) -> None:
@@ -1631,7 +1739,8 @@ class Engine:
         """Pop the accumulated latency observations: (kind, seconds) or
         (kind, seconds, exemplar_tag) with kind ∈ {queue_wait, prefill,
         ttft, itl, e2e} — ttft/itl carry a "rid-<n>" tag so the server's
-        histograms keep a last-request exemplar per bucket. The serve
+        histograms keep a last-request exemplar per bucket. A routed
+        family also queues (moe_imbalance, ratio) here. The serve
         loop (and the /metrics scrape) observes these into the server's
         histograms; draining transfers ownership so each record lands
         exactly once."""
@@ -1754,12 +1863,12 @@ class Engine:
                         break  # defer: nothing was popped, nothing is held
                     kind, batch, bucket, cached_len = plan
                     if kind == "batch":
-                        toks_dev = self._admit_paged_batch(batch, bucket)
-                        a_pad = toks_dev.shape[0]
+                        head = self._admit_paged_batch(batch, bucket)
+                        a_pad = self._head_tokens(head).shape[0]
                         padded = a_pad * bucket
                     else:
                         req, slot, seq, plen = batch[0][:4]
-                        toks_dev = (
+                        head = (
                             self._admit_prefix_hit(
                                 req, slot, seq, plen, cached_len
                             )
@@ -1770,18 +1879,27 @@ class Engine:
                         )
                         a_pad = 1
                         padded = -(-(plen - cached_len) // C) * C
+                blocks = [None] * len(batch)
                 with span("admit.wait") as wait:
-                    toks = np.asarray(toks_dev).reshape(-1)
+                    if self._routes:
+                        # The prompts' expert sets come back whole in the
+                        # transfer that brings the first tokens.
+                        toks, fetched = jax.device_get(head)
+                        toks = np.asarray(toks).reshape(-1)
+                    else:
+                        toks = np.asarray(head).reshape(-1)
+                if self._routes:
+                    blocks = self._admission_routes(batch, cached_len, fetched)
                 with span("admit.host") as tail:
-                    for (req, slot, _seq, plen, resumed, hashes), tok in zip(
-                        batch, toks
-                    ):
+                    for (
+                        (req, slot, _seq, plen, resumed, hashes), tok, block
+                    ) in zip(batch, toks, blocks):
                         if not resumed:
                             self._note_prefix_admission(
                                 req, slot, plen, cached_len, hashes
                             )
                         ev = self._finish_admission(
-                            req, slot, plen, int(tok), resumed
+                            req, slot, plen, int(tok), resumed, block
                         )
                         if ev is not None:
                             emitted.append(ev)
@@ -1796,6 +1914,85 @@ class Engine:
             self._timing.append(("admit_host", host.seconds + tail.seconds))
             self._timing.append(("admit_wait", wait.seconds))
         return emitted
+
+    def _head_tokens(self, head):
+        """The sampled first tokens of what an admission call returned: a
+        routed family's call returns them with its expert sets."""
+        return head[0] if self._routes else head
+
+    def _admission_routes(self, batch, cached_len: int, fetched) -> list:
+        """One admission call's expert sets, on the host: count them, and
+        cut each asking request's block `(start, rows)` (None for the
+        others). `fetched` is the fused call's whole `[A, S, routed
+        layers, k]` buffer, or the staged chunks' `(start, [C, routed
+        layers, k])` in the order they ran: a later chunk overwrites the
+        positions it recomputed, as it did in the cache."""
+        with self.profiler.span(
+            "step.routes", layers=self.moe["routed_layers"],
+            k=self.moe["k"],
+            asked=sum(e[0].route_backlog is not None for e in batch),
+        ) as sp:
+            if isinstance(fetched, list):
+                plen = batch[0][3]
+                rows = np.empty(
+                    (plen - cached_len, *fetched[0][1].shape[1:]),
+                    fetched[0][1].dtype,
+                )
+                for start, part in fetched:
+                    n = min(len(part), plen - start)
+                    rows[start - cached_len : start - cached_len + n] = part[:n]
+                per_request = [rows]
+                nbytes = sum(part.nbytes for _, part in fetched)
+            else:
+                per_request = [
+                    fetched[i, : entry[3]] for i, entry in enumerate(batch)
+                ]
+                nbytes = fetched.nbytes
+            kept = (
+                np.concatenate(per_request) if len(per_request) > 1
+                else per_request[0]
+            )
+            self._count_routes(
+                kept, np.zeros(len(kept), np.int64), 1, "prefill"
+            )
+            sp.note(rows=len(kept), bytes=nbytes)
+            return [
+                (cached_len, rows) if entry[0].route_backlog is not None
+                else None
+                for entry, rows in zip(batch, per_request)
+            ]
+
+    def _count_routes(
+        self, rows: np.ndarray, forward: np.ndarray, n_forwards: int,
+        kind: str,
+    ) -> None:
+        """Fold kept rows `[n, routed layers, k]` into `route_stats`, and
+        queue one imbalance reading per forward pass and routed layer:
+        the fullest expert's load over the mean load among that
+        forward's kept rows (`forward[i]` says which pass row i was in)."""
+        n, layers, k = rows.shape
+        experts = self.moe["experts"]
+        cell = forward[:, None, None] * layers + np.arange(layers)[None, :, None]
+        counts = np.bincount(
+            (cell * experts + rows).ravel(),
+            minlength=n_forwards * layers * experts,
+        ).reshape(n_forwards, layers, experts)
+        self.route_stats["expert_tokens"] += counts.sum((0, 1))
+        self.route_stats["rows_" + kind] += n
+        per_forward = np.bincount(forward, minlength=n_forwards)
+        live = per_forward > 0
+        ratio = counts[live].max(-1) * experts / (per_forward[live, None] * k)
+        self._timing.extend(
+            ("moe_imbalance", v) for v in ratio.ravel().tolist()
+        )
+
+    def _hand_routes(self, req: _Request, block: tuple) -> tuple:
+        """The blocks that ride on the event being made for an asking
+        request: what waited for one, then `block`."""
+        blocks = (*req.route_backlog, block)
+        req.route_backlog.clear()
+        self.route_stats["rows_sent"] += sum(len(b[1]) for b in blocks)
+        return blocks
 
     def _plan_admission(self):
         """Pop the next admission off the scheduler and grant its slots
@@ -2024,10 +2221,13 @@ class Engine:
         final chunk) — shared by chunked admission and prefix-cache-hit
         suffix prefill so the two paths cannot drift. Like
         _admit_paged_batch it returns the sampled first token ON THE
-        DEVICE: the caller decides where the host blocks on it."""
+        DEVICE: the caller decides where the host blocks on it. A routed
+        family's comes with every chunk's expert sets, `(token, [(start,
+        sets), ...])` in the order the chunks ran."""
         last_start, last_tokens = last
+        routes = []
         for start, tokens in mids:
-            self._stage_k, self._stage_v = self._stage_chunk_mid_jit(
+            self._stage_k, self._stage_v, *sets = self._stage_chunk_mid_jit(
                 self.params,
                 jnp.asarray(tokens),
                 jnp.asarray([start, plen, req.adapter_idx], jnp.int32),
@@ -2035,9 +2235,10 @@ class Engine:
                 self._stage_v,
                 self._lora,
             )
+            routes.extend((start, r) for r in sets)
         forced = req.out_tokens[-1] if req.out_tokens else -1
         (
-            tok_dev,
+            head,
             self._stage_k,
             self._stage_v,
             self.cache.k_pages,
@@ -2071,7 +2272,9 @@ class Engine:
             self._state,
             self._lora,
         )
-        return tok_dev
+        if not self._routes:
+            return head
+        return head[0], [*routes, (last_start, head[1])]
 
     def _admit_paged_batch(self, batch, bucket: int) -> jax.Array:
         A = len(batch)
@@ -2103,7 +2306,7 @@ class Engine:
             floats[i] = [req.params.temperature, req.params.top_p]
             bt_rows[i] = self._bt_host[slot]
         (
-            toks_dev,
+            head,
             self.cache.k_pages,
             self.cache.v_pages,
             self.cache.block_tables,
@@ -2129,17 +2332,24 @@ class Engine:
                 self._dk,
                 self._dv,
             )
-        return toks_dev  # [a_pad]; rows past len(batch) are padding
+        # Tokens [a_pad] (rows past len(batch) are padding); a routed
+        # family's come with the call's expert sets.
+        return head
 
     def _finish_admission(
         self, req: _Request, slot: int, plen: int, tok: int,
-        resumed: bool = False,
+        resumed: bool = False, block: tuple | None = None,
     ) -> StepEvent | None:
+        """`block`: the admission's expert sets for a request that asked,
+        `(first computed position, rows)`."""
         if resumed:
             if req.done:  # finished/cancelled while pending: don't revive
                 self._release(req)
                 return None
-            # tok is the FORCED already-emitted last token; no new event.
+            # tok is the FORCED already-emitted last token; no new event,
+            # so the recomputed rows wait for the next token's.
+            if block is not None:
+                req.route_backlog.append(block)
             req.position = plen
             req.last_token = tok
             self._active[slot] = req
@@ -2164,7 +2374,10 @@ class Engine:
             self._release(req)
         else:
             self._active[slot] = req
-        return StepEvent(req.rid, tok, finished, req.finish_reason)
+        return StepEvent(
+            req.rid, tok, finished, req.finish_reason,
+            None if block is None else self._hand_routes(req, block),
+        )
 
     @staticmethod
     def _chunk_plan(seq: list[int], plen: int, C: int):
@@ -2510,16 +2723,15 @@ class Engine:
                 C = self.cfg.prefill_chunk
                 hashes = self._prefix_hashes(seq, adapter_idx)
                 if C > 0 and plen > C:
-                    tok = int(
-                        self._admit_chunked_paged(req, slot, seq, plen, C)
-                    )
+                    head = self._admit_chunked_paged(req, slot, seq, plen, C)
                 else:
-                    tok = int(
-                        self._admit_paged_batch(
-                            [(req, slot, seq, plen, False, None)],
-                            self._bucket(plen),
-                        )[0]
+                    head = self._admit_paged_batch(
+                        [(req, slot, seq, plen, False, None)],
+                        self._bucket(plen),
                     )
+                # A handoff carries no expert routes (a request that asks
+                # of a disaggregated half is refused by the server).
+                tok = int(np.asarray(self._head_tokens(head)).reshape(-1)[0])
                 self._timing.append(("prefill", max(0.0, _now() - t0)))
                 self._timing.append(
                     ("ttft", max(0.0, _now() - t0), f"rid-{rid}")
@@ -3114,6 +3326,7 @@ class Engine:
             self._inflight = None
             current = None
             decode_mode = None
+            routes_seq = None  # routed: the chunk's expert sets, on device
             t0 = time.perf_counter()
             if self._active and prev is not None:
                 # SEQ-CAP BARRIER: dispatching chunk N+1 before reaping N
@@ -3202,6 +3415,8 @@ class Engine:
                             self._state,
                             self._lora,
                         )
+                        if self._routes:
+                            toks_seq, routes_seq = toks_seq
                         if self._draft:
                             # Keep the draft cache in lockstep with the
                             # chunk the target just decoded (see
@@ -3221,6 +3436,7 @@ class Engine:
                     list(self._active.items()),
                     chunk_len,
                     time.monotonic(),
+                    routes_seq,
                 )
                 if self._overlap and not is_spec and not self._spec:
                     # Reap current NEXT call: the device computes through
@@ -3337,14 +3553,20 @@ class Engine:
                 )
             if not cols:
                 return []  # every rider cancelled since dispatch — no transfer
+            routes_seq = inflight[4]
             col_of = None
             if len(cols) < int(toks_seq.shape[1]):
-                # Slice to the ACTIVE rows on-device before the host
-                # transfer: the decode chunk is a padded [chunk, B] buffer
-                # and fetching dead columns ships chunk*(B-A) junk tokens
-                # per step. The gather is a dependent device op, so timing
-                # block_until_ready on its output still measures the
-                # chunk's compute wait.
+                # Slice the TOKENS to the active rows on-device before the
+                # host transfer: the decode chunk is a padded [chunk, B]
+                # int32 buffer and fetching dead columns ships chunk*(B-A)
+                # junk tokens per step. The gather is a dependent device
+                # op, so timing block_until_ready on its output still
+                # measures the chunk's compute wait. A routed family's
+                # expert sets ([chunk, B, routed layers, k], one byte an
+                # id: 4 KB at 8 x 16 x 16 x 2) are read WHOLE: a second
+                # eager gather would be one more program and launch per
+                # reap for a buffer smaller than its dispatch, and the
+                # host indexes them by slot.
                 toks_seq = jnp.take(
                     toks_seq, jnp.asarray(cols, jnp.int32), axis=1
                 )
@@ -3354,7 +3576,15 @@ class Engine:
             with span("step.overlap_idle"):
                 toks_seq = jax.block_until_ready(toks_seq)
             with span("step.readback"):
-                toks_seq = np.asarray(jax.device_get(toks_seq))  # [chunk, A]
+                if routes_seq is None:
+                    toks_seq = np.asarray(jax.device_get(toks_seq))  # [chunk, A]
+                else:
+                    # The same transfer brings the chunk's expert sets.
+                    toks_seq, routes_seq = jax.device_get(
+                        (toks_seq, routes_seq)
+                    )
+            asked: list[tuple] = []  # (event index, step, slot, req, position)
+            kept: list[tuple[int, int]] = []  # (step, slot) whose token was kept
             with span("step.sample"):
                 emitted: list[StepEvent] = []
                 for k in range(toks_seq.shape[0]):
@@ -3366,6 +3596,12 @@ class Engine:
                     for slot, req in chunk_slots:
                         if req.done:
                             continue  # surplus chunk tokens discarded
+                        if routes_seq is not None:
+                            kept.append((k, slot))
+                            if req.route_backlog is not None:
+                                asked.append(
+                                    (len(emitted), k, slot, req, req.position)
+                                )
                         self._emit_token(
                             req,
                             int(toks_seq[
@@ -3374,7 +3610,32 @@ class Engine:
                             now,
                             emitted,
                         )
+            if routes_seq is not None:
+                self._chunk_routes(routes_seq, kept, asked, emitted)
             return emitted
+
+    def _chunk_routes(self, routes_seq, kept, asked, emitted) -> None:
+        """The host's work on a reaped chunk's expert sets `[chunk, B,
+        routed layers, k]`: count the rows whose tokens were kept (a
+        step's surplus past a stop is dropped with its token), and put
+        each asking request's row on the event of the token it produced:
+        the step's input sat at the request's position before the token."""
+        with self.profiler.span(
+            "step.routes", rows=len(kept), layers=self.moe["routed_layers"],
+            k=self.moe["k"], bytes=routes_seq.nbytes,
+            asked=len({entry[3].rid for entry in asked}),
+        ):
+            if kept:
+                steps, slots = np.asarray(kept).T
+                self._count_routes(
+                    routes_seq[steps, slots], steps, len(routes_seq), "decode"
+                )
+            for i, k, slot, req, position in asked:
+                emitted[i] = emitted[i]._replace(
+                    routes=self._hand_routes(
+                        req, (position, routes_seq[k, slot][None])
+                    )
+                )
 
     def _emit_token(
         self, req: _Request, tok: int, now: float, emitted: list[StepEvent]

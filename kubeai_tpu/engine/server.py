@@ -38,6 +38,7 @@ from kubeai_tpu.engine.engine import (
     EngineDraining,
     StepEvent,
 )
+from kubeai_tpu.engine.routes import encode_block, join_blocks
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.metrics import flightrecorder, tracing
 from kubeai_tpu.engine.tokenizer import Tokenizer, load_tokenizer
@@ -305,7 +306,50 @@ class EngineMetrics:
             self.registry,
             buckets=ITL_BUCKETS_S,
         )
+        # -- a routed family's expert load and the hand-over of routes
+        # (docs/concepts/expert-routes.md). Nothing moves for a dense one.
+        self.moe_expert_tokens = Counter(
+            "kubeai_engine_moe_expert_tokens_total",
+            "Token-layer assignments per global expert id (label "
+            "`expert`), summed over routed layers, over the rows whose "
+            "tokens were kept. Over any window their sum is "
+            "kubeai_engine_route_rows_total x routed layers x k.",
+            self.registry,
+        )
+        self.moe_imbalance = Histogram(
+            "kubeai_engine_moe_imbalance_ratio",
+            "The fullest expert's load over the mean load among the kept "
+            "rows of one forward pass (a decode step's live slots, an "
+            "admission call's prompt rows), one observation per pass and "
+            "routed layer. 1.0 = even; experts / k = every row took the "
+            "same set.",
+            self.registry,
+            buckets=(1.0, 1.1, 1.25, 1.5, 1.75, 2, 2.5, 3, 4, 6, 8, 16,
+                     32, 64, 128),
+        )
+        self.route_rows = Counter(
+            "kubeai_engine_route_rows_total",
+            "Positions whose expert sets the engine read back and whose "
+            "tokens were kept (label `kind`: prefill = prompt positions "
+            "an admission call computed, decode = decode steps whose "
+            "token was emitted).",
+            self.registry,
+        )
+        self.route_rows_sent = Counter(
+            "kubeai_engine_route_rows_sent_total",
+            "Rows of expert sets handed to requests that asked for them "
+            "(`kubeai_routes: true`), rows recomputed after a preemption "
+            "counted each time they are sent.",
+            self.registry,
+        )
+        self.route_requests = Counter(
+            "kubeai_engine_route_requests_total",
+            "Requests admitted that asked for their expert routes on an "
+            "engine that hands them over.",
+            self.registry,
+        )
         self._timing_hist = {
+            "moe_imbalance": self.moe_imbalance,
             "queue_wait": self.queue_wait,
             "prefill": self.prefill,
             "ttft": self.ttft,
@@ -364,11 +408,12 @@ class EngineMetrics:
             "kubeai_engine_step_phase_seconds",
             "Wall time per engine-step phase (label `phase`: schedule / "
             "prefill / decode / dispatch / overlap_idle / readback / "
-            "sample / kv_transfer) — the per-phase answer to 'why is ITL "
-            "high'. decode is the async jit DISPATCH; the device wait "
-            "surfaces as overlap_idle at reap (shrinking toward zero "
-            "under the overlapped step pipeline) and the token transfer "
-            "as readback.",
+            "sample / routes / kv_transfer) — the per-phase answer to "
+            "'why is ITL high'. decode is the async jit DISPATCH; the "
+            "device wait surfaces as overlap_idle at reap (shrinking "
+            "toward zero under the overlapped step pipeline) and the "
+            "token transfer as readback; routes is a routed family's "
+            "host work on the expert sets a step read back.",
             self.registry,
             buckets=ITL_BUCKETS_S,
         )
@@ -530,6 +575,19 @@ class EngineMetrics:
                  {"kind": "pad"}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
+        rstats = getattr(inner, "route_stats", None)
+        if rstats and getattr(inner, "moe", None):
+            for counter, total, labels in (
+                *(
+                    (self.moe_expert_tokens, int(n), {"expert": str(e)})
+                    for e, n in enumerate(rstats["expert_tokens"])
+                ),
+                (self.route_rows, rstats["rows_prefill"], {"kind": "prefill"}),
+                (self.route_rows, rstats["rows_decode"], {"kind": "decode"}),
+                (self.route_rows_sent, rstats["rows_sent"], {}),
+                (self.route_requests, rstats["requests"], {}),
+            ):
+                counter.inc(max(0.0, total - counter.get(**labels)), **labels)
         dstats = getattr(inner, "disagg_stats", None)
         if dstats:
             for direction, count_key, bytes_key in (
@@ -624,7 +682,11 @@ def engine_state_snapshot(engine) -> dict:
     sched = getattr(inner, "scheduler", None)
     kv_info = getattr(inner, "kv_cache_info", None)
     dev_info = getattr(inner, "device_info", None)
+    moe = getattr(inner, "moe", None)
     return {
+        # A family with a router: experts, k, routed layers, and whether
+        # this engine hands the routes over. A dense family has no key.
+        **({"moe": dict(moe)} if moe else {}),
         "slots_active": engine.num_active,
         "requests_pending": engine.num_pending,
         "kv_utilization": kvu() if kvu is not None else 0.0,
@@ -826,7 +888,7 @@ class EngineServer:
                     # parsing Prometheus text.
                     return self._json(
                         200,
-                        {
+                        outer._with_served_routes({
                             "model": outer.served_model_name,
                             "healthy": outer.healthy(),
                             "draining": outer.draining,
@@ -860,7 +922,7 @@ class EngineServer:
                                 outer.recorder.state_payload()
                             ),
                             **engine_state_snapshot(outer.engine),
-                        },
+                        }),
                     )
                 return self._json(404, {"error": {"message": "not found"}})
 
@@ -1329,12 +1391,31 @@ class EngineServer:
     def _handle_generate(self, http, body: dict, chat: bool):
         if self._draining.is_set():
             return self._drain_refusal(http)
+        # `kubeai_routes: true` asks for the expert sets the program took
+        # at every position it computed for this request. Refused, before
+        # anything is queued, where this replica cannot hand them over.
+        want_routes = body.get("kubeai_routes")
+        if want_routes is not None and not isinstance(want_routes, bool):
+            return http._json(
+                400, {"error": {"message": "kubeai_routes must be a boolean"}}
+            )
+        hid = (http.headers.get("X-Disagg-Handoff") or "").strip()
+        if want_routes:
+            refusal = self._routes_refusal() or (
+                "a request admitted from a KV handoff has no prompt rows "
+                "to hand over" if hid else ""
+            )
+            if refusal:
+                return http._json(
+                    400,
+                    {"error": {"message":
+                               f"kubeai_routes is not available: {refusal}"}},
+                )
         if self.role == "prefill":
             # A prefill-role engine NEVER enters decode: every generate
             # becomes prefill → KV handoff pushed to the decode address
             # the router named.
             return self._handle_prefill_generate(http, body, chat)
-        hid = (http.headers.get("X-Disagg-Handoff") or "").strip()
         if hid:
             return self._handle_decode_from_handoff(http, body, chat, hid)
         model_field = str(body.get("model") or self.served_model_name)
@@ -1477,14 +1558,16 @@ class EngineServer:
 
                 # kwargs-gated so engine stand-ins (tests) that predate
                 # continuation support keep working untouched.
-                resume_kw = (
+                opt_kw = (
                     {"resume_tokens": resume_tokens}
                     if resume_tokens and i == 0 else {}
                 )
+                if want_routes:
+                    opt_kw["routes"] = True
                 rid_i = self.engine.add_request(
                     prompt_ids, sp_i, adapter=adapter, on_admit=register,
                     priority=priority, client=sched_client,
-                    deadline_ms=deadline_ms, **resume_kw,
+                    deadline_ms=deadline_ms, **opt_kw,
                 )
                 reqs.append((rid_i, sub_i, sp_i))
         except DeadlineInfeasible as e:
@@ -1538,17 +1621,22 @@ class EngineServer:
         self._work.set()
         t0 = time.monotonic()
         span = getattr(http, "current_span", None)
+        # None = did not ask; False = asked of a family without a router,
+        # which is served and told so; True = its blocks ride along.
+        routes = None if not want_routes else self._moe() is not None
         try:
             if stream:
                 self._stream_response(http, reqs, display, chat, t0=t0,
                                       span=span,
                                       resume_tokens=resume_tokens,
-                                      resume_emitted=resume_emitted)
+                                      resume_emitted=resume_emitted,
+                                      routes=routes)
             else:
                 self._unary_response(http, reqs, display, chat,
                                      len(prompt_ids),
                                      resume_tokens=resume_tokens,
-                                     resume_emitted=resume_emitted)
+                                     resume_emitted=resume_emitted,
+                                     routes=routes)
         finally:
             # The duration the TTFT/e2e histograms see must also be
             # readable off the trace — spans and metrics have to agree.
@@ -1563,6 +1651,33 @@ class EngineServer:
                 with self._sub_lock:
                     self._subscribers.pop(rid_i, None)
             self.metrics.active_requests.dec()
+
+    # -- expert routes -----------------------------------------------------------
+
+    def _moe(self) -> dict | None:
+        inner = getattr(self.engine, "inner", self.engine)
+        return getattr(inner, "moe", None)
+
+    def _routes_refusal(self) -> str:
+        """Why a request that asks for its expert routes is refused here
+        ("" = it is served: with routes, or without where the family has
+        no router)."""
+        if self.role != "unified":
+            return (
+                f"a {self.role}-role replica of a disaggregated pair hands "
+                "no expert routes over"
+            )
+        if getattr(self.engine, "is_lockstep", False):
+            return "multi-host replicas hand no expert routes over"
+        inner = getattr(self.engine, "inner", self.engine)
+        return getattr(inner, "routes_unsupported", "")
+
+    def _with_served_routes(self, state: dict) -> dict:
+        """/v1/state's `moe.routes` is what a request would get: false
+        too where the engine could but this server refuses."""
+        if "moe" in state and self._routes_refusal():
+            state["moe"]["routes"] = False
+        return state
 
     # -- scheduling & validation helpers ---------------------------------------
 
@@ -2131,7 +2246,7 @@ class EngineServer:
         )
 
     def _collect(self, rid, sub, sp, on_delta=None, deadline=None,
-                 resume_tokens=(), resume_emitted=None):
+                 resume_tokens=(), resume_emitted=None, routes=None):
         """Drain tokens; detokenize incrementally; apply stop strings.
         Returns (text, finish_reason, n_completion_tokens).
 
@@ -2147,7 +2262,13 @@ class EngineServer:
         whole resumed text). on_delta receives (delta_text, new_tokens):
         the tokens consumed since its previous call, which streaming
         chunks expose as `token_ids` so the proxy can resume THIS stream
-        too if it dies."""
+        too if it dies.
+
+        `routes`: for a request that asked for its expert routes, the
+        list its events' blocks are appended to; `on_delta` takes out
+        what it sends. So that every consumed token goes out with its
+        row, `on_delta` is then also called with an empty delta when the
+        stream ends with tokens or rows unsent."""
         tokens: list[int] = list(resume_tokens)
         sent_tokens = len(tokens)
         if tokens:
@@ -2162,6 +2283,12 @@ class EngineServer:
         stopped = None  # the result, once a stop string ended the request
         if deadline is None:
             deadline = time.monotonic() + self.request_timeout
+
+        def owed() -> bool:
+            return routes is not None and (
+                bool(routes) or sent_tokens < len(tokens)
+            )
+
         done = False
         while not done:
             try:
@@ -2189,6 +2316,8 @@ class EngineServer:
                         finish, done = "timeout", True
                         break
                     tokens.append(ev.token)
+                    if routes is not None and ev.routes:
+                        routes.extend(ev.routes)
                     self.metrics.generated_tokens.inc()
                     text = self.tokenizer.decode(tokens)
                     # Stop strings act on detokenized text (engine core is
@@ -2201,7 +2330,7 @@ class EngineServer:
                             stop_hit = idx
                             break
                     if stop_hit is not None:
-                        if on_delta and stop_hit > emitted_len:
+                        if on_delta and (stop_hit > emitted_len or owed()):
                             on_delta(text[emitted_len:stop_hit],
                                      tokens[sent_tokens:])
                             sent_tokens = len(tokens)
@@ -2232,12 +2361,12 @@ class EngineServer:
         if stopped is not None:
             return stopped
         text = self.tokenizer.decode(tokens)
-        if on_delta and len(text) > emitted_len:
+        if on_delta and (len(text) > emitted_len or owed()):
             on_delta(text[emitted_len:], tokens[sent_tokens:])
         return text, finish, len(tokens)
 
     def _unary_response(self, http, reqs, display, chat, n_prompt,
-                        resume_tokens=(), resume_emitted=None):
+                        resume_tokens=(), resume_emitted=None, routes=None):
         # Usage counts the tokens actually generated (re-encoding the text
         # diverges around merges/special tokens and from the
         # generated_tokens metric). Choices decode CONCURRENTLY in the
@@ -2248,26 +2377,46 @@ class EngineServer:
         any_timeout = False
         deadline = time.monotonic() + self.request_timeout
         for i, (rid, sub, sp_i) in enumerate(reqs):
+            # A request that asked for its expert routes gets, in each
+            # choice, the tokens served and the blocks as one list (the
+            # same blocks a stream's chunks carry, joined where they
+            # touch); null where the family has no router.
+            ids: list[int] = []
+            blocks: list | None = None if routes is None else []
             text, finish, completion_tokens = self._collect(
                 rid, sub, sp_i, deadline=deadline,
+                on_delta=(
+                    None if routes is None
+                    else lambda _text, new_tokens=(): ids.extend(new_tokens)
+                ),
                 resume_tokens=resume_tokens if i == 0 else (),
                 resume_emitted=resume_emitted if i == 0 else None,
+                routes=blocks,
             )
             if finish == "timeout":
                 any_timeout = True
                 finish = "length"  # partial result; valid OpenAI value
             total_completion += completion_tokens
+            extra = {} if routes is None else {
+                "token_ids": [int(t) for t in ids],
+                "kubeai_routes": (
+                    [encode_block(*b) for b in join_blocks(blocks)]
+                    if routes else None
+                ),
+            }
             if chat:
                 choices.append(
                     {
                         "index": i,
                         "message": {"role": "assistant", "content": text},
                         "finish_reason": finish,
+                        **extra,
                     }
                 )
             else:
                 choices.append(
-                    {"index": i, "text": text, "finish_reason": finish}
+                    {"index": i, "text": text, "finish_reason": finish,
+                     **extra}
                 )
         if any_timeout and total_completion == 0:
             # No choice produced a single token within the budget —
@@ -2303,7 +2452,7 @@ class EngineServer:
         http._json(200, payload)
 
     def _stream_response(self, http, reqs, display, chat, t0=None, span=None,
-                         resume_tokens=(), resume_emitted=None):
+                         resume_tokens=(), resume_emitted=None, routes=None):
         """SSE stream. With n > 1 the choices stream SEQUENTIALLY in index
         order (each chunk carries its index, which is all the protocol
         requires); later choices decode concurrently and buffer while an
@@ -2312,7 +2461,10 @@ class EngineServer:
         Every content chunk carries a top-level `token_ids` field — the
         raw tokens behind its delta — which OpenAI clients ignore and
         the routing proxy accumulates so it can resume the stream as a
-        continuation request when this replica dies mid-generation."""
+        continuation request when this replica dies mid-generation. A
+        request that asked for its expert routes gets beside it
+        `kubeai_routes`: the blocks of the rows computed since its last
+        chunk (null where the family has no router)."""
         http.send_response(200)
         http.send_header("Content-Type", "text/event-stream")
         http.send_header("Cache-Control", "no-cache")
@@ -2326,7 +2478,7 @@ class EngineServer:
             http.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             http.wfile.flush()
 
-        def send_choice(choice: dict, token_ids=()):
+        def send_choice(choice: dict, token_ids=(), blocks=None):
             send_chunk(
                 {
                     "id": rid_s,
@@ -2340,14 +2492,24 @@ class EngineServer:
                         {"token_ids": [int(t) for t in token_ids]}
                         if token_ids else {}
                     ),
+                    **(
+                        {} if routes is None else
+                        {"kubeai_routes": [
+                            encode_block(*b) for b in blocks or ()
+                        ] if routes else None}
+                    ),
                 }
             )
 
         deadline = time.monotonic() + self.request_timeout
         ttft_seen = [False]
         for i, (rid, sub, sp_i) in enumerate(reqs):
+            unsent: list | None = None if routes is None else []
 
-            def on_delta(delta_text: str, new_tokens=(), _i=i):
+            def on_delta(delta_text: str, new_tokens=(), _i=i, _unsent=unsent):
+                blocks = None
+                if _unsent is not None:
+                    blocks, _unsent[:] = join_blocks(_unsent), []
                 if not ttft_seen[0]:
                     ttft_seen[0] = True
                     if span is not None and t0 is not None:
@@ -2361,19 +2523,20 @@ class EngineServer:
                             "delta": {"content": delta_text},
                             "finish_reason": None,
                         },
-                        token_ids=new_tokens,
+                        token_ids=new_tokens, blocks=blocks,
                     )
                 else:
                     send_choice(
                         {"index": _i, "text": delta_text,
                          "finish_reason": None},
-                        token_ids=new_tokens,
+                        token_ids=new_tokens, blocks=blocks,
                     )
 
             _text, finish, _n = self._collect(
                 rid, sub, sp_i, on_delta=on_delta, deadline=deadline,
                 resume_tokens=resume_tokens if i == 0 else (),
                 resume_emitted=resume_emitted if i == 0 else None,
+                routes=unsent,
             )
             if finish == "timeout":
                 # Headers are already on the wire; the best we can do is a

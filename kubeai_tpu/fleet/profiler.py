@@ -20,6 +20,11 @@ step into phases:
   readback   — jax.device_get of the (ready) decode chunk: the actual
                device→host token transfer.
   sample     — host-side token emission (stop checks, slot release)
+  routes     — a routed family's host work on the expert sets a step
+               read back (`step.routes`, under a reap and under each
+               admission call): counting the experts' load and cutting
+               the rows of the requests that asked for them. The part
+               under an admission call is inside `prefill` as well.
   kv_transfer — paged-KV handoff export/import (disaggregated serving;
                recorded outside the step timeline)
 
@@ -53,7 +58,7 @@ from collections import deque
 # Canonical phase vocabulary (metric label values; docs list them).
 PHASES = (
     "schedule", "prefill", "decode", "dispatch", "overlap_idle",
-    "readback", "sample", "kv_transfer",
+    "readback", "sample", "routes", "kv_transfer",
 )
 _PHASE_OF_SPAN = {"step." + p: p for p in PHASES}
 

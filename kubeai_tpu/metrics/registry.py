@@ -279,7 +279,8 @@ _METRIC_NAME_RE = re.compile(r"^kubeai_[a-z0-9_]+$")
 def lint_registry(registry: Registry) -> list[str]:
     """Metric-name hygiene for one registry: names match
     `^kubeai_[a-z0-9_]+$` and are unique, counters end in `_total`,
-    histograms in `_seconds`. Returns human-readable violations (empty =
+    histograms in their unit (`_seconds`, or `_ratio` for one of a
+    quotient). Returns human-readable violations (empty =
     clean); a unit test walks every instrument bundle through this so new
     instruments can't silently drift from the naming scheme."""
     errors: list[str] = []
@@ -293,8 +294,10 @@ def lint_registry(registry: Registry) -> list[str]:
             errors.append(f"{m.name}: duplicate metric name in registry")
         seen.add(m.name)
         if isinstance(m, Histogram):
-            if not m.name.endswith("_seconds"):
-                errors.append(f"{m.name}: histogram must end in _seconds")
+            if not m.name.endswith(("_seconds", "_ratio")):
+                errors.append(
+                    f"{m.name}: histogram must end in _seconds or _ratio"
+                )
         elif isinstance(m, Counter):
             if not m.name.endswith("_total"):
                 errors.append(f"{m.name}: counter must end in _total")

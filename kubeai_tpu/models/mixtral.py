@@ -24,7 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from kubeai_tpu.models.llama import _prefill_attention
-from kubeai_tpu.models.registry import ModelFamily, register_model_family
+from kubeai_tpu.models.registry import (
+    ModelFamily,
+    register_model_family,
+    route_dtype,
+)
 from kubeai_tpu.ops.attention import decode_attention
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -49,6 +53,12 @@ class MixtralConfig:
     @property
     def head_size(self) -> int:
         return self.hidden_size // self.num_heads
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers that have a router: every one here. A model with leading
+        dense layers has fewer, and its forwards hand over that many."""
+        return self.num_layers
 
     @staticmethod
     def from_hf_dict(d: dict) -> "MixtralConfig":
@@ -148,6 +158,11 @@ def _moe_ffn(x, lp, cfg):
     Dense top-k MoE: softmax over the selected experts' router logits,
     all experts computed batched over the (sharded) expert axis, combine
     weighted by the routing probabilities.
+
+    Returns (y [B, S, E], topi [B, S, k]): the output and the global ids
+    of the experts each token took, in the router's order (best first).
+    `topi` is the tensor the weight map is scattered from, not a second
+    `top_k`, so what is handed over is what was computed with.
     """
     with jax.named_scope("moe_router"):
         router_logits = jnp.einsum(
@@ -169,10 +184,22 @@ def _moe_ffn(x, lp, cfg):
     y = jnp.einsum("bsxm,xme->bsxe", g * u, lp["w_down"])
     return jnp.einsum(
         "bsxe,bsx->bse", y, weights.astype(y.dtype)
-    )
+    ), topi
 
 
-def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None):
+def _stack_routes(topi_all, cfg):
+    """The layer scan's stacked `topi` [routed layers, *rows, k] as the
+    forwards hand it over: [*rows, routed layers, k], in the smallest
+    unsigned integer type that holds an expert id."""
+    return jnp.moveaxis(topi_all, 0, -2).astype(route_dtype(cfg.num_experts))
+
+
+def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
+            routes=False):
+    """Whole-prompt prefill. `routes=True` appends the expert sets taken,
+    [B, S, routed layers, k] (see `_stack_routes`); the engine always asks
+    for them, a caller that does not gets the three outputs it always
+    got. The same holds for the other three forwards below."""
     B, S = tokens.shape
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     inv_freq = jnp.asarray(rope_frequencies(D, cfg.rope_theta))
@@ -189,10 +216,10 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None):
         attn = _prefill_attention(q, k, v)
         x = x + jnp.einsum("bsh,he->bse", attn.reshape(B, S, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _moe_ffn(h2, lp, cfg)
-        return x, (k, v)
+        y, topi = _moe_ffn(h2, lp, cfg)
+        return x + y, (k, v, topi)
 
-    x, (k_all, v_all) = jax.lax.scan(layer, x, params["layers"])
+    x, (k_all, v_all, topi_all) = jax.lax.scan(layer, x, params["layers"])
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     idx = jnp.clip(lengths - 1, 0, S - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
@@ -200,11 +227,13 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None):
         "be,ve->bv", last, params["lm_head"],
         preferred_element_type=jnp.float32,
     )
+    if routes:
+        return logits, k_all, v_all, _stack_routes(topi_all, cfg)
     return logits, k_all, v_all
 
 
 def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
-                lora=None, lora_idx=None):
+                lora=None, lora_idx=None, *, routes=False):
     B = tokens.shape[0]
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
     inv_freq = jnp.asarray(rope_frequencies(D, cfg.rope_theta))
@@ -228,28 +257,31 @@ def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
         attn = decode_attention(q, kc, vc, lengths)
         x = x + jnp.einsum("bh,he->be", attn.reshape(B, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _moe_ffn(h2[:, None], lp, cfg)[:, 0]
-        return x, (kc, vc)
+        y, topi = _moe_ffn(h2[:, None], lp, cfg)
+        return x + y[:, 0], (kc, vc, topi[:, 0])
 
-    x, (k_cache, v_cache) = jax.lax.scan(
+    x, (k_cache, v_cache, topi_all) = jax.lax.scan(
         layer, x, {"p": params["layers"], "kc": k_cache, "vc": v_cache}
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     logits = jnp.einsum(
         "be,ve->bv", x, params["lm_head"], preferred_element_type=jnp.float32
     )
+    if routes:
+        return logits, k_cache, v_cache, _stack_routes(topi_all, cfg)
     return logits, k_cache, v_cache
 
 
 def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                       block_tables, lora=None, lora_idx=None, *,
-                      attn_kernel=None):
+                      attn_kernel=None, routes=False):
     """Paged decode (block tables). The attention layout follows the
     pool as in llama.decode_step_paged: a bf16 pool stays stacked outside
     the layer scan, is read in place by the layer-indexed kernel and
     written by one batched scatter after it ("fused"); a quantized pool
     takes scatter-then-attend inside the scan ("per_layer"). `attn_kernel`
-    names one explicitly. MoE FFN unchanged."""
+    names one explicitly. MoE FFN unchanged; `routes=True` appends the
+    expert sets of the B rows, [B, routed layers, k]."""
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
         paged_decode_attention,
@@ -287,7 +319,8 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     def layer_finish(x, attn, lp):
         x = x + jnp.einsum("bh,he->be", attn.reshape(B, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
-        return x + _moe_ffn(h2[:, None], lp, cfg)[:, 0]
+        y, topi = _moe_ffn(h2[:, None], lp, cfg)
+        return x + y[:, 0], topi[:, 0]
 
     if attn_kernel == "per_layer":
 
@@ -301,9 +334,10 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                 attn = paged_decode_attention(
                     q, kp, vp, block_tables, lengths
                 )
-            return layer_finish(x, attn, lp), (kp, vp)
+            x, topi = layer_finish(x, attn, lp)
+            return x, (kp, vp, topi)
 
-        x, (k_pages, v_pages) = jax.lax.scan(
+        x, (k_pages, v_pages, topi_all) = jax.lax.scan(
             layer_pl, x,
             {"p": params["layers"], "kp": k_pages, "vp": v_pages},
         )
@@ -317,9 +351,10 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
                     q, k_pages, v_pages, k, v, block_tables, positions,
                     scanned["li"],
                 )
-            return layer_finish(x, attn, lp), (k, v)
+            x, topi = layer_finish(x, attn, lp)
+            return x, (k, v, topi)
 
-        x, (k_all, v_all) = jax.lax.scan(
+        x, (k_all, v_all, topi_all) = jax.lax.scan(
             layer, x,
             {
                 "p": params["layers"],
@@ -337,6 +372,8 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
             "be,ve->bv", x, params["lm_head"],
             preferred_element_type=jnp.float32,
         )
+    if routes:
+        return logits, k_pages, v_pages, _stack_routes(topi_all, cfg)
     return logits, k_pages, v_pages
 
 
@@ -351,12 +388,15 @@ def prefill_chunk(
     want_logits: bool = False,
     lora=None,  # accepted for signature parity; mixtral carries no LoRA
     lora_idx=None,
+    *,
+    routes: bool = False,
 ):
     """Chunked incremental prefill for Mixtral (llama-pattern attention
     chunk + the dense top-k MoE FFN, which is shape-generic over the
     chunk's [1, C, E]). Enables chunked admission and the prefix cache
     for the MoE family; equivalence vs whole-prompt prefill is
-    test-enforced."""
+    test-enforced. `routes=True` appends the chunk's expert sets,
+    [C, routed layers, k], padding rows of a last chunk included."""
     from kubeai_tpu.ops.attention import chunked_prefill_attention
 
     B, C = tokens.shape
@@ -383,15 +423,16 @@ def prefill_chunk(
         attn = chunked_prefill_attention(q, kc[None], vc[None], start[None])
         x = x + jnp.einsum("bsh,he->bse", attn.reshape(B, C, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
-        x = x + _moe_ffn(h2, lp, cfg)
-        return x, {"kc": kc, "vc": vc}
+        y, topi = _moe_ffn(h2, lp, cfg)
+        return x + y, {"kc": kc, "vc": vc, "topi": topi[0]}
 
-    x, caches = jax.lax.scan(
+    x, outs = jax.lax.scan(
         layer, x, {"p": params["layers"], "kc": k_slot, "vc": v_slot}
     )
-    k_slot, v_slot = caches["kc"], caches["vc"]
+    k_slot, v_slot = outs["kc"], outs["vc"]
+    tail = (_stack_routes(outs["topi"], cfg),) if routes else ()
     if not want_logits:
-        return None, k_slot, v_slot
+        return (None, k_slot, v_slot, *tail)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     idx = jnp.clip(length - 1 - start, 0, C - 1)
     last = jax.lax.dynamic_slice(x, (0, idx, 0), (1, 1, x.shape[-1]))[:, 0]
@@ -399,7 +440,7 @@ def prefill_chunk(
         "be,ve->bv", last, params["lm_head"],
         preferred_element_type=jnp.float32,
     )
-    return logits, k_slot, v_slot
+    return (logits, k_slot, v_slot, *tail)
 
 
 register_model_family(
@@ -414,5 +455,8 @@ register_model_family(
         decode_step_paged=decode_step_paged,
         prefill_chunk=prefill_chunk,
         hf_architectures=("MixtralForCausalLM",),
+        route_dims=lambda cfg: (
+            cfg.num_experts, cfg.num_experts_per_tok, cfg.routed_layers
+        ),
     )
 )

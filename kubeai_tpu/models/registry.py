@@ -14,6 +14,14 @@ from typing import Callable
 _FAMILIES: dict[str, "ModelFamily"] = {}
 
 
+def route_dtype(num_experts: int) -> str:
+    """The smallest unsigned integer type that holds a global expert id:
+    what a routed family's forwards hand their expert sets over in."""
+    return "uint8" if num_experts <= 256 else (
+        "uint16" if num_experts <= 65536 else "uint32"
+    )
+
+
 class ModelFamily:
     """A family bundle: config parser, param init, prefill/decode fns."""
 
@@ -35,8 +43,16 @@ class ModelFamily:
         hf_architectures: tuple[str, ...] = (),
         feature: str = "TextGeneration",
         hidden_states=None,
+        route_dims: Callable | None = None,
     ):
         self.hidden_states = hidden_states
+        # A family whose FFN routes tokens to experts says so here:
+        # route_dims(cfg) -> (experts, experts per token, routed layers).
+        # Its prefill / decode_step / decode_step_paged / prefill_chunk
+        # then take `routes=True` and append the expert sets they took,
+        # [*rows, routed layers, k] of global expert ids (route_dtype).
+        # The pp stage forwards and the verify forwards hand none over.
+        self.route_dims = route_dims
         self.name = name
         self.config_from_hf = config_from_hf
         self.tiny_config = tiny_config
@@ -60,6 +76,11 @@ class ModelFamily:
         self.prefill_chunk = prefill_chunk
         self.hf_architectures = hf_architectures
         self.feature = feature
+
+    @property
+    def routes(self) -> bool:
+        """Whether this family has a router (derived: it said its dims)."""
+        return self.route_dims is not None
 
 
 def register_model_family(family: ModelFamily) -> ModelFamily:
