@@ -363,6 +363,10 @@ class Engine:
         # they computed against the tokens of the shapes they ran
         # (padded - useful = padding); EngineMetrics folds the deltas in.
         self.admit_stats = {"calls": 0, "useful_tokens": 0, "padded_tokens": 0}
+        # The KV a decode chunk reads, from the walk that grows the active
+        # slots' pages: slots and pages (ceil(tokens / page) each) at the
+        # newest dispatch, and the pages summed over every dispatched chunk.
+        self.live_kv = {"slots": 0, "pages": 0, "pages_total": 0}
 
         # Resolve the cache mode: paged needs family support; otherwise
         # fall back to the slot cache. Chunked prefill works in both modes
@@ -2479,11 +2483,14 @@ class Engine:
         from kubeai_tpu.engine.paged_cache import OutOfPages
 
         chunk = self._decode_lookahead() + max(0, int(inflight_lag))
+        page = self.cfg.page_size
+        live: dict[int, int] = {}  # slot -> pages that hold its tokens
         for slot, req in sorted(
             self._active.items(), key=lambda kv: kv[1].rid
         ):
             if self._active.get(slot) is not req:
                 continue  # preempted by an earlier iteration of this loop
+            live[slot] = -(-req.position // page)
             need = min(req.position + chunk + 1, self.cfg.max_seq_len)
             while True:
                 before = len(self._alloc.pages_for(slot))
@@ -2498,16 +2505,20 @@ class Engine:
                     # Victim selection: lowest priority class first (a
                     # batch request must never evict a realtime one),
                     # youngest within a class (least progress lost).
-                    self._preempt(max(
+                    victim = max(
                         victims,
                         key=lambda r: (
                             CLASS_RANK.get(r.priority, 0), r.rid
                         ),
-                    ))
+                    )
+                    live.pop(victim.slot, None)
+                    self._preempt(victim)
                     continue
                 break
             if len(pages) != before:
                 self._set_bt_row(slot, pages)
+        self.live_kv["slots"] = len(live)
+        self.live_kv["pages"] = sum(live.values())
 
     def _set_bt_row(self, slot: int, pages: list[int]) -> None:
         """Update the host block-table mirror for one slot and mark the
@@ -2642,6 +2653,9 @@ class Engine:
                 (self._n_pages - 1) * self.cfg.page_size
             )
             info["pool_bytes"] = int(self.cache.nbytes())
+            # What the newest decode chunk had to read (0 before the first).
+            info["live_slots"] = self.live_kv["slots"]
+            info["live_pages"] = self.live_kv["pages"]
         else:
             info["pool_bytes"] = int(
                 self.cache.k.nbytes + self.cache.v.nbytes
@@ -3357,7 +3371,12 @@ class Engine:
                                 jnp.asarray(self._bt_host), self._bt_sharding
                             )
                             self._bt_dirty = False
-                with span("step.decode", kv_layout=self.kv_layout):
+                self.live_kv["pages_total"] += self.live_kv["pages"]
+                with span(
+                    "step.decode", kv_layout=self.kv_layout,
+                    live_slots=self.live_kv["slots"],
+                    live_pages=self.live_kv["pages"],
+                ):
                     if self.cache_mode != "paged":
                         toks_seq, self.cache.k, self.cache.v, self._state = (
                             self._decode_jit(

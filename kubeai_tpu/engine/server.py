@@ -270,6 +270,14 @@ class EngineMetrics:
             "hit each).",
             self.registry,
         )
+        self.decode_live_pages = Counter(
+            "kubeai_engine_decode_live_pages_total",
+            "KV pages that hold the active slots' tokens (ceil(tokens / "
+            "page) a slot), added up once per dispatched decode chunk: "
+            "its delta over the chunks of a window is the mean live "
+            "pages the decode attention kernel reads a layer.",
+            self.registry,
+        )
         self.prefill_tokens = Counter(
             "kubeai_engine_prefill_tokens_total",
             "Token positions computed by admission calls (label `kind`: "
@@ -575,6 +583,10 @@ class EngineMetrics:
                  {"kind": "pad"}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
+        live = getattr(inner, "live_kv", None)
+        if live:
+            self.decode_live_pages.inc(max(
+                0.0, live["pages_total"] - self.decode_live_pages.get()))
         rstats = getattr(inner, "route_stats", None)
         if rstats and getattr(inner, "moe", None):
             for counter, total, labels in (

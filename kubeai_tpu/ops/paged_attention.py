@@ -13,9 +13,15 @@ actually occupies. Two implementations with one contract:
       are SCALAR-PREFETCHED so the BlockSpec index_map selects each
       slot's next real page for DMA. Pages past a slot's length re-map
       to the slot's LAST valid page — consecutive grid steps with an
-      unchanged block index elide the copy, so HBM traffic ≈
-      sum(ceil(len/page)) pages, not B*max_pages (the revisiting trick;
-      compute for those steps is skipped with pl.when).
+      unchanged block index elide the copy and pl.when skips their
+      compute, so the bytes read are sum(ceil(len/page)) pages. The time
+      is not: a grid step costs about 0.1 us per pool operand whether or
+      not a page is there (timed alone on a v5e in PR 28 on this grid
+      with the stacked pool: 24 slots x 32 page slots x 2 pools = 154 us
+      a layer with the attention taken out), so it follows B*max_pages.
+      pp stages and an explicit per_layer layout run it; the decode path
+      of a bf16 pool is the stacked kernel below, which walks the live
+      pages instead of a grid.
 
 Sliding-window (Gemma-2) and logit softcap are supported in both paths:
 window masks keys at positions < length - window.
@@ -625,38 +631,115 @@ def paged_verify_attention(
 #      copies each whole pool once a step.
 #   2. pallas_call is opaque to XLA, so the sliced operand MATERIALIZES
 #      (no fusion into the kernel).
-# This kernel takes the FULL [NL, ...] pool plus a scalar-prefetched layer
-# index (the index map adds the layer offset — no slicing, no
-# materialization), attends the NEW token as an explicit extra column
-# merged at finalize (so the pool stays read-only and the scatter defers
-# to ONE batched write after the layer scan), and DMAs a STRIP of pages
-# per grid step. Its jitted wrapper is named `_paged_pallas...` because the
-# benchmark's `paged_attn_ms` finds the custom call by that prefix.
+# This kernel takes the FULL [NL, ...] pool where it lies in HBM plus a
+# scalar-prefetched layer index (no slicing, no materialization) and attends
+# the NEW token as an explicit extra column merged when a slot finishes (so
+# the pool stays read-only and the scatter defers to ONE batched write after
+# the layer scan).
+#
+# Its time follows the live KV (timed alone on a v5e in PR 28, PERF.md
+# section 6). A grid over (slots, page slots) with one BlockSpec a page cost
+# 0.1 us per slot, page slot and pool whether or not a page was there (24 x
+# 32 x 2 of them: 154 us a layer with the attention taken out), so there is
+# no such grid: ONE invocation walks the live pages of the slots that hold
+# any, in order, with two cursors. The fetch cursor starts each page's K and
+# V copies (128 KiB each at Mistral's shapes, straight from the pool) into a
+# ring of VMEM buffers, `depth` pages ahead of the compute cursor, which
+# waits for its page, attends it and hands the buffer back. A slot that
+# holds no page costs a few scalar instructions. Its jitted wrapper is named
+# `_paged_pallas...` because the benchmark's `paged_attn_ms` finds the custom
+# call by that prefix.
+
+
+def _live_page_range(bt_ref, pos_ref, win_ref, b, *, page_size, max_pages):
+    """(first, n_pages): slot b's pages [first, n_pages) hold the OLD tokens
+    a decode step attends. Both cursors of the kernel ask here. A slot that
+    holds no page (its block-table row starts with -1: freed, or never
+    admitted) has none whatever its position says (the engine advances every
+    row's position, a free row's too, and clears only the row), so it
+    attends its own new token alone."""
+    pos = pos_ref[b]
+    win = win_ref[0]
+    n_pages = jnp.where(
+        bt_ref[b, 0] < 0, 0, jnp.minimum(pl.cdiv(pos, page_size), max_pages)
+    )
+    first = jnp.where(
+        win > 0, jnp.maximum(pos + 1 - win, 0) // page_size, 0
+    )
+    return first, n_pages
+
+
+def _split_bf16(p):
+    """f32 p as three bf16 pieces whose sum is p (8 + 8 + 8 mantissa bits):
+    p goes to the MXU against a bf16 V at full precision, in bf16 passes,
+    with no cast of V."""
+    hi = p.astype(jnp.bfloat16)
+    rest = p - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 def _fused_attend_page(
-    q_ref, k_ref, valid, m_ref, l_ref, acc_ref, v_ref,
-    *, scale, logit_softcap, kvh,
+    q, k, v, pos, lo, off, m_ref, l_ref, acc_ref,
+    *, scale, logit_softcap, kvh, group,
 ):
-    """Online-softmax update of all kv heads over one [page] block.
-    k_ref/v_ref are [1, 1, page, KVH, D] strip blocks."""
-    for kh in range(kvh):
-        q = q_ref[0, kh].astype(jnp.float32) * scale  # [G, D]
-        k = k_ref[0, 0, :, kh].astype(jnp.float32)  # [page, D]
-        v = v_ref[0, 0, :, kh].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [G, page]
-        if logit_softcap is not None:
-            s = jnp.tanh(s / logit_softcap) * logit_softcap
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[kh]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[kh] = m_new
-        l_ref[kh] = l_ref[kh] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[kh] = acc_ref[kh] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+    """One online-softmax update of all heads over one page: k, v are its
+    [page, KVH, D] blocks, q the slot's [H, D] query rows, off the page's
+    first token position.
+
+    A page is attended as it lies: its block read as the [page * KVH, D]
+    matrix it is (row = token, head), so one q x K^T dot gives every head's
+    scores [H, page * KVH] and one p x V dot every head's values, with the
+    pairs of a query row and another head's column masked out. Eight times
+    the useful multiplies on a matrix unit that was idle, in place of a
+    strided head slice, two casts, a transpose and two 4-row f32 dots per
+    head and page. Operands enter the dots in the dtype they are stored in
+    (bf16 x bf16 products are exact in the f32 accumulator; an f32 pool is
+    multiplied in f32); scale and softcap act on the f32 scores; p stays
+    f32, as three bf16 pieces when V is bf16."""
+    h = kvh * group
+    page, _, d = k.shape
+    cols = page * kvh
+    if q.dtype != k.dtype:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    # One bf16 pass is exact for bf16 operands; anything else in f32.
+    precision = (
+        jax.lax.Precision.DEFAULT if k.dtype == jnp.bfloat16
+        else jax.lax.Precision.HIGHEST
+    )
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0) // group
+    tok = col // kvh
+    # Old tokens only (the new one is merged at the slot's end), in-window.
+    valid = (
+        ((col % kvh) == row_head) & (tok < pos - off) & (tok >= lo - off)
+    )
+    s = jax.lax.dot_general(
+        q, k.reshape(cols, d), (((1,), (1,)), ((), ())),
+        precision=precision, preferred_element_type=jnp.float32,
+    ) * scale  # [H, cols]
+    if logit_softcap is not None:
+        s = jnp.tanh(s / logit_softcap) * logit_softcap
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    m_ref[:] = m_new
+    l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    v = v.reshape(cols, d)
+    if v.dtype == jnp.bfloat16:
+        pv = jnp.dot(
+            jnp.concatenate(_split_bf16(p), axis=0), v,
+            precision=precision, preferred_element_type=jnp.float32,
+        )  # [3H, D]: one pass of V for the three pieces
+        pv = pv[:h] + pv[h:2 * h] + pv[2 * h:]
+    else:
+        pv = jnp.dot(
+            p, v, precision=precision, preferred_element_type=jnp.float32
         )
+    acc_ref[:] = acc_ref[:] * alpha + pv
 
 
 def _paged_fused_kernel(
@@ -665,99 +748,144 @@ def _paged_fused_kernel(
     pos_ref,  # [B] int32 OLD lengths (the new token's position)
     win_ref,  # [1] int32 sliding window (<= 0 = disabled)
     layer_ref,  # [1] int32 layer index into the stacked pool
-    # blocks
-    q_ref,  # [1, KVH, G, D]
-    kn_ref,  # [1, KVH, D] the new token's K (not yet in the pool)
-    vn_ref,  # [1, KVH, D]
-    *refs,  # strip k blocks, strip v blocks [1, 1, page, KVH, D], then o_ref
-    # (scratch appended by pallas: m, l, acc)
+    # whole arrays in VMEM
+    q_ref,  # [B, H, D], H = KVH * G, kv-head major
+    kn_ref,  # [B, H, D] the new token's K (not yet in the pool), per q head
+    vn_ref,  # [B, H, D]
+    # whole arrays where they lie (HBM)
+    k_hbm,  # [NL, P, page, KVH, D]
+    v_hbm,
+    o_ref,  # [B, H, D] VMEM
+    # scratch
+    k_buf,  # [depth, page, KVH, D] the ring
+    v_buf,
+    sems,  # DMA semaphores [2, depth]
+    m_ref,  # [H, 1] f32, the slot being attended
+    l_ref,  # [H, 1] f32
+    acc_ref,  # [H, D] f32
+    *,
     page_size: int,
     kvh: int,
     group: int,
-    strip: int,
+    depth: int,
     scale: float,
     logit_softcap: float | None,
 ):
-    k_refs = refs[:strip]
-    v_refs = refs[strip:2 * strip]
-    o_ref = refs[2 * strip]  # [1, KVH, G, D]
-    m_ref, l_ref, acc_ref = refs[2 * strip + 1:2 * strip + 4]
-
-    b = pl.program_id(0)
-    s = pl.program_id(1)
-    ns = pl.num_programs(1)
-    pos = pos_ref[b]
+    nb, mp = bt_ref.shape
+    layer = layer_ref[0]
     win = win_ref[0]
-    length = pos + 1  # including the new token
-    n_pages = pl.cdiv(pos, page_size)  # pages holding OLD tokens
-    first = jnp.where(
-        win > 0, jnp.maximum(length - win, 0) // page_size, 0
+    live = functools.partial(
+        _live_page_range, bt_ref, pos_ref, win_ref,
+        page_size=page_size, max_pages=mp,
     )
 
-    @pl.when(s == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    # A cursor is (slot, page): it walks the live pages of the slots that
+    # have any, in order; slot == nb means past the end (rows are read at
+    # min(slot, nb - 1), so a finished cursor reads nothing out of bounds).
+    def first_live(b):
+        """The first slot >= b that has a live page, at its first one."""
+        def dead(b):
+            first, n_pages = live(jnp.minimum(b, nb - 1))
+            return (b < nb) & (first >= n_pages)
 
-    for t in range(strip):
-        i = s * strip + t
+        b = jax.lax.while_loop(dead, lambda b: b + 1, b)
+        return b, live(jnp.minimum(b, nb - 1))[0]
 
-        @pl.when((i >= first) & (i < n_pages))
-        def _attend(i=i, t=t):
-            pcol = i * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (group, page_size), 1
-            )
-            valid = pcol < pos  # old tokens only; new token merged below
-            valid = valid & ((win <= 0) | (pcol >= length - win))
-            _fused_attend_page(
-                q_ref, k_refs[t], valid, m_ref, l_ref, acc_ref, v_refs[t],
-                scale=scale, logit_softcap=logit_softcap, kvh=kvh,
-            )
+    def advance(b, i):
+        _, n_pages = live(jnp.minimum(b, nb - 1))
+        # Dead slots are walked over only when a slot is left.
+        return jax.lax.cond(
+            i + 1 < n_pages, lambda: (b, i + 1), lambda: first_live(b + 1)
+        )
 
-    @pl.when(s == ns - 1)
-    def _finalize():
-        # Merge the new token as one extra column (always valid — it is
-        # the query's own position, inside any window), then normalize.
-        q = q_ref[0].astype(jnp.float32) * scale  # [KVH, G, D]
-        kn = kn_ref[0].astype(jnp.float32)  # [KVH, D]
-        vn = vn_ref[0].astype(jnp.float32)
-        s_new = jnp.sum(q * kn[:, None, :], axis=-1)  # [KVH, G]
-        if logit_softcap is not None:
-            s_new = jnp.tanh(s_new / logit_softcap) * logit_softcap
-        s_new = s_new[..., None]  # [KVH, G, 1]
-        m_prev = m_ref[:]
-        m_fin = jnp.maximum(m_prev, s_new)
-        p = jnp.exp(s_new - m_fin)
-        alpha = jnp.exp(m_prev - m_fin)
-        l_fin = l_ref[:] * alpha + p
-        acc_fin = acc_ref[:] * alpha + p * vn[:, None, :]
-        out = acc_fin / jnp.maximum(l_fin, 1e-30)  # [KVH, G, D]
-        o_ref[0] = out.astype(o_ref.dtype)
+    def copies(b, i, buf):
+        page_id = jnp.maximum(bt_ref[jnp.minimum(b, nb - 1), i], 0)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page_id], k_buf.at[buf], sems.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page_id], v_buf.at[buf], sems.at[1, buf]
+            ),
+        )
 
+    def fetch(b, i, buf):
+        @pl.when(b < nb)
+        def _start():
+            for copy in copies(b, i, buf):
+                copy.start()
 
-def _fused_page_index(
-    b, s, bt_ref, pos_ref, win_ref, layer_ref, *, page_size, strip, t
-):
-    """Index map for strip member t: slot b's (s*strip + t)-th page of
-    layer layer_ref[0]. Outside the live range the index clamps to the
-    nearest live page so an unchanged block index elides the DMA."""
-    pos = pos_ref[b]
-    win = win_ref[0]
-    last = jnp.maximum(pl.cdiv(pos, page_size) - 1, 0)
-    first = jnp.where(
-        win > 0, jnp.maximum(pos + 1 - win, 0) // page_size, 0
+    # A slot that holds no page attends its new token alone: a softmax over
+    # one column is 1, so its output is that token's V. Slots with pages
+    # overwrite their rows below.
+    o_ref[...] = vn_ref[...].astype(o_ref.dtype)
+
+    first_slot, first_page = first_live(jnp.int32(0))
+    fb, fi = first_slot, first_page
+    for buf in range(depth):  # fill the ring
+        fetch(fb, fi, buf)
+        fb, fi = advance(fb, fi)
+
+    def attend_next(carry):
+        j, cb, ci, fb, fi = carry  # pages done; compute and fetch cursors
+        buf = j % depth
+        first, n_pages = live(cb)
+        pos = pos_ref[cb]
+
+        @pl.when(ci == first)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+        for copy in copies(cb, ci, buf):
+            copy.wait()
+        _fused_attend_page(
+            q_ref[cb], k_buf[buf], v_buf[buf], pos,
+            jnp.where(win > 0, pos + 1 - win, 0),  # first in-window position
+            ci * page_size, m_ref, l_ref, acc_ref,
+            scale=scale, logit_softcap=logit_softcap, kvh=kvh, group=group,
+        )
+        fetch(fb, fi, buf)  # the buffer is free again
+
+        @pl.when(ci + 1 >= n_pages)
+        def _finalize():
+            # Merge the new token as one extra column (always valid — it
+            # is the query's own position, inside any window), normalize.
+            q = q_ref[cb].astype(jnp.float32) * scale  # [H, D]
+            kn = kn_ref[cb].astype(jnp.float32)
+            vn = vn_ref[cb].astype(jnp.float32)
+            s_new = jnp.sum(q * kn, axis=-1, keepdims=True)  # [H, 1]
+            if logit_softcap is not None:
+                s_new = jnp.tanh(s_new / logit_softcap) * logit_softcap
+            m_prev = m_ref[:]
+            m_fin = jnp.maximum(m_prev, s_new)
+            p = jnp.exp(s_new - m_fin)
+            alpha = jnp.exp(m_prev - m_fin)
+            l_fin = l_ref[:] * alpha + p
+            acc_fin = acc_ref[:] * alpha + p * vn
+            out = acc_fin / jnp.maximum(l_fin, 1e-30)  # [H, D]
+            o_ref[cb] = out.astype(o_ref.dtype)
+
+        return (j + 1, *advance(cb, ci), *advance(fb, fi))
+
+    jax.lax.while_loop(
+        lambda carry: carry[1] < nb, attend_next,
+        (jnp.int32(0), first_slot, first_page, fb, fi),
     )
-    clamped = jnp.clip(s * strip + t, first, last)
-    page_id = jnp.maximum(bt_ref[b, clamped], 0)
-    return layer_ref[0], page_id, 0, 0, 0
 
 
-# Pages fetched per grid step: 4 x 64-token pages are 1 MB of K+V per step
-# at KVH=8/D=128/bf16. Not tuned on the chip: at 24 slots of short caches
-# the kernel's time goes to grid steps that find no live page (PERF.md
-# section 6, PR 25).
-FUSED_STRIP = 4
+# The ring of page buffers: as many pages of K and V as fit in this many
+# bytes of VMEM, 2 to 8. One page ahead hides a copy's latency; alone on a
+# v5e 2, 4 and 8 pages read the same within 2% (PR 28).
+_FUSED_RING_BYTES = 1 << 20
+
+
+def fused_ring_depth(page: int, kvh: int, d: int, itemsize: int) -> int:
+    """Pages the fetch cursor runs ahead, from the shapes the kernel sees
+    (Mistral's 128 KiB page: 4; Gemma-2's 256 KiB: 2; a tp=4 shard's 32
+    KiB: 8)."""
+    return max(2, min(8, _FUSED_RING_BYTES // (2 * page * kvh * d * itemsize)))
 
 
 @functools.partial(
@@ -780,66 +908,52 @@ def _paged_pallas_stacked(
     interpret: bool,
 ):
     b, kvh, g, d = q.shape
-    _, p, page, _, _ = k_pages.shape
-    mp = block_tables.shape[1]
-    strip = min(FUSED_STRIP, mp)
-    ns = -(-mp // strip)
+    h = kvh * g
+    _, _, page, _, _ = k_pages.shape
+    depth = fused_ring_depth(page, kvh, d, k_pages.dtype.itemsize)
 
     kernel = functools.partial(
         _paged_fused_kernel,
         page_size=page,
         kvh=kvh,
         group=g,
-        strip=strip,
+        depth=depth,
         scale=scale,
         logit_softcap=logit_softcap,
     )
-    page_spec = [
-        pl.BlockSpec(
-            (1, 1, page, kvh, d),
-            functools.partial(
-                _fused_page_index, page_size=page, strip=strip, t=t
-            ),
-        )
-        for t in range(strip)
-    ]
+    in_vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, ns),
-        in_specs=[
-            pl.BlockSpec(
-                (1, kvh, g, d), lambda b_, s_, *refs: (b_, 0, 0, 0)
-            ),
-            pl.BlockSpec((1, kvh, d), lambda b_, s_, *refs: (b_, 0, 0)),
-            pl.BlockSpec((1, kvh, d), lambda b_, s_, *refs: (b_, 0, 0)),
-            *page_spec,  # k strip
-            *page_spec,  # v strip (same index maps)
-        ],
-        out_specs=pl.BlockSpec(
-            (1, kvh, g, d), lambda b_, s_, *refs: (b_, 0, 0, 0)
-        ),
+        grid=(1,),
+        in_specs=[in_vmem, in_vmem, in_vmem, in_place, in_place],
+        out_specs=in_vmem,
         scratch_shapes=[
-            pltpu.VMEM((kvh, g, 1), jnp.float32),
-            pltpu.VMEM((kvh, g, 1), jnp.float32),
-            pltpu.VMEM((kvh, g, d), jnp.float32),
+            pltpu.VMEM((depth, page, kvh, d), k_pages.dtype),
+            pltpu.VMEM((depth, page, kvh, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            # Slots are independent (scratch re-inits at s == 0 per slot):
-            # a chip with two TensorCores may split them (a v5e has one).
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(
         block_tables, positions, window, layer,
-        q, k_new, v_new,
-        *([k_pages] * strip), *([v_pages] * strip),
+        # Query heads as rows [H, D], kv-head major; the new token's K and
+        # V once per query head, so the kernel merges it row by row.
+        q.reshape(b, h, d),
+        jnp.repeat(k_new, g, axis=1), jnp.repeat(v_new, g, axis=1),
+        k_pages, v_pages,
     )
-    return out
+    return out.reshape(b, kvh, g, d)
 
 
 def ref_paged_decode_attention_fused(
@@ -886,8 +1000,10 @@ def ref_paged_decode_attention_fused(
     if logit_softcap is not None:
         logits = jnp.tanh(logits / logit_softcap) * logit_softcap
     col = jnp.arange(L + 1)
-    # Columns < positions are old tokens; column L is the new token.
-    mask = (col[None, :] < positions[:, None]) | (col[None, :] == L)
+    # Columns < positions are old tokens (none for a slot that holds no
+    # page, whatever its position says); column L is the new token.
+    old = (col[None, :] < positions[:, None]) & (block_tables[:, :1] >= 0)
+    mask = old | (col[None, :] == L)
     if window is not None:
         win = jnp.asarray(window, jnp.int32)
         lengths = positions + 1

@@ -102,15 +102,30 @@ def test_pool_movers_sees_the_per_layer_layout(topo, cell):
     assert out["decode"].temp_size_in_bytes > 3 * GIB
 
 
+def kernel_calls(hlo: str) -> list[str]:
+    """Names of the Mosaic custom calls. The trace names a custom call as
+    the HLO does: after the jitted wrapper round the `pallas_call`."""
+    return re.findall(r"%?([\w.]+) = [^=]*custom-call\([^\n]*"
+                      r'custom_call_target="tpu_custom_call"', hlo)
+
+
 def test_the_kernel_is_named_for_the_trace_metric(decode):
     """`perf/layer_metrics/paged_attn_ms*.json` match `^_paged_pallas`."""
-    # The trace names a custom call as the HLO does: after the jitted
-    # wrapper round the `pallas_call`.
-    names = re.findall(r"%?([\w.]+) = [^=]*custom-call\([^\n]*"
-                       r'custom_call_target="tpu_custom_call"',
-                       decode["decode_text"])
+    names = kernel_calls(decode["decode_text"])
     assert names, "no tpu_custom_call in the decode chunk"
     assert all(re.match(r"_paged_pallas", n) for n in names), names
+
+
+def test_the_kernel_lowers_at_two_kv_heads_a_chip(topo):
+    """The configuration on file for the four-chip cell: Mixtral at tp=4
+    leaves the kernel 2 KV heads (a [page, 2, 128] block, 8 query rows),
+    the narrowest tiling the fold of heads into one dot has to lower at.
+    (Its chunk still moves the pool, as PR 27's did: PERF.md section 7.)"""
+    out = aot.compile_cell(topo, aot.load_config("mixtral-8x7b-v5e4"),
+                           admit=1, bucket=128, what=("decode",))
+    names = kernel_calls(out["decode_text"])
+    assert names and all(re.match(r"_paged_pallas", n) for n in names), names
+    assert aot.peak_bytes(out["decode"]) < HBM
 
 
 def test_thirty_two_slots_fit(topo, cell):

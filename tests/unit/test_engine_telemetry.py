@@ -140,6 +140,7 @@ NEW_HISTOGRAMS = (
 NEW_COUNTERS = (
     "kubeai_engine_admit_calls_total",
     "kubeai_engine_prefill_tokens_total",
+    "kubeai_engine_decode_live_pages_total",
 )
 
 
@@ -179,6 +180,9 @@ def test_host_timeline_counters_and_names_after_one_stream(streamed):
         ("kubeai_engine_prefill_tokens_total", (("kind", "useful"),))]
     pad = parsed[("kubeai_engine_prefill_tokens_total", (("kind", "pad"),))]
     assert useful == 5 and useful + pad == 16  # "hello" in the 16-bucket
+    # One page held the stream's tokens at each chunk it dispatched.
+    chunks = parsed[("kubeai_engine_decode_live_pages_total", ())]
+    assert 1 <= chunks == server.engine.live_kv["pages_total"] <= 8
     # One wait and one host observation per admission call; they stay
     # inside the step's prefill phase.
     assert m.admit_wait.get() == m.admit_host.get() == 1
@@ -235,6 +239,9 @@ def test_step_stats_and_kv_utilization_move_during_decode(server):
     state = json.loads(body)
     assert "kv_utilization" in state
     assert state["last_step"]["tokens"] >= 1
+    # What the newest decode chunk read: the one stream's one page.
+    assert (state["kv_cache"]["live_slots"],
+            state["kv_cache"]["live_pages"]) == (1, 1)
 
 
 def test_itl_records_match_fake_clock_ticks(monkeypatch):

@@ -167,6 +167,67 @@ def test_the_decode_span_names_the_kv_layout(tiny, kw, layout):
     assert eng.kv_cache_info()["kv_layout"] == layout
 
 
+def test_the_decode_span_counts_the_live_kv(tiny, monkeypatch):
+    """`live_slots` / `live_pages` on `step.decode`, the counter the server
+    folds in and `/v1/state` say what the allocator holds for the ACTIVE
+    slots: a request that finished leaves nothing behind, however many
+    steps its slot then stays free."""
+    from kubeai_tpu.engine.server import EngineMetrics, engine_state_snapshot
+
+    eng, rec = _engine(tiny)
+    page = eng.cfg.page_size
+    walks = []  # what each page-growing walk left, read from outside it
+
+    def walked(*a, _orig=eng._ensure_decode_pages, **kw):
+        _orig(*a, **kw)
+        held = {s: len(eng._alloc.pages_for(s))
+                for s in range(eng.cfg.num_slots)}
+        walks.append({
+            "slots": len(eng._active),
+            "pages": sum(-(-r.position // page)
+                         for r in eng._active.values()),
+            "held_active": sum(held[s] for s in eng._active),
+            "held_free": sum(n for s, n in held.items()
+                             if s not in eng._active),
+            "free_rows": [int(eng._bt_host[s, 0])
+                          for s in held if s not in eng._active],
+        })
+
+    monkeypatch.setattr(eng, "_ensure_decode_pages", walked)
+    short = SamplingParams(temperature=0.0, max_tokens=3)
+    long = SamplingParams(temperature=0.0, max_tokens=60)
+    eng.add_request(list(range(1, 30)), short)
+    eng.add_request(list(range(1, 40)), long)
+    while eng.has_work():
+        eng.step()
+    spans = rec.named("step.decode")
+    assert len(spans) == len(walks) >= 8
+    for sp, w in zip(spans, walks):
+        assert sp["attrs"]["live_slots"] == w["slots"]
+        assert sp["attrs"]["live_pages"] == w["pages"]
+        # The allocator holds those pages and the chunk's look-ahead, for
+        # the active slots only; a free slot's row starts with -1.
+        assert w["pages"] <= w["held_active"] <= w["pages"] + 2 * w["slots"]
+        assert w["held_free"] == 0 and set(w["free_rows"]) <= {-1}
+    # The short request's slot stayed free for several steps.
+    alone = [w for w in walks if w["slots"] == 1]
+    assert len(alone) >= 4 and walks[0]["slots"] == 2
+    assert [w["pages"] for w in alone] == sorted(w["pages"] for w in alone)
+    # The last walk sees the long request one or two chunks from its end.
+    assert alone[-1]["pages"] in (-(-(39 + 60 - 9) // page),
+                                  -(-(39 + 60 - 1) // page))
+    total = sum(w["pages"] for w in walks)
+    assert eng.live_kv == {"slots": 1, "pages": alone[-1]["pages"],
+                           "pages_total": total}
+    metrics = EngineMetrics()
+    metrics.sync_engine(eng)
+    metrics.sync_engine(eng)  # a counter: folded in once
+    assert metrics.decode_live_pages.get() == total
+    state = engine_state_snapshot(eng)["kv_cache"]
+    assert (state["live_slots"], state["live_pages"]) == (
+        1, alone[-1]["pages"])
+
+
 def test_admission_counters_with_mixed_admissions(tiny, monkeypatch):
     """Batches of several buckets, a chunked prompt and a prefix-cache hit:
     useful + pad is the tokens of the shapes that ran, every device call is

@@ -23,6 +23,8 @@ B, KVH, G, D, PAGE, MP = 3, 2, 4, 32, 8, 4
 H = KVH * G
 P = 1 + B * MP  # pool: scratch page 0 + full reservation
 L_MAX = MP * PAGE
+# Widest error of PR 27's stacked kernel on the bf16 test's inputs.
+PARENT_BF16_ERR = 0.00748
 
 
 @pytest.fixture(autouse=True)
@@ -197,6 +199,124 @@ def test_fused_empty_slot_returns_value_of_new_token():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(out), atol=1e-4, rtol=1e-4
     )
+
+
+def _stacked_inputs(b, kvh, g, d, old_lengths, *, page=8, mp=4, dtype,
+                    n_layers=2, seed=0, dead=()):
+    """Random stacked pools (every page filled: what lies past a length or
+    in nobody's page must not matter), block tables that cover each slot's
+    old tokens and its new one, all -1 for the slots in `dead`."""
+    rng = np.random.default_rng(seed)
+    n_pages = 1 + b * mp
+
+    def rand(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dtype)
+
+    bt = np.full((b, mp), -1, np.int32)
+    for s, ln in enumerate(old_lengths):
+        if s not in dead:
+            n = min(-(-(ln + 1) // page), mp)
+            bt[s, :n] = 1 + s * mp + np.arange(n)
+    return (
+        rand(b, kvh * g, d),
+        rand(n_layers, n_pages, page, kvh, d),
+        rand(n_layers, n_pages, page, kvh, d),
+        rand(b, kvh, d), rand(b, kvh, d), jnp.asarray(bt),
+        jnp.asarray(old_lengths, jnp.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "cap, win", [(None, None), (30.0, None), (None, 12), (50.0, 7)],
+    ids=["plain", "softcap", "window", "softcap-window"],
+)
+@pytest.mark.parametrize(
+    "kvh, g, d", [(8, 4, 128), (2, 4, 128), (8, 2, 256)],
+    ids=["mistral", "mixtral-tp4-shard", "gemma2"],
+)
+def test_stacked_kernel_matches_reference_at_the_served_head_shapes(
+    kvh, g, d, cap, win
+):
+    """KV heads fold into one dot a page: the head counts and widths the
+    served families bring (8 x 4 x 128; 2 x 4 x 128 a chip at tp=4;
+    8 x 2 x 256 with softcap and a window), at a tiny page."""
+    q, kp, vp, kn, vn, bt, pos = _stacked_inputs(
+        3, kvh, g, d, [5, 17, 30], dtype=jnp.float32, seed=kvh + d)
+    got = paged_decode_attention_fused(
+        q, kp, vp, kn, vn, bt, pos, 1, logit_softcap=cap, window=win)
+    want = ref_paged_decode_attention_fused(
+        q, kp, vp, kn, vn, bt, pos, jnp.int32(1),
+        logit_softcap=cap, window=win)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=1e-4, rtol=1e-4)
+    if win is not None:  # keys really fall out of the window
+        full = ref_paged_decode_attention_fused(
+            q, kp, vp, kn, vn, bt, pos, jnp.int32(1), logit_softcap=cap)
+        assert float(jnp.max(jnp.abs(got - full))) > 1e-4
+
+
+def test_a_slot_that_holds_no_page_attends_only_its_new_token():
+    """The engine advances a freed slot's position with everyone's, and
+    clears only its block-table row: the row says the slot is dead, so its
+    stale position (1,500 here, far past the table) buys no work, leaves
+    every live slot's output as it was to the bit, and its own output is
+    its new token's value."""
+    from kubeai_tpu.ops.paged_attention import _live_page_range
+
+    live = _stacked_inputs(
+        3, KVH, G, D, [5, 17, 30], page=PAGE, mp=MP, dtype=jnp.float32,
+        seed=21)
+    q, kp, vp, kn, vn, bt, pos = _stacked_inputs(
+        4, KVH, G, D, [5, 17, 30, 1500], page=PAGE, mp=MP,
+        dtype=jnp.float32, seed=22, dead=(3,))
+    # The same three live slots, beside a dead fourth.
+    q, kn, vn = (x.at[:3].set(y) for x, y in zip((q, kn, vn),
+                                                   (live[0], live[3], live[4])))
+    kp, vp = (x.at[:, : live[1].shape[1]].set(y)
+              for x, y in zip((kp, vp), (live[1], live[2])))
+    assert (np.asarray(bt[:3]) == np.asarray(live[5])).all()
+    assert (np.asarray(bt[3]) == -1).all() and int(pos[3]) == 1500
+    for win in (None, 12):
+        without = paged_decode_attention_fused(*live[:6], live[6], 1,
+                                               window=win)
+        got = paged_decode_attention_fused(q, kp, vp, kn, vn, bt, pos, 1,
+                                           window=win)
+        np.testing.assert_array_equal(np.asarray(got[:3]),
+                                      np.asarray(without))
+        own = jnp.broadcast_to(vn[3][:, None, :], (KVH, G, D)).reshape(H, D)
+        assert np.isfinite(np.asarray(got[3])).all()
+        np.testing.assert_allclose(np.asarray(got[3]), np.asarray(own),
+                                   atol=1e-6)
+        # The reference reads the row the same way.
+        want = ref_paged_decode_attention_fused(
+            q, kp, vp, kn, vn, bt, pos, jnp.int32(1), window=win)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+    # The helper both cursors of the kernel ask: no page for the dead slot,
+    # the usual range for a live one, never more pages than the table has.
+    w = jnp.asarray([0], jnp.int32)
+    rng = dict(page_size=PAGE, max_pages=MP)
+    assert [int(x) for x in _live_page_range(bt, pos, w, 3, **rng)] == [0, 0]
+    assert [int(x) for x in _live_page_range(bt, pos, w, 2, **rng)] == [0, 4]
+    w12 = jnp.asarray([12], jnp.int32)  # 30 old tokens, keys from 19 on
+    assert [int(x) for x in _live_page_range(bt, pos, w12, 2, **rng)] == [2, 4]
+    alive = bt.at[3, 0].set(1)  # the stale position alone: capped at MP
+    assert [int(x) for x in _live_page_range(alive, pos, w, 3, **rng)] == [0, MP]
+
+
+def test_stacked_kernel_on_a_bf16_pool_against_the_f32_reference():
+    """The pool the engine serves from: bf16 K and V enter the dots as
+    they are stored. Against the reference computed in float32 on the same
+    bf16 values, the kernel is as close as the kernel it replaced was on
+    these inputs (0.00748 at the widest: the rounding of a bf16 output)."""
+    q, kp, vp, kn, vn, bt, pos = _stacked_inputs(
+        3, 8, 4, 128, [5, 17, 30], dtype=jnp.bfloat16, seed=31)
+    got = paged_decode_attention_fused(q, kp, vp, kn, vn, bt, pos, 1)
+    assert got.dtype == jnp.bfloat16
+    f32 = [x.astype(jnp.float32) for x in (q, kp, vp, kn, vn)]
+    want = ref_paged_decode_attention_fused(*f32, bt, pos, jnp.int32(1))
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+    assert err <= PARENT_BF16_ERR, err
 
 
 def test_window_matches_dense_masked_oracle():
