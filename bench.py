@@ -84,11 +84,6 @@ def _parse_args(argv=None):
         "to run anywhere but a TPU",
     )
     ap.add_argument(
-        "--cache-mode", default="paged", choices=["paged", "slot"],
-        help="KV cache layout (paged = block tables, reads resident pages "
-        "only; slot = dense [slots, max_seq_len] reservation)",
-    )
-    ap.add_argument(
         "--uniform-prompts", action="store_true",
         help="all prompts exactly --prompt-len (default: mixed lengths in "
         "[prompt-len/4, prompt-len], the serving-realistic case where "
@@ -144,8 +139,7 @@ def _parse_args(argv=None):
     ap.add_argument(
         "--kv-dtype", default="", choices=["", "bfloat16", "int8"],
         help="paged KV cache storage dtype (int8 = quantized pages: "
-        "~2x slot capacity at equal HBM; requires --cache-mode paged "
-        "and no --speculate)",
+        "~2x slot capacity at equal HBM; requires no --speculate)",
     )
     ap.add_argument(
         "--decode-chunk", type=int, default=32,
@@ -240,7 +234,6 @@ def main(argv=None) -> int:
         cfg=EngineConfig(
             num_slots=args.slots,
             max_seq_len=args.max_seq_len,
-            cache_mode=args.cache_mode,
             speculate=args.speculate,
             spec_adaptive=args.spec_adaptive == "on",
             quantization=args.quantization,
@@ -361,7 +354,7 @@ def _measure_prefill(args, eng, cfg, model_name) -> int:
         "metric": f"{model_name} prefill admission throughput, "
         f"shared {args.prompt_len}-token prefix + {tail}-token tails, "
         f"prefix_cache={'on' if args.prefix_cache else 'off'}, "
-        f"bs={args.slots}, {args.cache_mode} kv cache, "
+        f"bs={args.slots}, paged kv cache, "
         f"chunk={args.prefill_chunk}, page={args.page_size}"
         + (" (smoke)" if args.smoke else ""),
         "value": round(done_tokens / dt if dt > 0 else 0.0, 2),
@@ -397,7 +390,6 @@ def _measure_coldstart(args, cfg, model_name) -> int:
     ecfg = EngineConfig(
         num_slots=args.slots,
         max_seq_len=args.max_seq_len,
-        cache_mode=args.cache_mode,
         decode_chunk=max(1, args.decode_chunk),
     )
     mesh = single_device_mesh()
@@ -465,7 +457,6 @@ def _measure_step_overlap(args, cfg, model_name) -> int:
             cfg=EngineConfig(
                 num_slots=args.slots,
                 max_seq_len=args.max_seq_len,
-                cache_mode=args.cache_mode,
                 quantization=args.quantization,
                 kv_dtype=args.kv_dtype,
                 decode_chunk=max(1, args.decode_chunk),
@@ -530,7 +521,7 @@ def _measure_step_overlap(args, cfg, model_name) -> int:
     speedup = over_tps / sync_tps if sync_tps > 0 else 0.0
     _emit({
         "metric": f"{model_name} overlapped step pipeline vs sync decode, "
-        f"bs={args.slots}, {args.cache_mode} kv cache, "
+        f"bs={args.slots}, paged kv cache, "
         f"chunk={max(1, args.decode_chunk)}"
         + (" (smoke)" if args.smoke else ""),
         "value": round(speedup, 3),
@@ -547,11 +538,8 @@ def _measure_step_overlap(args, cfg, model_name) -> int:
 def _result_line(args, eng, model_name, toks_per_s):
     return {
         "metric": f"{model_name} decode throughput, continuous batching, "
-        f"bs={args.slots}, {args.cache_mode} kv cache"
-        + (
-            f" ({eng.kv_layout} layout)"
-            if eng.cache_mode == "paged" else ""
-        )
+        f"bs={args.slots}, paged kv cache"
+        + f" ({eng.kv_layout} layout)"
         + ", "
         + ("uniform" if args.uniform_prompts else "mixed")
         + " prompts"

@@ -26,7 +26,7 @@ invariants pin:
       the overlapped loop decodes >= 1.3x the synchronous throughput;
   (b) TOKEN IDENTITY — byte-identical per-request token streams,
       overlap on vs off, for greedy AND seeded sampling, across the
-      paged / slot / chunked-prefill admission models;
+      paged / chunked-prefill admission models;
   (c) BARRIERS — mid-run arrivals (admission barrier) and a mid-run
       drain (drain barrier) both force a reap and still produce
       identical streams;
@@ -106,14 +106,14 @@ class _Device:
 
 class _SimEngine:
     """The step loop under test. `mode` picks the admission model
-    (paged = batched whole-prompt, slot = serial whole-prompt,
+    (paged = batched whole-prompt,
     chunked = per-PREFILL_CHUNK prefill calls); `overlap` picks the
     loop shape. Barrier rules mirror Engine.step/_barrier_locked."""
 
     def __init__(self, requests, mode: str = "paged",
                  overlap: bool = False, num_slots: int = 4,
                  drain_after_step: int | None = None):
-        assert mode in ("paged", "slot", "chunked")
+        assert mode in MODES
         self.mode = mode
         self.overlap = overlap
         self.num_slots = num_slots
@@ -184,8 +184,6 @@ class _SimEngine:
         if self.mode == "paged":
             # Batched admission: same-bucket prompts share one call.
             calls = 1
-        elif self.mode == "slot":
-            calls = len(batch)
         else:  # chunked prefill: one call per PREFILL_CHUNK tokens
             calls = sum(
                 -(-r.prompt_len // PREFILL_CHUNK) for r in batch
@@ -287,7 +285,7 @@ def _workload(seeded: bool):
     ]
 
 
-MODES = ("paged", "slot", "chunked")
+MODES = ("paged", "chunked")
 
 
 def run_sim() -> dict:

@@ -4,7 +4,7 @@ JetStream-style serving loop, in-process:
 
   add_request() ──► pending queue
                          │ (free slot?)
-                 prefill (bucketed S, jitted) ─► insert KV into slot
+                 prefill (bucketed S, jitted) ─► scatter KV into pages
                          │
         step(): one batched decode over ALL active slots (jitted, donated
                 cache) ─► sample ─► host-side stop checks ─► free slots
@@ -34,7 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from kubeai_tpu.engine.kvcache import KVCache, insert_sequence
 from kubeai_tpu.engine.sampling import SamplingParams, sample
 from kubeai_tpu.models.registry import ModelFamily, get_model_family
 from kubeai_tpu.parallel import sharding as psh
@@ -57,25 +56,22 @@ def _now() -> float:
 class EngineConfig:
     num_slots: int = 8
     max_seq_len: int = 1024
-    # KV cache layout: "paged" (block tables over a shared page pool; decode
-    # reads only resident pages — the default) or "slot" (fixed
-    # [slots, max_seq_len] reservation per slot). Families without a paged
-    # decode path fall back to "slot".
-    cache_mode: str = "paged"
+    # The KV cache is a pool of pages of this many tokens, shared by the
+    # slots through block tables; decode reads only resident pages.
     page_size: int = 64
     # Page-pool size. 0 = full reservation (num_slots * max_seq_len worth
-    # of pages + the reserved scratch page): identical capacity to the slot
-    # cache, no preemption possible. Set smaller to oversubscribe slots —
+    # of pages + the reserved scratch page): every slot can reach
+    # max_seq_len, no preemption possible. Set smaller to oversubscribe slots —
     # admission defers on pool exhaustion and decode preempts (recompute)
     # the youngest request when it can't grow.
     num_pages: int = 0
-    # Batched admission (paged mode): up to this many same-bucket pending
+    # Batched admission: up to this many same-bucket pending
     # prompts prefill in ONE device call — each dispatch costs a full
     # round trip to the chip, so admission under a request burst is
     # dispatch-bound without batching. Rows pad to the next power of two
     # (bounded compile count).
     max_admit_batch: int = 8
-    # Speculative decoding (paged mode, families with a verify forward):
+    # Speculative decoding (families with a verify forward):
     # propose this many tokens per step via prompt-lookup (n-gram match
     # against the request's own context — no draft model) and verify all
     # of them in ONE forward. Accepted tokens cost one model pass total,
@@ -105,12 +101,11 @@ class EngineConfig:
     # Chunked prefill: prompts longer than this are prefilled in fixed
     # [1, prefill_chunk] steps — ONE compiled graph for every prompt
     # length and O(chunk * max_seq_len) activation memory (0 = whole-
-    # prompt bucketed prefill only). Works in both cache modes: slot mode
-    # chunks straight into the slot's cache row; paged mode stages chunks
-    # in a one-slot buffer and scatters pages on the final chunk.
-    # Requires family support.
+    # prompt bucketed prefill only). Chunks are staged in a one-slot
+    # buffer and scattered into pages on the final chunk. Requires family
+    # support.
     prefill_chunk: int = 0
-    # Automatic prefix caching (paged mode + prefill_chunk > 0): full
+    # Automatic prefix caching (needs prefill_chunk > 0): full
     # prompt pages register under a content-hash chain (adapter-aware)
     # when a request completes admission; a later prompt with the same
     # page-aligned prefix ADOPTS those pages read-only and prefills only
@@ -123,7 +118,7 @@ class EngineConfig:
     # docs/benchmarks/prefix-aware-load-balancing.md).
     prefix_cache: bool = False
     cache_dtype: Any = jnp.bfloat16
-    # KV-cache quantization (paged mode): "" / "bfloat16" stores pages in
+    # KV-cache quantization: "" / "bfloat16" stores pages in
     # cache_dtype; "int8" stores pages as int8 with per-token-per-head f32
     # scales riding alongside ({"q8", "scale"} pool leaves — see
     # ops/kv_quant.py), roughly doubling slot capacity at equal HBM
@@ -166,8 +161,8 @@ class EngineConfig:
     # Pipeline parallelism (mesh pp axis > 1): decode microbatch count for
     # the GPipe schedule. 0 = the pp stage count (steady-state utilization
     # M/(M+P-1); raise toward num_slots for higher utilization at smaller
-    # per-tick batches). Requires a family with decode_step_paged_pp,
-    # paged cache mode, and num_slots % M == 0; composes with dp, tp, sp
+    # per-tick batches). Requires a family with decode_step_paged_pp
+    # and num_slots % M == 0; composes with dp, tp, sp
     # (ring-attention prefill), int8 quantization, and prompt-lookup
     # speculation.
     pp_microbatches: int = 0
@@ -368,20 +363,15 @@ class Engine:
         # newest dispatch, and the pages summed over every dispatched chunk.
         self.live_kv = {"slots": 0, "pages": 0, "pages_total": 0}
 
-        # Resolve the cache mode: paged needs family support; otherwise
-        # fall back to the slot cache. Chunked prefill works in both modes
-        # (paged stages chunks in a one-slot buffer, then scatters).
-        self.cache_mode = cfg.cache_mode
         self._spec = 0  # resolved speculation window (see below)
-        if cfg.cache_mode == "paged" and (
-            getattr(self.family, "decode_step_paged", None) is None
-        ):
-            self.cache_mode = "slot"
-        elif cfg.cache_mode not in ("paged", "slot"):
-            raise ValueError(f"unknown cache_mode {cfg.cache_mode!r}")
+        if getattr(self.family, "decode_step_paged", None) is None:
+            raise ValueError(
+                f"family {self.family.name} has no decode_step_paged: the "
+                "engine's only KV cache is the page pool"
+            )
 
-        # KV quantization: validated here, materialized in the paged
-        # branch below ({"q8", "scale"} pool leaves; ops/kv_quant.py).
+        # KV quantization: validated here, materialized with the pool
+        # below ({"q8", "scale"} pool leaves; ops/kv_quant.py).
         from kubeai_tpu.ops.kv_quant import resolve_kv_dtype
         from kubeai_tpu.ops.paged_attention import resolve_decode_kernel
 
@@ -392,17 +382,11 @@ class Engine:
         self.decode_kernel = resolve_decode_kernel(
             cfg.decode_kernel, quantized=self._kv_quant
         )
-        if self._kv_quant:
-            if self.cache_mode != "paged":
-                raise ValueError(
-                    "kv_dtype='int8' requires cache_mode='paged' (pages "
-                    "are the quantization unit)"
-                )
-            if cfg.speculate > 0 or draft is not None:
-                raise ValueError(
-                    "kv_dtype='int8' does not compose with speculative "
-                    "decoding yet (the verify kernels read bf16 pools)"
-                )
+        if self._kv_quant and (cfg.speculate > 0 or draft is not None):
+            raise ValueError(
+                "kv_dtype='int8' does not compose with speculative "
+                "decoding yet (the verify kernels read bf16 pools)"
+            )
 
         # Pipeline parallelism: stage-local layers + KV over the pp mesh
         # axis (GPipe microbatched decode; see models/llama.py
@@ -410,7 +394,7 @@ class Engine:
         # shard_map is manual over pp only (axis_names), so Megatron tp
         # sharding stays GSPMD-managed inside each stage (the 70B/v5e-8
         # plan is pp=2 × tp=4). Composes with sp too (ring-attention
-        # prefill; see below). Scope: paged cache, llama-family.
+        # prefill; see below). Scope: llama-family.
         self._pp = self.mesh.shape.get("pp", 1)
         self._pp_microbatches = 0
         if self._pp > 1:
@@ -425,8 +409,6 @@ class Engine:
                     f"family {self.family.name} does not support pipeline "
                     "parallelism (no decode_step_paged_pp)"
                 )
-            if self.cache_mode != "paged":
-                raise ValueError("pipeline parallelism requires cache_mode='paged'")
             # sp composes: prefill runs ring attention over the sp axis
             # (resolve_prefill binds the mesh) while the pp decode
             # shard_map simply replicates its per-tick microbatch inputs
@@ -448,12 +430,11 @@ class Engine:
         # The layout the compiled decode chunk has: "stacked" = the
         # [NL, ...] pool read and written in place, "per_layer" = a
         # layer's cache sliced out of the stack and written back (int8
-        # pools, pp stages, the slot cache). Fixed at compile time, so
-        # the step.decode span and /v1/state just name it.
+        # pools, pp stages). Fixed at compile time, so the step.decode
+        # span and /v1/state just name it.
         self.kv_layout = (
             "stacked"
-            if self.cache_mode == "paged" and self._pp == 1
-            and self.decode_kernel == "fused"
+            if self._pp == 1 and self.decode_kernel == "fused"
             else "per_layer"
         )
 
@@ -534,132 +515,111 @@ class Engine:
             "spilled_pages": 0,
             "filled_pages": 0,
         }
-        if self.cache_mode == "paged":
-            from kubeai_tpu.engine.paged_cache import PageAllocator, PagedKVCache
+        from kubeai_tpu.engine.paged_cache import PageAllocator, PagedKVCache
 
-            n_pages = cfg.effective_num_pages()
-            self._n_pages = n_pages
-            max_pages = -(-cfg.max_seq_len // cfg.page_size)
-            # Pages replicated across dp (page ids are global); KV heads on
-            # tp exactly like the slot cache; the layer axis shards over
-            # pp so each pipeline stage holds only its own layers' pages.
-            pool_sharding = psh.named_sharding(
-                self.mesh,
-                (psh.LAYERS, None, None, psh.KV_HEADS, None),
-                cache_rules,
+        n_pages = cfg.effective_num_pages()
+        self._n_pages = n_pages
+        max_pages = -(-cfg.max_seq_len // cfg.page_size)
+        # Pages replicated across dp (page ids are global); KV heads on
+        # tp; the layer axis shards over pp, so each pipeline stage holds
+        # only its own layers' pages.
+        pool_sharding = psh.named_sharding(
+            self.mesh,
+            (psh.LAYERS, None, None, psh.KV_HEADS, None),
+            cache_rules,
+        )
+        if self._kv_quant:
+            # Dict pool leaves: int8 pages shard like bf16 pages; the
+            # [NL, pages, page, KVH] scale leaf drops the head_dim
+            # axis. device_put and jit out_shardings both take the
+            # pytree form.
+            pool_sharding = {
+                "q8": pool_sharding,
+                "scale": psh.named_sharding(
+                    self.mesh,
+                    (psh.LAYERS, None, None, psh.KV_HEADS),
+                    cache_rules,
+                ),
+            }
+        if n_pages - 1 < max_pages:
+            raise ValueError(
+                f"num_pages={n_pages} cannot hold one max_seq_len "
+                f"sequence ({max_pages} pages + scratch); preemption "
+                "could not guarantee progress"
             )
-            if self._kv_quant:
-                # Dict pool leaves: int8 pages shard like bf16 pages; the
-                # [NL, pages, page, KVH] scale leaf drops the head_dim
-                # axis. device_put and jit out_shardings both take the
-                # pytree form.
-                pool_sharding = {
-                    "q8": pool_sharding,
-                    "scale": psh.named_sharding(
-                        self.mesh,
-                        (psh.LAYERS, None, None, psh.KV_HEADS),
-                        cache_rules,
-                    ),
-                }
-            if n_pages - 1 < max_pages:
+        self._bt_sharding = psh.named_sharding(
+            self.mesh, (None, None), cache_rules
+        )
+        # Born sharded: each device allocates only its own part of
+        # the pool.
+        self.cache = PagedKVCache.create(
+            model_cfg.num_layers,
+            n_pages,
+            cfg.page_size,
+            cfg.num_slots,
+            cfg.max_seq_len,
+            model_cfg.num_kv_heads,
+            model_cfg.head_size,
+            dtype="int8" if self._kv_quant else cfg.cache_dtype,
+            pool_sharding=pool_sharding,
+            table_sharding=self._bt_sharding,
+        )
+        self._alloc = PageAllocator(
+            n_pages, cfg.page_size, max_pages_per_slot=max_pages
+        )
+        self._prefix_cache = bool(cfg.prefix_cache)
+        if self._prefix_cache:
+            if cfg.prefill_chunk <= 0:
                 raise ValueError(
-                    f"num_pages={n_pages} cannot hold one max_seq_len "
-                    f"sequence ({max_pages} pages + scratch); preemption "
-                    "could not guarantee progress"
+                    "prefix_cache needs prefill_chunk > 0 (cache hits "
+                    "prefill only the uncached suffix, which runs "
+                    "through the staged-chunk path)"
                 )
-            self._bt_sharding = psh.named_sharding(
-                self.mesh, (None, None), cache_rules
+            if self._pp > 1:
+                raise ValueError(
+                    "prefix_cache does not compose with pipeline "
+                    "parallelism yet"
+                )
+            if (cfg.max_seq_len - cfg.prefill_chunk) // cfg.page_size < 1:
+                # The adoptable prefix is capped at max_seq_len -
+                # prefill_chunk (the padded suffix chunk must fit the
+                # staging buffer); at or past the cap the cache can
+                # NEVER hit and every admission pays pure hashing
+                # overhead.
+                import logging
+
+                logging.getLogger(__name__).warning(
+                    "prefix_cache is inert: prefill_chunk=%d leaves "
+                    "no adoptable pages under max_seq_len=%d "
+                    "(page_size=%d) — shrink prefill_chunk",
+                    cfg.prefill_chunk, cfg.max_seq_len, cfg.page_size,
+                )
+        # Host mirror of the block tables: page growth/release edits
+        # this; one small [slots, MP] transfer syncs the device copy
+        # before the next decode dispatch (_bt_dirty).
+        self._bt_host = np.full((cfg.num_slots, max_pages), -1, np.int32)
+        self._bt_dirty = False
+        # Chunked prefill staging: chunks write a ONE-slot [NL, L,
+        # KVH, D] buffer (the exact layout the chunk graph already
+        # speaks); the last chunk scatters the staged sequence through
+        # the block tables in the same device call. Costs one slot's
+        # KV of extra HBM, keeps the single compiled chunk graph.
+        self._stage_k = self._stage_v = None
+        if cfg.prefill_chunk > 0:
+            self._stage_sharding = psh.named_sharding(
+                self.mesh, (None, None, psh.KV_HEADS, None), cache_rules
             )
-            # Born sharded: each device allocates only its own part of
-            # the pool.
-            self.cache = PagedKVCache.create(
+            stage_shape = (
                 model_cfg.num_layers,
-                n_pages,
-                cfg.page_size,
-                cfg.num_slots,
                 cfg.max_seq_len,
                 model_cfg.num_kv_heads,
                 model_cfg.head_size,
-                dtype="int8" if self._kv_quant else cfg.cache_dtype,
-                pool_sharding=pool_sharding,
-                table_sharding=self._bt_sharding,
             )
-            self._alloc = PageAllocator(
-                n_pages, cfg.page_size, max_pages_per_slot=max_pages
+            self._stage_k = jnp.zeros(
+                stage_shape, cfg.cache_dtype, device=self._stage_sharding
             )
-            self._prefix_cache = bool(cfg.prefix_cache)
-            if self._prefix_cache:
-                if cfg.prefill_chunk <= 0:
-                    raise ValueError(
-                        "prefix_cache needs prefill_chunk > 0 (cache hits "
-                        "prefill only the uncached suffix, which runs "
-                        "through the staged-chunk path)"
-                    )
-                if self._pp > 1:
-                    raise ValueError(
-                        "prefix_cache does not compose with pipeline "
-                        "parallelism yet"
-                    )
-                if (cfg.max_seq_len - cfg.prefill_chunk) // cfg.page_size < 1:
-                    # The adoptable prefix is capped at max_seq_len -
-                    # prefill_chunk (the padded suffix chunk must fit the
-                    # staging buffer); at or past the cap the cache can
-                    # NEVER hit and every admission pays pure hashing
-                    # overhead.
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "prefix_cache is inert: prefill_chunk=%d leaves "
-                        "no adoptable pages under max_seq_len=%d "
-                        "(page_size=%d) — shrink prefill_chunk",
-                        cfg.prefill_chunk, cfg.max_seq_len, cfg.page_size,
-                    )
-            # Host mirror of the block tables: page growth/release edits
-            # this; one small [slots, MP] transfer syncs the device copy
-            # before the next decode dispatch (_bt_dirty).
-            self._bt_host = np.full((cfg.num_slots, max_pages), -1, np.int32)
-            self._bt_dirty = False
-            cache_sharding = pool_sharding
-            # Chunked prefill staging: chunks write a ONE-slot [NL, L,
-            # KVH, D] buffer (the exact layout the chunk graph already
-            # speaks); the last chunk scatters the staged sequence through
-            # the block tables in the same device call. Costs one slot's
-            # KV of extra HBM, keeps the single compiled chunk graph.
-            self._stage_k = self._stage_v = None
-            if cfg.prefill_chunk > 0:
-                self._stage_sharding = psh.named_sharding(
-                    self.mesh, (None, None, psh.KV_HEADS, None), cache_rules
-                )
-                stage_shape = (
-                    model_cfg.num_layers,
-                    cfg.max_seq_len,
-                    model_cfg.num_kv_heads,
-                    model_cfg.head_size,
-                )
-                self._stage_k = jnp.zeros(
-                    stage_shape, cfg.cache_dtype, device=self._stage_sharding
-                )
-                self._stage_v = jnp.zeros(
-                    stage_shape, cfg.cache_dtype, device=self._stage_sharding
-                )
-        else:
-            if cfg.prefix_cache:
-                raise ValueError(
-                    "prefix_cache requires cache_mode='paged' (pages are "
-                    "the sharing unit)"
-                )
-            self._prefix_cache = False
-            cache_sharding = psh.named_sharding(
-                self.mesh, KVCache.logical_axes(), cache_rules
-            )
-            self.cache = KVCache.create(
-                model_cfg.num_layers,
-                cfg.num_slots,
-                cfg.max_seq_len,
-                model_cfg.num_kv_heads,
-                model_cfg.head_size,
-                dtype=cfg.cache_dtype,
-                sharding=cache_sharding,
+            self._stage_v = jnp.zeros(
+                stage_shape, cfg.cache_dtype, device=self._stage_sharding
             )
 
         # Per-slot decode state lives ON DEVICE (replicated): steady-state
@@ -695,8 +655,7 @@ class Engine:
             )
             self._adapter_free = list(range(1, cfg.max_adapters + 1))
 
-        # Chunked-prefill support is resolved ONCE here; both cache-mode
-        # builders reuse it.
+        # Chunked-prefill support is resolved ONCE here.
         self._chunk_fn = None
         if cfg.prefill_chunk > 0:
             self._chunk_fn = getattr(self.family, "prefill_chunk", None)
@@ -708,8 +667,7 @@ class Engine:
         self._draft = None
         if cfg.speculate > 0:
             if (
-                self.cache_mode == "paged"
-                and getattr(self.family, "decode_verify_paged", None)
+                getattr(self.family, "decode_verify_paged", None)
                 is not None
                 and (
                     self._pp == 1
@@ -742,15 +700,25 @@ class Engine:
                         dparams, self.family.param_specs(dcfg), self.mesh,
                         dc_rules,
                     )
+                    # The draft's KV is dense, a [max_seq_len] row a slot
+                    # (slots on dp, KV heads on tp): family.decode_step's
+                    # layout, private to the proposer.
                     self._draft_sharding = psh.named_sharding(
-                        self.mesh, KVCache.logical_axes(), dc_rules
+                        self.mesh,
+                        (None, psh.KV_SLOTS, None, psh.KV_HEADS, None),
+                        dc_rules,
                     )
-                    dc = KVCache.create(
+                    draft_shape = (
                         dcfg.num_layers, cfg.num_slots, cfg.max_seq_len,
-                        dcfg.num_kv_heads, dcfg.head_size, cfg.cache_dtype,
-                        sharding=self._draft_sharding,
+                        dcfg.num_kv_heads, dcfg.head_size,
                     )
-                    self._dk, self._dv = dc.k, dc.v
+                    self._dk, self._dv = (
+                        jnp.zeros(
+                            draft_shape, cfg.cache_dtype,
+                            device=self._draft_sharding,
+                        )
+                        for _ in range(2)
+                    )
                     self._draft = True
             else:
                 if draft is not None:
@@ -759,16 +727,15 @@ class Engine:
                     # the misconfiguration.
                     raise ValueError(
                         "draft model provided but speculation is "
-                        f"unavailable (cache_mode={self.cache_mode!r}, "
-                        f"pp={self._pp}, family verify="
+                        f"unavailable (pp={self._pp}, family verify="
                         f"{getattr(self.family, 'decode_verify_paged', None) is not None})"
                     )
                 import logging
 
                 logging.getLogger(__name__).warning(
-                    "speculate=%d requested but unavailable (cache_mode=%s, "
-                    "family verify=%s) — running vanilla decode",
-                    cfg.speculate, self.cache_mode,
+                    "speculate=%d requested but unavailable "
+                    "(family verify=%s) — running vanilla decode",
+                    cfg.speculate,
                     getattr(self.family, "decode_verify_paged", None)
                     is not None,
                 )
@@ -802,7 +769,7 @@ class Engine:
             "requests": 0,
         }
 
-        self._build_jits(cache_sharding)
+        self._build_jits_paged(pool_sharding)
 
     # ---- expert routes ---------------------------------------------------------
 
@@ -810,19 +777,13 @@ class Engine:
     def routes_unsupported(self) -> str:
         """Why a request that asks for its expert routes is refused ("" =
         it is served; by a dense family without any). Not a setting: the
-        pp stage forwards, the verify forwards and the slot cache's
-        admission hand no routes over."""
+        pp stage forwards and the verify forwards hand no routes over."""
         if self._pp > 1:
             return "pipeline-parallel stage forwards hand no expert routes over"
         if self._spec:
             return (
                 "speculative decoding's verify forwards hand no expert "
                 "routes over"
-            )
-        if self.family.routes and self.cache_mode != "paged":
-            return (
-                "the slot cache's admission hands no expert routes over "
-                "(cache_mode='slot')"
             )
         return ""
 
@@ -877,178 +838,8 @@ class Engine:
             "mesh": {k: int(v) for k, v in self.mesh.shape.items()},
         }
 
-    def _build_jits(self, cache_sharding) -> None:
-        if self.cache_mode == "paged":
-            self._build_jits_paged(cache_sharding)
-            return
-        fam, mcfg = self.family, self.model_cfg
-        prefill_fn = self._resolve_prefill()
-        max_len = self.cfg.max_seq_len
-        chunk = max(1, self.cfg.decode_chunk)
-
-        def _prefill_admit(params, tokens, ints, floats, ck, cv, state, lora):
-            """Fused prefill → cache insert → first-token sample → slot-state
-            update: ONE device call per admitted request. `ints` packs
-            [length, slot, seed, top_k, adapter, forced]; `floats` packs
-            [temp, top_p] — two small transfers instead of seven.
-            forced >= 0 overrides the sampled token (preemption / stream
-            resume — cross-graph re-sampling could diverge by ULPs)."""
-            length, slot, seed, topk = ints[0], ints[1], ints[2], ints[3]
-            adapter, forced = ints[4], ints[5]
-            temp, topp = floats[0], floats[1]
-            if lora is None:
-                logits, k_all, v_all = prefill_fn(
-                    params, mcfg, tokens, length[None]
-                )
-            else:
-                logits, k_all, v_all = prefill_fn(
-                    params, mcfg, tokens, length[None],
-                    lora=lora, lora_idx=adapter[None],
-                )
-            ck, cv = insert_sequence(ck, cv, k_all[:, 0], v_all[:, 0], slot)
-            tok = sample(
-                logits,
-                seed.astype(jnp.uint32)[None],
-                length[None],
-                temp[None],
-                topk[None],
-                topp[None],
-            )[0]
-            tok = jnp.where(forced >= 0, forced, tok)
-            state = dict(
-                tokens=state["tokens"].at[slot].set(tok),
-                positions=state["positions"].at[slot].set(length),
-                seeds=state["seeds"].at[slot].set(seed.astype(jnp.uint32)),
-                temp=state["temp"].at[slot].set(temp),
-                topk=state["topk"].at[slot].set(topk),
-                topp=state["topp"].at[slot].set(topp),
-                lora_idx=state["lora_idx"].at[slot].set(adapter),
-            )
-            return tok, ck, cv, state
-
-        self._prefill_admit_jit = self.jit(
-            _prefill_admit,
-            donate_argnums=(4, 5, 6),
-            out_shardings=(None, cache_sharding, cache_sharding, None),
-            static_argnames=(),
-        )
-
-        def _decode_chunk(params, ck, cv, state, lora):
-            """`chunk` decode steps fused via lax.scan; emits [chunk, B]
-            tokens per device call. No host inputs besides the (donated,
-            device-resident) cache and slot state. Write positions are
-            clamped so rows that pass their stop point within a chunk stay
-            in-bounds (their surplus tokens are discarded host-side)."""
-            seeds, temp = state["seeds"], state["temp"]
-            topk, topp = state["topk"], state["topp"]
-
-            def body(carry, _):
-                tokens, positions, ck, cv = carry
-                if lora is None:
-                    logits, ck, cv = fam.decode_step(
-                        params, mcfg, tokens, positions, ck, cv
-                    )
-                else:
-                    logits, ck, cv = fam.decode_step(
-                        params, mcfg, tokens, positions, ck, cv,
-                        lora=lora, lora_idx=state["lora_idx"],
-                    )
-                # Sampled token lands at position+1 — the fold-in value, so
-                # a seeded request replays identically across batches.
-                toks = sample(logits, seeds, positions + 1, temp, topk, topp)
-                next_pos = jnp.minimum(positions + 1, max_len - 1)
-                return (toks, next_pos, ck, cv), toks
-
-            (tokens, positions, ck, cv), toks_seq = jax.lax.scan(
-                body,
-                (state["tokens"], state["positions"], ck, cv),
-                None,
-                length=chunk,
-            )
-            state = dict(state, tokens=tokens, positions=positions)
-            return toks_seq, ck, cv, state
-
-        self._decode_jit = self.jit(
-            _decode_chunk,
-            donate_argnums=(1, 2, 3),
-            out_shardings=(None, cache_sharding, cache_sharding, None),
-        )
-
-        if self.cfg.prefill_chunk > 0:
-            chunk_fn = self._chunk_fn
-
-            def _slot_slice(c, slot):
-                nl, _, L, kvh, d = c.shape
-                sl = jax.lax.dynamic_slice(
-                    c, (0, slot, 0, 0, 0), (nl, 1, L, kvh, d)
-                )
-                return sl[:, 0]
-
-            def _slot_write(c, slot, sl):
-                return jax.lax.dynamic_update_slice(
-                    c, sl[:, None].astype(c.dtype), (0, slot, 0, 0, 0)
-                )
-
-            def _chunk_mid(params, tokens, ints, ck, cv, lora):
-                start, slot, length, adapter = ints[0], ints[1], ints[2], ints[3]
-                ks, vs = _slot_slice(ck, slot), _slot_slice(cv, slot)
-                _, ks, vs = chunk_fn(
-                    params, mcfg, tokens, start, length, ks, vs,
-                    want_logits=False,
-                    lora=lora,
-                    lora_idx=None if lora is None else adapter[None],
-                )
-                return _slot_write(ck, slot, ks), _slot_write(cv, slot, vs)
-
-            self._prefill_chunk_mid_jit = self.jit(
-                _chunk_mid,
-                donate_argnums=(3, 4),
-                static_argnums=(),
-                out_shardings=(cache_sharding, cache_sharding),
-            )
-
-            def _chunk_last(params, tokens, ints, floats, ck, cv, state, lora):
-                start, slot, length = ints[0], ints[1], ints[2]
-                adapter, seed, topk = ints[3], ints[4], ints[5]
-                forced = ints[6]
-                temp, topp = floats[0], floats[1]
-                ks, vs = _slot_slice(ck, slot), _slot_slice(cv, slot)
-                logits, ks, vs = chunk_fn(
-                    params, mcfg, tokens, start, length, ks, vs,
-                    want_logits=True,
-                    lora=lora,
-                    lora_idx=None if lora is None else adapter[None],
-                )
-                ck = _slot_write(ck, slot, ks)
-                cv = _slot_write(cv, slot, vs)
-                tok = sample(
-                    logits,
-                    seed.astype(jnp.uint32)[None],
-                    length[None],
-                    temp[None],
-                    topk[None],
-                    topp[None],
-                )[0]
-                tok = jnp.where(forced >= 0, forced, tok)
-                state = dict(
-                    tokens=state["tokens"].at[slot].set(tok),
-                    positions=state["positions"].at[slot].set(length),
-                    seeds=state["seeds"].at[slot].set(seed.astype(jnp.uint32)),
-                    temp=state["temp"].at[slot].set(temp),
-                    topk=state["topk"].at[slot].set(topk),
-                    topp=state["topp"].at[slot].set(topp),
-                    lora_idx=state["lora_idx"].at[slot].set(adapter),
-                )
-                return tok, ck, cv, state
-
-            self._prefill_chunk_last_jit = self.jit(
-                _chunk_last,
-                donate_argnums=(4, 5, 6),
-                out_shardings=(None, cache_sharding, cache_sharding, None),
-            )
-
     def _build_jits_paged(self, pool_sharding) -> None:
-        """Paged-cache compiled paths: admission scatters the prefilled
+        """The compiled paths: admission scatters the prefilled
         sequence through the slot's block-table row; decode scatters one
         token per slot and attends over resident pages only."""
         fam, mcfg = self.family, self.model_cfg
@@ -1754,18 +1545,12 @@ class Engine:
 
     def kv_utilization(self) -> float:
         """Fraction of KV-cache capacity in use: allocated pages over the
-        pool (paged mode) or occupied token positions over total slot
-        capacity (slot mode). Pages parked idle in the prefix cache count
+        pool. Pages parked idle in the prefix cache count
         as free — they are reclaimable by any admission."""
-        if self.cache_mode == "paged":
-            total = self._n_pages - 1  # page 0 is reserved scratch
-            if total <= 0:
-                return 0.0
-            return 1.0 - self._alloc.free_pages / total
-        cap = self.cfg.num_slots * self.cfg.max_seq_len
-        if cap <= 0:
+        total = self._n_pages - 1  # page 0 is reserved scratch
+        if total <= 0:
             return 0.0
-        return sum(r.position for r in self._active.values()) / cap
+        return 1.0 - self._alloc.free_pages / total
 
     def _bucket(self, n: int) -> int:
         for b in self.cfg.buckets():
@@ -1785,67 +1570,7 @@ class Engine:
         return req
 
     def _admit_pending(self) -> list[StepEvent]:
-        """Prefill pending requests into free slots. Returns emitted tokens."""
-        if self.cache_mode == "paged":
-            return self._admit_pending_paged()
-        emitted = []
-        while len(self._sched) and self._free_slots:
-            req = self._sched.peek()
-            slot = self._free_slots[-1]
-            # Resume (stream continuation / preemption recompute): the
-            # prefix re-prefills as context with the last emitted token
-            # FORCED — same contract as the paged path.
-            resumed = bool(req.out_tokens)
-            seq = (
-                req.prompt + req.out_tokens[:-1] if resumed else req.prompt
-            )
-            plen = len(seq)
-            self._pop_pending()
-            self._free_slots.pop()
-            req.slot = slot
-            C = self.cfg.prefill_chunk
-            if C > 0 and plen > C:
-                tok = self._admit_chunked(req, slot, seq, plen, C)
-                ev = self._finish_admission(req, slot, plen, tok, resumed)
-                if ev is not None:
-                    emitted.append(ev)
-                continue
-            bucket = self._bucket(plen)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :plen] = seq
-            tok_dev, self.cache.k, self.cache.v, self._state = (
-                self._prefill_admit_jit(
-                    self.params,
-                    jnp.asarray(tokens),
-                    jnp.asarray(
-                        [
-                            plen,
-                            slot,
-                            # uint32 seed bit-cast into the int32 pack; the
-                            # jit reinterprets it back via astype(uint32).
-                            int(np.uint32(req.seed).view(np.int32)),
-                            req.params.top_k,
-                            req.adapter_idx,
-                            req.out_tokens[-1] if resumed else -1,
-                        ],
-                        jnp.int32,
-                    ),
-                    jnp.asarray(
-                        [req.params.temperature, req.params.top_p], jnp.float32
-                    ),
-                    self.cache.k,
-                    self.cache.v,
-                    self._state,
-                    self._lora,
-                )
-            )
-            ev = self._finish_admission(req, slot, plen, int(tok_dev), resumed)
-            if ev is not None:
-                emitted.append(ev)
-        return emitted
-
-    def _admit_pending_paged(self) -> list[StepEvent]:
-        """Paged admission, BATCHED: same-bucket pending prompts prefill
+        """Admission, BATCHED: same-bucket pending prompts prefill
         in one fused device call (up to cfg.max_admit_batch per call).
         A preempted request resumes by RECOMPUTE — re-prefill prompt +
         already-emitted tokens (minus the last, whose KV the next decode
@@ -2187,7 +1912,7 @@ class Engine:
     def _admit_chunked_paged(
         self, req: _Request, slot: int, seq: list[int], plen: int, C: int
     ) -> jax.Array:
-        """Chunked prefill in paged mode: chunks accumulate in the one-slot
+        """Chunked prefill: chunks accumulate in the one-slot
         staging buffer; the final chunk scatters the whole staged sequence
         through the slot's freshly-allocated block-table row."""
         mids, last = self._chunk_plan(seq, plen, C)
@@ -2400,52 +2125,6 @@ class Engine:
         ]
         return mids, (plen - C, arr[None, plen - C : plen])
 
-    def _admit_chunked(
-        self, req: _Request, slot: int, seq: list[int], plen: int, C: int
-    ) -> int:
-        """Prefill a long prompt chunk-by-chunk into the slot cache; the
-        final chunk also samples the first token and updates slot state.
-        `seq` includes a resume prefix when the request is a continuation
-        (the forced token then overrides the sample)."""
-        mids, (last_start, last_tokens) = self._chunk_plan(seq, plen, C)
-        for start, tokens in mids:
-            self.cache.k, self.cache.v = self._prefill_chunk_mid_jit(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray(
-                    [start, slot, plen, req.adapter_idx], jnp.int32
-                ),
-                self.cache.k,
-                self.cache.v,
-                self._lora,
-            )
-        tok_dev, self.cache.k, self.cache.v, self._state = (
-            self._prefill_chunk_last_jit(
-                self.params,
-                jnp.asarray(last_tokens),
-                jnp.asarray(
-                    [
-                        last_start,
-                        slot,
-                        plen,
-                        req.adapter_idx,
-                        int(np.uint32(req.seed).view(np.int32)),
-                        req.params.top_k,
-                        req.out_tokens[-1] if req.out_tokens else -1,
-                    ],
-                    jnp.int32,
-                ),
-                jnp.asarray(
-                    [req.params.temperature, req.params.top_p], jnp.float32
-                ),
-                self.cache.k,
-                self.cache.v,
-                self._state,
-                self._lora,
-            )
-        )
-        return int(tok_dev)
-
     def _check_stop(self, req: _Request) -> bool:
         if req.last_token in req.stop_token_ids:
             req.done = True
@@ -2564,13 +2243,12 @@ class Engine:
         if req.slot >= 0:
             self._active.pop(req.slot, None)
             self._free_slots.append(req.slot)
-            if self.cache_mode == "paged":
-                # Free the pages and clear the row BEFORE the next decode:
-                # a stale row would scatter the (junk) token of a freed
-                # slot into pages that may now belong to a live sequence.
-                self._alloc.release(req.slot)
-                self._bt_host[req.slot] = -1
-                self._bt_dirty = True
+            # Free the pages and clear the row BEFORE the next decode:
+            # a stale row would scatter the (junk) token of a freed
+            # slot into pages that may now belong to a live sequence.
+            self._alloc.release(req.slot)
+            self._bt_host[req.slot] = -1
+            self._bt_dirty = True
             req.slot = -1
         # Finished/cancelled requests leave the table immediately: callers
         # consume tokens from step() events, so retaining them would leak
@@ -2639,28 +2317,20 @@ class Engine:
             kv_capacity_factor(self.model_cfg.head_size)
             if self._kv_quant else 1.0
         )
-        info = {
+        return {
             "dtype": self._kv_dtype_name(),
             "quantized": self._kv_quant,
             "capacity_factor": factor,
             "slot_capacity": int(self.cfg.num_slots),
             "kv_layout": self.kv_layout,
-        }
-        if self.cache_mode == "paged":
-            info["num_pages"] = int(self._n_pages)
-            info["page_size"] = int(self.cfg.page_size)
-            info["token_capacity"] = int(
-                (self._n_pages - 1) * self.cfg.page_size
-            )
-            info["pool_bytes"] = int(self.cache.nbytes())
+            "num_pages": int(self._n_pages),
+            "page_size": int(self.cfg.page_size),
+            "token_capacity": int((self._n_pages - 1) * self.cfg.page_size),
+            "pool_bytes": int(self.cache.nbytes()),
             # What the newest decode chunk had to read (0 before the first).
-            info["live_slots"] = self.live_kv["slots"]
-            info["live_pages"] = self.live_kv["pages"]
-        else:
-            info["pool_bytes"] = int(
-                self.cache.k.nbytes + self.cache.v.nbytes
-            )
-        return info
+            "live_slots": self.live_kv["slots"],
+            "live_pages": self.live_kv["pages"],
+        }
 
     def export_handoff(
         self,
@@ -2685,11 +2355,6 @@ class Engine:
         from kubeai_tpu.disagg.handoff import KVHandoff
         from kubeai_tpu.engine.paged_cache import OutOfPages
 
-        if self.cache_mode != "paged":
-            raise RuntimeError(
-                "KV handoff export requires cache_mode='paged' (pages are "
-                "the transfer unit)"
-            )
         params = params or SamplingParams()
         adapter_idx = 0
         if adapter:
@@ -2818,10 +2483,6 @@ class Engine:
         compiled graph."""
         from kubeai_tpu.disagg.handoff import HandoffError
 
-        if self.cache_mode != "paged":
-            raise RuntimeError(
-                "KV handoff import requires cache_mode='paged'"
-            )
         mcfg = self.model_cfg
         nl, _n_pages, _page, kvh, d = handoff.k_pages.shape
         if (nl, kvh, d) != (
@@ -3024,7 +2685,7 @@ class Engine:
         the who-holds-which-prefix map. Advisory: routing hints built on
         it can go stale without harming correctness (admission re-checks
         through lookup())."""
-        if self.cache_mode != "paged" or not self._prefix_cache:
+        if not self._prefix_cache:
             return []
         with self._lock:
             return [h.hex() for h in self._alloc.holdings()]
@@ -3032,7 +2693,7 @@ class Engine:
     def cached_prefix_depth(self, hashes_hex: list[str]) -> int:
         """How many leading pages of the chain are held locally right
         now — what a peer fetch would NOT need to transfer."""
-        if self.cache_mode != "paged" or not self._prefix_cache:
+        if not self._prefix_cache:
             return 0
         try:
             hashes = [bytes.fromhex(h) for h in hashes_hex]
@@ -3056,7 +2717,7 @@ class Engine:
         chains incomparable across replicas."""
         from kubeai_tpu.disagg.handoff import KVPageExport
 
-        if self.cache_mode != "paged" or not self._prefix_cache:
+        if not self._prefix_cache:
             return None
         try:
             hashes = [bytes.fromhex(h) for h in hashes_hex]
@@ -3119,7 +2780,7 @@ class Engine:
         already held)."""
         from kubeai_tpu.disagg.handoff import HandoffError
 
-        if self.cache_mode != "paged" or not self._prefix_cache:
+        if not self._prefix_cache:
             return 0
         if export.n_pages == 0:
             return 0
@@ -3360,31 +3021,23 @@ class Engine:
                     emitted.extend(self._process_chunk(prev, "seq_cap"))
                     prev = None
             if self._active:
-                if self.cache_mode == "paged":
-                    with span("step.schedule"):
-                        self._ensure_decode_pages(
-                            inflight_lag=prev[2] if prev is not None else 0
+                with span("step.schedule"):
+                    self._ensure_decode_pages(
+                        inflight_lag=prev[2] if prev is not None else 0
+                    )
+                if self._bt_dirty:
+                    with span("step.dispatch"):
+                        self.cache.block_tables = jax.device_put(
+                            jnp.asarray(self._bt_host), self._bt_sharding
                         )
-                    if self._bt_dirty:
-                        with span("step.dispatch"):
-                            self.cache.block_tables = jax.device_put(
-                                jnp.asarray(self._bt_host), self._bt_sharding
-                            )
-                            self._bt_dirty = False
+                        self._bt_dirty = False
                 self.live_kv["pages_total"] += self.live_kv["pages"]
                 with span(
                     "step.decode", kv_layout=self.kv_layout,
                     live_slots=self.live_kv["slots"],
                     live_pages=self.live_kv["pages"],
                 ):
-                    if self.cache_mode != "paged":
-                        toks_seq, self.cache.k, self.cache.v, self._state = (
-                            self._decode_jit(
-                                self.params, self.cache.k, self.cache.v,
-                                self._state, self._lora,
-                            )
-                        )
-                    elif self._spec and self._spec_pick():
+                    if self._spec and self._spec_pick():
                         decode_mode = "spec"
                         if self._draft:
                             proposals, self._dk, self._dv = (

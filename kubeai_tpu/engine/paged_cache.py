@@ -1,12 +1,11 @@
 """Paged KV cache: block-table paging over a shared page pool.
 
-The slot cache (kvcache.py) preallocates [slots, max_seq_len] per slot —
-simple and fast, but HBM scales with the worst case. Paging allocates
-fixed-size pages on demand from a shared pool, so memory scales with the
-TOKENS ACTUALLY RESIDENT, buying more concurrent slots per chip under
-mixed-length traffic (the vLLM insight, rebuilt TPU-style: static
-shapes — the pool and block tables are fixed-size buffers; only their
-CONTENTS change).
+A [slots, max_seq_len] reservation per slot is simple, but its HBM
+scales with the worst case. Paging allocates fixed-size pages on demand
+from a shared pool, so memory scales with the TOKENS ACTUALLY RESIDENT,
+buying more concurrent slots per chip under mixed-length traffic (the
+vLLM insight, rebuilt TPU-style: static shapes — the pool and block
+tables are fixed-size buffers; only their CONTENTS change).
 
 Layout:
   k_pages / v_pages: [NL, n_pages, page_size, KVH, D]
@@ -18,10 +17,9 @@ Ops (jit-safe, tested against contiguous semantics):
   scatter_token      — write one token's K/V per slot through the tables
   insert_sequence    — write a prefilled sequence through the tables
 
-Engine integration (cache_mode="paged" + a Pallas ragged-paged-attention
-decode kernel that reads pages in place instead of gathering) is the
-round-2 item tracked in ROADMAP.md; this module is the validated
-bookkeeping + functional reference it drops into.
+This is the engine's only KV cache (engine.py); the decode kernels that
+read pages in place are in ops/paged_attention.py. The ops below are the
+functional reference they are tested against.
 """
 
 from __future__ import annotations

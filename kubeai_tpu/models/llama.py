@@ -277,8 +277,8 @@ def prefill(
     """Full-prompt forward. Returns (last_token_logits [B, V],
     k_all [NL, B, S, KVH, D], v_all [NL, B, S, KVH, D]).
 
-    The caller inserts the returned KV into the slot cache
-    (kubeai_tpu.engine.kvcache.insert_sequence).
+    The caller scatters the returned KV into the slot's pages
+    (kubeai_tpu.ops.paged_attention.batched_scatter_sequence).
 
     Long-context serving: when `mesh` carries an sp axis of size > 1 (and
     the padded length divides by it), prefill attention runs as RING
@@ -1100,13 +1100,15 @@ def prefill_chunk(
     tokens: jnp.ndarray,  # [1, C] one chunk (right-padded on the last chunk)
     start: jnp.ndarray,  # scalar int32: absolute position of tokens[:, 0]
     length: jnp.ndarray,  # scalar int32: true total prompt length
-    k_slot: jnp.ndarray,  # [NL, L, KVH, D] this slot's cache
+    k_slot: jnp.ndarray,  # [NL, L, KVH, D] one slot's dense buffer
     v_slot: jnp.ndarray,
     want_logits: bool = False,
     lora: dict | None = None,
     lora_idx: jnp.ndarray | None = None,
 ):
-    """One chunk of incremental prefill against the slot cache.
+    """One chunk of incremental prefill against one slot's dense
+    [NL, L, KVH, D] buffer (the engine's staging buffer, or the draft
+    model's cache row).
 
     The same compiled graph serves every chunk of every prompt length
     (static [1, C] shape) — unlike whole-prompt prefill, which compiles per

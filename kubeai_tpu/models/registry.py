@@ -60,8 +60,8 @@ class ModelFamily:
         self.param_specs = param_specs
         self.prefill = prefill
         self.decode_step = decode_step
-        # Paged-KV decode (block tables + page pools). None = family only
-        # supports the slot cache; the engine falls back automatically.
+        # Paged-KV decode (block tables + page pools): what the engine
+        # serves with. An Engine refuses a family that has none.
         self.decode_step_paged = decode_step_paged
         # Pipeline-parallel paged decode (stage-local KV over the pp mesh
         # axis). None = family cannot serve on a pp>1 mesh.

@@ -120,7 +120,8 @@ def decode_attention(
     logit_softcap: float | None = None,  # Gemma-2 tanh capping
     window: jnp.ndarray | int | None = None,  # sliding window; <= 0 = off
 ) -> jnp.ndarray:
-    """Single-token decode attention against the slot cache with length mask."""
+    """Single-token decode attention against a dense [B, L] cache with
+    length mask (family.decode_step: the draft model's cache)."""
     b, h, d = q.shape
     kvh = k_cache.shape[2]
     scale = scale if scale is not None else d ** -0.5

@@ -1,8 +1,8 @@
 """Paged decode attention: block-table paging, ragged lengths, TPU kernel.
 
-The decode hot op. The slot cache reads O(B * max_seq_len) of KV per step
-regardless of true lengths; paging reads only the pages a sequence
-actually occupies. Two implementations with one contract:
+The decode hot op. A dense [slots, max_seq_len] cache reads
+O(B * max_seq_len) of KV per step regardless of true lengths; paging
+reads only the pages a sequence actually occupies. Two implementations with one contract:
 
   ref_paged_decode_attention — jnp gather-through-block-tables reference
       (every backend but a TPU; see ops/dispatch.py for the one rule).

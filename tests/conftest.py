@@ -116,7 +116,7 @@ def pytest_configure(config):
         "stepperf: overlapped step pipeline suite — fake-device-clock "
         "overlap sim (>=1.3x decode throughput when host time >=30% of "
         "the step, zero token divergence), token-identity matrix "
-        "(overlap on/off x greedy/seeded x cache modes), barrier "
+        "(overlap on/off x greedy/seeded x prefill modes), barrier "
         "coverage, watchdog/overlap interaction, topology refusals "
         "(runs in the fast tier; select with -m stepperf)",
     )

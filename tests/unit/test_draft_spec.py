@@ -95,7 +95,7 @@ def test_self_draft_acceptance_is_total_where_lookup_collapses():
     all γ tokens — while prompt-lookup on the same prompts accepts
     (nearly) nothing. This is the draft's reason to exist.
 
-    float32: the draft chain (slot-cache attention) and verify (paged
+    float32: the draft chain (dense-cache attention) and verify (paged
     multi-query path) are different implementations, and a random-init
     tiny model's flat logits near-tie often enough in bf16 to break
     draft/target agreement ~20% of the time (exactness is unaffected —
@@ -137,7 +137,7 @@ def test_self_draft_acceptance_is_total_where_lookup_collapses():
 @pytest.mark.slow
 def test_draft_with_chunked_prefill_matches_vanilla():
     """Round-5 composition: chunked TARGET admission keeps the draft's
-    slot cache in sync via the draft's own chunked prefill
+    dense cache in sync via the draft's own chunked prefill
     (_draft_admit_chunked), so long prompts stream exactly like vanilla
     with a disagreeing draft."""
     rng = np.random.default_rng(17)
@@ -176,10 +176,15 @@ def test_draft_without_speculation_rejected():
     hide the misconfiguration."""
     with pytest.raises(ValueError, match="speculate == 0"):
         _mk(speculate=0, draft=(DRAFT_CFG, DRAFT_PARAMS))
+    # Nor where the family has no verify forward (mixtral has none).
+    from kubeai_tpu.models import mixtral
+
+    mcfg = mixtral.MixtralConfig.tiny()
+    mparams = mixtral.init_params(mcfg, jax.random.PRNGKey(1))
     with pytest.raises(ValueError, match="unavailable"):
-        _mk(
-            speculate=3, draft=(DRAFT_CFG, DRAFT_PARAMS),
-            cache_mode="slot",
+        Engine(
+            "mixtral", mcfg, mparams, draft=(mcfg, mparams),
+            cfg=EngineConfig(num_slots=2, max_seq_len=64, speculate=3),
         )
 
 

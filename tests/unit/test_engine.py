@@ -231,3 +231,12 @@ def test_chunked_prefill_with_lora_and_seeds():
     assert base.generate([prompt], sp, adapter="fin") == chunked.generate(
         [prompt], sp, adapter="fin"
     )
+
+
+def test_engine_config_field_count():
+    """A ratchet on the engine's knobs, lowered by each PR that removes
+    one and raised by none (ROADMAP.md C4: every field is a path somebody
+    has to keep working)."""
+    import dataclasses
+
+    assert len(dataclasses.fields(EngineConfig)) <= 20

@@ -1,7 +1,10 @@
-"""Paged-cache engine: slot-vs-paged equivalence, page accounting,
-oversubscription preemption with recompute resume."""
+"""Paged-cache engine: equivalence with the cache-free forward, page
+accounting, oversubscription preemption with recompute resume."""
+
+import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -13,10 +16,10 @@ CFG = llama.LlamaConfig.tiny()
 PARAMS = llama.init_params(CFG, jax.random.PRNGKey(0))
 
 
-def _make(mode, **kw):
+def _make(**kw):
     defaults = dict(num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4)
     defaults.update(kw)
-    return Engine("llama", CFG, PARAMS, cfg=EngineConfig(cache_mode=mode, **defaults))
+    return Engine("llama", CFG, PARAMS, cfg=EngineConfig(**defaults))
 
 
 def _prompts(n, rng=None, vocab=CFG.vocab_size):
@@ -27,51 +30,115 @@ def _prompts(n, rng=None, vocab=CFG.vocab_size):
     ]
 
 
-def test_paged_is_default_for_llama():
-    eng = _make("paged")
-    assert eng.cache_mode == "paged"
-    assert Engine(
+def test_the_cache_is_the_page_pool():
+    from kubeai_tpu.engine.paged_cache import PagedKVCache
+
+    eng = Engine(
         "llama", CFG, PARAMS, cfg=EngineConfig(num_slots=2, max_seq_len=64)
-    ).cache_mode == "paged"
+    )
+    assert isinstance(eng.cache, PagedKVCache)
+    assert eng.kv_cache_info()["num_pages"] == eng.cfg.effective_num_pages()
 
 
-@pytest.mark.slow
-def test_slot_paged_equivalence_greedy():
-    """Same prompts, greedy: identical token streams from both caches."""
-    prompts = _prompts(6)
-    sp = SamplingParams(temperature=0.0, max_tokens=12)
-    out_slot = _make("slot").generate(prompts, sp)
-    out_paged = _make("paged").generate(prompts, sp)
-    assert out_slot == out_paged
+def test_a_family_without_a_paged_forward_is_refused():
+    """Not a silent second cache: the page pool is the only one."""
+    from kubeai_tpu.models.registry import ModelFamily, get_model_family
+
+    full = get_model_family("llama")
+    stub = ModelFamily("dense-only-stub", **{k: getattr(full, k) for k in (
+        "config_from_hf", "tiny_config", "init_params", "param_specs",
+        "prefill", "decode_step")})
+    assert stub.decode_step_paged is None
+    with pytest.raises(ValueError, match="dense-only-stub"):
+        Engine(stub, CFG, PARAMS, cfg=EngineConfig(num_slots=2, max_seq_len=64))
 
 
-@pytest.mark.slow
-def test_slot_paged_equivalence_seeded_sampling():
-    prompts = _prompts(4, np.random.default_rng(7))
-    sp = SamplingParams(temperature=0.9, top_k=20, max_tokens=10, seed=123)
-    out_slot = _make("slot").generate(prompts, sp)
-    out_paged = _make("paged").generate(prompts, sp)
-    assert out_slot == out_paged
-
-
-def _family_world(name):
+def _family_world(name, dtype=None):
     """(family, tiny config, params): llama, mixtral (MoE FFN) and gemma-2
-    (sliding window on every other layer, attention and final softcap)."""
-    import dataclasses
-
+    (sliding window on every other layer, attention and final softcap).
+    `dtype` overrides the weights' (the configs' own is bfloat16)."""
     from kubeai_tpu.models import gemma, mixtral
 
     if name == "llama":
-        return "llama", CFG, PARAMS
-    if name == "mixtral":
-        cfg = mixtral.MixtralConfig.tiny()
-        return "mixtral", cfg, mixtral.init_params(cfg, jax.random.PRNGKey(1))
-    cfg = dataclasses.replace(
-        gemma.GemmaConfig.tiny(), sandwich_norms=True,
-        attn_logit_softcapping=50.0, final_logit_softcapping=30.0,
-        query_pre_attn_scalar=16.0, sliding_window=8,
+        cfg, init, key = CFG, llama.init_params, 0
+    elif name == "mixtral":
+        cfg, init, key = mixtral.MixtralConfig.tiny(), mixtral.init_params, 1
+    else:
+        cfg, init, key = dataclasses.replace(
+            gemma.GemmaConfig.tiny(), sandwich_norms=True,
+            attn_logit_softcapping=50.0, final_logit_softcapping=30.0,
+            query_pre_attn_scalar=16.0, sliding_window=8,
+        ), gemma.init_params, 2
+    if dtype is not None:
+        cfg = dataclasses.replace(cfg, dtype=dtype)
+    family = "gemma" if name == "gemma2" else name
+    return family, cfg, init(cfg, jax.random.PRNGKey(key))
+
+
+def check_against_cache_free_forward(name, prompt_lens, sp, **engine_kw):
+    """Serve random prompts of `prompt_lens` from a paged engine over
+    float32 weights and pool (no near tie decides a token), then re-run
+    `family.prefill` on prompt + tokens so far, with no cache of any kind,
+    and put its last-position logits through the sampler at the fold-in
+    value the engine uses (the context's length): every served token
+    must be the one that comes out."""
+    from kubeai_tpu.engine.sampling import sample
+    from kubeai_tpu.models.registry import get_model_family
+
+    family, cfg, params = _family_world(name, dtype=jnp.float32)
+    ecfg = EngineConfig(**{
+        "num_slots": 3, "max_seq_len": 64, "page_size": 16,
+        "decode_chunk": 4, "cache_dtype": jnp.float32, **engine_kw,
+    })
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
+               for n in prompt_lens]
+    outs = Engine(family, cfg, params, cfg=ecfg).generate(prompts, sp)
+    assert [len(o) for o in outs] == [sp.max_tokens] * len(prompts)
+
+    # One row a served token: the context that produced it, right-padded.
+    contexts = [p + o[:i] for p, o in zip(prompts, outs)
+                for i in range(len(o))]
+    lengths = np.asarray([len(c) for c in contexts], np.int32)
+    tokens = np.zeros((len(contexts), ecfg.max_seq_len), np.int32)
+    for row, c in zip(tokens, contexts):
+        row[: len(c)] = c
+    logits = jax.jit(get_model_family(family).prefill, static_argnums=1)(
+        params, cfg, jnp.asarray(tokens), jnp.asarray(lengths))[0]
+    n = len(contexts)
+    want = sample(
+        logits,
+        jnp.full((n,), (sp.seed or 0) & 0xFFFFFFFF, jnp.uint32),
+        jnp.asarray(lengths),
+        jnp.full((n,), sp.temperature, jnp.float32),
+        jnp.full((n,), sp.top_k, jnp.int32),
+        jnp.full((n,), sp.top_p, jnp.float32),
     )
-    return "gemma", cfg, gemma.init_params(cfg, jax.random.PRNGKey(2))
+    assert [t for o in outs for t in o] == np.asarray(want).tolist()
+
+
+@pytest.mark.parametrize("name", ["llama", "gemma2", "mixtral"])
+def test_paged_greedy_equals_the_cache_free_forward(name):
+    """The independent check of the paged path: batched admission, the
+    decode chunk (of 4, so several) and the in-place kernel's reference,
+    across a page boundary (16) and, for gemma-2, past the window (8)."""
+    check_against_cache_free_forward(
+        name, (5, 13, 19), SamplingParams(temperature=0.0, max_tokens=6))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize(
+    "sp",
+    [
+        SamplingParams(temperature=0.0, max_tokens=12),
+        SamplingParams(temperature=0.9, top_k=20, max_tokens=10, seed=123),
+    ],
+    ids=["greedy", "seeded-sampling"],
+)
+def test_paged_equals_the_cache_free_forward_long(sp):
+    """The long form: more prompts than slots, more tokens, and the
+    seeded sampler's position fold-in."""
+    check_against_cache_free_forward("llama", (3, 9, 17, 26, 33, 39), sp)
 
 
 @pytest.fixture(scope="module", params=["llama", "mixtral", "gemma2"])
@@ -128,7 +195,7 @@ def test_int8_pool_with_nothing_set_boots_and_generates(layouts):
 
 @pytest.mark.slow
 def test_pages_released_on_completion():
-    eng = _make("paged")
+    eng = _make()
     total = eng._alloc.free_pages
     outs = eng.generate(_prompts(5), SamplingParams(temperature=0.0, max_tokens=6))
     assert len(outs) == 5
@@ -139,7 +206,7 @@ def test_pages_released_on_completion():
 def test_oversubscribed_pool_defers_admission():
     # Pool holds ~1.5 max sequences; 4 slots want in. Admission defers,
     # everyone completes eventually.
-    eng = _make("paged", num_pages=1 + 12)  # 12 usable pages of 16 toks
+    eng = _make(num_pages=1 + 12)  # 12 usable pages of 16 toks
     sp = SamplingParams(temperature=0.0, max_tokens=8)
     outs = eng.generate(_prompts(4), sp)
     assert all(len(o) == 8 for o in outs)
@@ -153,27 +220,27 @@ def test_preemption_recompute_matches_unconstrained():
     # Long generations force page growth mid-decode.
     prompts = [rng.integers(1, CFG.vocab_size, 20).tolist() for _ in range(3)]
     sp = SamplingParams(temperature=0.0, max_tokens=40)
-    want = _make("paged").generate(prompts, sp)
+    want = _make().generate(prompts, sp)
 
-    tight = _make("paged", num_pages=1 + 9)  # pages for ~2 sequences
+    tight = _make(num_pages=1 + 9)  # pages for ~2 sequences
     got = tight.generate(prompts, sp)
     assert got == want
 
     # Seeded sampling also replays identically across preemption.
     sp2 = SamplingParams(temperature=0.8, top_k=16, max_tokens=30, seed=9)
-    want2 = _make("paged").generate(prompts, sp2)
-    got2 = _make("paged", num_pages=1 + 9).generate(prompts, sp2)
+    want2 = _make().generate(prompts, sp2)
+    got2 = _make(num_pages=1 + 9).generate(prompts, sp2)
     assert got2 == want2
 
 
 def test_pool_too_small_for_one_sequence_rejected():
     with pytest.raises(ValueError):
-        _make("paged", num_pages=4)  # < max_seq_len/page_size + scratch
+        _make(num_pages=4)  # < max_seq_len/page_size + scratch
 
 
 @pytest.mark.slow
 def test_cancel_frees_pages():
-    eng = _make("paged")
+    eng = _make()
     total = eng._alloc.free_pages
     sp = SamplingParams(temperature=0.0, max_tokens=50)
     rid = eng.add_request(list(range(1, 30)), sp)
@@ -219,7 +286,7 @@ def test_ring_prefill_serving_path(monkeypatch):
     got = eng_sp.generate(prompts, sp_param)
     assert calls["n"] > 0, "ring attention never engaged in serving prefill"
 
-    want = _make("paged", num_slots=2).generate(prompts, sp_param)
+    want = _make(num_slots=2).generate(prompts, sp_param)
     assert got == want
 
 
@@ -236,8 +303,8 @@ def test_speculative_greedy_matches_vanilla():
         rng.integers(1, CFG.vocab_size, 9).tolist(),
     ]
     sp = SamplingParams(temperature=0.0, max_tokens=30)
-    want = _make("paged").generate(prompts, sp)
-    eng = _make("paged", speculate=4, spec_adaptive=False)
+    want = _make().generate(prompts, sp)
+    eng = _make(speculate=4, spec_adaptive=False)
     assert eng._spec == 4
     got = eng.generate(prompts, sp)
     assert got == want
@@ -245,8 +312,8 @@ def test_speculative_greedy_matches_vanilla():
     # Boundary: generation runs into max_seq_len mid-window.
     long_prompt = ([3, 4, 5] * 40)[:110]
     sp2 = SamplingParams(temperature=0.0, max_tokens=64)
-    want2 = _make("paged").generate([long_prompt], sp2)
-    got2 = _make("paged", speculate=4, spec_adaptive=False).generate([long_prompt], sp2)
+    want2 = _make().generate([long_prompt], sp2)
+    got2 = _make(speculate=4, spec_adaptive=False).generate([long_prompt], sp2)
     assert got2 == want2
 
 
@@ -258,8 +325,8 @@ def test_speculative_seeded_matches_vanilla():
         rng.integers(1, CFG.vocab_size, 17).tolist(),
     ]
     sp = SamplingParams(temperature=0.9, top_k=12, max_tokens=20, seed=77)
-    want = _make("paged").generate(prompts, sp)
-    got = _make("paged", speculate=3, spec_adaptive=False).generate(prompts, sp)
+    want = _make().generate(prompts, sp)
+    got = _make(speculate=3, spec_adaptive=False).generate(prompts, sp)
     assert got == want
 
 
@@ -267,7 +334,7 @@ def test_speculative_seeded_matches_vanilla():
 def test_speculative_accepts_on_repetitive_text():
     """On repetitive context the lookup proposals are right, so steps
     emit >1 token — fewer device steps than tokens."""
-    eng = _make("paged", speculate=4, spec_adaptive=False)
+    eng = _make(speculate=4, spec_adaptive=False)
     prompt = ([11, 12, 13, 14, 15] * 10)[:45]
     sp = SamplingParams(temperature=0.0, max_tokens=24)
     out = eng.generate([prompt], sp)[0]
@@ -322,10 +389,8 @@ def test_chunked_prefill_paged_matches_whole_prompt():
         SamplingParams(temperature=0.0, max_tokens=10),
         SamplingParams(temperature=0.9, top_k=12, max_tokens=8, seed=77),
     ):
-        want = _make("paged").generate(prompts, sp)
-        chunked = _make("paged", prefill_chunk=16)
-        assert chunked.cache_mode == "paged"  # no slot fallback anymore
-        assert chunked.generate(prompts, sp) == want
+        want = _make().generate(prompts, sp)
+        assert _make(prefill_chunk=16).generate(prompts, sp) == want
 
 
 @pytest.mark.slow
@@ -335,8 +400,8 @@ def test_chunked_prefill_paged_preemption_resume():
     rng = np.random.default_rng(13)
     prompts = [rng.integers(1, CFG.vocab_size, 30).tolist() for _ in range(3)]
     sp = SamplingParams(temperature=0.0, max_tokens=40)
-    want = _make("paged", prefill_chunk=16).generate(prompts, sp)
-    tight = _make("paged", prefill_chunk=16, num_pages=1 + 9)
+    want = _make(prefill_chunk=16).generate(prompts, sp)
+    tight = _make(prefill_chunk=16, num_pages=1 + 9)
     assert tight.generate(prompts, sp) == want
 
 
@@ -344,17 +409,16 @@ def test_chunked_prefill_paged_preemption_resume():
 def test_chunked_prefill_nondivisible_tail():
     """ceil(plen/C)*C > max_seq_len used to make the final chunk's
     dynamic_update_slice CLAMP its start and silently corrupt staged KV;
-    the backward-aligned final chunk must match whole-prompt output in
-    both cache modes."""
+    the backward-aligned final chunk must match whole-prompt output,
+    and the cache-free forward."""
     rng = np.random.default_rng(17)
     prompt = rng.integers(1, CFG.vocab_size, 97).tolist()  # 7*16 = 112 > 100
     sp = SamplingParams(temperature=0.0, max_tokens=3)
-    for mode in ("slot", "paged"):
-        want = _make(mode, max_seq_len=100).generate([prompt], sp)
-        got = _make(mode, max_seq_len=100, prefill_chunk=16).generate(
-            [prompt], sp
-        )
-        assert got == want, mode
+    want = _make(max_seq_len=100).generate([prompt], sp)
+    got = _make(max_seq_len=100, prefill_chunk=16).generate([prompt], sp)
+    assert got == want
+    check_against_cache_free_forward(
+        "llama", (97,), sp, max_seq_len=100, prefill_chunk=16)
 
 
 @pytest.mark.slow
@@ -368,8 +432,8 @@ def test_adaptive_speculation_streams_match_vanilla():
         rng.integers(1, CFG.vocab_size, 21).tolist(),  # random: chunk-friendly
     ]
     sp = SamplingParams(temperature=0.0, max_tokens=40)
-    want = _make("paged").generate(prompts, sp)
-    eng = _make("paged", speculate=4)  # spec_adaptive defaults True
+    want = _make().generate(prompts, sp)
+    eng = _make(speculate=4)  # spec_adaptive defaults True
     got = eng.generate(prompts, sp)
     assert got == want
     # Both arms were sampled at least once (epsilon-greedy bootstrap).
@@ -380,7 +444,7 @@ def test_adaptive_speculation_streams_match_vanilla():
 def test_adaptive_pick_follows_measured_throughput():
     """The mode chooser is epsilon-greedy on the tokens/s EMAs: after both
     arms are sampled it runs the winner, probing the loser periodically."""
-    eng = _make("paged", speculate=4, spec_probe_every=8)
+    eng = _make(speculate=4, spec_probe_every=8)
     # Bootstrap: first two calls per arm (call 1 = compile, not folded).
     assert eng._spec_pick() is True
     eng._spec_observe("spec", 4, 1.0)
@@ -401,7 +465,7 @@ def test_adaptive_pick_follows_measured_throughput():
 
 
 def test_adaptive_off_always_speculates():
-    eng = _make("paged", speculate=4, spec_adaptive=False)
+    eng = _make(speculate=4, spec_adaptive=False)
     assert all(eng._spec_pick() for _ in range(50))
 
 
@@ -421,7 +485,7 @@ def test_speculation_on_sp_mesh_matches_single_device():
     repetitive = ([7, 8, 9, 10] * 12)[:40]
     prompts = [repetitive, [1, 2, 3, 4]]
     sp_param = SamplingParams(temperature=0.0, max_tokens=12)
-    want = _make("paged", num_slots=2).generate(prompts, sp_param)
+    want = _make(num_slots=2).generate(prompts, sp_param)
     mesh = build_mesh(MeshConfig(sp=2), devices=devs[:2])
     eng = Engine(
         "llama", CFG, PARAMS, mesh=mesh,

@@ -484,11 +484,6 @@ def test_a_routed_familys_programs_gain_one_small_output_each(tiny):
     (toks, routes), *_ = out
     assert (toks.shape, routes.shape, routes.dtype) == (
         (2,), (2, 16, 2, 2), jnp.uint8)
-    # The slot cache's programs hand none over: they are what they were.
-    slot = _engine(tiny, cache_mode="slot")
-    assert not slot._routes and slot.moe["routes"] is False
-    (toks, blocks), = _run(slot, [[1, 2, 3]], ask=False)
-    assert len(toks) == 6 and slot.route_stats["rows_decode"] == 0
 
 
 def test_a_dense_family_reads_back_one_bare_array_a_reap(dense, monkeypatch):
@@ -539,13 +534,10 @@ def _refusing(kind, devices8, tiny):
     if kind == "speculation":
         return Engine(name, cfg, params, cfg=dataclasses.replace(
             ecfg, speculate=2)), "unified"
-    if kind == "slot-cache":
-        return _engine(tiny, cache_mode="slot"), "unified"
     return _engine(tiny), kind  # one half of a disaggregated pair
 
 
-@pytest.mark.parametrize("kind", ["pp", "speculation", "slot-cache",
-                                  "prefill", "decode"])
+@pytest.mark.parametrize("kind", ["pp", "speculation", "prefill", "decode"])
 def test_engines_that_hand_no_routes_over_say_so_and_answer_400(kind, devices8, tiny):
     eng, role = _refusing(kind, devices8, tiny)
     if role == "unified":

@@ -125,9 +125,9 @@ def tiny():
     return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
 
 
-def _engine(tiny, **kw):
+def _engine(tiny, mesh=None, **kw):
     cfg, params = tiny
-    eng = Engine("llama", cfg, params, cfg=EngineConfig(
+    eng = Engine("llama", cfg, params, mesh=mesh, cfg=EngineConfig(
         num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4, **kw))
     rec = Recorder()
     eng.profiler._annotate = rec
@@ -152,15 +152,22 @@ def _count_calls(monkeypatch, eng, *names):
 
 
 @pytest.mark.parametrize(
-    "kw, layout",
-    [({}, "stacked"), ({"kv_dtype": "int8"}, "per_layer"),
-     ({"cache_mode": "slot"}, "per_layer")],
-    ids=["bf16-pool", "int8-pool", "slot-cache"],
+    "pp, kw, layout",
+    [(1, {}, "stacked"), (1, {"kv_dtype": "int8"}, "per_layer"),
+     (2, {}, "per_layer")],
+    ids=["bf16-pool", "int8-pool", "pp-stages"],
 )
-def test_the_decode_span_names_the_kv_layout(tiny, kw, layout):
+def test_the_decode_span_names_the_kv_layout(tiny, devices8, pp, kw, layout):
     """A compile-time choice: every `step.decode` span of an engine carries
-    the same word, the one `/v1/state` gives under `kv_cache`."""
-    eng, rec = _engine(tiny, **kw)
+    the same word, the one `/v1/state` gives under `kv_cache`. A stage of
+    a pipeline slices its layers' pages out of the stack."""
+    from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = (
+        build_mesh(MeshConfig(pp=pp), devices=devices8[:pp]) if pp > 1
+        else None
+    )
+    eng, rec = _engine(tiny, mesh=mesh, **kw)
     _drive(eng, [[1, 2, 3], [4, 5, 6, 7]])
     spans = rec.named("step.decode")
     assert spans and {s["attrs"]["kv_layout"] for s in spans} == {layout}

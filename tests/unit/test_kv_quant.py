@@ -344,11 +344,10 @@ def raw(tiny):
 @pytest.mark.parametrize(
     "kw,msg",
     [
-        (dict(cache_mode="slot"), "paged"),
         (dict(speculate=2), "speculative"),
         (dict(decode_kernel="fused"), "fused"),
     ],
-    ids=["slot-cache", "speculation", "fused-kernel"],
+    ids=["speculation", "fused-kernel"],
 )
 def test_int8_engine_config_refusals(tiny, kw, msg):
     cfg, params = tiny

@@ -195,43 +195,15 @@ def test_gemma2_sliding_window_parity(tmp_path):
 
 
 @pytest.mark.slow
-def test_gemma_mixtral_paged_equivalence():
-    """Slot-vs-paged decode equivalence for the non-llama families
-    (gemma2 incl. alternating sliding-window layers; mixtral MoE)."""
-    import dataclasses
+@pytest.mark.parametrize("name", ["gemma2", "mixtral"])
+def test_gemma_mixtral_paged_equivalence(name):
+    """Paged decode against the cache-free forward for the non-llama
+    families (gemma2 incl. alternating sliding-window layers; mixtral
+    MoE): the long form of test_engine_paged.py's tier-1 cases."""
+    from test_engine_paged import check_against_cache_free_forward
 
-    from kubeai_tpu.models import gemma as gm, mixtral as mx
-
-    g2 = dataclasses.replace(
-        gm.GemmaConfig.tiny(), sandwich_norms=True,
-        attn_logit_softcapping=50.0, final_logit_softcapping=30.0,
-        sliding_window=8,
-    )
-    for fam, cfg, params in (
-        ("gemma", g2, gm.init_params(g2, jax.random.PRNGKey(1))),
-        (
-            "mixtral",
-            mx.MixtralConfig.tiny(),
-            mx.init_params(mx.MixtralConfig.tiny(), jax.random.PRNGKey(2)),
-        ),
-    ):
-        prompts = [
-            np.random.default_rng(5).integers(1, 200, n).tolist()
-            for n in (5, 19, 33)
-        ]
-        sp = SamplingParams(temperature=0.0, max_tokens=10)
-        outs = {}
-        for mode in ("slot", "paged"):
-            eng = Engine(
-                fam, cfg, params,
-                cfg=EngineConfig(
-                    num_slots=3, max_seq_len=64, cache_mode=mode,
-                    page_size=16, decode_chunk=4,
-                ),
-            )
-            assert eng.cache_mode == mode
-            outs[mode] = eng.generate(prompts, sp)
-        assert outs["slot"] == outs["paged"], fam
+    check_against_cache_free_forward(
+        name, (5, 19, 33, 27, 11), SamplingParams(temperature=0.0, max_tokens=10))
 
 
 @pytest.mark.parametrize(

@@ -1,5 +1,5 @@
 """Paged KV cache: allocator bookkeeping + paged gather/scatter must be
-semantically identical to the contiguous slot cache."""
+semantically identical to a contiguous [slots, max_len] cache."""
 
 import jax
 import jax.numpy as jnp

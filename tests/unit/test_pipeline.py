@@ -118,7 +118,6 @@ def _pp_world(devices, pp, num_layers=4, microbatches=0):
     ref = Engine("llama", cfg, params, cfg=ecfg)
     mesh = build_mesh(MeshConfig(pp=pp), devices=devices[:pp])
     eng = Engine("llama", cfg, params, mesh=mesh, cfg=ecfg)
-    assert eng.cache_mode == "paged"
     return cfg, params, ref, eng
 
 
@@ -258,11 +257,6 @@ def test_engine_pp_validation(devices8):
     with pytest.raises(ValueError, match="not divisible"):
         Engine("llama", cfg, params, mesh=mesh,
                cfg=EngineConfig(num_slots=4, max_seq_len=64))
-    mesh2 = build_mesh(MeshConfig(pp=2), devices=devices8[:2])
-    with pytest.raises(ValueError, match="paged"):
-        Engine("llama", cfg, params, mesh=mesh2,
-               cfg=EngineConfig(num_slots=4, max_seq_len=64,
-                                cache_mode="slot"))
 
 
 @pytest.mark.slow

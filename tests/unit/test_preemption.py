@@ -36,7 +36,7 @@ TOK = ByteTokenizer()
 PROMPT = "the quick brown fox jumps over the lazy dog"
 
 
-# ---- engine-level continuation (token identity, both cache modes) -----------
+# ---- engine-level continuation (token identity, both prefill modes) --------
 
 
 def _drain(eng, rids):
@@ -68,10 +68,9 @@ def _engine(tiny, **overrides):
 
 
 @pytest.mark.parametrize("mode_kw", [
-    {"cache_mode": "paged"},
-    {"cache_mode": "slot"},
-    {"cache_mode": "paged", "prefill_chunk": 8},
-], ids=["paged", "slot", "paged-chunked"])
+    {},
+    {"prefill_chunk": 8},
+], ids=["paged", "paged-chunked"])
 @pytest.mark.parametrize("sampling", [
     {"temperature": 0.0, "seed": 7},
     {"temperature": 0.9, "top_k": 8, "seed": 7},
@@ -79,7 +78,7 @@ def _engine(tiny, **overrides):
 def test_engine_continuation_token_identical(tiny, mode_kw, sampling):
     """add_request(resume_tokens=prefix) resumes the sampling RNG at the
     correct step: the continuation equals the uninterrupted tail exactly,
-    for greedy AND seeded sampling, in every cache/prefill mode."""
+    for greedy AND seeded sampling, in every prefill mode."""
     sp = SamplingParams(max_tokens=24, **sampling)
     prompt = TOK.encode(PROMPT)
 

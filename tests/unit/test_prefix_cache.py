@@ -218,14 +218,6 @@ def test_prefix_cache_pages_shared_not_duplicated():
 def test_prefix_cache_config_validation():
     with pytest.raises(ValueError, match="prefill_chunk"):
         _mk(prefix_cache=True, prefill_chunk=0)
-    with pytest.raises(ValueError, match="paged"):
-        Engine(
-            "llama", CFG, PARAMS,
-            cfg=EngineConfig(
-                prefix_cache=True, cache_mode="slot", prefill_chunk=32,
-                num_slots=2, max_seq_len=128,
-            ),
-        )
 
 
 def test_allocator_failed_ensure_preserves_cache():

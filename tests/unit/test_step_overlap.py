@@ -90,13 +90,12 @@ def _collect(out, evs):
 
 
 @pytest.mark.parametrize("mode_kw", [
-    {"cache_mode": "paged"},
-    {"cache_mode": "slot"},
-    {"cache_mode": "paged", "prefill_chunk": 8},
-], ids=["paged", "slot", "paged-chunked"])
+    {},
+    {"prefill_chunk": 8},
+], ids=["paged", "paged-chunked"])
 def test_token_identity_overlap_vs_sync(tiny, pair, mode_kw):
     """Greedy AND seeded streams are byte-identical with the pipeline on."""
-    if mode_kw == {"cache_mode": "paged"}:
+    if not mode_kw:
         on, off = pair
     else:
         on = _engine(tiny, "on", **mode_kw)

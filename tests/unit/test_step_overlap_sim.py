@@ -1,7 +1,7 @@
 """Tier-1 gate on the deterministic overlapped-step sim: the >=1.3x
 decode-throughput claim (with modelled host time >=30% of the
 synchronous step), byte-identical token streams (overlap on vs off,
-greedy AND seeded, across paged/slot/chunked-prefill admission models),
+greedy AND seeded, across paged/chunked-prefill admission models),
 barrier coverage (mid-run admission and drain both force a reap), and
 the phase-accounting claim (overlap_idle shrinks under overlap) hold on
 every run — and the sim itself is deterministic."""
