@@ -358,6 +358,11 @@ class Engine:
         # they computed against the tokens of the shapes they ran
         # (padded - useful = padding); EngineMetrics folds the deltas in.
         self.admit_stats = {"calls": 0, "useful_tokens": 0, "padded_tokens": 0}
+        # Reaped chunks by what forced the reap (the `step.reap` span's
+        # `barrier`): "none" ran behind the next dispatched chunk.
+        self.step_reaps = dict.fromkeys(
+            ("none", "admission", "seq_cap", "spec", "external"), 0
+        )
         # The KV a decode chunk reads, from the walk that grows the active
         # slots' pages: slots and pages (ceil(tokens / page) each) at the
         # newest dispatch, and the pages summed over every dispatched chunk.
@@ -3215,41 +3220,24 @@ class Engine:
         the ordinary reap behind it."""
         toks_seq, chunk_slots = inflight[0], inflight[1]
         span = self.profiler.span
-        cols = [slot for slot, req in chunk_slots if not req.done]
-        with span(
-            "step.reap", rows=len(cols), chunk=inflight[2], barrier=barrier
-        ):
+        rows = sum(not req.done for _, req in chunk_slots)
+        self.step_reaps[barrier] += 1
+        with span("step.reap", rows=rows, chunk=inflight[2], barrier=barrier):
             if isinstance(toks_seq, tuple) and toks_seq[0] == "spec":
                 return self._process_spec(
                     toks_seq[1], toks_seq[2], chunk_slots
                 )
-            if not cols:
+            if not rows:
                 return []  # every rider cancelled since dispatch — no transfer
             routes_seq = inflight[4]
-            col_of = None
-            if len(cols) < int(toks_seq.shape[1]):
-                # Slice the TOKENS to the active rows on-device before the
-                # host transfer: the decode chunk is a padded [chunk, B]
-                # int32 buffer and fetching dead columns ships chunk*(B-A)
-                # junk tokens per step. The gather is a dependent device
-                # op, so timing block_until_ready on its output still
-                # measures the chunk's compute wait. A routed family's
-                # expert sets ([chunk, B, routed layers, k], one byte an
-                # id: 4 KB at 8 x 16 x 16 x 2) are read WHOLE: a second
-                # eager gather would be one more program and launch per
-                # reap for a buffer smaller than its dispatch, and the
-                # host indexes them by slot.
-                toks_seq = jnp.take(
-                    toks_seq, jnp.asarray(cols, jnp.int32), axis=1
-                )
-                col_of = {slot: i for i, slot in enumerate(cols)}
+            # No slice on the device: it would queue behind the next chunk.
             # Device compute the host could NOT hide: ~the whole device
             # step in the synchronous loop, →0 under perfect overlap.
             with span("step.overlap_idle"):
-                toks_seq = jax.block_until_ready(toks_seq)
+                jax.block_until_ready(toks_seq)
             with span("step.readback"):
                 if routes_seq is None:
-                    toks_seq = np.asarray(jax.device_get(toks_seq))  # [chunk, A]
+                    toks_seq = np.asarray(jax.device_get(toks_seq))
                 else:
                     # The same transfer brings the chunk's expert sets.
                     toks_seq, routes_seq = jax.device_get(
@@ -3275,12 +3263,7 @@ class Engine:
                                     (len(emitted), k, slot, req, req.position)
                                 )
                         self._emit_token(
-                            req,
-                            int(toks_seq[
-                                k, slot if col_of is None else col_of[slot]
-                            ]),
-                            now,
-                            emitted,
+                            req, int(toks_seq[k, slot]), now, emitted
                         )
             if routes_seq is not None:
                 self._chunk_routes(routes_seq, kept, asked, emitted)

@@ -270,6 +270,15 @@ class EngineMetrics:
             "hit each).",
             self.registry,
         )
+        self.step_reaps = Counter(
+            "kubeai_engine_step_reaps_total",
+            "Decode chunks reaped (waited for, read back, emitted), by "
+            "what forced the reap (label `barrier`, as on the `step.reap` "
+            "span): none = behind the next dispatched chunk, so the "
+            "host's pass hid behind the device; admission / seq_cap / "
+            "spec = ahead of it; external = outside a step.",
+            self.registry,
+        )
         self.decode_live_pages = Counter(
             "kubeai_engine_decode_live_pages_total",
             "KV pages that hold the active slots' tokens (ceil(tokens / "
@@ -583,6 +592,11 @@ class EngineMetrics:
                  {"kind": "pad"}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
+        for barrier, total in getattr(inner, "step_reaps", {}).items():
+            self.step_reaps.inc(
+                max(0.0, total - self.step_reaps.get(barrier=barrier)),
+                barrier=barrier,
+            )
         live = getattr(inner, "live_kv", None)
         if live:
             self.decode_live_pages.inc(max(

@@ -141,6 +141,7 @@ NEW_COUNTERS = (
     "kubeai_engine_admit_calls_total",
     "kubeai_engine_prefill_tokens_total",
     "kubeai_engine_decode_live_pages_total",
+    "kubeai_engine_step_reaps_total",
 )
 
 

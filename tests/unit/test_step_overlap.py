@@ -1,7 +1,7 @@
 """Overlapped step pipeline suite: token identity (overlap on vs off,
 greedy AND seeded, across paged/slot/chunked-prefill), the conservative
 barriers (cancel, drain, handoff export/import) over REAL engines and
-real HTTP, the active-row readback slice, the watchdog/overlap
+real HTTP, the reap that launches no device program, the watchdog/overlap
 interaction, topology refusals (pp, lockstep), and the new
 dispatch/readback/overlap_idle phase vocabulary."""
 
@@ -16,7 +16,8 @@ import jax
 import numpy as np
 import pytest
 
-from testutil import http_post
+from testutil import http_get, http_post
+from tests.unit.test_host_timeline import Recorder
 
 from kubeai_tpu.engine import Engine, EngineConfig
 from kubeai_tpu.engine.engine import EngineDraining, StepOverlapUnsupported
@@ -25,6 +26,7 @@ from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.engine.server import EngineServer
 from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.fleet.profiler import PHASES, phase_totals
+from kubeai_tpu.metrics.registry import parse_prometheus_text
 from kubeai_tpu.models import llama
 from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -182,38 +184,158 @@ def test_handoff_export_import_under_overlap(tiny, pair):
     assert run(on) == run(off)
 
 
-# ---- readback slices to active rows (full-padded-batch regression) -----------
+# ---- a reap launches no device program -------------------------------------------
+
+
+class _ReapWatch:
+    """Wraps an engine's decode program and `_process_chunk`, and patches
+    `jax.block_until_ready` / `jax.device_get`: every array a reap waits
+    for or reads is kept as (barrier, the dispatch that made it or None,
+    dispatches made so far, shape). None means the array is no decode
+    program's output: something derived from one, so a program launched by
+    the reap."""
+
+    def __init__(self, eng, monkeypatch):
+        self.born = {}  # id(output) -> (dispatch number, the array, kept alive)
+        self.dispatched = 0
+        self.barrier = None
+        self.touched = []
+        decode, process = eng._decode_jit, eng._process_chunk
+
+        def dispatching(*args):
+            out = decode(*args)
+            self.dispatched += 1
+            for leaf in jax.tree_util.tree_leaves(out[0]):
+                self.born[id(leaf)] = (self.dispatched, leaf)
+            return out
+
+        def reaping(inflight, barrier="none"):
+            self.barrier = barrier
+            try:
+                return process(inflight, barrier)
+            finally:
+                self.barrier = None
+
+        def watching(real):
+            def call(x, *a, **kw):
+                if self.barrier is not None:
+                    for leaf in jax.tree_util.tree_leaves(x):
+                        made = self.born.get(id(leaf), (None,))[0]
+                        self.touched.append(
+                            (self.barrier, made, self.dispatched, leaf.shape))
+                return real(x, *a, **kw)
+            return call
+
+        eng._decode_jit, eng._process_chunk = dispatching, reaping
+        monkeypatch.setattr(
+            jax, "block_until_ready", watching(jax.block_until_ready))
+        monkeypatch.setattr(jax, "device_get", watching(jax.device_get))
 
 
 @pytest.mark.parametrize("overlap", ["off", "on"])
-def test_readback_transfers_only_active_rows(tiny, overlap, monkeypatch):
-    """One active request in a 4-slot engine: each decode-chunk readback
-    must move chunk x 1 elements, not the full chunk x num_slots batch."""
+def test_reap_launches_no_device_program(tiny, overlap, monkeypatch):
+    """What a reap blocks on and reads IS the decode program's own output,
+    the padded [chunk, num_slots] buffer; and while the live rows fall from
+    4 to 1 nothing is compiled after the first chunk (the on-device slice
+    this guards against was one program per count of live rows)."""
     eng = _engine(tiny, overlap)
-    chunk = eng.cfg.decode_chunk
-    shapes = []
-    real = jax.device_get
+    watch = _ReapWatch(eng, monkeypatch)
+    rids = [
+        eng.add_request(PROMPTS[0], SamplingParams(
+            temperature=0.0, max_tokens=n))
+        for n in (6, 10, 14, 22)
+    ]
+    out = {r: [] for r in rids}
+    live, late = set(), []  # late: compiled once the first chunk was reaped
 
-    def counting(x, *a, **kw):
-        out = real(x, *a, **kw)
-        if not isinstance(out, tuple):
-            arr = np.asarray(out)
-            if arr.ndim == 2 and arr.shape[0] == chunk:
-                shapes.append(arr.shape)
-        return out
+    def on_compile(event, _secs, **_kw):
+        if (watch.touched
+                and event == "/jax/core/compile/backend_compile_duration"):
+            late.append(event)
 
-    monkeypatch.setattr(jax, "device_get", counting)
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        while eng.has_work():
+            _collect(out, eng.step())
+            live.add(len(eng._active))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    lengths = [len(out[r]) for r in rids]  # the last may meet EOS first
+    assert lengths[:3] == [6, 10, 14] and lengths[3] > 14
+    assert {4, 3, 2, 1} <= live
+    assert late == [], f"{len(late)} programs compiled after the first reap"
+    shape = (eng.cfg.decode_chunk, eng.cfg.num_slots)
+    assert watch.touched and all(
+        made is not None and got == shape
+        for _, made, _, got in watch.touched), watch.touched
+    # One wait and one read a reap, on the same array.
+    assert watch.touched[0::2] == watch.touched[1::2]
+
+
+def test_reap_of_chunk_n_precedes_the_end_of_chunk_n_plus_1(tiny, monkeypatch):
+    """On a step with nothing waiting chunk N+1 is dispatched first and
+    chunk N reaped behind it. The reap may touch only chunk N's outputs:
+    an array derived after N+1's dispatch is ready only when N+1 is, and
+    chunk N's tokens would reach the host a chunk late."""
+    eng = _engine(tiny, "on")
+    watch = _ReapWatch(eng, monkeypatch)
     [stream] = eng.generate([PROMPTS[0]], SamplingParams(
         temperature=0.0, max_tokens=16))
     assert len(stream) == 16
-    assert shapes, "no decode-chunk readbacks observed"
-    assert all(s[1] == 1 for s in shapes), (
-        f"full-padded-batch readback (num_slots={eng.cfg.num_slots}): "
-        f"{shapes}"
-    )
-    # Pin the transferred element count: ceil(15 decode tokens / 4) chunks
-    # of 4x1 — the unsliced transfer would be 4x that.
-    assert sum(a * b for a, b in shapes) == 16
+    assert all(made is not None for _, made, _, _ in watch.touched), (
+        "the reap read an array derived on the device")
+    behind = [t for t in watch.touched if t[0] == "none" and t[2] > t[1]]
+    assert len(behind) >= 2 * 3, watch.touched
+    for barrier, made, dispatched, _ in watch.touched:
+        if barrier == "none":
+            # N+1 is in flight (or, at the tail, nothing is) and the reap
+            # waits for N alone.
+            assert dispatched - made in (0, 1), watch.touched
+
+
+def test_step_reaps_counter_by_barrier(tiny, monkeypatch):
+    """`Engine.step_reaps` counts what the `step.reap` spans say, and
+    `/metrics` carries it as kubeai_engine_step_reaps_total{barrier}."""
+    eng = _engine(tiny, "on")
+    rec = Recorder()
+    monkeypatch.setattr(eng.profiler, "_annotate", rec)
+    sp = SamplingParams(temperature=0.0, max_tokens=24)
+    r0 = eng.add_request(PROMPTS[0], sp)
+    _step_until_inflight(eng)
+    eng.step()  # nothing waiting: reaped behind the next dispatch
+    assert eng.step_reaps["none"] == 1
+    eng.add_request(PROMPTS[1], sp)
+    eng.step()  # a prompt waits: reaped first
+    assert eng.step_reaps["admission"] == 1
+    assert eng._inflight is not None
+    assert eng.cancel(r0)  # outside a step
+    assert eng.step_reaps["external"] == 1
+    while eng.has_work():
+        eng.step()
+    by_span = dict.fromkeys(eng.step_reaps, 0)
+    for reap in rec.named("step.reap"):
+        by_span[reap["attrs"]["barrier"]] += 1
+    assert eng.step_reaps == by_span
+    assert by_span["none"] > 1 and by_span["seq_cap"] == by_span["spec"] == 0
+
+    srv = EngineServer(eng, TOK, "m", host="127.0.0.1", port=0)
+    srv.start()
+    try:
+        addr = f"127.0.0.1:{srv.port}"
+        st, _ = http_post(addr, "/v1/completions", {
+            "model": "m", "prompt": "count me", "max_tokens": 12,
+            "temperature": 0}, timeout=60)
+        assert st == 200
+        _, body = http_get(addr, "/metrics")
+    finally:
+        srv.stop()
+    parsed = parse_prometheus_text(body.decode())
+    on_wire = {
+        dict(labels)["barrier"]: value for (name, labels), value
+        in parsed.items() if name == "kubeai_engine_step_reaps_total"
+    }
+    assert on_wire == {k: float(v) for k, v in eng.step_reaps.items()}
+    assert on_wire["none"] > by_span["none"]
 
 
 # ---- phase vocabulary --------------------------------------------------------
