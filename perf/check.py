@@ -22,19 +22,49 @@ The control (a study, not part of a run) is the same reference computed in a
 lower precision: at each position of the same prompts and tokens, the gap of
 the token that precision puts first.
 
+**A configuration that routes tokens to experts.** With seeded weights a
+router's k-th and (k+1)-th scores lie within the served precision's rounding
+for some tokens in every layer; the program takes the other expert there, and
+a reference that took its own would compare two different computations. So
+the timed run asks the program for the expert sets it took (`kubeai_routes`,
+docs/concepts/expert-routes.md), and where the reference's `forward` has a
+`routes` parameter it is made to FOLLOW them: each routed layer computes with
+the given sets, and the reference says what it would have taken itself. The
+three numbers above are then read on the followed computation, and three
+more hold the router:
+
+- `route_rows_bad`: sampled requests whose blocks are not exactly rows
+  `0 .. P+N-2` of shape `[routed layers, k]` as `/v1/state` says (limit 0;
+  `assemble_routes`). Such a request is not followed.
+- `followed_share`: the share of (position, routed layer) decisions whose
+  given set is not the reference's own, as sets. Rounding flips a few per
+  cent; a router that takes other experts, or a lower precision, many more.
+- `route_trail`: the `ROUTE_TRAIL_QUANTILE` quantile, over all decisions, of
+  how far the given set trails the reference's own: the reference's k-th
+  selection score minus the lowest selection score in the given set (0 where
+  the sets are equal). A sound flip trails by rounding; a wrong expert by the
+  spread of the router's scores.
+
+The control of a routed reference takes its own sets in the lower precision;
+the float32 reference follows those, and the same six numbers are read.
+
 `compared` in what is returned holds each number that decided `correct`
 beside its limit (a number that could not be read is `None`, and over).
 """
 
 from __future__ import annotations
 
+import base64
 import gc
+import inspect
 import random
 
 from perf import traffic
 
 SAMPLE_REQUESTS = 6
 SAMPLE_MIN_TOKENS = 600
+ROUTE_TRAIL_QUANTILE = 0.99
+ROUTE_DTYPES = {"uint8": "<u1", "uint16": "<u2", "uint32": "<u4"}
 
 
 def device_arrays(*owners) -> list:
@@ -74,6 +104,74 @@ def break_tokens(engine, every: int = 5) -> None:
     engine.step = broken
 
 
+def break_router(params, reference):
+    """The fault `--break-path route` plants, before the engine is built:
+    the expert columns of every router leaf (the reference module's
+    `ROUTER_LEAVES`, last axis = experts) are rolled by one, so the program
+    takes, and hands over, other experts than the reference's router would."""
+    import jax
+    import jax.numpy as jnp
+
+    names = getattr(reference, "ROUTER_LEAVES", ())
+    hit = []
+
+    def roll(path, leaf):
+        if getattr(path[-1], "key", None) not in names:
+            return leaf
+        hit.append(path)
+        return jax.device_put(jnp.roll(leaf, 1, axis=-1), leaf.sharding)
+
+    params = jax.tree_util.tree_map_with_path(roll, params)
+    if not hit:
+        raise SystemExit("perf: --break-path route found no router leaf "
+                         f"{names} in the served weights")
+    return params
+
+
+def takes_routes(reference) -> bool:
+    """A reference whose `forward` has a `routes` parameter is a routed
+    family's; one without is a dense family's and is called as ever."""
+    return "routes" in inspect.signature(reference.forward).parameters
+
+
+def assemble_routes(blocks, positions: int, moe: dict):
+    """The rows `[positions, routed layers, k]` of one request from its
+    `kubeai_routes` blocks in arrival order, or None where they break the
+    row rule: every block of the shape `/v1/state` says, expert ids below
+    `experts` and distinct in a set, the first block at position 0, each
+    later one starting where the rows held so far end (a gap is missing
+    rows), and exactly `positions` rows at the end (neither cut nor
+    padded). A block may step back only as a re-admission after a
+    preemption does: from position 0, over everything held so far; the
+    later rows are the ones the cache holds."""
+    import numpy as np
+
+    shape = [int(moe["routed_layers"]), int(moe["k"])]
+    rows = np.zeros((0, *shape), np.int64)
+    for block in blocks or ():
+        try:
+            dtype = np.dtype(ROUTE_DTYPES[block["dtype"]])
+            start, n = int(block["start"]), int(block["rows"])
+            raw = base64.b64decode(block["data"])
+            if list(block["shape"]) != shape or n < 1 or (
+                    len(raw) != n * shape[0] * shape[1] * dtype.itemsize):
+                return None
+            new = np.frombuffer(raw, dtype).reshape(n, *shape).astype(np.int64)
+        except (KeyError, TypeError, ValueError):
+            return None
+        if start == len(rows):
+            rows = np.concatenate([rows, new])
+        elif start == 0 and n >= len(rows):
+            rows = new
+        else:
+            return None
+    ordered = np.sort(rows, axis=-1)
+    if len(rows) != positions or (rows >= int(moe["experts"])).any() or (
+            ordered[..., 1:] == ordered[..., :-1]).any():
+        return None
+    return rows
+
+
 def sample(records: list[dict], seed: int) -> list[dict]:
     done = [r for r in records if r.get("ok") and r["token_ids"]]
     if not done:
@@ -99,15 +197,33 @@ def _gaps(np, logits, tokens):
     return best - got, logits.argmax(axis=-1)
 
 
+def _route_readings(np, given, own, trail) -> tuple[dict, int]:
+    """Over the followed sequences: the share of decisions whose given set
+    is not the reference's own, the trail's quantile and its largest; and
+    the number of decisions they were read over."""
+    pairs = [(g, o, t) for g, o, t in zip(given, own, trail) if g is not None]
+    if not pairs:
+        return {"followed_share": None, "route_trail": None,
+                "route_trail_max": None}, 0
+    differs = np.concatenate([
+        (np.sort(g, -1) != np.sort(o, -1)).any(-1).ravel() for g, o, _ in pairs])
+    trails = np.concatenate([t.ravel() for _, _, t in pairs])
+    return {"followed_share": float(differs.mean()),
+            "route_trail": float(np.quantile(trails, ROUTE_TRAIL_QUANTILE)),
+            "route_trail_max": float(trails.max())}, int(differs.size)
+
+
 def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
-                             controls=(), log=print) -> dict:
+                             controls=(), log=print, moe=None) -> dict:
+    """`moe` is `/v1/state`'s block of an engine that hands its routes over
+    (the timed run then asked for them); None for any other engine."""
     import numpy as np
 
     limits = cfg.get("correct", {})
+    routed = bool(moe) and takes_routes(reference)
     picked = sample(records, seed)
-    gaps, flips, short = [], 0, 0
-    control = {q: {"gaps": [], "flips": 0} for q in controls}
-    seqs, served_of = [], []
+    gaps, flips, short, rows_bad = [], 0, 0, 0
+    seqs, served_of, given = [], [], []
     for r in picked:
         served = [int(t) for t in r["token_ids"]]
         short += int(len(served) != r["max_tokens"])
@@ -115,26 +231,32 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
         seq = traffic.prompt_tokens(seed, r["index"], plen, vocab) + served[:-1]
         seqs.append((seq, list(range(plen - 1, plen - 1 + len(served)))))
         served_of.append(np.asarray(served))
+        if routed:
+            given.append(assemble_routes(r.get("routes"), len(seq), moe))
+            rows_bad += int(given[-1] is None)
     # One shape for the whole cell: the mix's longest request, rounded up.
     _, longest_prompt = traffic.prompt_length_range(mix)
     longest_output = mix["output_tokens"].get(
         "high", mix["output_tokens"].get("value"))
     padding = {"pad_to": -(-(longest_prompt + longest_output) // 256) * 256,
                "rows_pad": -(-longest_output // 128) * 128}
-    logits = [np.asarray(x)
-              for x in reference.forward(cfg, key, seqs, **padding)]
+
+    def forward(quant=None, routes=None):
+        """(logits, own sets, trail); the last two None for a dense family."""
+        if not routed:
+            out = reference.forward(cfg, key, seqs, quant=quant, **padding), None, None
+        else:
+            out = reference.forward(cfg, key, seqs, quant=quant, routes=routes,
+                                    **padding)
+        return [np.asarray(x) for x in out[0]], out[1], out[2]
+
+    logits, own, trail = forward(routes=given)
     firsts = []
     for lg, served in zip(logits, served_of):
         g, first = _gaps(np, lg, served)
         gaps.extend(g.tolist())
         flips += int((first != served).sum())
         firsts.append(first)
-    for q in controls:
-        lower = [np.asarray(x) for x in reference.forward(cfg, key, seqs, quant=q, **padding)]
-        for lg, lq, first in zip(logits, lower, firsts):
-            gq, _ = _gaps(np, lg, lq.argmax(axis=-1))
-            control[q]["gaps"].extend(gq.tolist())
-            control[q]["flips"] += int((lq.argmax(axis=-1) != first).sum())
     n = len(gaps)
     readings = {
         "max_gap": max(gaps) if gaps else None,
@@ -142,6 +264,26 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
         "flip_share": flips / n if n else None,
         "short": short,
     }
+    if routed:
+        of_routes, decisions = _route_readings(np, given, own, trail)
+        log(f"correct: routes followed over {decisions} decisions of "
+            f"{len(picked) - rows_bad} requests")
+        readings.update(route_rows_bad=rows_bad, **of_routes)
+    control = {}
+    for q in controls if n else ():
+        # The lower precision in the program's place. A routed one takes
+        # its own sets, which the float32 reference then follows.
+        lower, sets_q, _ = forward(quant=q, routes=[None] * len(seqs))
+        full, own_q, trail_q = (
+            forward(routes=sets_q) if routed else (logits, None, None))
+        gq, fq = [], 0
+        for lg, lq, first in zip(full, lower, firsts):
+            gq.extend(_gaps(np, lg, lq.argmax(axis=-1))[0].tolist())
+            fq += int((lq.argmax(axis=-1) != first).sum())
+        control[q] = {"max_gap": max(gq), "mean_gap": sum(gq) / n,
+                      "flip_share": fq / n}
+        if routed:
+            control[q].update(_route_readings(np, sets_q, own_q, trail_q)[0])
     ok = bool(picked)
     compared = {}
     for name, value in readings.items():
@@ -154,12 +296,16 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
         compared[name] = [value, limit]
         log(f"correct: {name} = {_fmt(value)}  limit {limit}  "
             f"{'ok' if within else 'OVER'}")
+    for name in limits:
+        if name not in readings:  # a limit nothing was read for is over
+            ok = False
+            compared[name] = [None, limits[name]]
+            log(f"correct: {name} = unread  limit {limits[name]}  OVER")
     log(f"correct: sample of {len(picked)} requests, {n} served tokens")
-    control = {q: {"max_gap": max(c["gaps"]), "mean_gap": sum(c["gaps"]) / n,
-                   "flip_share": c["flips"] / n}
-               for q, c in control.items()} if n else {}
     for q, c in control.items():
         log(f"control {q}: " + "  ".join(f"{k} = {_fmt(v)}" for k, v in c.items()))
+        over = [k for k, v in c.items() if k in limits and v > limits[k]]
+        log(f"control {q} lands over: {', '.join(over) or 'NO LIMIT'}")
     return {"correct": ok, **readings, "tokens": n, "compared": compared,
             "control": control}
 

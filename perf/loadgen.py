@@ -11,6 +11,14 @@ late the generator ran; a stream that outlives the window is cut at its first
 event after the window's end (its first token, if that is still to come). Closed loop: each client sends its next request when
 its last one has ended, from `-preroll_s` until the window closes; what is
 still streaming then is cut.
+
+Where the spec says `routes` (the server's `/v1/state` reported a router
+whose routes it hands over), every request carries `"kubeai_routes": true`
+and its record keeps, under `routes`, the blocks of every chunk's top-level
+`kubeai_routes` in arrival order, as they came: `{start, rows, shape, dtype,
+data}` with `data` in base64 (docs/concepts/expert-routes.md; perf/check.py
+holds them to the row rule). Without it the request body and the records are
+what they always were.
 """
 
 from __future__ import annotations
@@ -46,21 +54,30 @@ class Clock:
             sleep(min(left, 0.5))
 
 
+def request_body(model, req, vocab, seed, routes=False) -> str:
+    body = {
+        "model": model,
+        "prompt": text_of(
+            traffic.prompt_tokens(seed, req["index"], req["prompt_len"], vocab)
+        ),
+        "max_tokens": req["max_tokens"], "temperature": 0.0, "stream": True,
+    }
+    if routes:
+        body["kubeai_routes"] = True
+    return json.dumps(body)
+
+
 def one_request(host, port, model, req, vocab, seed, clock, timeout,
-                stop_at=None) -> dict:
+                stop_at=None, routes=False) -> dict:
     """Send one streamed completion and record every token-bearing event.
     Past `stop_at` the stream is cut (the server cancels a request whose
     client went away) and the record says so."""
     rec = {"index": req["index"], "due": req.get("due"),
            "prompt_len": req["prompt_len"], "max_tokens": req["max_tokens"],
            "ok": False, "events": [], "token_ids": []}
-    body = json.dumps({
-        "model": model,
-        "prompt": text_of(
-            traffic.prompt_tokens(seed, req["index"], req["prompt_len"], vocab)
-        ),
-        "max_tokens": req["max_tokens"], "temperature": 0.0, "stream": True,
-    })
+    if routes:
+        rec["routes"] = []
+    body = request_body(model, req, vocab, seed, routes)
     conn = http.client.HTTPConnection(host, port, timeout=timeout)
     try:
         rec["sent"] = clock.now()
@@ -88,10 +105,13 @@ def one_request(host, port, model, req, vocab, seed, clock, timeout,
         # Parsing waits until the stream has ended, so it costs the timed
         # path nothing.
         for t, raw in lines:
-            ids = json.loads(raw).get("token_ids")
+            chunk = json.loads(raw)
+            ids = chunk.get("token_ids")
             if ids:
                 rec["events"].append([t, len(ids)])
                 rec["token_ids"].extend(ids)
+            if routes:
+                rec["routes"].extend(chunk.get("kubeai_routes") or ())
         if rec["ok"]:
             rec["end"] = clock.now()
     except (OSError, http.client.HTTPException, ValueError) as e:
@@ -174,7 +194,8 @@ def main(argv) -> int:
 
     def send(req, stop_at=None):
         return one_request(spec["host"], spec["port"], spec["model"], req,
-                           spec["vocab"], spec["seed"], clock, timeout, stop_at)
+                           spec["vocab"], spec["seed"], clock, timeout, stop_at,
+                           routes=bool(spec.get("routes")))
 
     run = run_open if spec["mix"]["loop"] == "open" else run_closed
     records = run(spec, clock, send)

@@ -74,11 +74,13 @@ def histogram_sum_share(spec, obs):
 
 
 def histogram_sum_per_step(spec, obs):
-    """delta(_sum) over every label, per engine step completed."""
+    """delta(_sum) over every label (or those `where` keeps), per engine
+    step completed."""
     steps = obs["steps1"] - obs["steps0"]
     if steps <= 0:
         return None
-    return delta(obs, spec["metric"] + "_sum") / steps * spec.get("scale", 1.0)
+    return (delta(obs, spec["metric"] + "_sum", spec.get("where"))
+            / steps * spec.get("scale", 1.0))
 
 
 def counter_delta(spec, obs):

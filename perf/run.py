@@ -124,14 +124,12 @@ def warm_up(engine, mix: dict, vocab: int) -> int:
                 SamplingParams(temperature=0.0, max_tokens=1))
         sent += batch
         drain()
-    # The decode chunk, with every count of live rows from all slots down
-    # to one: the engine slices a chunk's tokens to its live rows on the
-    # device, one small program per count.
-    chunk = engine.cfg.decode_chunk
+    # The decode chunk, every slot live: one program whatever the count of
+    # live rows (a reap launches none of its own).
     for i in range(engine.cfg.num_slots):
         engine.add_request(
             traffic.prompt_tokens(1, sent + i, lo, vocab),
-            SamplingParams(temperature=0.0, max_tokens=2 + chunk * (i + 1)))
+            SamplingParams(temperature=0.0, max_tokens=2 + engine.cfg.decode_chunk))
     sent += engine.cfg.num_slots
     drain()
     return sent
@@ -237,6 +235,8 @@ class Bench:
         t = time.time()
         params = jax.block_until_ready(self._make_params(self.key))
         log(f"weights on the device in {time.time() - t:.1f} s")
+        if args.break_path == "route":
+            params = check.break_router(params, self.reference)
         t = time.time()
         self.engine = Engine(family, model_cfg, params, mesh=mesh,
                              cfg=EngineConfig(**cfg["engine"]),
@@ -256,6 +256,14 @@ class Bench:
             self.engine, BenchTokenizer(self.vocab), cell["config"],
             host="127.0.0.1", port=0)
         self.server.start()
+        # A router whose routes this engine hands over: the timed run asks
+        # for them and `correct` follows them. Any other engine has None.
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{self.server.port}/v1/state", timeout=30) as resp:
+            moe = json.load(resp).get("moe")
+        self.moe = moe if moe and moe.get("routes") else None
+        if self.moe:
+            log(f"routes asked of every request: {self.moe}")
 
     def reseed(self, seed: int) -> None:
         """Study mode: new weights from another seed, in place, and the
@@ -311,6 +319,7 @@ class Bench:
             "host": "127.0.0.1", "port": port, "model": self.cell["config"],
             "vocab": self.vocab,
             "clients": traffic.num_clients(mix, self.engine_cfg),
+            **({"routes": True} if self.moe else {}),
         }
         out_dir = os.path.join(ROOT, "perf_out")
         os.makedirs(out_dir, exist_ok=True)
@@ -397,7 +406,7 @@ class Bench:
         t = time.time()
         v = check.served_against_reference(
             self.reference, self.cfg, self.key, self.mix, obs["records"], seed,
-            self.vocab, controls=controls, log=log)
+            self.vocab, controls=controls, log=log, moe=self.moe)
         log(f"reference check took {time.time() - t:.1f} s")
         return v
 
@@ -452,9 +461,11 @@ def main(argv=None) -> int:
                     help="study: after one set-up, a window and a check per seed")
     ap.add_argument("--sweep", default="",
                     help="study: after one set-up, a window per offered rate")
-    ap.add_argument("--break-path", default="", choices=("", "token"),
-                    help="alter served tokens where they are produced, to "
-                    "show `correct` come out false")
+    ap.add_argument("--break-path", default="", choices=("", "token", "route"),
+                    help="plant a fault to show `correct` come out false: "
+                    "`token` alters served tokens where they are produced, "
+                    "`route` permutes the router's expert columns in the "
+                    "served weights")
     args = ap.parse_args(argv)
 
     bench, cell, cfg = load_cell(args.workload, args.rehearse)
@@ -473,12 +484,16 @@ def main(argv=None) -> int:
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in b.devices)
     attempted, failed = e2e.counts(mix["loop"], obs["records"], args.seconds)
-    metrics = b.per_layer(obs) if args.trace else b.end_to_end(obs)
     # The program's state goes before the reference runs: the peak stays the
     # program's, and the reference has the chip to itself.
     check.free_engine(b.engine)
     v = b.verdict(obs, args.seed, [c for c in args.control.split(",") if c])
     correct = bool(v["correct"] and failed == 0 and attempted > 0)
+    # What the check read of the router is an observation like any other: a
+    # per-layer metric of kind `observed` shows a drift before it fails.
+    if v.get("followed_share") is not None:
+        obs["route_followed_share"] = 100.0 * v["followed_share"]
+    metrics = b.per_layer(obs) if args.trace else b.end_to_end(obs)
 
     device = {"platform": b.platform, "kind": b.kind, "count": b.chips,
               "memory_peak_bytes": int(peak)}

@@ -3,6 +3,8 @@ and that a later PR can add a configuration, a mix, a cell and a per-layer
 metric, and a whole architecture (reference, reader kind, cost function, a
 cache of its own shape, a rehearsal cell), with new files and entries only."""
 
+import base64
+import importlib
 import importlib.util
 import json
 import os
@@ -142,7 +144,14 @@ def test_configuration_files_state_their_engine_and_limits(name):
         cfg = json.load(f)
     assert cfg["chips"] in (1, 4)
     assert {"num_slots", "max_seq_len"} <= set(cfg["engine"])
-    assert {"max_gap", "mean_gap", "short"} == set(cfg["correct"])
+    # A reference that follows routes is a routed family's, and its
+    # configurations state the router's three limits beside the dense three.
+    reference = importlib.import_module("perf.reference." + cfg["reference"])
+    routed = {"route_rows_bad", "followed_share", "route_trail"}
+    assert set(cfg["correct"]) == {"max_gap", "mean_gap", "short"} | (
+        routed if perf_check.takes_routes(reference) else set())
+    assert cfg["correct"]["short"] == 0 and cfg["correct"].get("route_rows_bad", 0) == 0
+    assert 0 < cfg["correct"].get("followed_share", 0.5) < 1
     assert cfg["mesh"]["tp"] == cfg["chips"]
 
 
@@ -302,6 +311,174 @@ def test_a_later_pr_adds_a_config_mix_cell_and_metric_as_files_only(tmp_path):
     assert [m["name"] for m in run.metrics_for(b, "per_layer", cell)
             if m["name"].startswith("latent")] == ["latent_resident_mib"]
     assert run.load_cell("tiny-mistral.closed", True)[1]["config"] == "tiny-mistral"
+
+    for p, content in before.items():
+        assert (root / "perf" / p).read_bytes() == content
+
+
+STUB_ROUTED_REFERENCE = '''"""A stub routed architecture, numpy only: one leading dense layer, then
+layers with 32 experts of which a token takes 4.
+
+Routed layers are layers 1.. (routed layer j is layer j + 1). The selection
+score is `sigmoid(router logit) + expert_bias`: the 4 largest are taken; the
+weights are the sigmoids (without the bias) of the taken set, renormalised."""
+import numpy as np
+
+ROUTER_LEAVES = ("router",)
+X, K = 32, 4
+
+
+def _weights(hf, key):
+    rng = np.random.default_rng(int(key))
+    E, V, L = hf["hidden_size"], hf["vocab_size"], hf["num_hidden_layers"]
+    n = lambda *shape: rng.standard_normal(shape).astype(np.float32)  # noqa: E731
+    return {"embed": n(V, E), "head": n(E, V) / np.sqrt(E), "dense": n(E, E) / np.sqrt(E),
+            "router": n(L - 1, E, X) * 2.0 / np.sqrt(E), "bias": n(L - 1, X) * 0.05,
+            "experts": n(L - 1, X, E, E) / np.sqrt(E)}
+
+
+def _low(x, quant):
+    """A lower precision: fewer mantissa bits in every activation."""
+    if quant is None:
+        return x
+    bits = {"bf16": 8, "fp8": 3, "int8": 5}[quant]
+    m, e = np.frexp(x)
+    return np.ldexp(np.round(m * 2**bits) / 2**bits, e).astype(np.float32)
+
+
+def forward(hf, key, seqs, quant=None, routes=None, pad_to=0, rows_pad=0):
+    w = _weights(hf, key)
+    logits, own, trail = [], [], []
+    for i, (tokens, rows) in enumerate(seqs):
+        x = _low(w["embed"][np.asarray(tokens)], quant)
+        # No attention: a running mean makes every position see its past.
+        x = np.cumsum(x, 0) / np.arange(1, len(tokens) + 1)[:, None]
+        x = x + _low(np.tanh(x @ w["dense"]), quant)
+        given = None if routes is None else routes[i]
+        o_seq, t_seq = [], []
+        for j in range(hf["num_hidden_layers"] - 1):
+            h = _low(x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6), quant)
+            score = 1.0 / (1.0 + np.exp(-_low(h @ w["router"][j], quant)))
+            select = score + w["bias"][j]
+            mine = np.argsort(-select, axis=-1, kind="stable")[:, :K]
+            sets = mine if given is None else np.asarray(given)[:, j]
+            taken = np.take_along_axis(select, sets, -1)
+            t_seq.append(np.take_along_axis(select, mine, -1)[:, -1] - taken.min(-1))
+            o_seq.append(mine)
+            wt = np.take_along_axis(score, sets, -1)
+            wt = wt / wt.sum(-1, keepdims=True)
+            out = np.einsum("te,tkef->tkf", h, w["experts"][j][sets])
+            x = x + _low(np.einsum("tk,tkf->tf", wt, np.tanh(out)), quant)
+        logits.append(_low(x[np.asarray(rows)], quant) @ w["head"])
+        own.append(np.stack(o_seq, 1))
+        trail.append(np.stack(t_seq, 1))
+    return logits if routes is None else (logits, own, trail)
+'''
+
+
+def serve_with(reference, cfg, key, seed, quant, n_requests=8, roll=0):
+    """Records as the load generator would keep them, from a stand-in
+    program: the stub in `quant`, decoding greedily and handing over the
+    sets it took on the wire's terms (uint8 ids, the prompt's block with the
+    first token, then a row a token). `roll` plants the router fault."""
+    import numpy as np
+
+    records = []
+    for index in range(n_requests):
+        plen, n_out = 6 + index, 5 + index % 3
+        seq = traffic.prompt_tokens(seed, index, plen, cfg["vocab_size"])
+        served, blocks = [], []
+        for step in range(n_out):
+            (lg,), (own,), _ = reference.forward(
+                cfg, key, [(seq + served, [plen - 1 + step])], quant=quant,
+                routes=[None])
+            took = ((own + roll) % 32).astype(np.uint8)
+            start = 0 if step == 0 else plen + step - 1
+            blocks.append({
+                "start": start, "rows": len(took) - start, "shape": [3, 4],
+                "dtype": "uint8",
+                "data": base64.b64encode(took[start:].tobytes()).decode()})
+            served.append(int(lg[0].argmax()))
+        records.append({"index": index, "ok": True, "prompt_len": plen,
+                        "max_tokens": n_out, "token_ids": served,
+                        "routes": blocks})
+    return records
+
+
+def test_a_later_pr_adds_a_routed_architecture_as_files_only(tmp_path):
+    """What the next `model_config` PR does for an architecture with a
+    router: a reference with a `routes` parameter (k = 4 of 32 experts, a
+    leading dense layer, a sigmoid-plus-bias selection score), a
+    configuration with the six limits, a cell and the router's per-layer
+    metrics, in a copy of the tree, with the harness byte for byte what it
+    is. The copy's own check then follows the routes a stand-in program
+    hands over, passes it, and fails its router fault and its control."""
+    root = tmp_path / "repo"
+    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: (root / "perf" / p).read_bytes() for p in HARNESS}
+    (root / "perf" / "reference" / "stub_routed.py").write_text(STUB_ROUTED_REFERENCE)
+    cfg = {"architectures": ["StubRoutedForCausalLM"], "hidden_size": 32,
+           "num_hidden_layers": 4, "vocab_size": 96, "reference": "stub_routed",
+           "reduced": {}, "chips": 1, "mesh": {"tp": 1},
+           "engine": {"num_slots": 4, "max_seq_len": 64},
+           "correct": {"max_gap": 0.02, "mean_gap": 0.0005, "short": 0,
+                       "route_rows_bad": 0, "followed_share": 0.08,
+                       "route_trail": 0.01}}
+    (root / "perf" / "configs" / "stub-routed.json").write_text(json.dumps(cfg))
+    b = load()
+    b["configs"].append({**b["configs"][0], "name": "stub-routed", "reduced": [],
+                         "file": "perf/configs/stub-routed.json"})
+    b["workloads"].append({"name": "stub-routed.decode-sat", "chips": 1,
+                           "config": "stub-routed", "traffic": "decode-sat",
+                           "why": "4 of 32 experts under the saturated closed loop"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if m["name"] in ("out_tok_s", "routes_ms_per_step", "moe_imbalance",
+                         "route_followed_share"):
+            m["workloads"].append("stub-routed.decode-sat")
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    assert check(str(root)) == []
+
+    chk = load_file(root / "perf" / "check.py", "perf_check_copy")
+    reference = load_file(root / "perf" / "reference" / "stub_routed.py", "stub_routed")
+    assert chk.takes_routes(reference)
+    moe = {"experts": 32, "k": 4, "routed_layers": 3, "routes": True}
+    mix = {"prompt_tokens": {"dist": "uniform", "low": 6, "high": 13},
+           "output_tokens": {"dist": "uniform", "low": 5, "high": 7}}
+    seed, key, logs = 78, 1234, []
+
+    def verdict(records, **kw):
+        return chk.served_against_reference(
+            reference, cfg, key, mix, records, seed, cfg["vocab_size"],
+            log=logs.append, moe=moe, **kw)
+
+    sound = verdict(serve_with(reference, cfg, key, seed, "bf16"), controls=["fp8"])
+    assert set(sound["compared"]) == set(cfg["correct"])
+    assert sound["correct"] is True, sound["compared"]
+    assert sound["route_rows_bad"] == 0
+    assert 0 < sound["followed_share"] <= cfg["correct"]["followed_share"]
+    # The control took its own sets in float8 and the reference followed them.
+    assert sound["control"]["fp8"]["followed_share"] > cfg["correct"]["followed_share"]
+    assert any(l.startswith("control fp8 lands over: ") and "NO LIMIT" not in l
+               for l in logs)
+    # Not followed, the same sound records read a flip's gap and not rounding:
+    # this is what a routed family was compared on before.
+    blind = chk.served_against_reference(
+        reference, cfg, key, mix, serve_with(reference, cfg, key, seed, "bf16"),
+        seed, cfg["vocab_size"], log=logs.append, moe=None)
+    assert blind["mean_gap"] > sound["mean_gap"] and blind["max_gap"] > cfg["correct"]["max_gap"]
+    assert blind["compared"]["followed_share"] == [None, cfg["correct"]["followed_share"]]
+    assert blind["correct"] is False
+    # The router fault: other experts taken and handed over.
+    broken = verdict(serve_with(reference, cfg, key, seed, "bf16", roll=1))
+    assert broken["correct"] is False and broken["route_rows_bad"] == 0
+    assert broken["followed_share"] > 0.9
+    assert broken["route_trail"] > cfg["correct"]["route_trail"]
+    # A request that lost a row is not followed, and is counted.
+    records = serve_with(reference, cfg, key, seed, "bf16")
+    del records[2]["routes"][-1]
+    lost = verdict(records)
+    assert lost["route_rows_bad"] == 1 and lost["correct"] is False
 
     for p, content in before.items():
         assert (root / "perf" / p).read_bytes() == content

@@ -55,7 +55,9 @@ def test_metric_is_a_data_file_of_an_existing_reader_kind(name, suffix):
         entries = [m for m in json.load(f)["per_layer"] if m["name"] == name + suffix]
     cell, moves = CELLS[suffix]
     assert len(entries) == 1
-    assert entries[0]["workloads"] == [cell] and entries[0]["moves"] == moves
+    # Its own cell first; a later cell that reports the same end-to-end
+    # metric (the four-chip Mixtral cell) may follow.
+    assert entries[0]["workloads"][0] == cell and entries[0]["moves"] == moves
     assert entries[0]["source"] == "program_counter"
     assert entries[0]["unit"] == ("ms" if "_ms" in name else "count")
     assert entries[0]["better"] == (
