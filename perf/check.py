@@ -48,6 +48,44 @@ more hold the router:
 The control of a routed reference takes its own sets in the lower precision;
 the float32 reference follows those, and the same six numbers are read.
 
+**A generator of its own.** The comparison above assumes one token a
+forward, left to right: served token i is held against row `P-1+i` of one
+forward over `prompt + served[:-1]`. A family that generates otherwise (a
+block of positions denoised over several forwards and committed by
+confidence; tokens drafted and verified) chooses token i at a forward and a
+row that depend on decisions taken inside the served precision's rounding,
+as a router's k-th score is. So its reference FOLLOWS what the program
+handed over, and says itself what to ask for and how to replay it. Such a
+module has (perf/README.md, "A generator of its own"):
+
+- `HANDOVER`: a tuple of request flags. The timed run sets each `true` in
+  every request and keeps each chunk's blocks of that name, in arrival order,
+  undecoded, under `handover[<flag>]` on the record;
+- `DECISIONS`: the kinds of decision it follows (`routes`, `order`, ...);
+- `replay(cfg, key, requests, *, state, quant=None, follow=None, pad_to,
+  rows_pad)`: a request is `{"prompt", "served", "handover"}`, `state` is the
+  server's `/v1/state`. A request comes back as None where its hand-over
+  breaks the family's own rule (missing, surplus, mis-shaped, out of order:
+  never cut or padded), else as `{"logits", "own", <kind>: {"differs",
+  "trail"}, ...}`: `logits` float32 `[N, vocab]`, row i the reference's
+  logits at the forward and row that, by the hand-over, chose served token i,
+  on the state the hand-over describes, every handed-over decision followed;
+  `own` what it would have decided itself at each decision, in the form
+  `follow` takes; `differs` a boolean a decision (the given one is not its
+  own) and `trail` how far the given one trails its own on the score the
+  family selects by (0 where equal). `follow=None` follows the request's
+  hand-over, `follow="own"` decides for itself on the served state, a list
+  (one entry a request) follows those.
+
+What stays here: the sample, `short`, the three gap readings from the
+returned rows against the served tokens, `decisions_bad` (sampled requests
+that came back None; limit 0), and a kind: `<kind>_followed_share` (the mean
+of `differs`), `<kind>_trail` (its `ROUTE_TRAIL_QUANTILE` quantile) and
+`<kind>_trail_max` (printed). The control is `replay(quant=q, follow="own")`
+in the program's place and then the float32 `replay(follow=<its own>)`.
+`compared_names` says which limits a configuration of each kind of family has
+to state.
+
 `compared` in what is returned holds each number that decided `correct`
 beside its limit (a number that could not be read is `None`, and over).
 """
@@ -134,6 +172,27 @@ def takes_routes(reference) -> bool:
     return "routes" in inspect.signature(reference.forward).parameters
 
 
+def replays(reference) -> bool:
+    """A reference with `replay` is a family's that generates in its own
+    way: it is asked to replay what the program handed over."""
+    return hasattr(reference, "replay")
+
+
+DENSE = ("max_gap", "mean_gap", "short")
+
+
+def compared_names(reference) -> set[str]:
+    """The limits a configuration has to state under `correct`, learned
+    from what its reference module has."""
+    if replays(reference):
+        return {*DENSE, "decisions_bad", *(
+            f"{kind}_{what}" for kind in reference.DECISIONS
+            for what in ("followed_share", "trail"))}
+    if takes_routes(reference):
+        return {*DENSE, "route_rows_bad", "followed_share", "route_trail"}
+    return set(DENSE)
+
+
 def assemble_routes(blocks, positions: int, moe: dict):
     """The rows `[positions, routed layers, k]` of one request from its
     `kubeai_routes` blocks in arrival order, or None where they break the
@@ -197,62 +256,112 @@ def _gaps(np, logits, tokens):
     return best - got, logits.argmax(axis=-1)
 
 
+def _followed(np, differs, trails, names) -> tuple[dict, int]:
+    """`names` = (share, trail, largest trail): the share of decisions whose
+    given one is not the reference's own, the trail's quantile and its
+    largest; and the number of decisions they were read over."""
+    differs = np.concatenate([np.ravel(d) for d in differs] or [np.zeros(0)])
+    trails = np.concatenate([np.ravel(t) for t in trails] or [np.zeros(0)])
+    if not differs.size or differs.size != trails.size:
+        return dict.fromkeys(names), 0
+    return dict(zip(names, (
+        float(differs.mean()),
+        float(np.quantile(trails, ROUTE_TRAIL_QUANTILE)),
+        float(trails.max())))), int(differs.size)
+
+
 def _route_readings(np, given, own, trail) -> tuple[dict, int]:
-    """Over the followed sequences: the share of decisions whose given set
-    is not the reference's own, the trail's quantile and its largest; and
-    the number of decisions they were read over."""
+    """Over the followed sequences of a routed reference."""
     pairs = [(g, o, t) for g, o, t in zip(given, own, trail) if g is not None]
-    if not pairs:
-        return {"followed_share": None, "route_trail": None,
-                "route_trail_max": None}, 0
-    differs = np.concatenate([
-        (np.sort(g, -1) != np.sort(o, -1)).any(-1).ravel() for g, o, _ in pairs])
-    trails = np.concatenate([t.ravel() for _, _, t in pairs])
-    return {"followed_share": float(differs.mean()),
-            "route_trail": float(np.quantile(trails, ROUTE_TRAIL_QUANTILE)),
-            "route_trail_max": float(trails.max())}, int(differs.size)
+    return _followed(
+        np, [(np.sort(g, -1) != np.sort(o, -1)).any(-1) for g, o, _ in pairs],
+        [t for _, _, t in pairs],
+        ("followed_share", "route_trail", "route_trail_max"))
+
+
+def _decision_readings(np, kinds, results) -> tuple[dict, int]:
+    """Over the replayed requests of a generator's reference, a kind."""
+    out, decisions = {}, 0
+    for kind in kinds:
+        got = [r[kind] for r in results if r is not None]
+        of_kind, n = _followed(
+            np, [g["differs"] for g in got], [g["trail"] for g in got],
+            (f"{kind}_followed_share", f"{kind}_trail", f"{kind}_trail_max"))
+        out.update(of_kind)
+        decisions += n
+    return out, decisions
+
+
+def _follower(np, reference, cfg, key, seqs, given, requests, state, padding):
+    """How a family's reference is run. `run(quant=None, follow=None)` gives
+    the logits a request (None for one that could not be replayed), what the
+    reference decided itself (for a later `follow`; None for a family that
+    follows nothing), the readings of the decisions it followed, and their
+    number. `follow=None` follows what the program handed over, `"own"`
+    nothing, a list (one entry a request) those."""
+    if replays(reference):
+        def run(quant=None, follow=None):
+            results = reference.replay(cfg, key, requests, state=state,
+                                       quant=quant, follow=follow, **padding)
+            return ([None if r is None else np.asarray(r["logits"]) for r in results],
+                    [None if r is None else r["own"] for r in results],
+                    *_decision_readings(np, reference.DECISIONS, results))
+    elif given is not None:
+        def run(quant=None, follow=None):
+            sets = (given if follow is None else
+                    [None] * len(seqs) if follow == "own" else follow)
+            logits, own, trail = reference.forward(
+                cfg, key, seqs, quant=quant, routes=sets, **padding)
+            return ([np.asarray(x) for x in logits], own,
+                    *_route_readings(np, sets, own, trail))
+    else:
+        def run(quant=None, follow=None):
+            logits = reference.forward(cfg, key, seqs, quant=quant, **padding)
+            return [np.asarray(x) for x in logits], None, {}, 0
+    return run
 
 
 def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
-                             controls=(), log=print, moe=None) -> dict:
-    """`moe` is `/v1/state`'s block of an engine that hands its routes over
-    (the timed run then asked for them); None for any other engine."""
+                             controls=(), log=print, moe=None, state=None) -> dict:
+    """`vocab` is what the prompts were drawn from. `moe` is `/v1/state`'s
+    block of an engine that hands its routes over (the timed run then asked
+    for them), None for any other engine; `state` is `/v1/state` whole, for a
+    reference that replays."""
     import numpy as np
 
     limits = cfg.get("correct", {})
-    routed = bool(moe) and takes_routes(reference)
+    replayed = replays(reference)
+    routed = not replayed and bool(moe) and takes_routes(reference)
     picked = sample(records, seed)
-    gaps, flips, short, rows_bad = [], 0, 0, 0
-    seqs, served_of, given = [], [], []
+    gaps, flips, short = [], 0, 0
+    seqs, served_of, requests = [], [], []
+    given = [] if routed else None
     for r in picked:
         served = [int(t) for t in r["token_ids"]]
         short += int(len(served) != r["max_tokens"])
         plen = r["prompt_len"]
-        seq = traffic.prompt_tokens(seed, r["index"], plen, vocab) + served[:-1]
-        seqs.append((seq, list(range(plen - 1, plen - 1 + len(served)))))
+        prompt = traffic.prompt_tokens(seed, r["index"], plen, vocab)
+        seqs.append((prompt + served[:-1],
+                     list(range(plen - 1, plen - 1 + len(served)))))
         served_of.append(np.asarray(served))
+        requests.append({"prompt": prompt, "served": served,
+                         "handover": r.get("handover") or {}})
         if routed:
-            given.append(assemble_routes(r.get("routes"), len(seq), moe))
-            rows_bad += int(given[-1] is None)
+            given.append(assemble_routes(r.get("routes"), len(seqs[-1][0]), moe))
     # One shape for the whole cell: the mix's longest request, rounded up.
     _, longest_prompt = traffic.prompt_length_range(mix)
     longest_output = mix["output_tokens"].get(
         "high", mix["output_tokens"].get("value"))
     padding = {"pad_to": -(-(longest_prompt + longest_output) // 256) * 256,
                "rows_pad": -(-longest_output // 128) * 128}
+    run = _follower(np, reference, cfg, key, seqs, given, requests, state, padding)
 
-    def forward(quant=None, routes=None):
-        """(logits, own sets, trail); the last two None for a dense family."""
-        if not routed:
-            out = reference.forward(cfg, key, seqs, quant=quant, **padding), None, None
-        else:
-            out = reference.forward(cfg, key, seqs, quant=quant, routes=routes,
-                                    **padding)
-        return [np.asarray(x) for x in out[0]], out[1], out[2]
-
-    logits, own, trail = forward(routes=given)
+    logits, _, of_decisions, decisions = run()
     firsts = []
     for lg, served in zip(logits, served_of):
+        if lg is None:
+            firsts.append(None)
+            continue
         g, first = _gaps(np, lg, served)
         gaps.extend(g.tolist())
         flips += int((first != served).sum())
@@ -264,26 +373,28 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
         "flip_share": flips / n if n else None,
         "short": short,
     }
-    if routed:
-        of_routes, decisions = _route_readings(np, given, own, trail)
-        log(f"correct: routes followed over {decisions} decisions of "
-            f"{len(picked) - rows_bad} requests")
-        readings.update(route_rows_bad=rows_bad, **of_routes)
+    if routed or replayed:
+        # Requests whose hand-over broke the rule: a routed one is not
+        # followed, a replayed one is left out of every other reading.
+        bad = sum(g is None for g in (given if routed else logits))
+        log(f"correct: {'routes' if routed else ', '.join(reference.DECISIONS)} "
+            f"followed over {decisions} decisions of {len(picked) - bad} requests")
+        readings.update({"route_rows_bad" if routed else "decisions_bad": bad},
+                        **of_decisions)
     control = {}
     for q in controls if n else ():
-        # The lower precision in the program's place. A routed one takes
-        # its own sets, which the float32 reference then follows.
-        lower, sets_q, _ = forward(quant=q, routes=[None] * len(seqs))
-        full, own_q, trail_q = (
-            forward(routes=sets_q) if routed else (logits, None, None))
+        # The lower precision in the program's place. One that follows
+        # decisions takes its own, which the float32 reference then follows.
+        lower, took, _, _ = run(quant=q, follow="own")
+        full, _, of_q, _ = (logits, None, {}, 0) if took is None else run(follow=took)
         gq, fq = [], 0
         for lg, lq, first in zip(full, lower, firsts):
+            if first is None:
+                continue
             gq.extend(_gaps(np, lg, lq.argmax(axis=-1))[0].tolist())
             fq += int((lq.argmax(axis=-1) != first).sum())
         control[q] = {"max_gap": max(gq), "mean_gap": sum(gq) / n,
-                      "flip_share": fq / n}
-        if routed:
-            control[q].update(_route_readings(np, sets_q, own_q, trail_q)[0])
+                      "flip_share": fq / n, **of_q}
     ok = bool(picked)
     compared = {}
     for name, value in readings.items():
@@ -304,10 +415,17 @@ def served_against_reference(reference, cfg, key, mix, records, seed, vocab,
     log(f"correct: sample of {len(picked)} requests, {n} served tokens")
     for q, c in control.items():
         log(f"control {q}: " + "  ".join(f"{k} = {_fmt(v)}" for k, v in c.items()))
-        over = [k for k, v in c.items() if k in limits and v > limits[k]]
+        over = [k for k, v in c.items()
+                if k in limits and v is not None and v > limits[k]]
         log(f"control {q} lands over: {', '.join(over) or 'NO LIMIT'}")
+    # What was read of the decisions followed, in per cent, for a per-layer
+    # metric of kind `observed`: a router's is `route_followed_share`, a
+    # generator's kinds keep their names.
+    observed = {("route_" + name if routed else name): 100.0 * value
+                for name, value in of_decisions.items()
+                if name.endswith("followed_share") and value is not None}
     return {"correct": ok, **readings, "tokens": n, "compared": compared,
-            "control": control}
+            "control": control, "observed": observed}
 
 
 def _fmt(value) -> str:

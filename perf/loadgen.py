@@ -17,8 +17,13 @@ whose routes it hands over), every request carries `"kubeai_routes": true`
 and its record keeps, under `routes`, the blocks of every chunk's top-level
 `kubeai_routes` in arrival order, as they came: `{start, rows, shape, dtype,
 data}` with `data` in base64 (docs/concepts/expert-routes.md; perf/check.py
-holds them to the row rule). Without it the request body and the records are
-what they always were.
+holds them to the row rule). Where the spec names `handover` flags (the
+configuration's reference module has `HANDOVER`: a generator of its own, see
+perf/check.py), every request carries each flag as `true` and its record
+keeps, under `handover[<flag>]`, the blocks of every chunk's top-level key of
+that name, in arrival order, as they came: nothing is decoded here, the
+family's reference says what they mean. With neither, the request body and
+the records are what they always were.
 """
 
 from __future__ import annotations
@@ -54,7 +59,7 @@ class Clock:
             sleep(min(left, 0.5))
 
 
-def request_body(model, req, vocab, seed, routes=False) -> str:
+def request_body(model, req, vocab, seed, routes=False, handover=()) -> str:
     body = {
         "model": model,
         "prompt": text_of(
@@ -64,11 +69,13 @@ def request_body(model, req, vocab, seed, routes=False) -> str:
     }
     if routes:
         body["kubeai_routes"] = True
+    for flag in handover:
+        body[flag] = True
     return json.dumps(body)
 
 
 def one_request(host, port, model, req, vocab, seed, clock, timeout,
-                stop_at=None, routes=False) -> dict:
+                stop_at=None, routes=False, handover=()) -> dict:
     """Send one streamed completion and record every token-bearing event.
     Past `stop_at` the stream is cut (the server cancels a request whose
     client went away) and the record says so."""
@@ -77,7 +84,9 @@ def one_request(host, port, model, req, vocab, seed, clock, timeout,
            "ok": False, "events": [], "token_ids": []}
     if routes:
         rec["routes"] = []
-    body = request_body(model, req, vocab, seed, routes)
+    if handover:
+        rec["handover"] = {flag: [] for flag in handover}
+    body = request_body(model, req, vocab, seed, routes, handover)
     conn = http.client.HTTPConnection(host, port, timeout=timeout)
     try:
         rec["sent"] = clock.now()
@@ -112,6 +121,8 @@ def one_request(host, port, model, req, vocab, seed, clock, timeout,
                 rec["token_ids"].extend(ids)
             if routes:
                 rec["routes"].extend(chunk.get("kubeai_routes") or ())
+            for flag in handover:
+                rec["handover"][flag].extend(chunk.get(flag) or ())
         if rec["ok"]:
             rec["end"] = clock.now()
     except (OSError, http.client.HTTPException, ValueError) as e:
@@ -195,7 +206,8 @@ def main(argv) -> int:
     def send(req, stop_at=None):
         return one_request(spec["host"], spec["port"], spec["model"], req,
                            spec["vocab"], spec["seed"], clock, timeout, stop_at,
-                           routes=bool(spec.get("routes")))
+                           routes=bool(spec.get("routes")),
+                           handover=tuple(spec.get("handover") or ()))
 
     run = run_open if spec["mix"]["loop"] == "open" else run_closed
     records = run(spec, clock, send)

@@ -230,7 +230,14 @@ class Bench:
             lambda k: self.reference.served_params(cfg, k),
             out_shardings=psh.param_shardings(
                 family.param_specs(model_cfg), mesh))
+        # The tokenizer and the logits keep the whole vocabulary; prompts
+        # (the warm-up's, the load generator's, the check's) keep off the
+        # ids a configuration reserves.
         self.vocab = cfg["vocab_size"]
+        self.prompt_vocab = traffic.prompt_vocab(cfg)
+        if self.prompt_vocab != self.vocab:
+            log(f"prompts drawn from the first {self.prompt_vocab} of "
+                f"{self.vocab} ids")
         self.key = seed_key(jax, args.seed)
         t = time.time()
         params = jax.block_until_ready(self._make_params(self.key))
@@ -245,7 +252,7 @@ class Bench:
         self.engine_cfg = {f: getattr(self.engine.cfg, f) for f in (
             "num_slots", "max_seq_len", "page_size", "decode_chunk",
             "max_admit_batch")}
-        n_warm = warm_up(self.engine, mix, self.vocab)
+        n_warm = warm_up(self.engine, mix, self.prompt_vocab)
         log(f"engine built and {len(warm_shapes(self.engine, mix))} prefill "
             f"shapes and the decode chunk warmed with {n_warm} requests in "
             f"{time.time() - t:.1f} s; {self.compiles.count} programs "
@@ -258,12 +265,18 @@ class Bench:
         self.server.start()
         # A router whose routes this engine hands over: the timed run asks
         # for them and `correct` follows them. Any other engine has None.
+        # A reference with `HANDOVER` (a generator of its own) names the
+        # flags itself and is handed `/v1/state` whole.
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{self.server.port}/v1/state", timeout=30) as resp:
-            moe = json.load(resp).get("moe")
-        self.moe = moe if moe and moe.get("routes") else None
+            self.state = json.load(resp)
+        self.handover = list(getattr(self.reference, "HANDOVER", ()))
+        moe = self.state.get("moe")
+        self.moe = moe if moe and moe.get("routes") and not self.handover else None
         if self.moe:
             log(f"routes asked of every request: {self.moe}")
+        if self.handover:
+            log(f"handed over by every request: {self.handover}")
 
     def reseed(self, seed: int) -> None:
         """Study mode: new weights from another seed, in place, and the
@@ -317,9 +330,10 @@ class Bench:
         spec = {
             "mix": mix, "seed": seed, "seconds": seconds, "t0_wall": t0_wall,
             "host": "127.0.0.1", "port": port, "model": self.cell["config"],
-            "vocab": self.vocab,
+            "vocab": self.prompt_vocab,
             "clients": traffic.num_clients(mix, self.engine_cfg),
             **({"routes": True} if self.moe else {}),
+            **({"handover": self.handover} if self.handover else {}),
         }
         out_dir = os.path.join(ROOT, "perf_out")
         os.makedirs(out_dir, exist_ok=True)
@@ -406,7 +420,8 @@ class Bench:
         t = time.time()
         v = check.served_against_reference(
             self.reference, self.cfg, self.key, self.mix, obs["records"], seed,
-            self.vocab, controls=controls, log=log, moe=self.moe)
+            self.prompt_vocab, controls=controls, log=log, moe=self.moe,
+            state=self.state)
         log(f"reference check took {time.time() - t:.1f} s")
         return v
 
@@ -489,10 +504,10 @@ def main(argv=None) -> int:
     check.free_engine(b.engine)
     v = b.verdict(obs, args.seed, [c for c in args.control.split(",") if c])
     correct = bool(v["correct"] and failed == 0 and attempted > 0)
-    # What the check read of the router is an observation like any other: a
-    # per-layer metric of kind `observed` shows a drift before it fails.
-    if v.get("followed_share") is not None:
-        obs["route_followed_share"] = 100.0 * v["followed_share"]
+    # What the check read of the decisions it followed (a router's, a
+    # generator's) is an observation like any other: a per-layer metric of
+    # kind `observed` shows a drift before it fails.
+    obs.update(v["observed"])
     metrics = b.per_layer(obs) if args.trace else b.end_to_end(obs)
 
     device = {"platform": b.platform, "kind": b.kind, "count": b.chips,

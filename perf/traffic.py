@@ -65,6 +65,20 @@ def prompt_tokens(seed: int, index: int, length: int, vocab: int) -> list[int]:
     return [r.randrange(vocab) for _ in range(length)]
 
 
+def prompt_vocab(cfg: dict) -> int:
+    """The ids a prompt is drawn from, `[0, this)`. A family's reserved ids
+    (a mask, end-of-turn marks) lie at the top of its table, and in a prompt
+    one of them is an instruction and not a token; a configuration whose
+    family has such ids states `prompt_vocab_size`, the first of them. The
+    tokenizer and the logits keep the whole `vocab_size`."""
+    vocab = int(cfg["vocab_size"])
+    n = int(cfg.get("prompt_vocab_size", vocab))
+    if not 0 < n <= vocab:
+        raise ValueError(
+            f"prompt_vocab_size {n} is not in 1..vocab_size ({vocab})")
+    return n
+
+
 def _sized(mix: dict, n: int, seed: int, stream: int) -> list[tuple[int, int]]:
     prompts = stratified(mix["prompt_tokens"], n)
     outputs = stratified(mix["output_tokens"], n)

@@ -4,6 +4,7 @@ metric, and a whole architecture (reference, reader kind, cost function, a
 cache of its own shape, a rehearsal cell), with new files and entries only."""
 
 import base64
+import glob
 import importlib
 import importlib.util
 import json
@@ -144,15 +145,69 @@ def test_configuration_files_state_their_engine_and_limits(name):
         cfg = json.load(f)
     assert cfg["chips"] in (1, 4)
     assert {"num_slots", "max_seq_len"} <= set(cfg["engine"])
-    # A reference that follows routes is a routed family's, and its
-    # configurations state the router's three limits beside the dense three.
+    # What a configuration has to state is learned from its reference module:
+    # the dense three; the router's three beside them where `forward` follows
+    # routes; where it has `replay` (a generator of its own), `decisions_bad`
+    # and a share and a trail for each kind in its `DECISIONS`.
     reference = importlib.import_module("perf.reference." + cfg["reference"])
-    routed = {"route_rows_bad", "followed_share", "route_trail"}
-    assert set(cfg["correct"]) == {"max_gap", "mean_gap", "short"} | (
-        routed if perf_check.takes_routes(reference) else set())
-    assert cfg["correct"]["short"] == 0 and cfg["correct"].get("route_rows_bad", 0) == 0
-    assert 0 < cfg["correct"].get("followed_share", 0.5) < 1
+    assert set(cfg["correct"]) == perf_check.compared_names(reference)
+    for name, limit in cfg["correct"].items():
+        if name in ("short", "route_rows_bad", "decisions_bad"):
+            assert limit == 0, name
+        elif name.endswith("followed_share"):
+            assert 0 < limit < 1, name
+        else:
+            assert limit > 0, name
     assert cfg["mesh"]["tp"] == cfg["chips"]
+    traffic.prompt_vocab(cfg)  # raises unless it is in 1..vocab_size
+
+
+def named_configuration_files(root=ROOT) -> set[str]:
+    """The files that an entry of BENCHMARK.json or of a rehearsal file names."""
+    named = {c["file"] for c in load(root)["configs"]}
+    perf = os.path.join(root, "perf")
+    for path in [os.path.join(perf, "rehearse.json"), *sorted(
+            glob.glob(os.path.join(perf, "rehearse.d", "*.json")))]:
+        with open(path) as f:
+            named |= {c["file"] for c in json.load(f)["configs"]}
+    return named
+
+
+@pytest.mark.parametrize("name", sorted(
+    os.listdir(os.path.join(ROOT, "perf", "configs"))))
+def test_every_configuration_file_is_named_by_an_entry(name):
+    """The guard against `config_not_added`: a `model_config` PR that brings
+    the file and not the entry under `configs` (and a workload that runs it)
+    sees red here before the driver refuses it. A rehearsal preset is named
+    by its rehearsal file."""
+    assert f"perf/configs/{name}" in named_configuration_files(), (
+        f"perf/configs/{name} is named by no entry under `configs` of "
+        "BENCHMARK.json and by no rehearsal file: add the entry and a workload "
+        "that runs it (perf/README.md, 'Adding things')")
+
+
+@pytest.mark.parametrize("name", sorted(
+    f for f in os.listdir(os.path.join(ROOT, "perf", "reference"))
+    if f.endswith(".py") and f != "__init__.py"))
+def test_every_reference_module_is_named_by_a_configuration_file(name):
+    families = set()
+    for f in os.listdir(os.path.join(ROOT, "perf", "configs")):
+        with open(os.path.join(ROOT, "perf", "configs", f)) as fh:
+            families.add(json.load(fh)["reference"] + ".py")
+    assert name in families, (
+        f"perf/reference/{name} is the reference of no file under perf/configs/")
+
+
+def test_a_configuration_brought_without_its_entry_is_seen(tmp_path):
+    root = tmp_path / "repo"
+    shutil.copytree(os.path.join(ROOT, "perf"), root / "perf",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
+    before = named_configuration_files(str(root))
+    assert before == {"perf/configs/" + f for f in os.listdir(root / "perf" / "configs")}
+    shutil.copy(root / "perf" / "configs" / "tiny-mistral.json",
+                root / "perf" / "configs" / "brought-alone.json")
+    assert "perf/configs/brought-alone.json" not in named_configuration_files(str(root))
 
 
 PUBLISHED = {  # the models' public config.json, widths and all
@@ -179,7 +234,8 @@ def test_only_depth_is_reduced_from_the_published_config(name):
     assert (cut["from"], cut["to"]) == (32, cfg["num_hidden_layers"])
 
 
-HARNESS = ("run.py", "readers.py", "check.py", "costs.py", "traffic.py", "loadgen.py")
+HARNESS = ("run.py", "readers.py", "check.py", "costs.py", "traffic.py", "loadgen.py",
+           "tokenizer.py")
 
 STUB_REFERENCE = '''"""A stub architecture: a latent cache of 576 numbers a token a layer."""
 from perf.reference.mistral import forward, served_params  # noqa: F401
