@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from kubeai_tpu.ops import dispatch
+from kubeai_tpu.parallel.sharding import kv_heads_axis
 
 NEG_INF = -1e30
 
@@ -635,7 +636,10 @@ def paged_verify_attention(
 # scalar-prefetched layer index (no slicing, no materialization) and attends
 # the NEW token as an explicit extra column merged when a slot finishes (so
 # the pool stays read-only and the scatter defers to ONE batched write after
-# the layer scan).
+# the layer scan). That write indexes the layer where a shard holds fewer
+# than 8 KV heads (`_write_token_rows` says why): as a slice, the layer made
+# the compiler lay a 2-KV-head pool out for the write in a way this kernel
+# cannot read, and copy it whole every decode step.
 #
 # Its time follows the live KV (timed alone on a v5e in PR 28, PERF.md
 # section 6). A grid over (slots, page slots) with one BlockSpec a page cost
@@ -1127,6 +1131,39 @@ def batched_sequence_page_coords(
     return page_ids, jnp.broadcast_to(pos % page_size, page_ids.shape)
 
 
+def _write_token_rows(pool, rows, page_ids, offsets):
+    """pool[l, page_ids[i], offsets[i]] = rows[l, i] for every layer l and
+    every index i of `page_ids` (of any rank), in the one of two forms that
+    keeps the pool in the row-major layout it arrives in and the attention
+    kernel reads (PERF.md section 6, PR 35).
+
+    Where one shard's [KVH, D] rows fill the TPU's (8, 128) tile, the layer
+    is a slice: a window is [NL, KVH, D], few and large. Where they do not
+    (2 KV heads a chip: Mixtral at tp=4) the compiler would put the layers
+    of such a window next to D to fill its tile, carry the pool round the
+    decode chunk in that layout and copy it whole to the row-major one every
+    decode step; there the layer is an INDEX and a window one token's row.
+    That form costs 65-95 ns a window on a v5e, 16 times as many of them,
+    which is why it is not the only one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    kvh = pool.shape[3]
+    axis = None if mesh.empty else kv_heads_axis(mesh.shape, kvh)
+    heads_a_shard = kvh // mesh.shape[axis] if axis else kvh
+    rows = rows.astype(pool.dtype)
+    if heads_a_shard % 8 == 0:
+        return pool.at[:, page_ids, offsets].set(rows)
+    layers = jnp.arange(pool.shape[0]).reshape((-1,) + (1,) * page_ids.ndim)
+    return pool.at[layers, page_ids[None], offsets[None]].set(rows)
+
+
+def _write_quantized_rows(pool, q8, scale, page_ids, offsets):
+    """The same write into an int8 pool and its per-row scales."""
+    return {
+        "q8": _write_token_rows(pool["q8"], q8, page_ids, offsets),
+        "scale": _write_token_rows(pool["scale"], scale, page_ids, offsets),
+    }
+
+
 def batched_scatter_sequence(
     k_pages: jnp.ndarray,  # [NL, P, page, KVH, D]
     v_pages: jnp.ndarray,
@@ -1144,22 +1181,13 @@ def batched_scatter_sequence(
         k8, ks = quantize_kv(k_seq)
         v8, vs = quantize_kv(v_seq)
         return (
-            {
-                "q8": k_pages["q8"].at[:, page_ids, offsets].set(k8),
-                "scale": k_pages["scale"].at[:, page_ids, offsets].set(ks),
-            },
-            {
-                "q8": v_pages["q8"].at[:, page_ids, offsets].set(v8),
-                "scale": v_pages["scale"].at[:, page_ids, offsets].set(vs),
-            },
+            _write_quantized_rows(k_pages, k8, ks, page_ids, offsets),
+            _write_quantized_rows(v_pages, v8, vs, page_ids, offsets),
         )
-    k_pages = k_pages.at[:, page_ids, offsets].set(
-        k_seq.astype(k_pages.dtype)
+    return (
+        _write_token_rows(k_pages, k_seq, page_ids, offsets),
+        _write_token_rows(v_pages, v_seq, page_ids, offsets),
     )
-    v_pages = v_pages.at[:, page_ids, offsets].set(
-        v_seq.astype(v_pages.dtype)
-    )
-    return k_pages, v_pages
 
 
 def sequence_page_coords(
@@ -1204,12 +1232,6 @@ def scatter_sequence_prequantized(
     values and their scales pass through untouched — re-quantizing would
     break the byte-identity a quantized handoff round-trip guarantees."""
     return (
-        {
-            "q8": k_pages["q8"].at[:, page_ids, offsets].set(k8_seq),
-            "scale": k_pages["scale"].at[:, page_ids, offsets].set(ks_seq),
-        },
-        {
-            "q8": v_pages["q8"].at[:, page_ids, offsets].set(v8_seq),
-            "scale": v_pages["scale"].at[:, page_ids, offsets].set(vs_seq),
-        },
+        _write_quantized_rows(k_pages, k8_seq, ks_seq, page_ids, offsets),
+        _write_quantized_rows(v_pages, v8_seq, vs_seq, page_ids, offsets),
     )

@@ -1,5 +1,6 @@
-"""Guard on the COMPILED decode chunk of the benchmark's one-chip cell: the
-KV page pool is read and written where it lies. Ahead-of-time compiles for a
+"""Guard on the COMPILED decode chunk of the benchmark's cells, the one-chip
+one and the four-chip one (one shard of a tp=4 pool holds 2 KV heads): the KV
+page pool is read and written where it lies. Ahead-of-time compiles for a
 described v5e (nothing runs; a compile that passes is not a chip run), through
 the benchmark's own helper `tests/perf/aot.py`, which this file only reads."""
 
@@ -38,26 +39,59 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", True)
 
 
-@pytest.fixture(scope="module")
-def cell():
-    """(configuration, stacked-pool shape, one layer's pool shape) as HLO
-    prints them."""
-    from kubeai_tpu.engine.engine import EngineConfig
+# Per configuration: what the compiler may hold beside the arguments, and the
+# program's peak (GiB a chip). Mixtral's chunk held 1.159 / 13.096 with six
+# pool copies until the page write took the layer as an index (AOT, PR 35).
+# `window`: the page write's `update_window_dims`. Eight KV heads a chip
+# take [NL, KVH, D] windows (the layer a slice), two take a token's
+# [KVH, D] row (the layer an index): ops/paged_attention.py:_write_token_rows.
+CELLS = {
+    "mistral-7b-v5e1": {"temp": 1.0, "peak": 11.5, "what": ("decode",),
+                        "window": "{0,2,3}"},
+    "mixtral-8x7b-v5e4": {"temp": 0.5, "peak": 12.5,
+                          "what": ("decode", "prefill"), "window": "{2,3}"},
+}
 
-    cfg = aot.load_config("mistral-7b-v5e1")
+
+def pool_shapes(cfg: dict) -> tuple[str, str]:
+    """(stacked pool, one layer's pool) of ONE chip, as HLO prints them: the
+    compiled text is a shard's program, and KV heads split over tp."""
+    from kubeai_tpu.engine.engine import EngineConfig
+    from kubeai_tpu.parallel.sharding import kv_heads_axis
+
     ecfg = EngineConfig(**cfg["engine"])
+    kvh = cfg["num_key_value_heads"]
+    axis = kv_heads_axis(cfg["mesh"], kvh)
     layer = (ecfg.effective_num_pages(), ecfg.page_size,
-             cfg["num_key_value_heads"],
+             kvh // cfg["mesh"].get(axis, 1),
              cfg["hidden_size"] // cfg["num_attention_heads"])
     dims = lambda shape: ",".join(str(d) for d in shape)  # noqa: E731
-    return cfg, dims((cfg["num_hidden_layers"],) + layer), dims(layer)
+    return dims((cfg["num_hidden_layers"],) + layer), dims(layer)
 
 
 @pytest.fixture(scope="module")
-def decode(topo, cell):
-    """The cell's decode chunk as the engine builds it with nothing set."""
-    return aot.compile_cell(topo, cell[0], admit=1, bucket=128,
-                            what=("decode",))
+def cell():
+    """(configuration, stacked-pool shape, one layer's pool shape) of the
+    one-chip cell."""
+    cfg = aot.load_config("mistral-7b-v5e1")
+    return (cfg,) + pool_shapes(cfg)
+
+
+@pytest.fixture(scope="module")
+def compiled(topo):
+    """name -> the cell's decode chunk (and, for the four-chip cell, its
+    prefill-admit 8 x 256) as the engine builds them with nothing set;
+    compiled once a module."""
+    done = {}
+
+    def get(name):
+        if name not in done:
+            done[name] = aot.compile_cell(
+                topo, aot.load_config(name), admit=8, bucket=256,
+                what=CELLS[name]["what"])
+        return done[name]
+
+    return get
 
 
 def pool_movers(hlo: str, shapes: tuple[str, ...]) -> list[str]:
@@ -78,16 +112,41 @@ def pool_movers(hlo: str, shapes: tuple[str, ...]) -> list[str]:
     return moved
 
 
-def test_the_chunk_keeps_no_pool_sized_temporary(decode):
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_the_chunk_keeps_no_pool_sized_temporary(compiled, name):
     # Two whole-pool copies were 3.94 GiB of temporaries (AOT, PR 25).
-    assert decode["decode"].temp_size_in_bytes < GIB
-    assert aot.peak_bytes(decode["decode"]) < 11.5 * GIB
+    stats = compiled(name)["decode"]
+    assert stats.temp_size_in_bytes < CELLS[name]["temp"] * GIB
+    assert aot.peak_bytes(stats) < CELLS[name]["peak"] * GIB
 
 
-def test_no_instruction_moves_a_pool(decode, cell):
-    _, stacked, layer = cell
-    assert f"bf16[{stacked}]" in decode["decode_text"]  # the shapes are right
-    assert pool_movers(decode["decode_text"], (stacked, layer)) == []
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_no_instruction_moves_a_pool(compiled, name):
+    stacked, layer = pool_shapes(aot.load_config(name))
+    text = compiled(name)["decode_text"]
+    assert f"bf16[{stacked}]" in text  # the shapes are right
+    assert pool_movers(text, (stacked, layer)) == []
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_the_page_write_takes_the_window_the_shard_needs(compiled, name):
+    """Mistral's write stays the coarse one (a token's row a window costs
+    its cells 0.7% of a decode step and 2.3 ms a chat admission call: PERF.md
+    section 6, PR 35), Mixtral's the fine one that keeps the pool's layout."""
+    stacked, _ = pool_shapes(aot.load_config(name))
+    writes = re.findall(
+        r"= bf16\[" + stacked + r"\]\S* scatter\([^\n]*"
+        r"update_window_dims=(\{[\d,]+\})[^\n]*kv_page_write",
+        compiled(name)["decode_text"])
+    assert writes == [CELLS[name]["window"]] * 2, writes  # K and V
+
+
+def test_the_four_chip_admission_keeps_no_pool_sized_temporary(compiled):
+    """Mixtral's prefill-admit 8 x 256 copied both pools in and out round
+    its page write: 0.566 GiB of temporaries, 0.190 since (AOT, PR 35)."""
+    stats = compiled("mixtral-8x7b-v5e4")["prefill"]
+    assert stats.temp_size_in_bytes < 0.3 * GIB
+    assert aot.peak_bytes(stats) < 12.5 * GIB
 
 
 def test_pool_movers_sees_the_per_layer_layout(topo, cell):
@@ -109,20 +168,20 @@ def kernel_calls(hlo: str) -> list[str]:
                       r'custom_call_target="tpu_custom_call"', hlo)
 
 
-def test_the_kernel_is_named_for_the_trace_metric(decode):
+def test_the_kernel_is_named_for_the_trace_metric(compiled):
     """`perf/layer_metrics/paged_attn_ms*.json` match `^_paged_pallas`."""
-    names = kernel_calls(decode["decode_text"])
+    names = kernel_calls(compiled("mistral-7b-v5e1")["decode_text"])
     assert names, "no tpu_custom_call in the decode chunk"
     assert all(re.match(r"_paged_pallas", n) for n in names), names
 
 
-def test_the_kernel_lowers_at_two_kv_heads_a_chip(topo):
+def test_the_kernel_lowers_at_two_kv_heads_a_chip(compiled):
     """The configuration on file for the four-chip cell: Mixtral at tp=4
     leaves the kernel 2 KV heads (a [page, 2, 128] block, 8 query rows),
     the narrowest tiling the fold of heads into one dot has to lower at.
-    (Its chunk still moves the pool, as PR 27's did: PERF.md section 7.)"""
-    out = aot.compile_cell(topo, aot.load_config("mixtral-8x7b-v5e4"),
-                           admit=1, bucket=128, what=("decode",))
+    The kernel reads the pool as the chunk's parameters hold it: the cases
+    above hold this chunk to no pool-shaped copy, as they hold Mistral's."""
+    out = compiled("mixtral-8x7b-v5e4")
     names = kernel_calls(out["decode_text"])
     assert names and all(re.match(r"_paged_pallas", n) for n in names), names
     assert aot.peak_bytes(out["decode"]) < HBM

@@ -1,6 +1,8 @@
 """Paged decode attention: jnp reference vs dense oracle vs Pallas kernel
 (interpret mode on CPU)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,12 +11,15 @@ import pytest
 from kubeai_tpu.engine.paged_cache import PageAllocator, set_block_table
 from kubeai_tpu.ops.attention import decode_attention
 from kubeai_tpu.ops.paged_attention import (
+    batched_scatter_sequence,
+    batched_sequence_page_coords,
     paged_decode_attention,
     paged_decode_attention_fused,
     ref_paged_decode_attention,
     ref_paged_decode_attention_fused,
     scatter_decode_token,
     scatter_sequence,
+    scatter_sequence_prequantized,
     sequence_page_coords,
     token_page_coords,
 )
@@ -389,6 +394,150 @@ def test_scatter_sequence_matches_paged_layout():
     # Padded tail landed in scratch page 0, not in any allocated page.
     for t in range(ln, S):
         assert int(page_ids[t]) == 0
+
+
+# ---- the page write's values ---------------------------------------------------
+#
+# `batched_scatter_sequence` indexes the layer where a shard holds fewer than
+# 8 KV heads (PR 35: there a [NL, KVH, D] window made the TPU compiler carry
+# the pool in another layout than the kernel reads) and slices it elsewhere.
+# The layer as a slice is the oracle for both.
+
+
+def _layer_sliced_write(pool, rows, page_ids, offsets):
+    return pool.at[:, page_ids, offsets].set(rows.astype(pool.dtype))
+
+
+def _write_case(nl, kvh, kind, seed):
+    """(rng, pool shape, page_ids, offsets): a decode step's [B, 1]
+    coordinates, or an admission's [A, S] with padded tails and a padding row
+    (length 0) on scratch page 0."""
+    rng = np.random.default_rng(seed)
+    page, mp, d = 8, 4, 16
+    rows_n = 5
+    n_pages = 1 + rows_n * mp
+    bt = rng.permutation(n_pages - 1).reshape(rows_n, mp) + 1  # all distinct
+    if kind == "decode":
+        positions = jnp.asarray(rng.integers(0, page * mp, rows_n), jnp.int32)
+        ids, offs = token_page_coords(jnp.asarray(bt, jnp.int32), positions,
+                                      page)
+        ids, offs = ids[:, None], offs[:, None]
+    else:
+        lengths = jnp.asarray([13, 24, 0, 1, 17], jnp.int32)
+        ids, offs = batched_sequence_page_coords(
+            jnp.asarray(bt, jnp.int32), lengths, 24, page)
+        assert int((ids == 0).sum()) == 24 * rows_n - int(lengths.sum())
+    shape = (nl, n_pages, page, kvh, d)
+    return rng, shape, ids, offs
+
+
+@pytest.mark.parametrize("kind", ["decode", "admission"])
+@pytest.mark.parametrize("nl,kvh", [(16, 8), (16, 2), (3, 1)])
+def test_the_page_write_equals_the_layer_sliced_one_bf16(nl, kvh, kind):
+    rng, shape, ids, offs = _write_case(nl, kvh, kind, seed=nl * 10 + kvh)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    seq = (nl,) + ids.shape + shape[3:]
+    # float32 rows, as a prefill in float32 would hand them: the write casts.
+    k_seq = jnp.asarray(rng.standard_normal(seq), jnp.float32)
+    v_seq = jnp.asarray(rng.standard_normal(seq), jnp.float32)
+    kp2, vp2 = jax.jit(batched_scatter_sequence)(kp, vp, k_seq, v_seq, ids,
+                                                 offs)
+    assert kp2.dtype == kp.dtype and kp2.shape == kp.shape
+    for got, pool, rows in ((kp2, kp, k_seq), (vp2, vp, v_seq)):
+        want = _layer_sliced_write(pool, rows, ids, offs)
+        # Every live page bit for bit; scratch page 0 takes the padding, in
+        # whatever order, and nothing reads it.
+        np.testing.assert_array_equal(
+            np.asarray(got[:, 1:]).view(np.uint16),
+            np.asarray(want[:, 1:]).view(np.uint16))
+    assert not np.array_equal(np.asarray(kp2[:, 1:]), np.asarray(kp[:, 1:]))
+
+
+@pytest.mark.parametrize("kvh,tp,indexed", [
+    (8, 1, False),   # Mistral on one chip: the rows fill the (8, 128) tile
+    (8, 4, True),    # Mixtral at tp=4: 2 KV heads a chip
+    (2, 1, True),
+    (32, 4, False),  # 8 a chip
+    (8, 8, True),    # 1 a chip
+])
+def test_the_page_write_takes_the_form_the_shard_needs(kvh, tp, indexed):
+    """What chooses is what the code sees: the pool's KV heads and the mesh
+    it is traced under (`kv_heads_axis`, the rule that places the pool)."""
+    from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    rng, shape, ids, offs = _write_case(3, kvh, "admission", seed=kvh + tp)
+    kp = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    k_seq = jnp.asarray(
+        rng.standard_normal((3,) + ids.shape + shape[3:]), jnp.bfloat16)
+    write = jax.jit(batched_scatter_sequence)
+    mesh = build_mesh(MeshConfig(tp=tp), devices=jax.devices()[:tp])
+    with jax.set_mesh(mesh):
+        text = write.lower(kp, kp, k_seq, k_seq, ids, offs).as_text()
+        got, _ = write(kp, kp, k_seq, k_seq, ids, offs)
+    # A scatter that takes the layer as an index inserts it: three inserted
+    # window dimensions (layer, page, offset) and not two.
+    dims = set(re.findall(r"inserted_window_dims = \[([\d, ]+)\]", text))
+    assert dims == ({"0, 1, 2"} if indexed else {"1, 2"}), dims
+    want = _layer_sliced_write(kp, k_seq, ids, offs)
+    np.testing.assert_array_equal(
+        np.asarray(got[:, 1:]).view(np.uint16),
+        np.asarray(want[:, 1:]).view(np.uint16))
+
+
+def _int8_pool(rng, shape):
+    return {
+        "q8": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+        "scale": jnp.asarray(rng.uniform(0.01, 1.0, shape[:-1]), jnp.float32),
+    }
+
+
+@pytest.mark.parametrize("kind", ["decode", "admission"])
+@pytest.mark.parametrize("nl,kvh", [(16, 8), (16, 2), (3, 1)])
+def test_the_page_write_equals_the_layer_sliced_one_int8(nl, kvh, kind):
+    from kubeai_tpu.ops.kv_quant import quantize_kv
+
+    rng, shape, ids, offs = _write_case(nl, kvh, kind, seed=nl * 10 + kvh + 1)
+    kp, vp = _int8_pool(rng, shape), _int8_pool(rng, shape)
+    seq = (nl,) + ids.shape + shape[3:]
+    k_seq = jnp.asarray(rng.standard_normal(seq), jnp.bfloat16)
+    v_seq = jnp.asarray(rng.standard_normal(seq), jnp.bfloat16)
+    kp2, vp2 = jax.jit(batched_scatter_sequence)(kp, vp, k_seq, v_seq, ids,
+                                                 offs)
+
+    @jax.jit  # quantized as the write quantizes: inside one compiled program
+    def layer_sliced(pool, rows):
+        q8, scale = quantize_kv(rows)
+        return {"q8": _layer_sliced_write(pool["q8"], q8, ids, offs),
+                "scale": _layer_sliced_write(pool["scale"], scale, ids, offs)}
+
+    for got, pool, rows in ((kp2, kp, k_seq), (vp2, vp, v_seq)):
+        want = layer_sliced(pool, rows)
+        for leaf in ("q8", "scale"):
+            assert got[leaf].dtype == pool[leaf].dtype
+            np.testing.assert_array_equal(
+                np.asarray(got[leaf][:, 1:]), np.asarray(want[leaf][:, 1:]))
+            assert not np.array_equal(
+                np.asarray(got[leaf][:, 1:]), np.asarray(pool[leaf][:, 1:]))
+
+
+@pytest.mark.parametrize("nl,kvh", [(16, 8), (16, 2), (3, 1)])
+def test_the_prequantized_write_equals_the_layer_sliced_one(nl, kvh):
+    """A hand-off import: one sequence's int8 rows and scales, verbatim."""
+    rng, shape, ids, offs = _write_case(nl, kvh, "admission", seed=nl + kvh)
+    ids, offs = ids[0], offs[0]  # one sequence of 24, 13 live
+    kp, vp = _int8_pool(rng, shape), _int8_pool(rng, shape)
+    seq = (nl, ids.shape[0]) + shape[3:]
+    new = [jnp.asarray(rng.integers(-127, 128, seq), jnp.int8),
+           jnp.asarray(rng.uniform(0.01, 1.0, seq[:-1]), jnp.float32),
+           jnp.asarray(rng.integers(-127, 128, seq), jnp.int8),
+           jnp.asarray(rng.uniform(0.01, 1.0, seq[:-1]), jnp.float32)]
+    kp2, vp2 = jax.jit(scatter_sequence_prequantized)(kp, vp, *new, ids, offs)
+    for got, pool, (q8, scale) in ((kp2, kp, new[:2]), (vp2, vp, new[2:])):
+        for leaf, rows in (("q8", q8), ("scale", scale)):
+            want = _layer_sliced_write(pool[leaf], rows, ids, offs)
+            np.testing.assert_array_equal(
+                np.asarray(got[leaf][:, 1:]), np.asarray(want[:, 1:]))
 
 
 def test_allocator_oversubscription_and_rollback():
