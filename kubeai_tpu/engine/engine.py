@@ -25,6 +25,7 @@ reference: internal/vllmclient/client.go:30-73.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Any, NamedTuple
@@ -206,13 +207,24 @@ class StepEvent(NamedTuple):
     event of token i >= 2 carries row P + i - 2, the position whose
     forward produced it; rows recomputed after a preemption or for a
     resume prefix ride in front of the next token's row, under their
-    positions (docs/concepts/expert-routes.md)."""
+    positions (docs/concepts/expert-routes.md).
+
+    `forwards` is None unless the request asked for its forwards
+    (`add_request(forwards=True)`) of a family that generates by blocks:
+    then a tuple of `(start, rows, commit, tokens)`, one a forward of the
+    model over this request's rows, in the order they ran: `rows` the
+    `[n, routed layers, k]` expert sets of positions `start ..`, `commit`
+    the offsets of the rows that forward committed and `tokens` what it
+    committed them to (both empty for the prompt's forward and for the one
+    that writes a finished block's K and V). They ride on the first token
+    served of the block they filled (docs/concepts/block-diffusion.md)."""
 
     rid: int
     token: int
     finished: bool
     finish_reason: str = ""
     routes: tuple | None = None
+    forwards: tuple | None = None
 
 
 @dataclasses.dataclass
@@ -252,6 +264,10 @@ class _Request:
     # blocks computed while it emitted nothing (a resumed admission's
     # recompute), which ride on its next event.
     route_backlog: list | None = None
+    # None unless the request asked for its forwards (a family that
+    # generates by blocks): the forwards that served no token yet (the
+    # prompt's), which ride on its next event.
+    forward_backlog: list | None = None
 
 
 class EngineDraining(RuntimeError):
@@ -369,7 +385,10 @@ class Engine:
         self.live_kv = {"slots": 0, "pages": 0, "pages_total": 0}
 
         self._spec = 0  # resolved speculation window (see below)
-        if getattr(self.family, "decode_step_paged", None) is None:
+        if (
+            getattr(self.family, "decode_step_paged", None) is None
+            and self._block is None
+        ):
             raise ValueError(
                 f"family {self.family.name} has no decode_step_paged: the "
                 "engine's only KV cache is the page pool"
@@ -387,6 +406,8 @@ class Engine:
         self.decode_kernel = resolve_decode_kernel(
             cfg.decode_kernel, quantized=self._kv_quant
         )
+        if self._block is not None:
+            self._check_block_engine(draft)
         if self._kv_quant and (cfg.speculate > 0 or draft is not None):
             raise ValueError(
                 "kv_dtype='int8' does not compose with speculative "
@@ -632,7 +653,14 @@ class Engine:
         B = cfg.num_slots
         rep = psh.named_sharding(self.mesh, (None,), rules)
         self._state = {
-            "tokens": jnp.zeros((B,), jnp.int32, device=rep),
+            # One token a slot; a block of them for a family that fills
+            # blocks (positions: the block's first).
+            "tokens": jnp.zeros(
+                (B, self._block["block_length"]) if self._block else (B,),
+                jnp.int32,
+                device=psh.named_sharding(self.mesh, (None, None), rules)
+                if self._block else rep,
+            ),
             "positions": jnp.zeros((B,), jnp.int32, device=rep),
             "seeds": jnp.zeros((B,), jnp.uint32, device=rep),
             "temp": jnp.zeros((B,), jnp.float32, device=rep),
@@ -758,7 +786,7 @@ class Engine:
                 "experts": int(experts),
                 "k": int(k),
                 "routed_layers": int(layers),
-                "routes": self._routes,
+                "routes": self._routes and not self.routes_unsupported,
             }
         # Cumulative, plain host values (EngineMetrics folds the deltas
         # in): token-layer assignments per global expert id, rows whose
@@ -772,6 +800,19 @@ class Engine:
             "rows_decode": 0,
             "rows_sent": 0,
             "requests": 0,
+            "touched_prefill": 0,
+            "touched_decode": 0,
+            "passes_prefill": 0,
+            "passes_decode": 0,
+        }
+        # A family that generates by blocks (cumulative, like route_stats):
+        # forwards of one slot's block by kind, the tokens they committed
+        # (both over the blocks that served a token), forwards of the model
+        # the chunk programs ran (each over every slot), the chunks reaped,
+        # forwards handed to requests that asked, and such requests.
+        self.block_stats = {
+            "denoise": 0, "commit": 0, "tokens": 0, "program_forwards": 0,
+            "chunks": 0, "forwards_sent": 0, "requests": 0,
         }
 
         self._build_jits_paged(pool_sharding)
@@ -790,6 +831,11 @@ class Engine:
                 "speculative decoding's verify forwards hand no expert "
                 "routes over"
             )
+        if self._block:
+            return (
+                "a family that generates by blocks routes its rows anew at "
+                "every forward: ask for kubeai_forwards"
+            )
         return ""
 
     @property
@@ -799,7 +845,58 @@ class Engine:
         counters; a request that asks gets its own rows), so the programs
         are the same whether or not anybody asks; a dense family's are
         what they were."""
-        return self.family.routes and not self.routes_unsupported
+        return self.family.routes and self._pp == 1 and not self._spec
+
+    # ---- generation by blocks --------------------------------------------------
+
+    @functools.cached_property
+    def _block(self) -> dict | None:
+        """How a family that generates by diffusion over blocks does it
+        (`block_length`, `denoising_steps`, `confidence_threshold`,
+        `mask_token_id`: the model's, from its configuration), None for a
+        family that generates one token a forward. Derived, not set."""
+        gen = getattr(self.family, "block_generation", None)
+        return gen(self.model_cfg) if gen else None
+
+    @property
+    def block_generation(self) -> dict | None:
+        """What /v1/state says of a family that generates by blocks."""
+        return self._block
+
+    @property
+    def _chunk_blocks(self) -> int:
+        """Blocks a slot fills in one decode chunk: `decode_chunk` tokens'
+        worth, so a chunk reads back `decode_chunk` tokens a slot as any
+        family's does."""
+        return max(1, self.cfg.decode_chunk // self._block["block_length"])
+
+    def _check_block_engine(self, draft) -> None:
+        """What a family that generates by blocks is not served with."""
+        cfg, R = self.cfg, self._block["block_length"]
+        refused = [
+            name for name, on in (
+                ("prefill_chunk", cfg.prefill_chunk > 0),
+                ("prefix_cache", cfg.prefix_cache),
+                ("speculate", cfg.speculate > 0 or draft is not None),
+                ("kv_dtype int8", self._kv_quant),
+                ("max_adapters", cfg.max_adapters > 0),
+                ("a pp mesh axis", self.mesh.shape.get("pp", 1) > 1),
+                # Its sparsely computed experts are one kernel on every
+                # device: an expert layer that holds a share is ROADMAP B2.
+                ("a tp mesh axis", self.mesh.shape.get("tp", 1) > 1),
+                ("decode_kernel per_layer", self.decode_kernel != "fused"),
+            ) if on
+        ]
+        if refused:
+            raise ValueError(
+                f"family {self.family.name} generates by blocks and is not "
+                f"served with: {', '.join(refused)}"
+            )
+        if cfg.max_seq_len % R or any(b % R for b in cfg.buckets()):
+            raise ValueError(
+                f"max_seq_len and the prefill buckets must be multiples of "
+                f"the block length {R}"
+            )
 
     # ---- compiled functions -------------------------------------------------
 
@@ -847,6 +944,8 @@ class Engine:
         """The compiled paths: admission scatters the prefilled
         sequence through the slot's block-table row; decode scatters one
         token per slot and attends over resident pages only."""
+        if self._block:
+            return self._build_jits_block(pool_sharding)
         fam, mcfg = self.family, self.model_cfg
         prefill_fn = self._resolve_prefill()
         max_len = self.cfg.max_seq_len
@@ -1386,6 +1485,201 @@ class Engine:
 
     # ---- public API ---------------------------------------------------------
 
+    def _build_jits_block(self, pool_sharding) -> None:
+        """The two compiled paths of a family that generates by blocks
+        (docs/concepts/block-diffusion.md), under the names and arguments
+        the one-token family's have, so the step loop calls them alike.
+
+        Admission prefills the prompt's WHOLE blocks under the block mask,
+        writes their K and V, and opens the slot's first block with the
+        prompt's left-over tokens; it samples nothing. The decode chunk
+        fills `_chunk_blocks` blocks a slot: each pass of its loop is one
+        forward of the model over every slot's block, after which a slot
+        whose block still holds a mask commits rows by the family's rule,
+        and a slot whose block is full has its K and V written and moves
+        to its next block."""
+        from kubeai_tpu.ops.paged_attention import (
+            batched_scatter_sequence,
+            batched_sequence_page_coords,
+        )
+
+        fam, mcfg, how = self.family, self.model_cfg, self._block
+        R, mask_id = how["block_length"], how["mask_token_id"]
+        page, max_len = self.cfg.page_size, self.cfg.max_seq_len
+        slots = self.cfg.num_slots
+        nblk = self._chunk_blocks
+        max_forwards = nblk * (how["denoising_steps"] + 1)
+        prefill_fn = self._resolve_prefill()
+        rows = jnp.arange(R)
+
+        def _block_admit(
+            params, tokens, ints, floats, bt_rows, kp, vp, bt, state, lora
+        ):
+            """`_prefill_admit`'s arguments; ints [A, 6] packs per row
+            [tokens held, slot, seed, top_k, adapter, unused]. Of the
+            tokens held the whole blocks are prefilled and written; the
+            rest open the slot's first block, masks after them. Padding
+            rows as there: slot = num_slots, bt_row = -1."""
+            held, at = ints[:, 0], ints[:, 1]
+            whole = held // R * R
+            _, k_all, v_all, routes = prefill_fn(
+                params, mcfg, tokens, whole, routes=True
+            )
+            page_ids, offsets = batched_sequence_page_coords(
+                bt_rows, whole, tokens.shape[1], page
+            )
+            kp, vp = batched_scatter_sequence(
+                kp, vp, k_all, v_all, page_ids, offsets
+            )
+            opening = jax.vmap(
+                lambda row, start: jax.lax.dynamic_slice(row, (start,), (R,))
+            )(tokens, jnp.minimum(whole, tokens.shape[1] - R))
+            opening = jnp.where(
+                rows[None, :] < (held - whole)[:, None], opening, mask_id
+            )
+            state = dict(
+                tokens=state["tokens"].at[at].set(opening),
+                positions=state["positions"].at[at].set(whole),
+                seeds=state["seeds"].at[at].set(ints[:, 2].astype(jnp.uint32)),
+                temp=state["temp"].at[at].set(floats[:, 0]),
+                topk=state["topk"].at[at].set(ints[:, 3]),
+                topp=state["topp"].at[at].set(floats[:, 1]),
+                lora_idx=state["lora_idx"],
+            )
+            # The prompts' expert sets [A, S, routed layers, k] are all
+            # the admission hands back: no token comes of a prefill.
+            return routes, kp, vp, bt.at[at].set(bt_rows), state
+
+        self._prefill_admit_jit = self.jit(
+            _block_admit,
+            donate_argnums=(5, 6),
+            out_shardings=(
+                None, pool_sharding, pool_sharding, self._bt_sharding, None,
+            ),
+        )
+
+        def _block_chunk(params, kp, vp, bt, state, lora):
+            seeds, temp = state["seeds"], state["temp"]
+            topk, topp = state["topk"], state["topp"]
+            mp = bt.shape[1]
+            slot_idx = jnp.arange(slots)[:, None]
+
+            def choose(logits, pos):
+                """Sampled tokens [slots, R]; the top ones when every slot
+                is greedy (the sampler's top-k over the vocabulary is not
+                run then)."""
+                flat = logits.reshape(slots * R, -1)
+                return jax.lax.cond(
+                    jnp.any(temp > 0),
+                    lambda: sample(
+                        flat, jnp.repeat(seeds, R), pos.reshape(-1) + 1,
+                        jnp.repeat(temp, R), jnp.repeat(topk, R),
+                        jnp.repeat(topp, R),
+                    ),
+                    lambda: jnp.argmax(
+                        jnp.where(
+                            jnp.arange(flat.shape[-1]) == mask_id,
+                            -jnp.inf, flat,
+                        ), axis=-1,
+                    ).astype(jnp.int32),
+                ).reshape(slots, R)
+
+            def forward(c):
+                tokens, positions, left = c["tokens"], c["positions"], c["left"]
+                logits, k_new, v_new, routes = fam.block_forward_paged(
+                    params, mcfg, tokens, positions, c["kp"], c["vp"], bt,
+                    routes=True,
+                )
+                pos = positions[:, None] + rows[None, :]
+                holds_mask = jnp.any(tokens == mask_id, axis=-1)
+                denoise = (left > 0) & holds_mask
+                write = (left > 0) & ~holds_mask
+                filled, commit, _ = fam.block_commit(
+                    mcfg, logits, tokens, choose(logits, pos), temp
+                )
+                commit = commit & denoise[:, None]
+                tokens = jnp.where(commit, filled, tokens)
+                # A finished block's K and V go to its pages; every other
+                # slot's rows to the scratch page.
+                pidx = pos // page
+                page_ids = jnp.where(
+                    (pidx < mp) & write[:, None],
+                    bt[slot_idx, jnp.minimum(pidx, mp - 1)], 0,
+                )
+                kp, vp = batched_scatter_sequence(
+                    c["kp"], c["vp"], k_new, v_new,
+                    jnp.maximum(page_ids, 0), pos % page,
+                )
+                # Its tokens go out under the block's number in the chunk.
+                here = write[None, :] & (
+                    jnp.arange(nblk)[:, None] == (nblk - left)[None, :]
+                )
+                out = jnp.where(here[:, None, :], tokens.T[None], c["out"])
+                f = c["f"]
+                record = {
+                    "kind": jnp.where(denoise, 1, jnp.where(write, 2, 0))
+                    .astype(jnp.int8),
+                    "start": positions,
+                    "commit": commit,
+                    "tokens": tokens,
+                    "routes": routes,
+                }
+                return dict(
+                    f=f + 1,
+                    tokens=jnp.where(write[:, None], mask_id, tokens),
+                    positions=jnp.where(
+                        write, jnp.minimum(positions + R, max_len - R),
+                        positions,
+                    ),
+                    left=left - write.astype(left.dtype),
+                    kp=kp, vp=vp, out=out,
+                    records=jax.tree.map(
+                        lambda buf, v: buf.at[f].set(v), c["records"], record
+                    ),
+                )
+
+            experts, k, layers = fam.route_dims(mcfg)
+            from kubeai_tpu.models.registry import route_dtype
+
+            init = dict(
+                f=jnp.int32(0),
+                tokens=state["tokens"],
+                positions=state["positions"],
+                # A slot that holds no page (free, or just freed) sits the
+                # chunk out.
+                left=jnp.where(bt[:, 0] >= 0, nblk, 0).astype(jnp.int32),
+                kp=kp, vp=vp,
+                out=jnp.zeros((nblk, R, slots), jnp.int32),
+                records={
+                    "kind": jnp.zeros((max_forwards, slots), jnp.int8),
+                    "start": jnp.zeros((max_forwards, slots), jnp.int32),
+                    "commit": jnp.zeros((max_forwards, slots, R), bool),
+                    "tokens": jnp.zeros((max_forwards, slots, R), jnp.int32),
+                    "routes": jnp.zeros(
+                        (max_forwards, slots, R, layers, k),
+                        route_dtype(experts),
+                    ),
+                },
+            )
+            c = jax.lax.while_loop(
+                lambda c: (c["f"] < max_forwards) & jnp.any(c["left"] > 0),
+                forward, init,
+            )
+            state = dict(state, tokens=c["tokens"], positions=c["positions"])
+            # [nblk * R, slots] tokens like any chunk's, and what each
+            # forward did beside them (the routed family's second output).
+            head = (
+                c["out"].reshape(nblk * R, slots),
+                dict(c["records"], forwards=c["f"]),
+            )
+            return head, c["kp"], c["vp"], state
+
+        self._decode_jit = self.jit(
+            _block_chunk,
+            donate_argnums=(1, 2),
+            out_shardings=(None, pool_sharding, pool_sharding, None),
+        )
+
     def add_request(
         self,
         prompt_tokens: list[int],
@@ -1397,6 +1691,7 @@ class Engine:
         deadline_ms: float | None = None,
         resume_tokens: list[int] | None = None,
         routes: bool = False,
+        forwards: bool = False,
     ) -> int:
         """Queue a request. `on_admit(rid)` runs under the engine lock
         before the request becomes visible to `step()` — callers use it to
@@ -1423,7 +1718,9 @@ class Engine:
         `routes=True` asks for the request's expert routes on its events
         (`StepEvent.routes`). A dense family serves it without any; an
         engine whose forwards hand none over (`routes_unsupported`)
-        refuses with ValueError."""
+        refuses with ValueError. `forwards=True` asks a family that
+        generates by blocks for what each forward over the request's rows
+        did (`StepEvent.forwards`); any other family serves it without."""
         if routes and self.routes_unsupported:
             raise ValueError(
                 f"expert routes are not available: {self.routes_unsupported}"
@@ -1482,6 +1779,7 @@ class Engine:
                 stop_token_ids=self.eos_token_ids,
                 t_enqueue=_now(),
                 route_backlog=[] if routes and self._routes else None,
+                forward_backlog=[] if forwards and self._block else None,
             )
             self._requests[rid] = req
             if on_admit is not None:
@@ -1504,6 +1802,8 @@ class Engine:
                 raise
             if req.route_backlog is not None:
                 self.route_stats["requests"] += 1
+            if req.forward_backlog is not None:
+                self.block_stats["requests"] += 1
             return rid
 
     def begin_drain(self) -> None:
@@ -1615,7 +1915,12 @@ class Engine:
                         padded = -(-(plen - cached_len) // C) * C
                 blocks = [None] * len(batch)
                 with span("admit.wait") as wait:
-                    if self._routes:
+                    if self._block:
+                        # No token comes of a block family's prefill: the
+                        # wait is for the prompts' expert sets alone.
+                        fetched = jax.device_get(head)
+                        toks = np.zeros(a_pad, np.int64)
+                    elif self._routes:
                         # The prompts' expert sets come back whole in the
                         # transfer that brings the first tokens.
                         toks, fetched = jax.device_get(head)
@@ -1651,8 +1956,10 @@ class Engine:
 
     def _head_tokens(self, head):
         """The sampled first tokens of what an admission call returned: a
-        routed family's call returns them with its expert sets."""
-        return head[0] if self._routes else head
+        routed family's call returns them with its expert sets. (A block
+        family's returns the expert sets alone, one row a prompt like the
+        tokens: no token comes of its prefill.)"""
+        return head[0] if self._routes and not self._block else head
 
     def _admission_routes(self, batch, cached_len: int, fetched) -> list:
         """One admission call's expert sets, on the host: count them, and
@@ -1664,7 +1971,10 @@ class Engine:
         with self.profiler.span(
             "step.routes", layers=self.moe["routed_layers"],
             k=self.moe["k"],
-            asked=sum(e[0].route_backlog is not None for e in batch),
+            asked=sum(
+                e[0].route_backlog is not None
+                or e[0].forward_backlog is not None for e in batch
+            ),
         ) as sp:
             if isinstance(fetched, list):
                 plen = batch[0][3]
@@ -1678,8 +1988,11 @@ class Engine:
                 per_request = [rows]
                 nbytes = sum(part.nbytes for _, part in fetched)
             else:
+                # A block family's prefill covers the whole blocks only.
+                R = self._block["block_length"] if self._block else 1
                 per_request = [
-                    fetched[i, : entry[3]] for i, entry in enumerate(batch)
+                    fetched[i, : entry[3] // R * R]
+                    for i, entry in enumerate(batch)
                 ]
                 nbytes = fetched.nbytes
             kept = (
@@ -1691,7 +2004,9 @@ class Engine:
             )
             sp.note(rows=len(kept), bytes=nbytes)
             return [
-                (cached_len, rows) if entry[0].route_backlog is not None
+                (cached_len, rows)
+                if entry[0].route_backlog is not None
+                or entry[0].forward_backlog is not None
                 else None
                 for entry, rows in zip(batch, per_request)
             ]
@@ -1715,6 +2030,10 @@ class Engine:
         self.route_stats["rows_" + kind] += n
         per_forward = np.bincount(forward, minlength=n_forwards)
         live = per_forward > 0
+        # Experts that hold a row, summed over (pass, routed layer): what a
+        # sparsely computed expert layer reads.
+        self.route_stats["touched_" + kind] += int((counts[live] > 0).sum())
+        self.route_stats["passes_" + kind] += int(live.sum()) * layers
         ratio = counts[live].max(-1) * experts / (per_forward[live, None] * k)
         self._timing.extend(
             ("moe_imbalance", v) for v in ratio.ravel().tolist()
@@ -1750,7 +2069,12 @@ class Engine:
         ):
             req = self._sched.peek()
             resumed = bool(req.out_tokens)
-            seq = req.prompt + req.out_tokens[:-1] if resumed else req.prompt
+            # A resumed request's last token has no K and V yet (the next
+            # decode step writes them); a block family's tokens are served
+            # once their block's K and V are written, so all are held.
+            seq = req.prompt + (
+                req.out_tokens if self._block else req.out_tokens[:-1]
+            )
             plen = len(seq)
             hashes = None
             hit = ()
@@ -2076,6 +2400,8 @@ class Engine:
     ) -> StepEvent | None:
         """`block`: the admission's expert sets for a request that asked,
         `(first computed position, rows)`."""
+        if self._block:
+            return self._finish_block_admission(req, slot, plen, resumed, block)
         if resumed:
             if req.done:  # finished/cancelled while pending: don't revive
                 self._release(req)
@@ -2112,6 +2438,31 @@ class Engine:
             req.rid, tok, finished, req.finish_reason,
             None if block is None else self._hand_routes(req, block),
         )
+
+    def _finish_block_admission(
+        self, req: _Request, slot: int, plen: int, resumed: bool, block
+    ) -> None:
+        """A block family's admission serves no token: the slot's first
+        block is open on the device, and its tokens come of the next
+        chunk. TTFT is taken there (`_emit_token`)."""
+        if req.done:  # finished/cancelled while pending: don't revive
+            self._release(req)
+            return None
+        if not resumed:
+            self._timing.append(
+                ("queue_wait", max(0.0, req.t_admit_start - req.t_enqueue))
+            )
+            self._timing.append(
+                ("prefill", max(0.0, _now() - req.t_admit_start))
+            )
+        if req.forward_backlog is not None and len(block[1]):
+            # The prompt's forward: its whole blocks' rows, nothing
+            # committed.
+            req.forward_backlog.append((0, block[1], (), ()))
+        req.position = plen
+        req.last_token = int(req.out_tokens[-1]) if req.out_tokens else 0
+        self._active[slot] = req
+        return None
 
     @staticmethod
     def _chunk_plan(seq: list[int], plen: int, C: int):
@@ -3041,6 +3392,16 @@ class Engine:
                     "step.decode", kv_layout=self.kv_layout,
                     live_slots=self.live_kv["slots"],
                     live_pages=self.live_kv["pages"],
+                    # A family that fills blocks: the blocks a slot fills
+                    # in this chunk and the most forwards that takes.
+                    **(
+                        {
+                            "blocks": self._chunk_blocks,
+                            "forwards": self._chunk_blocks
+                            * (self._block["denoising_steps"] + 1),
+                        }
+                        if self._block else {}
+                    ),
                 ):
                     if self._spec and self._spec_pick():
                         decode_mode = "spec"
@@ -3243,6 +3604,8 @@ class Engine:
                     toks_seq, routes_seq = jax.device_get(
                         (toks_seq, routes_seq)
                     )
+            if self._block:
+                return self._emit_blocks(toks_seq, routes_seq, chunk_slots)
             asked: list[tuple] = []  # (event index, step, slot, req, position)
             kept: list[tuple[int, int]] = []  # (step, slot) whose token was kept
             with span("step.sample"):
@@ -3292,6 +3655,86 @@ class Engine:
                     )
                 )
 
+    def _emit_blocks(self, toks_seq, records, chunk_slots) -> list[StepEvent]:
+        """A reaped chunk of a family that fills blocks. `toks_seq`
+        [blocks * R, slots] holds each slot's finished blocks in order;
+        `records` what every forward of the chunk did, [forwards, slots,
+        ...]: `kind` (0 = the slot sat it out, 1 = denoising, 2 = the
+        forward that writes a finished block), the block's `start`, the
+        rows it committed and to what, and the rows' expert sets.
+
+        A token is served in position order: rows of a request's first
+        block below its position are its prompt's and are skipped; rows
+        past `max_tokens` are dropped with the forwards of blocks that
+        served nothing. The forwards of a block ride on the first token it
+        served, for a request that asked; all kept forwards feed the expert
+        and block counters."""
+        R = self._block["block_length"]
+        n_forwards = int(records["forwards"])
+        kind = records["kind"][:n_forwards]
+        nslots = kind.shape[1]
+        base = np.zeros(nslots, np.int64)  # first position of the chunk
+        for slot, req in chunk_slots:
+            base[slot] = req.position // R * R
+        served = np.zeros((toks_seq.shape[0] // R, nslots), bool)
+        asked: list[tuple] = []  # (event index, block, slot, req)
+        with self.profiler.span("step.sample"):
+            emitted: list[StepEvent] = []
+            for k in range(toks_seq.shape[0]):
+                now = _now()
+                for slot, req in chunk_slots:
+                    if req.done or base[slot] + k < req.position:
+                        continue
+                    if not served[k // R, slot]:
+                        served[k // R, slot] = True
+                        if req.forward_backlog is not None:
+                            asked.append((len(emitted), k // R, slot, req))
+                    self._emit_token(
+                        req, int(toks_seq[k, slot]), now, emitted
+                    )
+        with self.profiler.span(
+            "step.routes", layers=self.moe["routed_layers"], k=self.moe["k"],
+            forwards=n_forwards, bytes=records["routes"].nbytes,
+            asked=len({entry[3].rid for entry in asked}),
+        ) as sp:
+            # The block of the chunk each (forward, slot) worked on, and
+            # whether that block served a token.
+            block_of = (records["start"][:n_forwards] - base[None, :]) // R
+            kept = (kind > 0) & np.take_along_axis(
+                served, np.clip(block_of, 0, len(served) - 1), axis=0
+            )
+            at, slots_at = np.nonzero(kept)
+            routes = records["routes"]
+            if len(at):
+                self._count_routes(
+                    routes[at, slots_at].reshape(-1, *routes.shape[3:]),
+                    np.repeat(at, R), n_forwards, "decode",
+                )
+            stats = self.block_stats
+            stats["denoise"] += int((kind[kept] == 1).sum())
+            stats["commit"] += int((kind[kept] == 2).sum())
+            stats["tokens"] += int(records["commit"][:n_forwards][kept].sum())
+            stats["program_forwards"] += n_forwards
+            stats["chunks"] += 1
+            for i, block, slot, req in asked:
+                mine = np.nonzero(kept[:, slot] & (block_of[:, slot] == block))[0]
+                handed = [
+                    (
+                        int(records["start"][f, slot]), routes[f, slot],
+                        tuple(np.nonzero(records["commit"][f, slot])[0].tolist()),
+                        tuple(records["tokens"][f, slot][
+                            records["commit"][f, slot]].tolist()),
+                    )
+                    for f in mine
+                ]
+                emitted[i] = emitted[i]._replace(
+                    forwards=(*req.forward_backlog, *handed)
+                )
+                stats["forwards_sent"] += len(req.forward_backlog) + len(handed)
+                req.forward_backlog.clear()
+            sp.note(rows=int(len(at)) * R)
+        return emitted
+
     def _emit_token(
         self, req: _Request, tok: int, now: float, emitted: list[StepEvent]
     ) -> bool:
@@ -3300,6 +3743,12 @@ class Engine:
         if req.t_prev_token:
             self._timing.append(
                 ("itl", max(0.0, now - req.t_prev_token), f"rid-{req.rid}")
+            )
+        elif self._block and not req.out_tokens:
+            # A block family's first token comes of a chunk, not of the
+            # admission.
+            self._timing.append(
+                ("ttft", max(0.0, now - req.t_enqueue), f"rid-{req.rid}")
             )
         req.t_prev_token = now
         req.out_tokens.append(tok)

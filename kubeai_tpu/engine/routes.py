@@ -16,6 +16,19 @@ or `uint32`, little-endian), row-major: row j is position `start + j`,
 inside it routed layer l's `k` experts in the router's order, best
 first. docs/concepts/expert-routes.md has the rules for which rows a
 request gets.
+
+A family that generates by diffusion over blocks routes its rows anew at
+every forward, so it hands over FORWARDS (`"kubeai_forwards": true`,
+docs/concepts/block-diffusion.md). A chunk of a stream carries many of them
+(10 for 8 tokens), so they are packed: one object for all the forwards a
+chunk carries, in the order they ran,
+
+    {"forwards": 10, "shape": [7, 8], "dtype": "uint8", "data": "<base64>"}
+
+`data` is, a forward, a header of little-endian uint32 (`start`, `rows`,
+`n`, then the `n` offsets of the rows that forward committed, then the `n`
+tokens it committed them to) followed by its `rows * shape[0] * shape[1]`
+expert ids of `dtype`, laid out as a `kubeai_routes` block's.
 """
 
 from __future__ import annotations
@@ -58,6 +71,50 @@ def encode_block(start: int, rows: np.ndarray) -> dict:
         "dtype": rows.dtype.name,
         "data": base64.b64encode(little.tobytes()).decode("ascii"),
     }
+
+
+def encode_forwards(forwards) -> dict:
+    """Forwards `(start, rows [n, routed layers, k], commit, tokens)` of a
+    family that generates by blocks, in the order they ran, as one JSON
+    object (the module's docstring has the layout)."""
+    first = forwards[0][1]
+    if first.dtype.name not in WIRE_DTYPES or first.ndim != 3:
+        raise ValueError(f"not expert ids: {first.dtype} {first.shape}")
+    little = first.dtype.newbyteorder("<")
+    parts = []
+    for start, rows, commit, tokens in forwards:
+        parts.append(np.asarray(
+            [start, len(rows), len(commit), *commit, *tokens], "<u4").tobytes())
+        parts.append(rows.astype(little, order="C").tobytes())
+    return {
+        "forwards": len(forwards),
+        "shape": [int(first.shape[1]), int(first.shape[2])],
+        "dtype": first.dtype.name,
+        "data": base64.b64encode(b"".join(parts)).decode("ascii"),
+    }
+
+
+def decode_forwards(block: dict) -> list:
+    """The inverse: `(start, rows, commit, tokens)` a forward. Raises
+    ValueError on an object whose bytes are not what its header says."""
+    if block["dtype"] not in WIRE_DTYPES:
+        raise ValueError(f"unknown route dtype {block['dtype']!r}")
+    dtype = np.dtype(block["dtype"]).newbyteorder("<")
+    layers, k = block["shape"]
+    raw = base64.b64decode(block["data"])
+    out, at = [], 0
+    for _ in range(block["forwards"]):
+        start, rows, n = (int(x) for x in np.frombuffer(raw, "<u4", 3, at))
+        marks = np.frombuffer(raw, "<u4", 2 * n, at + 12)
+        at += 12 + 8 * n
+        size = rows * layers * k * dtype.itemsize
+        ids = np.frombuffer(raw[at:at + size], dtype).reshape(rows, layers, k)
+        at += size
+        out.append((start, ids.astype(block["dtype"]),
+                    tuple(marks[:n].tolist()), tuple(marks[n:].tolist())))
+    if at != len(raw):
+        raise ValueError(f"{len(raw)} bytes of forwards, {at} accounted for")
+    return out
 
 
 def decode_block(block: dict) -> tuple[int, np.ndarray]:

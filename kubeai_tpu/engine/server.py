@@ -38,7 +38,7 @@ from kubeai_tpu.engine.engine import (
     EngineDraining,
     StepEvent,
 )
-from kubeai_tpu.engine.routes import encode_block, join_blocks
+from kubeai_tpu.engine.routes import encode_block, encode_forwards, join_blocks
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.metrics import flightrecorder, tracing
 from kubeai_tpu.engine.tokenizer import Tokenizer, load_tokenizer
@@ -352,6 +352,21 @@ class EngineMetrics:
             "token was emitted).",
             self.registry,
         )
+        self.moe_experts_touched = Counter(
+            "kubeai_engine_moe_experts_touched_total",
+            "Experts that at least one kept row was routed to, summed over "
+            "forward passes and routed layers (label `kind` as "
+            "kubeai_engine_route_rows_total): over "
+            "kubeai_engine_moe_passes_total, the experts a pass reads in a "
+            "layer when experts are computed sparsely.",
+            self.registry,
+        )
+        self.moe_passes = Counter(
+            "kubeai_engine_moe_passes_total",
+            "(Forward pass, routed layer) pairs that kept a row (label "
+            "`kind`).",
+            self.registry,
+        )
         self.route_rows_sent = Counter(
             "kubeai_engine_route_rows_sent_total",
             "Rows of expert sets handed to requests that asked for them "
@@ -363,6 +378,46 @@ class EngineMetrics:
             "kubeai_engine_route_requests_total",
             "Requests admitted that asked for their expert routes on an "
             "engine that hands them over.",
+            self.registry,
+        )
+        # -- a family that generates by diffusion over blocks
+        # (docs/concepts/block-diffusion.md). Nothing moves for any other.
+        self.block_forwards = Counter(
+            "kubeai_engine_block_forwards_total",
+            "Forwards of the model over one slot's block, over the blocks "
+            "that served a token (label `kind`: denoise = a forward that "
+            "commits rows of a block still masked, commit = the forward "
+            "over a finished block that writes its K and V).",
+            self.registry,
+        )
+        self.block_tokens = Counter(
+            "kubeai_engine_block_tokens_total",
+            "Tokens that denoising forwards committed, over the blocks that "
+            "served a token (a last block's rows past max_tokens included).",
+            self.registry,
+        )
+        self.block_program_forwards = Counter(
+            "kubeai_engine_block_program_forwards_total",
+            "Forwards of the model the decode chunks ran, each over every "
+            "slot's block: what a chunk's device time divides by.",
+            self.registry,
+        )
+        self.block_chunks = Counter(
+            "kubeai_engine_block_chunks_total",
+            "Decode chunks of a family that generates by blocks that were "
+            "reaped.",
+            self.registry,
+        )
+        self.forwards_sent = Counter(
+            "kubeai_engine_forwards_sent_total",
+            "Forwards handed to requests that asked for them "
+            "(`kubeai_forwards: true`).",
+            self.registry,
+        )
+        self.forward_requests = Counter(
+            "kubeai_engine_forward_requests_total",
+            "Requests admitted that asked for their forwards of a family "
+            "that generates by blocks.",
             self.registry,
         )
         self._timing_hist = {
@@ -610,8 +665,26 @@ class EngineMetrics:
                 ),
                 (self.route_rows, rstats["rows_prefill"], {"kind": "prefill"}),
                 (self.route_rows, rstats["rows_decode"], {"kind": "decode"}),
+                *(
+                    (counter, rstats[f"{name}_{kind}"], {"kind": kind})
+                    for counter, name in ((self.moe_experts_touched, "touched"),
+                                          (self.moe_passes, "passes"))
+                    for kind in ("prefill", "decode")
+                ),
                 (self.route_rows_sent, rstats["rows_sent"], {}),
                 (self.route_requests, rstats["requests"], {}),
+            ):
+                counter.inc(max(0.0, total - counter.get(**labels)), **labels)
+        bstats = getattr(inner, "block_stats", None)
+        if bstats and getattr(inner, "block_generation", None):
+            for counter, total, labels in (
+                (self.block_forwards, bstats["denoise"], {"kind": "denoise"}),
+                (self.block_forwards, bstats["commit"], {"kind": "commit"}),
+                (self.block_tokens, bstats["tokens"], {}),
+                (self.block_program_forwards, bstats["program_forwards"], {}),
+                (self.block_chunks, bstats["chunks"], {}),
+                (self.forwards_sent, bstats["forwards_sent"], {}),
+                (self.forward_requests, bstats["requests"], {}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
         dstats = getattr(inner, "disagg_stats", None)
@@ -698,6 +771,30 @@ class EngineMetrics:
             self.sched_service_rate.set(sched.get("service_rate", 0.0))
 
 
+# What a request can ask a forward to hand over: the request flag (also the
+# key of a response that carries it) and the StepEvent field its blocks come
+# on; `_encode_handed` puts them on the wire.
+HANDED_OVER = {"kubeai_routes": "routes", "kubeai_forwards": "forwards"}
+
+
+def _encode_handed(handover: dict | None, handed: dict | None) -> dict:
+    """The hand-over keys of a chunk or a choice. `handover` is `{flag:
+    whether the engine has any}` for the flags the request set (None or
+    empty: it set none, and the response is what it always was); `handed`
+    the blocks to send, a flag. A flag the engine has nothing for is null."""
+    out = {}
+    for flag, on in (handover or {}).items():
+        blocks = (handed or {}).get(flag) or ()
+        if not on:
+            out[flag] = None
+        elif flag == "kubeai_routes":
+            out[flag] = [encode_block(*b) for b in join_blocks(blocks)]
+        else:
+            # Packed: one object for all the forwards this chunk carries.
+            out[flag] = [encode_forwards(blocks)] if blocks else []
+    return out
+
+
 def engine_state_snapshot(engine) -> dict:
     """Serving-state snapshot shared by /metrics and /v1/state. Occupancy
     comes from the OUTER engine (LockstepEngine's num_pending includes
@@ -709,10 +806,15 @@ def engine_state_snapshot(engine) -> dict:
     kv_info = getattr(inner, "kv_cache_info", None)
     dev_info = getattr(inner, "device_info", None)
     moe = getattr(inner, "moe", None)
+    blocks = getattr(inner, "block_generation", None)
     return {
         # A family with a router: experts, k, routed layers, and whether
         # this engine hands the routes over. A dense family has no key.
         **({"moe": dict(moe)} if moe else {}),
+        # A family that generates by diffusion over blocks: block_length,
+        # denoising_steps, confidence_threshold, mask_token_id. A family
+        # that generates one token a forward has no key.
+        **({"generation": dict(blocks)} if blocks else {}),
         "slots_active": engine.num_active,
         "requests_pending": engine.num_pending,
         "kv_utilization": kvu() if kvu is not None else 0.0,
@@ -1425,17 +1527,28 @@ class EngineServer:
             return http._json(
                 400, {"error": {"message": "kubeai_routes must be a boolean"}}
             )
+        # `kubeai_forwards: true` asks a family that generates by blocks
+        # for every forward over this request's rows
+        # (docs/concepts/block-diffusion.md).
+        want_forwards = body.get("kubeai_forwards")
+        if want_forwards is not None and not isinstance(want_forwards, bool):
+            return http._json(
+                400, {"error": {"message": "kubeai_forwards must be a boolean"}}
+            )
         hid = (http.headers.get("X-Disagg-Handoff") or "").strip()
-        if want_routes:
-            refusal = self._routes_refusal() or (
+        for flag, wanted, refusal in (
+            ("kubeai_routes", want_routes, self._routes_refusal),
+            ("kubeai_forwards", want_forwards, self._handover_refusal),
+        ):
+            reason = wanted and (refusal() or (
                 "a request admitted from a KV handoff has no prompt rows "
                 "to hand over" if hid else ""
-            )
-            if refusal:
+            ))
+            if reason:
                 return http._json(
                     400,
                     {"error": {"message":
-                               f"kubeai_routes is not available: {refusal}"}},
+                               f"{flag} is not available: {reason}"}},
                 )
         if self.role == "prefill":
             # A prefill-role engine NEVER enters decode: every generate
@@ -1590,6 +1703,8 @@ class EngineServer:
                 )
                 if want_routes:
                     opt_kw["routes"] = True
+                if want_forwards:
+                    opt_kw["forwards"] = True
                 rid_i = self.engine.add_request(
                     prompt_ids, sp_i, adapter=adapter, on_admit=register,
                     priority=priority, client=sched_client,
@@ -1650,19 +1765,29 @@ class EngineServer:
         # None = did not ask; False = asked of a family without a router,
         # which is served and told so; True = its blocks ride along.
         routes = None if not want_routes else self._moe() is not None
+        # The same three for a request that asked for its forwards: of a
+        # family that generates one token a forward it is served and told so.
+        forwards = None if not want_forwards else (
+            self._block_generation() is not None
+        )
+        handover = {
+            name: on for name, on in (
+                ("kubeai_routes", routes), ("kubeai_forwards", forwards),
+            ) if on is not None
+        }
         try:
             if stream:
                 self._stream_response(http, reqs, display, chat, t0=t0,
                                       span=span,
                                       resume_tokens=resume_tokens,
                                       resume_emitted=resume_emitted,
-                                      routes=routes)
+                                      handover=handover)
             else:
                 self._unary_response(http, reqs, display, chat,
                                      len(prompt_ids),
                                      resume_tokens=resume_tokens,
                                      resume_emitted=resume_emitted,
-                                     routes=routes)
+                                     handover=handover)
         finally:
             # The duration the TTFT/e2e histograms see must also be
             # readable off the trace — spans and metrics have to agree.
@@ -1684,10 +1809,13 @@ class EngineServer:
         inner = getattr(self.engine, "inner", self.engine)
         return getattr(inner, "moe", None)
 
-    def _routes_refusal(self) -> str:
-        """Why a request that asks for its expert routes is refused here
-        ("" = it is served: with routes, or without where the family has
-        no router)."""
+    def _block_generation(self) -> dict | None:
+        inner = getattr(self.engine, "inner", self.engine)
+        return getattr(inner, "block_generation", None)
+
+    def _handover_refusal(self) -> str:
+        """Why this replica hands nothing of a forward over to a request,
+        whatever the engine ("" = it can)."""
         if self.role != "unified":
             return (
                 f"a {self.role}-role replica of a disaggregated pair hands "
@@ -1695,8 +1823,16 @@ class EngineServer:
             )
         if getattr(self.engine, "is_lockstep", False):
             return "multi-host replicas hand no expert routes over"
+        return ""
+
+    def _routes_refusal(self) -> str:
+        """Why a request that asks for its expert routes is refused here
+        ("" = it is served: with routes, or without where the family has
+        no router)."""
         inner = getattr(self.engine, "inner", self.engine)
-        return getattr(inner, "routes_unsupported", "")
+        return self._handover_refusal() or getattr(
+            inner, "routes_unsupported", ""
+        )
 
     def _with_served_routes(self, state: dict) -> dict:
         """/v1/state's `moe.routes` is what a request would get: false
@@ -2272,7 +2408,7 @@ class EngineServer:
         )
 
     def _collect(self, rid, sub, sp, on_delta=None, deadline=None,
-                 resume_tokens=(), resume_emitted=None, routes=None):
+                 resume_tokens=(), resume_emitted=None, handed=None):
         """Drain tokens; detokenize incrementally; apply stop strings.
         Returns (text, finish_reason, n_completion_tokens).
 
@@ -2290,11 +2426,12 @@ class EngineServer:
         chunks expose as `token_ids` so the proxy can resume THIS stream
         too if it dies.
 
-        `routes`: for a request that asked for its expert routes, the
-        list its events' blocks are appended to; `on_delta` takes out
-        what it sends. So that every consumed token goes out with its
-        row, `on_delta` is then also called with an empty delta when the
-        stream ends with tokens or rows unsent."""
+        `handed`: for a request that asked for its expert routes or its
+        forwards, `{flag: list}`: the lists its events' blocks are
+        appended to (`HANDED_OVER` names the event field of each flag);
+        `on_delta` takes out what it sends. So that every consumed token
+        goes out with its row, `on_delta` is then also called with an
+        empty delta when the stream ends with tokens or rows unsent."""
         tokens: list[int] = list(resume_tokens)
         sent_tokens = len(tokens)
         if tokens:
@@ -2311,8 +2448,8 @@ class EngineServer:
             deadline = time.monotonic() + self.request_timeout
 
         def owed() -> bool:
-            return routes is not None and (
-                bool(routes) or sent_tokens < len(tokens)
+            return handed is not None and (
+                any(handed.values()) or sent_tokens < len(tokens)
             )
 
         done = False
@@ -2342,8 +2479,8 @@ class EngineServer:
                         finish, done = "timeout", True
                         break
                     tokens.append(ev.token)
-                    if routes is not None and ev.routes:
-                        routes.extend(ev.routes)
+                    for flag, blocks in (handed or {}).items():
+                        blocks.extend(getattr(ev, HANDED_OVER[flag]) or ())
                     self.metrics.generated_tokens.inc()
                     text = self.tokenizer.decode(tokens)
                     # Stop strings act on detokenized text (engine core is
@@ -2392,7 +2529,7 @@ class EngineServer:
         return text, finish, len(tokens)
 
     def _unary_response(self, http, reqs, display, chat, n_prompt,
-                        resume_tokens=(), resume_emitted=None, routes=None):
+                        resume_tokens=(), resume_emitted=None, handover=None):
         # Usage counts the tokens actually generated (re-encoding the text
         # diverges around merges/special tokens and from the
         # generated_tokens metric). Choices decode CONCURRENTLY in the
@@ -2403,32 +2540,30 @@ class EngineServer:
         any_timeout = False
         deadline = time.monotonic() + self.request_timeout
         for i, (rid, sub, sp_i) in enumerate(reqs):
-            # A request that asked for its expert routes gets, in each
-            # choice, the tokens served and the blocks as one list (the
-            # same blocks a stream's chunks carry, joined where they
-            # touch); null where the family has no router.
+            # A request that asked for its expert routes (its forwards)
+            # gets, in each choice, the tokens served and the blocks as
+            # one list (the same blocks a stream's chunks carry, routes
+            # joined where they touch); null where the family has no
+            # router (does not generate by blocks).
             ids: list[int] = []
-            blocks: list | None = None if routes is None else []
+            handed = {flag: [] for flag in handover} if handover else None
             text, finish, completion_tokens = self._collect(
                 rid, sub, sp_i, deadline=deadline,
                 on_delta=(
-                    None if routes is None
+                    None if handed is None
                     else lambda _text, new_tokens=(): ids.extend(new_tokens)
                 ),
                 resume_tokens=resume_tokens if i == 0 else (),
                 resume_emitted=resume_emitted if i == 0 else None,
-                routes=blocks,
+                handed=handed,
             )
             if finish == "timeout":
                 any_timeout = True
                 finish = "length"  # partial result; valid OpenAI value
             total_completion += completion_tokens
-            extra = {} if routes is None else {
+            extra = {} if handed is None else {
                 "token_ids": [int(t) for t in ids],
-                "kubeai_routes": (
-                    [encode_block(*b) for b in join_blocks(blocks)]
-                    if routes else None
-                ),
+                **_encode_handed(handover, handed),
             }
             if chat:
                 choices.append(
@@ -2478,7 +2613,7 @@ class EngineServer:
         http._json(200, payload)
 
     def _stream_response(self, http, reqs, display, chat, t0=None, span=None,
-                         resume_tokens=(), resume_emitted=None, routes=None):
+                         resume_tokens=(), resume_emitted=None, handover=None):
         """SSE stream. With n > 1 the choices stream SEQUENTIALLY in index
         order (each chunk carries its index, which is all the protocol
         requires); later choices decode concurrently and buffer while an
@@ -2490,7 +2625,8 @@ class EngineServer:
         continuation request when this replica dies mid-generation. A
         request that asked for its expert routes gets beside it
         `kubeai_routes`: the blocks of the rows computed since its last
-        chunk (null where the family has no router)."""
+        chunk (null where the family has no router); one that asked for
+        its forwards `kubeai_forwards`, likewise."""
         http.send_response(200)
         http.send_header("Content-Type", "text/event-stream")
         http.send_header("Cache-Control", "no-cache")
@@ -2504,7 +2640,7 @@ class EngineServer:
             http.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
             http.wfile.flush()
 
-        def send_choice(choice: dict, token_ids=(), blocks=None):
+        def send_choice(choice: dict, token_ids=(), handed=None):
             send_chunk(
                 {
                     "id": rid_s,
@@ -2518,24 +2654,21 @@ class EngineServer:
                         {"token_ids": [int(t) for t in token_ids]}
                         if token_ids else {}
                     ),
-                    **(
-                        {} if routes is None else
-                        {"kubeai_routes": [
-                            encode_block(*b) for b in blocks or ()
-                        ] if routes else None}
-                    ),
+                    **_encode_handed(handover, handed),
                 }
             )
 
         deadline = time.monotonic() + self.request_timeout
         ttft_seen = [False]
         for i, (rid, sub, sp_i) in enumerate(reqs):
-            unsent: list | None = None if routes is None else []
+            unsent = {flag: [] for flag in handover} if handover else None
 
             def on_delta(delta_text: str, new_tokens=(), _i=i, _unsent=unsent):
                 blocks = None
                 if _unsent is not None:
-                    blocks, _unsent[:] = join_blocks(_unsent), []
+                    blocks = {flag: list(held) for flag, held in _unsent.items()}
+                    for held in _unsent.values():
+                        held.clear()
                 if not ttft_seen[0]:
                     ttft_seen[0] = True
                     if span is not None and t0 is not None:
@@ -2549,20 +2682,20 @@ class EngineServer:
                             "delta": {"content": delta_text},
                             "finish_reason": None,
                         },
-                        token_ids=new_tokens, blocks=blocks,
+                        token_ids=new_tokens, handed=blocks,
                     )
                 else:
                     send_choice(
                         {"index": _i, "text": delta_text,
                          "finish_reason": None},
-                        token_ids=new_tokens, blocks=blocks,
+                        token_ids=new_tokens, handed=blocks,
                     )
 
             _text, finish, _n = self._collect(
                 rid, sub, sp_i, on_delta=on_delta, deadline=deadline,
                 resume_tokens=resume_tokens if i == 0 else (),
                 resume_emitted=resume_emitted if i == 0 else None,
-                routes=unsent,
+                handed=unsent,
             )
             if finish == "timeout":
                 # Headers are already on the wire; the best we can do is a

@@ -42,16 +42,18 @@ from kubeai_tpu.parallel import sharding as sh
 
 
 @jax.named_scope("prefill_attention")
-def _prefill_attention(q, k, v):
+def _prefill_attention(q, k, v, mask_block: int = 1):
     """Aligned buckets of 256 tokens and up take the Pallas flash kernel
     wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
-    interpreter); the short and unaligned buckets keep the jnp path."""
+    interpreter); the short and unaligned buckets keep the jnp path.
+    `mask_block` > 1 is a block-diffusion family's mask: causal between
+    blocks of that many positions, full inside one."""
     S = q.shape[1]
     if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
         from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
-        return flash_causal_prefill(q, k, v)
-    return causal_prefill_attention(q, k, v)
+        return flash_causal_prefill(q, k, v, mask_block=mask_block)
+    return causal_prefill_attention(q, k, v, mask_block=mask_block)
 
 
 @dataclasses.dataclass(frozen=True)

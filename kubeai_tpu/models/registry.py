@@ -44,8 +44,21 @@ class ModelFamily:
         feature: str = "TextGeneration",
         hidden_states=None,
         route_dims: Callable | None = None,
+        block_forward_paged: Callable | None = None,
+        block_commit: Callable | None = None,
+        block_generation: Callable | None = None,
     ):
         self.hidden_states = hidden_states
+        # A family that generates by diffusion over blocks says so here:
+        # block_generation(cfg) -> {"block_length", "denoising_steps",
+        # "confidence_threshold", "mask_token_id"}. Its decode step is
+        # block_forward_paged (a block of positions a slot against the
+        # page pool, which it does not write) and block_commit (which rows
+        # a denoising forward commits); its prefill runs under the block
+        # mask and yields no token. It has no decode_step_paged.
+        self.block_forward_paged = block_forward_paged
+        self.block_commit = block_commit
+        self.block_generation = block_generation
         # A family whose FFN routes tokens to experts says so here:
         # route_dims(cfg) -> (experts, experts per token, routed layers).
         # Its prefill / decode_step / decode_step_paged / prefill_chunk

@@ -41,6 +41,8 @@ def causal_prefill_attention(
     logit_softcap: float | None = None,  # Gemma-2 tanh capping
     window: jnp.ndarray | int | None = None,  # sliding window; traced OK,
     #   <= 0 disables (lets a layer scan alternate local/global layers)
+    mask_block: int = 1,  # > 1: causal between blocks of this many
+    #   positions, full inside one (generation by diffusion over blocks)
 ) -> jnp.ndarray:
     """Causal self-attention over a freshly computed prompt segment.
 
@@ -59,7 +61,11 @@ def causal_prefill_attention(
         logits = jnp.tanh(logits / logit_softcap) * logit_softcap
     q_pos = jnp.arange(s) + q_offset
     k_pos = jnp.arange(k.shape[1])
-    mask = q_pos[:, None] >= k_pos[None, :]  # [Sq, Sk]
+    if mask_block > 1:
+        # Row i sees column j iff j // B <= i // B.
+        mask = k_pos[None, :] < (q_pos[:, None] // mask_block + 1) * mask_block
+    else:
+        mask = q_pos[:, None] >= k_pos[None, :]  # [Sq, Sk]
     if window is not None:
         win = jnp.asarray(window, jnp.int32)
         mask = mask & (

@@ -701,10 +701,10 @@ def _fused_attend_page(
     head and page. Operands enter the dots in the dtype they are stored in
     (bf16 x bf16 products are exact in the f32 accumulator; an f32 pool is
     multiplied in f32); scale and softcap act on the f32 scores; p stays
-    f32, as three bf16 pieces when V is bf16."""
+    f32, as three bf16 pieces when V is bf16. A block's new rows come as the
+    [cols, D] matrix already (column = token, head, like a page's)."""
     h = kvh * group
-    page, _, d = k.shape
-    cols = page * kvh
+    cols, d = (k.shape[0] * kvh, k.shape[2]) if k.ndim == 3 else k.shape
     if q.dtype != k.dtype:
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
     # One bf16 pass is exact for bf16 operands; anything else in f32.
@@ -754,8 +754,8 @@ def _paged_fused_kernel(
     layer_ref,  # [1] int32 layer index into the stacked pool
     # whole arrays in VMEM
     q_ref,  # [B, H, D], H = KVH * G, kv-head major
-    kn_ref,  # [B, H, D] the new token's K (not yet in the pool), per q head
-    vn_ref,  # [B, H, D]
+    kn_ref,  # [B, H, D] the new token's K (not yet in the pool), per q head;
+    vn_ref,  # rows > 1: [B, cols, D], the new rows as one page-like matrix
     # whole arrays where they lie (HBM)
     k_hbm,  # [NL, P, page, KVH, D]
     v_hbm,
@@ -772,9 +772,15 @@ def _paged_fused_kernel(
     kvh: int,
     group: int,
     depth: int,
+    rows: int,
     scale: float,
     logit_softcap: float | None,
 ):
+    """`rows` = 1: one new token a slot, `group` query heads a KV head.
+    `rows` = R > 1: a block of R new positions a slot, full inside itself.
+    Its R x G query rows a KV head all see the same old columns, so they
+    are the kernel's `group` and the walk over the pages is the same walk;
+    only the merge of the new rows differs."""
     nb, mp = bt_ref.shape
     layer = layer_ref[0]
     win = win_ref[0]
@@ -782,6 +788,22 @@ def _paged_fused_kernel(
         _live_page_range, bt_ref, pos_ref, win_ref,
         page_size=page_size, max_pages=mp,
     )
+    attend = functools.partial(
+        _fused_attend_page, m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref,
+        scale=scale, logit_softcap=logit_softcap, kvh=kvh, group=group,
+    )
+
+    def reset():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def merge_block(b):
+        """The block's own rows, every one seen by every one: a page of
+        `rows` tokens that is not in the pool yet; then normalize."""
+        attend(q_ref[b], kn_ref[b], vn_ref[b], rows, 0, 0)
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        o_ref[b] = out.astype(o_ref.dtype)
 
     # A cursor is (slot, page): it walks the live pages of the slots that
     # have any, in order; slot == nb means past the end (rows are read at
@@ -819,10 +841,24 @@ def _paged_fused_kernel(
             for copy in copies(b, i, buf):
                 copy.start()
 
-    # A slot that holds no page attends its new token alone: a softmax over
-    # one column is 1, so its output is that token's V. Slots with pages
-    # overwrite their rows below.
-    o_ref[...] = vn_ref[...].astype(o_ref.dtype)
+    if rows == 1:
+        # A slot that holds no page attends its new token alone: a softmax
+        # over one column is 1, so its output is that token's V. Slots with
+        # pages overwrite their rows below.
+        o_ref[...] = vn_ref[...].astype(o_ref.dtype)
+    else:
+        # A slot that holds no page attends its block alone.
+        def alone(b, carry):
+            first, n_pages = live(b)
+
+            @pl.when(first >= n_pages)
+            def _():
+                reset()
+                merge_block(b)
+
+            return carry
+
+        jax.lax.fori_loop(0, nb, alone, 0)
 
     first_slot, first_page = first_live(jnp.int32(0))
     fb, fi = first_slot, first_page
@@ -838,22 +874,22 @@ def _paged_fused_kernel(
 
         @pl.when(ci == first)
         def _init():
-            m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[:] = jnp.zeros_like(l_ref)
-            acc_ref[:] = jnp.zeros_like(acc_ref)
+            reset()
 
         for copy in copies(cb, ci, buf):
             copy.wait()
-        _fused_attend_page(
+        attend(
             q_ref[cb], k_buf[buf], v_buf[buf], pos,
             jnp.where(win > 0, pos + 1 - win, 0),  # first in-window position
-            ci * page_size, m_ref, l_ref, acc_ref,
-            scale=scale, logit_softcap=logit_softcap, kvh=kvh, group=group,
+            ci * page_size,
         )
         fetch(fb, fi, buf)  # the buffer is free again
 
         @pl.when(ci + 1 >= n_pages)
         def _finalize():
+            if rows > 1:
+                merge_block(cb)
+                return
             # Merge the new token as one extra column (always valid — it
             # is the query's own position, inside any window), normalize.
             q = q_ref[cb].astype(jnp.float32) * scale  # [H, D]
@@ -892,15 +928,20 @@ def fused_ring_depth(page: int, kvh: int, d: int, itemsize: int) -> int:
     return max(2, min(8, _FUSED_RING_BYTES // (2 * page * kvh * d * itemsize)))
 
 
+# The columns a block's new rows are padded to: one lane tile, so the dots
+# that merge them have the shapes the dots over a page have.
+_NEW_ROW_COLS = 128
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "logit_softcap", "interpret"),
 )
 def _paged_pallas_stacked(
-    q,  # [B, KVH, G, D]
+    q,  # [B, KVH, G, D]; a block of R rows: [B, KVH, R * G, D]
     k_pages,  # [NL, P, page, KVH, D] FULL stacked pool
     v_pages,
-    k_new,  # [B, KVH, D]
+    k_new,  # [B, KVH, D]; a block of R rows: [B, R, KVH, D]
     v_new,
     block_tables,  # [B, MP]
     positions,  # [B] old lengths
@@ -915,6 +956,19 @@ def _paged_pallas_stacked(
     h = kvh * g
     _, _, page, _, _ = k_pages.shape
     depth = fused_ring_depth(page, kvh, d, k_pages.dtype.itemsize)
+    rows = k_new.shape[1] if k_new.ndim == 4 else 1
+    if rows == 1:
+        # The new token's K and V once per query head, so the kernel merges
+        # it row by row.
+        new = [jnp.repeat(x, g, axis=1) for x in (k_new, v_new)]
+    else:
+        # The block's rows as a page would hold them ([token, head] rows of
+        # D), padded with rows the kernel masks.
+        pad = -(-max(_NEW_ROW_COLS, rows * kvh) // kvh) - rows
+        new = [
+            jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(b, -1, d)
+            for x in (k_new, v_new)
+        ]
 
     kernel = functools.partial(
         _paged_fused_kernel,
@@ -922,6 +976,7 @@ def _paged_pallas_stacked(
         kvh=kvh,
         group=g,
         depth=depth,
+        rows=rows,
         scale=scale,
         logit_softcap=logit_softcap,
     )
@@ -951,11 +1006,8 @@ def _paged_pallas_stacked(
         interpret=interpret,
     )(
         block_tables, positions, window, layer,
-        # Query heads as rows [H, D], kv-head major; the new token's K and
-        # V once per query head, so the kernel merges it row by row.
-        q.reshape(b, h, d),
-        jnp.repeat(k_new, g, axis=1), jnp.repeat(v_new, g, axis=1),
-        k_pages, v_pages,
+        # Query heads as rows [H, D], kv-head major.
+        q.reshape(b, h, d), *new, k_pages, v_pages,
     )
     return out.reshape(b, kvh, g, d)
 
@@ -1062,6 +1114,94 @@ def paged_decode_attention_fused(
         block_tables, positions, _window_arg(window), layer_arr.reshape(1),
     )
     return out.reshape(b, h, d)
+
+
+def ref_paged_block_attention_fused(
+    q: jnp.ndarray,  # [B, R, H, D] a block of R new positions a slot
+    k_pages: jnp.ndarray,  # [NL, P, page, KVH, D] stacked pools
+    v_pages: jnp.ndarray,
+    k_new: jnp.ndarray,  # [B, R, KVH, D] the block's own K (not in the pool)
+    v_new: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, MP]
+    positions: jnp.ndarray,  # [B] OLD lengths (the block's first position)
+    layer: jnp.ndarray,  # scalar int32
+    *,
+    scale: float | None = None,
+) -> jnp.ndarray:
+    """Reference semantics of the fused kernel at R rows a slot: every row
+    of the block attends the resident pages of `layer` below `positions`
+    and all R rows of the block itself (full inside a block)."""
+    b, r, h, d = q.shape
+    kvh = k_pages.shape[3]
+    kp = jax.lax.dynamic_index_in_dim(k_pages, layer, axis=0, keepdims=False)
+    vp = jax.lax.dynamic_index_in_dim(v_pages, layer, axis=0, keepdims=False)
+    bt = jnp.maximum(block_tables, 0)
+    k = kp[bt].reshape(b, -1, kvh, d)  # [B, L, KVH, D]
+    v = vp[bt].reshape(b, -1, kvh, d)
+    L = k.shape[1]
+    k = jnp.concatenate([k, k_new.astype(k.dtype)], axis=1)
+    v = jnp.concatenate([v, v_new.astype(v.dtype)], axis=1)
+    scale = scale if scale is not None else d ** -0.5
+    qg = (q * scale).reshape(b, r, kvh, h // kvh, d)
+    logits = jnp.einsum(
+        "bqkgd,blkd->bkgql", qg.astype(jnp.float32), k.astype(jnp.float32)
+    )
+    col = jnp.arange(L + r)
+    old = (col[None, :] < positions[:, None]) & (block_tables[:, :1] >= 0)
+    mask = old | (col[None, :] >= L)
+    logits = jnp.where(mask[:, None, None, None], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bkgql,blkd->bqkgd", probs, v.astype(jnp.float32))
+    return out.reshape(b, r, h, d).astype(q.dtype)
+
+
+def paged_block_attention_fused(
+    q: jnp.ndarray,  # [B, R, H, D]
+    k_pages: jnp.ndarray,  # [NL, P, page, KVH, D] stacked pools
+    v_pages: jnp.ndarray,
+    k_new: jnp.ndarray,  # [B, R, KVH, D]
+    v_new: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, MP]
+    positions: jnp.ndarray,  # [B] OLD lengths (the block's first position)
+    layer: jnp.ndarray | int,
+    *,
+    scale: float | None = None,
+) -> jnp.ndarray:
+    """The fused decode attention at R new rows a slot (a block that is
+    full inside itself): the stacked pool read in place by the same kernel,
+    the rows of a block folded into its query group. Dispatched like
+    paged_decode_attention_fused; the pool stays read-only."""
+    b, r, h, d = q.shape
+    if r == 1:  # one new token a slot: today's program
+        return paged_decode_attention_fused(
+            q[:, 0], k_pages, v_pages, k_new[:, 0], v_new[:, 0],
+            block_tables, positions, layer, scale=scale,
+        )[:, None]
+    kvh = k_pages.shape[3]
+    g = h // kvh
+    scale = scale if scale is not None else d ** -0.5
+    layer_arr = jnp.asarray(layer, jnp.int32)
+    mode = dispatch.kernel_mode()
+    if mode == "reference":
+        return ref_paged_block_attention_fused(
+            q, k_pages, v_pages, k_new, v_new, block_tables, positions,
+            layer_arr, scale=scale,
+        )
+    _check_page_size(k_pages.shape[2])
+    call = dispatch.over_kv_heads(
+        functools.partial(
+            _paged_pallas_stacked, scale=scale, logit_softcap=None,
+            interpret=mode == "interpret",
+        ),
+        kvh, (1, 3, 3, 2, 2, None, None, None, None),
+    )
+    # [B, R, KVH, G, D] -> [B, KVH, R * G, D]: a KV head's R x G query rows.
+    qk = jnp.moveaxis(q.reshape(b, r, kvh, g, d), 1, 2).reshape(b, kvh, r * g, d)
+    out = call(
+        qk, k_pages, v_pages, k_new, v_new, block_tables, positions,
+        _window_arg(None), layer_arr.reshape(1),
+    )
+    return jnp.moveaxis(out.reshape(b, kvh, r, g, d), 2, 1).reshape(b, r, h, d)
 
 
 # ---- paged cache writes (decode + admission) ---------------------------------

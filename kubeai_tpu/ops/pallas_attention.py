@@ -41,6 +41,7 @@ def _flash_kernel(
     block_k: int,
     seq_len: int,
     scale: float,
+    mask_block: int,
 ):
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
@@ -61,7 +62,14 @@ def _flash_kernel(
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        if mask_block > 1:
+            # Causal between blocks of `mask_block` positions, full inside
+            # one; `mask_block` divides the tiles, so the frontier below
+            # is the causal one.
+            seen = k_pos < (q_pos // mask_block + 1) * mask_block
+        else:
+            seen = q_pos >= k_pos
+        s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -79,7 +87,9 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_q", "block_k", "interpret", "scale", "group"),
+    static_argnames=(
+        "block_q", "block_k", "interpret", "scale", "group", "mask_block",
+    ),
 )
 def _flash_bhsd(
     q: jnp.ndarray,  # [B, H, S, D]
@@ -90,6 +100,7 @@ def _flash_bhsd(
     interpret: bool = False,
     scale: float = 1.0,
     group: int = 1,
+    mask_block: int = 1,
 ) -> jnp.ndarray:
     B, H, S, D = q.shape
     grid = (B, H, S // block_q)
@@ -99,6 +110,7 @@ def _flash_bhsd(
         block_k=block_k,
         seq_len=S,
         scale=scale,
+        mask_block=mask_block,
     )
     return pl.pallas_call(
         kernel,
@@ -124,10 +136,16 @@ def flash_causal_prefill(
     v: jnp.ndarray,
     *,
     block: int = 128,
+    mask_block: int = 1,
 ) -> jnp.ndarray:
-    """Flash attention with the causal_prefill_attention contract."""
+    """Flash attention with the causal_prefill_attention contract
+    (`mask_block` > 1: its block mask, for a divisor of `block`)."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
+    if block % mask_block:
+        raise ValueError(
+            f"a mask block of {mask_block} does not divide the {block}-row tile"
+        )
     if S < block or S % block:
         raise ValueError(
             f"flash prefill needs a sequence that is a multiple of {block}, "
@@ -152,7 +170,7 @@ def flash_causal_prefill(
         functools.partial(
             _flash_bhsd, block_q=block, block_k=block,
             interpret=dispatch.kernel_mode() == "interpret",
-            scale=D ** -0.5, group=group,
+            scale=D ** -0.5, group=group, mask_block=mask_block,
         ),
         KVH, (1, 1, 1),
     )(qt, kt, vt)
