@@ -202,7 +202,15 @@ def test_the_block_readers_on_a_worked_trace():
                               "jit__block_admit": {"count": 12, "total_s": 0.09}},
                   "ops": {"gmm.23 bf16[1024,768]": 0.7, "gmm.24 bf16[1024,768]": 0.7,
                           "gmm.25 bf16[1024,2048]": 0.6,
-                          "gmm.21 bf16[2048,768]": 0.5, "fusion.1 f32[32,4]": 0.2}},
+                          "gmm.21 bf16[2048,768]": 0.5, "fusion.1 f32[32,4]": 0.2},
+                  "ops_in": {
+                      "jit__block_chunk": {"count": 20, "total_s": 2.88, "ops": {
+                          "gmm.23 bf16[1024,768]": {"count": 1400, "total_s": 0.7},
+                          "gmm.24 bf16[1024,768]": {"count": 1400, "total_s": 0.7},
+                          "gmm.25 bf16[1024,2048]": {"count": 1400, "total_s": 0.6},
+                          "fusion.1 f32[32,4]": {"count": 200, "total_s": 0.2}}},
+                      "jit__block_admit": {"count": 12, "total_s": 0.09, "ops": {
+                          "gmm.21 bf16[2048,768]": {"count": 84, "total_s": 0.5}}}}},
         "hf": cfg, "engine": {"num_slots": 32}, "reference": ref,
         "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
     }
@@ -213,7 +221,7 @@ def test_the_block_readers_on_a_worked_trace():
         100 * need / 819e9 / 0.0144)
     assert readers.read(spec("forwards_per_token"), obs) == pytest.approx(1.25)
     # 200 forwards x 7 layers x (100 experts x 9.44 MB / 819 GB/s) over the
-    # 2.0 s of decode-shaped grouped products (the admission's are left out).
+    # 2.0 s of grouped products inside the chunk (the admission's are left out).
     assert readers.read(spec("moe_experts_roofline"), obs) == pytest.approx(
         100 * 200 * 7 * (100 * 2 * 3 * 2048 * 768 / 819e9) / 2.0)
     assert readers.read(spec("moe_experts_roofline"), obs) < 100
@@ -225,3 +233,95 @@ def test_the_block_readers_on_a_worked_trace():
             assert readers.read(spec(name), broken) is None
     assert readers.read(spec("forwards_per_token"),
                         {**obs, "metrics0": {}, "metrics1": {}}) is None
+
+
+def block_obs(chunk_ops, admit_ops, flops_per_s=197e12):
+    """What a traced run of the cell observes, from device events as
+    `trace_reduce.extract` gives them: 2 chunk programs of 100 ms and one
+    admission of 30 ms, each with the operations `[name, seconds]` handed in
+    laid end to end from its start, between two chunks that the slice's
+    edges cut (20 and 30 ms of them are seen, with a product each); 10
+    forwards a chunk and 100 experts touched a layer by the counters."""
+    from perf import readers, trace_reduce
+    from perf.reference import sdar_moe as ref
+
+    cut = [["gmm.23 bf16[1024,768]", 0.01]]
+    modules = [["jit__block_chunk(11)", 0.95, 0.02], ["jit__block_chunk(11)", 1.0, 0.1],
+               ["jit__block_admit(12)", 1.2, 0.03], ["jit__block_chunk(11)", 1.3, 0.1],
+               ["jit__block_chunk(11)", 1.45, 0.03]]
+    ops = []
+    for (_, at, _), mine in zip(modules, (cut, chunk_ops, admit_ops, chunk_ops, cut)):
+        for name, seconds in mine:
+            ops.append([name, at, seconds])
+            at += seconds
+
+    def counters(f, c, t, p):
+        return readers.parse_prometheus(
+            f"kubeai_engine_block_program_forwards_total {f}\n"
+            f"kubeai_engine_block_chunks_total {c}\n"
+            f'kubeai_engine_moe_experts_touched_total{{kind="decode"}} {t}\n'
+            f'kubeai_engine_moe_passes_total{{kind="decode"}} {p}\n')
+
+    return {
+        "metrics0": counters(0, 0, 0, 0), "metrics1": counters(100, 10, 70000, 700),
+        "polled": {}, "hf": load_config(CONFIG), "engine": {"num_slots": 32},
+        "reference": ref,
+        "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": flops_per_s},
+        "trace": trace_reduce.summarize(
+            {"devices": [{"name": "/device:TPU:0", "modules": modules, "ops": ops}]}),
+    }
+
+
+def roofline(obs):
+    from perf import readers
+
+    with open(os.path.join(ROOT, "perf", "layer_metrics",
+                           "moe_experts_roofline.json")) as f:
+        return readers.read(json.load(f), obs)
+
+
+DECODE = [["gmm.23 bf16[1024,768]", 0.03], ["gmm.24 bf16[1024,768]", 0.03],
+          ["gmm.25 bf16[1024,2048]", 0.03], ["fusion.341 f32[32,4]", 0.005]]
+# 20 forwards x 7 layers x 100 experts x 9.44 MB / 819 GB/s over 0.18 s.
+BYTES_BOUND = 100 * 20 * 7 * (100 * 2 * 3 * 2048 * 768 / 819e9) / 0.18
+
+
+@pytest.mark.parametrize("admit_ops", [
+    [],
+    [["gmm.21 bf16[16384,768]", 0.01]],
+    # One prompt in the 128 bucket: 1 x 128 x 8 = 1,024 assignments, the
+    # decode forward's own count, in a program of its own.
+    [["gmm.21 bf16[1024,768]", 0.01], ["gmm.22 bf16[1024,2048]", 0.02]],
+], ids=["no-admission", "admission-16384-rows", "admission-with-decode-rows"])
+def test_products_outside_the_chunk_do_not_move_the_roofline(admit_ops):
+    assert roofline(block_obs(DECODE, admit_ops)) == pytest.approx(BYTES_BOUND)
+    assert BYTES_BOUND < 100
+
+
+@pytest.mark.parametrize("assignments", [1024, 2048, 4096])
+def test_products_inside_the_chunk_count_whatever_their_rows(assignments):
+    """A forward of 8 rows a slot has 2,048 assignments where one of 4 has
+    1,024: found all the same, and the FLOPs follow the trace's own shape
+    (seen with an MXU slow enough for the FLOPs to bound the product)."""
+    ops = [[f"gmm.3{i} bf16[{assignments},{n}]", 0.03]
+           for i, n in enumerate((768, 768, 2048))]
+    assert roofline(block_obs(ops, [])) == pytest.approx(BYTES_BOUND)
+    flops = 2.0 * assignments * 3 * 2048 * 768  # a layer's three products
+    assert roofline(block_obs(ops, [], flops_per_s=1e12)) == pytest.approx(
+        100 * 20 * 7 * (flops / 1e12) / 0.18)
+
+
+def test_products_of_two_row_counts_in_one_chunk_take_their_mean():
+    ops = [[f"gmm.{i} bf16[{assignments},768]", seconds]
+           for i, (assignments, seconds) in enumerate(
+               [(1024, 0.01)] * 3 + [(2048, 0.02)] * 3)]
+    mean = 2.0 * 1536 * 3 * 2048 * 768
+    assert roofline(block_obs(ops, [], flops_per_s=1e12)) == pytest.approx(
+        100 * 20 * 7 * (mean / 1e12) / 0.18)
+
+
+@pytest.mark.parametrize("chunk_ops", [
+    [], [["fusion.341 f32[32,4]", 0.05]],
+], ids=["empty-chunk", "no-product-in-the-chunk"])
+def test_a_chunk_without_grouped_products_reads_nothing(chunk_ops):
+    assert roofline(block_obs(chunk_ops, [["gmm.21 bf16[1024,768]", 0.01]])) is None

@@ -48,6 +48,71 @@ def test_summary_of_the_recorded_slice(events):
     assert s["idle_gaps"] and s["idle_gaps"][0][0].startswith("before jit__")
 
 
+def slice_of(programs, ops):
+    return {"devices": [{"name": "/device:TPU:0", "modules": programs, "ops": ops}]}
+
+
+def test_the_recorded_slice_holds_no_whole_program(events):
+    """Its two programs are the slice's first and last: either may be cut,
+    so neither is counted a whole one, and `modules` reads what it read."""
+    s = trace_reduce.summarize(events)
+    assert s["ops_in"] == {}
+    assert trace_reduce.module_stats(s, "^jit__decode_chunk")[0] == 1
+    assert trace_reduce.module_stats(s, "^jit__decode_chunk", whole=True) == (0, 0)
+    assert trace_reduce.op_seconds(s, "^_paged_pallas", "^jit__decode_chunk") == 0
+
+
+def test_operations_by_the_whole_program_they_ran_in():
+    """A chunk cut at each edge of the slice, two whole chunks and an
+    admission between: the cut ones count in `modules` and `ops` as they
+    always did, and not among the programs a reader divides by."""
+    programs = [["jit__chunk(1)", 0.0, 0.04], ["jit__chunk(1)", 0.1, 0.1],
+                ["jit__admit(2)", 0.25, 0.02], ["jit__chunk(1)", 0.3, 0.1],
+                ["jit__chunk(1)", 0.45, 0.03]]
+    ops = [["gmm.1 bf16[8,8]", at, 0.01] for at in (0.0, 0.1, 0.15, 0.3, 0.35, 0.45)]
+    ops += [["gmm.1 bf16[8,8]", 0.25, 0.005], ["gmm.9 bf16[64,8]", 0.26, 0.005],
+            ["while.2 s32[]", 0.1, 0.1], ["fusion.3 f32[8]", 0.31, 0.002]]
+    s = trace_reduce.summarize(slice_of(programs, ops))
+    assert trace_reduce.module_stats(s, "^jit__chunk") == (4, pytest.approx(0.27))
+    assert trace_reduce.module_stats(s, "^jit__chunk", whole=True) == (
+        2, pytest.approx(0.2))
+    assert trace_reduce.module_stats(s, "^jit__admit", whole=True) == (
+        1, pytest.approx(0.02))
+    assert trace_reduce.op_seconds(s, "^gmm") == pytest.approx(0.07)
+    assert trace_reduce.op_seconds(s, "^gmm", "^jit__chunk") == pytest.approx(0.04)
+    assert trace_reduce.op_seconds(s, "^gmm", "^jit__admit") == pytest.approx(0.01)
+    assert trace_reduce.op_seconds(s, "^gmm", "^jit__") == pytest.approx(0.05)
+    assert trace_reduce.op_seconds(s, "^gmm", "^jit__no_such") == 0
+    assert trace_reduce.ops_in(s, "^gmm", "^jit__chunk") == {
+        "gmm.1 bf16[8,8]": {"count": 4, "total_s": pytest.approx(0.04)}}
+    assert set(s["ops_in"]["jit__chunk"]["ops"]) == {"gmm.1 bf16[8,8]", "fusion.3 f32[8]"}
+
+
+@pytest.mark.parametrize("start, program", [
+    (0.05, None),                   # inside the program the slice's start cut
+    (1.0, "jit__a"), (1.0999, "jit__a"),
+    (1.15, None),                   # in the gap between two programs
+    (1.2, "jit__b"),
+    (1.35, None),                   # inside the program the slice's end cut
+])
+def test_an_operation_belongs_to_the_program_that_holds_its_start(start, program):
+    s = trace_reduce.summarize(slice_of(
+        [["jit__b(2)", 1.2, 0.1], ["jit__a(1)", 1.0, 0.1],
+         ["jit__a(1)", 0.0, 0.1], ["jit__b(2)", 1.31, 0.1]],
+        [["gmm.1 bf16[8,8]", start, 0.01]]))
+    assert s["ops"] == {"gmm.1 bf16[8,8]": 0.01}  # the slice's sum keeps it
+    assert {k: v["ops"] for k, v in s["ops_in"].items() if v["ops"]} == (
+        {program: {"gmm.1 bf16[8,8]": {"count": 1, "total_s": 0.01}}} if program else {})
+
+
+def test_a_summary_without_programs_answers_a_question_by_program_with_nothing():
+    old = {"modules": {}, "ops": {"gmm.1 bf16[8,8]": 1.0}}  # as a test may build one
+    assert trace_reduce.op_seconds(old, "^gmm") == 1.0
+    assert trace_reduce.op_seconds(old, "^gmm", "^jit__") == 0
+    assert trace_reduce.ops_in(old, "^gmm", "^jit__") == {}
+    assert trace_reduce.module_stats(old, "^jit__", whole=True) == (0, 0)
+
+
 def test_no_device_events_is_nothing_to_read():
     assert trace_reduce.summarize({"devices": []}) is None
     assert readers.trace_idle_share({}, {"trace": None}) is None
