@@ -32,6 +32,7 @@ from kubeai_tpu.ops.attention import (
 )
 from kubeai_tpu.models.llama import _prefill_attention
 from kubeai_tpu.ops.norms import rms_norm
+from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
 from kubeai_tpu.parallel import sharding as sh
 
@@ -275,12 +276,14 @@ def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
         x = carry
         lp, kc, vc = scanned["p"], scanned["kc"], scanned["vc"]
         h = _norm(x, lp["input_norm"], cfg.rms_norm_eps)
-        q = jnp.einsum("be,eh->bh", h, lp["wq"]).reshape(B, 1, H, D)
-        k = jnp.einsum("be,eh->bh", h, lp["wk"]).reshape(B, 1, KVH, D)
-        v = jnp.einsum("be,eh->bh", h, lp["wv"]).reshape(B, 1, KVH, D)
-        q = apply_rope(q, pos1, inv_freq)[:, 0]
-        k = apply_rope(k, pos1, inv_freq)[:, 0]
-        v = v[:, 0]
+        q, k, v = split_heads(
+            jnp.einsum("be,eh->bh", h, lp["wq"]),
+            jnp.einsum("be,eh->bh", h, lp["wk"]),
+            jnp.einsum("be,eh->bh", h, lp["wv"]),
+            H, KVH, D,
+        )
+        q = apply_rope(q[:, None], pos1, inv_freq)[:, 0]
+        k = apply_rope(k[:, None], pos1, inv_freq)[:, 0]
         kc = kc.at[slot_idx, positions].set(k.astype(kc.dtype))
         vc = vc.at[slot_idx, positions].set(v.astype(vc.dtype))
         attn = decode_attention(
@@ -351,12 +354,15 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
 
     def layer_qkv(x, lp):
         h = _norm(x, lp["input_norm"], cfg.rms_norm_eps)
-        q = jnp.einsum("be,eh->bh", h, lp["wq"]).reshape(B, 1, H, D)
-        k = jnp.einsum("be,eh->bh", h, lp["wk"]).reshape(B, 1, KVH, D)
-        v = jnp.einsum("be,eh->bh", h, lp["wv"]).reshape(B, 1, KVH, D)
-        q = apply_rope(q, pos1, inv_freq)[:, 0]
-        k = apply_rope(k, pos1, inv_freq)[:, 0]
-        return q * (_q_scale(cfg) * D ** 0.5), k, v[:, 0]
+        q, k, v = split_heads(
+            jnp.einsum("be,eh->bh", h, lp["wq"]),
+            jnp.einsum("be,eh->bh", h, lp["wk"]),
+            jnp.einsum("be,eh->bh", h, lp["wv"]),
+            H, KVH, D,
+        )
+        q = apply_rope(q[:, None], pos1, inv_freq)[:, 0]
+        k = apply_rope(k[:, None], pos1, inv_freq)[:, 0]
+        return q * (_q_scale(cfg) * D ** 0.5), k, v
 
     def layer_finish(x, attn, lp):
         a_out = jnp.einsum("bh,he->be", attn.reshape(B, H * D), lp["wo"])

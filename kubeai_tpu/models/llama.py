@@ -28,6 +28,7 @@ import numpy as np
 
 from kubeai_tpu.ops import dispatch
 from kubeai_tpu.ops.norms import rms_norm
+from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import (
     apply_rope,
     rope_attention_scaling,
@@ -394,12 +395,14 @@ def decode_step(
             return out
 
         h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-        q = proj(h, lp["wq"], "wq", lp.get("bq")).reshape(B, 1, H, D)
-        k = proj(h, lp["wk"], "wk", lp.get("bk")).reshape(B, 1, KVH, D)
-        v = proj(h, lp["wv"], "wv", lp.get("bv")).reshape(B, 1, KVH, D)
-        q = apply_rope(q, pos1, inv_freq, msc)[:, 0]  # [B, H, D]
-        k = apply_rope(k, pos1, inv_freq, msc)[:, 0]  # [B, KVH, D]
-        v = v[:, 0]
+        q, k, v = split_heads(
+            proj(h, lp["wq"], "wq", lp.get("bq")),
+            proj(h, lp["wk"], "wk", lp.get("bk")),
+            proj(h, lp["wv"], "wv", lp.get("bv")),
+            H, KVH, D,
+        )
+        q = apply_rope(q[:, None], pos1, inv_freq, msc)[:, 0]  # [B, H, D]
+        k = apply_rope(k[:, None], pos1, inv_freq, msc)[:, 0]  # [B, KVH, D]
         # Scatter the new token's K/V into each slot at its position.
         kc = kc.at[slot_idx, positions].set(k.astype(kc.dtype))
         vc = vc.at[slot_idx, positions].set(v.astype(vc.dtype))
@@ -428,8 +431,8 @@ def _decode_layer_qkv(x, lp, lor, cfg, inv_freq, msc, pos1, lora_idx):
     is reused for the output projection. One body for every paged decode
     layout — decode_step_paged's fused AND per_layer branches, and the
     pipeline path (_paged_decode_layer) — so the projection/LoRA math
-    cannot drift between them."""
-    B = x.shape[0]
+    cannot drift between them. The three projections go to heads through
+    ops.projections.split_heads, which says why."""
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
 
     def proj(h, w, target, bias=None):
@@ -443,12 +446,15 @@ def _decode_layer_qkv(x, lp, lor, cfg, inv_freq, msc, pos1, lora_idx):
         return out
 
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-    q = proj(h, lp["wq"], "wq", lp.get("bq")).reshape(B, 1, H, D)
-    k = proj(h, lp["wk"], "wk", lp.get("bk")).reshape(B, 1, KVH, D)
-    v = proj(h, lp["wv"], "wv", lp.get("bv")).reshape(B, 1, KVH, D)
-    q = apply_rope(q, pos1, inv_freq, msc)[:, 0]  # [B, H, D]
-    k = apply_rope(k, pos1, inv_freq, msc)[:, 0]  # [B, KVH, D]
-    return q, k, v[:, 0], proj
+    q, k, v = split_heads(
+        proj(h, lp["wq"], "wq", lp.get("bq")),
+        proj(h, lp["wk"], "wk", lp.get("bk")),
+        proj(h, lp["wv"], "wv", lp.get("bv")),
+        H, KVH, D,
+    )
+    q = apply_rope(q[:, None], pos1, inv_freq, msc)[:, 0]  # [B, H, D]
+    k = apply_rope(k[:, None], pos1, inv_freq, msc)[:, 0]  # [B, KVH, D]
+    return q, k, v, proj
 
 
 @jax.named_scope("layer_finish")
@@ -849,9 +855,12 @@ def _paged_verify_layer(
         return out
 
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-    q = proj(h, lp["wq"], "wq", lp.get("bq")).reshape(B, K, H, D)
-    k = proj(h, lp["wk"], "wk", lp.get("bk")).reshape(B, K, KVH, D)
-    v = proj(h, lp["wv"], "wv", lp.get("bv")).reshape(B, K, KVH, D)
+    q, k, v = split_heads(
+        proj(h, lp["wq"], "wq", lp.get("bq")),
+        proj(h, lp["wk"], "wk", lp.get("bk")),
+        proj(h, lp["wv"], "wv", lp.get("bv")),
+        H, KVH, D,
+    )
     q = apply_rope(q, pos_k, inv_freq, msc)
     k = apply_rope(k, pos_k, inv_freq, msc)
     kp = kp.at[page_ids, offsets].set(k.astype(kp.dtype))

@@ -41,6 +41,7 @@ from kubeai_tpu.models.registry import (
 from kubeai_tpu.ops.attention import decode_attention
 from kubeai_tpu.ops.grouped_matmul import grouped_matmul
 from kubeai_tpu.ops.norms import rms_norm
+from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
 from kubeai_tpu.parallel import sharding as sh
 
@@ -249,12 +250,16 @@ def _layer_xs(params, cfg):
 def _qkv(h, lp, cfg, positions, inv_freq):
     """h [B, S, E] at `positions` [B, S] -> q [B, S, H, D], k and v
     [B, S, KVH, D], q and k rotated; with `qk_norm`, RMS-normed a head
-    before the rotation."""
-    B, S = h.shape[:2]
+    before the rotation. One body for a prompt's rows and a decode step's:
+    ops.projections.split_heads reads S and holds the few-row forwards'
+    projections before their head reshape (it says why)."""
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size
-    q = jnp.einsum("bse,eh->bsh", h, lp["wq"]).reshape(B, S, H, D)
-    k = jnp.einsum("bse,eh->bsh", h, lp["wk"]).reshape(B, S, KVH, D)
-    v = jnp.einsum("bse,eh->bsh", h, lp["wv"]).reshape(B, S, KVH, D)
+    q, k, v = split_heads(
+        jnp.einsum("bse,eh->bsh", h, lp["wq"]),
+        jnp.einsum("bse,eh->bsh", h, lp["wk"]),
+        jnp.einsum("bse,eh->bsh", h, lp["wv"]),
+        H, KVH, D,
+    )
     if cfg.qk_norm:
         with jax.named_scope("qk_norm"):
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)

@@ -1,8 +1,9 @@
 """Guard on the COMPILED decode chunk of the benchmark's cells, the one-chip
 one and the four-chip one (one shard of a tp=4 pool holds 2 KV heads): the KV
-page pool is read and written where it lies. Ahead-of-time compiles for a
-described v5e (nothing runs; a compile that passes is not a chip run), through
-the benchmark's own helper `tests/perf/aot.py`, which this file only reads."""
+page pool is read and written where it lies, and so are the stacked attention
+projection weights (PR 39). Ahead-of-time compiles for a described v5e
+(nothing runs; a compile that passes is not a chip run), through the
+benchmark's own helper `tests/perf/aot.py`, which this file only reads."""
 
 import os
 import re
@@ -94,10 +95,14 @@ def compiled(topo):
     return get
 
 
-def pool_movers(hlo: str, shapes: tuple[str, ...]) -> list[str]:
-    """Instructions that produce a pool-shaped array by moving one: a
-    `copy`, `dynamic-slice` or `dynamic-update-slice`, alone or as a
-    fusion the compiler named after them."""
+POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def pool_movers(hlo: str, shapes: tuple[str, ...], ops=POOL_MOVES,
+                fused=POOL_MOVES) -> list[str]:
+    """Instructions that produce an array of one of `shapes` by moving one:
+    a `copy`, `dynamic-slice` or `dynamic-update-slice` (`ops`), alone or
+    as a fusion the compiler named after one of them (`fused`)."""
     moved = []
     for line in hlo.splitlines():
         m = re.match(
@@ -105,9 +110,7 @@ def pool_movers(hlo: str, shapes: tuple[str, ...]) -> list[str]:
         if m is None or m.group(2) not in shapes:
             continue
         name, op = m.group(1), m.group(3)
-        if op in ("copy", "dynamic-slice", "dynamic-update-slice") or (
-                op == "fusion" and re.search(
-                    r"copy|dynamic-slice|dynamic-update-slice", name)):
+        if op in ops or (op == "fusion" and re.search("|".join(fused), name)):
             moved.append(line.strip()[:160])
     return moved
 
@@ -147,6 +150,78 @@ def test_the_four_chip_admission_keeps_no_pool_sized_temporary(compiled):
     stats = compiled("mixtral-8x7b-v5e4")["prefill"]
     assert stats.temp_size_in_bytes < 0.3 * GIB
     assert aot.peak_bytes(stats) < 12.5 * GIB
+
+
+def projection_shapes(cfg: dict) -> tuple[dict, int]:
+    """({"stacked": shapes, "layer": shapes}, bytes of the smallest stacked
+    one) of the attention projection weights `wq` and `wk` / `wv` on ONE
+    chip, as HLO prints them: `[NL, E, heads x D]` with the heads split
+    over tp, and one layer of it with and without its leading 1."""
+    tp = cfg["mesh"].get("tp", 1)
+    nl, e = cfg["num_hidden_layers"], cfg["hidden_size"]
+    d = cfg.get("head_dim") or e // cfg["num_attention_heads"]
+    outs = sorted({cfg["num_attention_heads"] * d // tp,
+                   cfg["num_key_value_heads"] * d // tp})
+    shapes = {
+        "stacked": tuple(f"{nl},{e},{o}" for o in outs),
+        "layer": tuple(f"{lead}{e},{o}" for o in outs for lead in ("1,", "")),
+    }
+    return shapes, nl * e * outs[0] * 2
+
+
+def weight_movers(hlo: str, cfg: dict) -> list[str]:
+    """Instructions that copy or transpose a stacked projection weight, or
+    a fusion that only moves one layer of it (the
+    `constant_dynamic-slice_fusion` that took a layer's transposed `wq`
+    into fast memory and computed nothing: PERF.md section 6, PR 39). A
+    bare `dynamic-slice` is not counted: inside the fusion that multiplies
+    it is how a layer is read where it lies. `wo` has `wq`'s shape and is
+    held to the same."""
+    shapes, _ = projection_shapes(cfg)
+    return pool_movers(
+        hlo, shapes["stacked"] + shapes["layer"], ops=("copy", "transpose"),
+        fused=("copy", "transpose", "dynamic-slice"))
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_no_instruction_moves_a_stacked_projection_weight(compiled, name):
+    """The decode layer's q / k / v projections each read the stacked
+    parameter where it lies (kubeai_tpu/ops/projections.py)."""
+    cfg = aot.load_config(name)
+    shapes, _ = projection_shapes(cfg)
+    text = compiled(name)["decode_text"]
+    for stacked in shapes["stacked"]:
+        assert f"bf16[{stacked}]" in text  # the shapes are right
+    assert weight_movers(text, cfg) == []
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_the_chunk_keeps_no_weight_sized_temporary(compiled, name):
+    """A transposed copy of `wq` and `wk` was the chunk's 0.626 GiB of
+    temporaries (0.126 GiB a tp=4 shard); 0 since (AOT, PR 39). Under the
+    smallest stacked projection weight: 128 MiB, 32 MiB a shard."""
+    _, smallest = projection_shapes(aot.load_config(name))
+    assert smallest == {"mistral-7b-v5e1": 128, "mixtral-8x7b-v5e4": 32}[
+        name] * 2**20
+    assert compiled(name)["decode"].temp_size_in_bytes < smallest
+
+
+def test_weight_movers_sees_the_folded_projections(topo, cell, monkeypatch):
+    """The guard above is not blind: with the plain
+    `einsum(...).reshape(...)` form in the decode layer the compiler
+    transposes both stacked weights in the chunk's entry and moves a
+    layer of each in every layer of every step."""
+    from kubeai_tpu.ops import projections
+
+    monkeypatch.setattr(projections, "HELD_BELOW_ROWS", 0)
+    cfg = cell[0]
+    out = aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",))
+    shapes, smallest = projection_shapes(cfg)
+    moved = weight_movers(out["decode_text"], cfg)
+    for stacked in shapes["stacked"]:
+        assert any(f"bf16[{stacked}]" in m and " copy(" in m for m in moved), moved
+    assert any("dynamic-slice_fusion" in m for m in moved), moved
+    assert out["decode"].temp_size_in_bytes > 4 * smallest
 
 
 def test_pool_movers_sees_the_per_layer_layout(topo, cell):
