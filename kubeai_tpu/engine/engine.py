@@ -367,9 +367,16 @@ class Engine:
         # trace's clock through the TraceAnnotation handed over here. The
         # serve loop drains the phases into a histogram and /v1/profile
         # reads the ring: plain floats, no registry in the hot path.
-        from kubeai_tpu.fleet.profiler import StepProfiler
+        from kubeai_tpu.fleet.profiler import DeviceQueueBook, StepProfiler
 
         self.profiler = StepProfiler(annotate=jax.profiler.TraceAnnotation)
+        # The device queue's book (same module): seconds the device had
+        # nothing queued before a dispatch, always on. Fed where the work
+        # happens: the dispatches in `step.decode` and `_admit_pending`,
+        # the ends of `step.overlap_idle` and `admit.wait`, and the serve
+        # loop's idle branch. EngineMetrics folds it into
+        # kubeai_engine_device_starved_seconds / _dispatches_total.
+        self.device_queue = DeviceQueueBook()
         # Admission device calls made by step(), and the prompt tokens
         # they computed against the tokens of the shapes they ran
         # (padded - useful = padding); EngineMetrics folds the deltas in.
@@ -1896,6 +1903,7 @@ class Engine:
                     if plan is None:
                         break  # defer: nothing was popped, nothing is held
                     kind, batch, bucket, cached_len = plan
+                    queue = self.device_queue.dispatching("prefill")
                     if kind == "batch":
                         head = self._admit_paged_batch(batch, bucket)
                         a_pad = self._head_tokens(head).shape[0]
@@ -1913,6 +1921,7 @@ class Engine:
                         )
                         a_pad = 1
                         padded = -(-(plen - cached_len) // C) * C
+                    launched = self._launched(head)
                 blocks = [None] * len(batch)
                 with span("admit.wait") as wait:
                     if self._block:
@@ -1927,6 +1936,7 @@ class Engine:
                         toks = np.asarray(toks).reshape(-1)
                     else:
                         toks = np.asarray(head).reshape(-1)
+                self.device_queue.waited(launched, "admit")
                 if self._routes:
                     blocks = self._admission_routes(batch, cached_len, fetched)
                 with span("admit.host") as tail:
@@ -1945,7 +1955,7 @@ class Engine:
                 useful = sum(entry[3] for entry in batch) - cached_len
                 call.note(
                     kind=kind, bucket=bucket, batch=len(batch), a_pad=a_pad,
-                    useful_tokens=useful, padded_tokens=padded,
+                    useful_tokens=useful, padded_tokens=padded, **queue,
                 )
             self.admit_stats["calls"] += 1
             self.admit_stats["useful_tokens"] += useful
@@ -1953,6 +1963,18 @@ class Engine:
             self._timing.append(("admit_host", host.seconds + tail.seconds))
             self._timing.append(("admit_wait", wait.seconds))
         return emitted
+
+    def _launched(self, out):
+        """Tell the device queue's book that the newest program launched
+        returns `out` (an array, or a tree whose first leaf stands for it:
+        a program's outputs are ready together) and return what the book
+        holds, for `waited`. With a draft model nothing is held: its
+        catch-up and admission programs queue behind the target's, and
+        their outputs are donated onward, so the tail is unknown and
+        every dispatch reads `drained`."""
+        tail = None if self._draft else jax.tree_util.tree_leaves(out)[0]
+        self.device_queue.dispatched(tail)
+        return tail
 
     def _head_tokens(self, head):
         """The sampled first tokens of what an admission call returned: a
@@ -2730,7 +2752,7 @@ class Engine:
         with self._lock:
             # Overlap barrier: this borrows a slot + pages synchronously;
             # an unreaped chunk's stop-driven frees must land first.
-            self._barrier_locked()
+            self._barrier_locked(launches=True)
             if self._draining:
                 raise EngineDraining("engine is draining")
             if not self._free_slots:
@@ -2878,7 +2900,7 @@ class Engine:
             # Overlap barrier: handoff import admits a slot OUTSIDE
             # _admit_pending (bypassing step()'s admission barrier), so
             # reap here before the slot/page grant.
-            self._barrier_locked()
+            self._barrier_locked(launches=True)
             if self._draining:
                 raise EngineDraining("engine is draining")
             adapter_idx = 0
@@ -3085,7 +3107,7 @@ class Engine:
         with self._lock:
             # Overlap barrier: the exported bytes must be a settled
             # snapshot — an in-flight chunk is still WRITING pages.
-            self._barrier_locked()
+            self._barrier_locked(launches=True)
             pages = self._alloc.lookup(hashes)
             if max_bytes > 0:
                 pages = pages[: max_bytes // page_nbytes]
@@ -3170,7 +3192,7 @@ class Engine:
         with self._lock:
             # Overlap barrier: seeding idle-pool pages races an unreaped
             # chunk's frees/allocations — reap before touching the pool.
-            self._barrier_locked()
+            self._barrier_locked(launches=True)
             seeded = self._alloc.seed_unowned(hashes)
             if seeded is None:
                 return 0
@@ -3321,6 +3343,8 @@ class Engine:
         Returns a list of StepEvents in emission order.
         """
         span = self.profiler.span
+        book = self.device_queue
+        started = book.now()
         # The span opens before the lock: a handler thread adding or
         # cancelling a request holds it, and that wait is the step's too.
         with span(
@@ -3334,6 +3358,7 @@ class Engine:
             # wait lands in overlap_idle and the transfer in readback
             # inside _process_chunk), sample = host token emission.
             phases = self.profiler.begin_step()
+            book.begin_step(started)
             emitted: list[StepEvent] = []
             if self._pending_events:
                 # Tokens reaped by an out-of-step barrier (cancel, drain,
@@ -3402,7 +3427,8 @@ class Engine:
                         }
                         if self._block else {}
                     ),
-                ):
+                ) as launch:
+                    launch.note(**book.dispatching("decode"))
                     if self._spec and self._spec_pick():
                         decode_mode = "spec"
                         if self._draft:
@@ -3468,6 +3494,7 @@ class Engine:
                             )
                 self._steps += 1
                 is_spec = isinstance(toks_seq, tuple)
+                self._launched(toks_seq[1] if is_spec else toks_seq)
                 chunk_len = 0 if is_spec else int(toks_seq.shape[0])
                 current = (
                     toks_seq,
@@ -3531,11 +3558,14 @@ class Engine:
                 or prev is not None
                 or self._inflight is not None
             ):
+                starved_s, dispatches = book.end_step()
                 self.profiler.observe_step(
                     phases,
                     tokens=len(emitted),
                     batch=len(self._active),
                     duration_s=step_s,
+                    starved_s=starved_s,
+                    dispatches=dispatches,
                 )
             return emitted
 
@@ -3554,13 +3584,18 @@ class Engine:
         self._inflight = None
         return self._process_chunk(inflight, barrier)
 
-    def _barrier_locked(self) -> None:
+    def _barrier_locked(self, launches: bool = False) -> None:
         """Barrier for callers OUTSIDE step() (cancel/drain/handoff/
         prefix paths, under the engine lock): reap the in-flight chunk
-        and queue its events for the next step() so no token is lost."""
+        and queue its events for the next step() so no token is lost.
+        `launches`: the caller goes on to device work of its own (a
+        hand-off's prefill, page gathers and scatters), which the device
+        queue's book does not watch: the tail is unknown from here."""
         evs = self._reap_inflight_locked()
         if evs:
             self._pending_events.extend(evs)
+        if launches:
+            self.device_queue.dispatched(None)
 
     def inflight_info(self) -> dict | None:
         """Snapshot of the dispatched-but-unreaped chunk for the server
@@ -3584,7 +3619,14 @@ class Engine:
         rows = sum(not req.done for _, req in chunk_slots)
         self.step_reaps[barrier] += 1
         with span("step.reap", rows=rows, chunk=inflight[2], barrier=barrier):
+            # What emptied the queue if this chunk is the newest program.
+            after = "reap_" + ("sync" if barrier == "none" else barrier)
             if isinstance(toks_seq, tuple) and toks_seq[0] == "spec":
+                # The wait for the verify forward, which the window's one
+                # fused readback would otherwise hide in its own seconds.
+                with span("step.overlap_idle"):
+                    jax.block_until_ready(toks_seq[1])
+                self.device_queue.waited(toks_seq[1], after)
                 return self._process_spec(
                     toks_seq[1], toks_seq[2], chunk_slots
                 )
@@ -3596,6 +3638,7 @@ class Engine:
             # step in the synchronous loop, →0 under perfect overlap.
             with span("step.overlap_idle"):
                 jax.block_until_ready(toks_seq)
+            self.device_queue.waited(toks_seq, after)
             with span("step.readback"):
                 if routes_seq is None:
                     toks_seq = np.asarray(jax.device_get(toks_seq))

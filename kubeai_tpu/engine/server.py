@@ -279,6 +279,35 @@ class EngineMetrics:
             "spec = ahead of it; external = outside a step.",
             self.registry,
         )
+        self.device_starved = Histogram(
+            "kubeai_engine_device_starved_seconds",
+            "Seconds the device had nothing queued before a dispatch, as "
+            "the engine thread observed it: from its return from a "
+            "blocking wait on the newest program dispatched (label "
+            "`after`: reap_admission / reap_seq_cap / reap_spec / "
+            "reap_external = a decode chunk reaped ahead of the next "
+            "dispatch, reap_sync = reaped with nothing dispatched behind "
+            "it, admit = an admission's first tokens) to the next "
+            "dispatch (label `before`: prefill | decode). A LOWER bound "
+            "of the device's gap: the wake-up after the device finished "
+            "and the launch after the call are left out. Time in which "
+            "the engine had no work is not counted.",
+            self.registry,
+            buckets=ITL_BUCKETS_S,
+        )
+        self.dispatches = Counter(
+            "kubeai_engine_dispatches_total",
+            "Programs Engine.step dispatched (label `before`: prefill = "
+            "one per admission device call, decode = one per decode / "
+            "speculation / block chunk), by the state of the device's "
+            "queue at that moment (label `queue`: empty = observed empty, "
+            "its seconds are in kubeai_engine_device_starved_seconds; "
+            "drained = not observed empty, yet the newest program had "
+            "already finished: the device ran dry behind the host's back "
+            "for an unknown time; busy = the dispatch hid behind device "
+            "work).",
+            self.registry,
+        )
         self.decode_live_pages = Counter(
             "kubeai_engine_decode_live_pages_total",
             "KV pages that hold the active slots' tokens (ceil(tokens / "
@@ -652,6 +681,17 @@ class EngineMetrics:
                 max(0.0, total - self.step_reaps.get(barrier=barrier)),
                 barrier=barrier,
             )
+        book = getattr(inner, "device_queue", None)
+        if book is not None:
+            for after, before, seconds in book.drain():
+                self.device_starved.observe(
+                    seconds, after=after, before=before
+                )
+            for (before, queue), total in book.dispatches.items():
+                labels = {"before": before, "queue": queue}
+                self.dispatches.inc(
+                    max(0.0, total - self.dispatches.get(**labels)), **labels
+                )
         live = getattr(inner, "live_kv", None)
         if live:
             self.decode_live_pages.inc(max(
@@ -923,8 +963,11 @@ class EngineServer:
         # as the step's (an engine stand-in without one gets an inert one).
         from kubeai_tpu.fleet.profiler import StepProfiler
 
-        prof = getattr(getattr(engine, "inner", engine), "profiler", None)
+        inner = getattr(engine, "inner", engine)
+        prof = getattr(inner, "profiler", None)
         self._span = (prof or StepProfiler()).span
+        # The device queue's book (same module), told when the loop idles.
+        self._device_queue = getattr(inner, "device_queue", None)
         self._stop = threading.Event()
         self._work = threading.Event()
         # Graceful drain (SIGTERM / POST /v1/drain): refuse new work with
@@ -1171,6 +1214,9 @@ class EngineServer:
         while not self._stop.is_set():
             try:
                 if not self.engine.has_work():
+                    # An idle engine is not a starved device.
+                    if self._device_queue is not None:
+                        self._device_queue.idle()
                     self._work.wait(timeout=0.01)
                     self._work.clear()
                     continue

@@ -16,7 +16,10 @@ step into phases:
                (`block_until_ready`). In the synchronous loop this is
                ~the whole device step; under the overlapped step
                pipeline it shrinks toward zero — the overlap win,
-               made visible per step.
+               made visible per step. Its end is what the device queue's
+               book (`DeviceQueueBook`, below) takes from it: when the
+               chunk waited for is the newest program dispatched, the
+               device has nothing queued from that instant on.
   readback   — jax.device_get of the (ready) decode chunk: the actual
                device→host token transfer.
   sample     — host-side token emission (stop checks, slot release)
@@ -27,10 +30,6 @@ step into phases:
                under an admission call is inside `prefill` as well.
   kv_transfer — paged-KV handoff export/import (disaggregated serving;
                recorded outside the step timeline)
-
-(`host_sync` — the old single bucket covering device wait + transfer —
-split into dispatch/readback/overlap_idle when the overlapped step
-pipeline landed.)
 
 The engine records plain floats under its own lock — it never touches a
 metrics registry from the hot path (same discipline as `Engine._timing`).
@@ -47,6 +46,10 @@ device trace and is inert while no profiler session is open. Spans that
 are no phase (`serve.step`, `step.reap`, `step.admit`, `admit.host`,
 `admit.wait`, `serve.fanout`, `serve.sync`, `http.emit`, `kv.export`,
 `kv.import`) exist only in such a trace; docs/concepts/observability.md has the table.
+
+`DeviceQueueBook` is the engine thread's book of the device's queue: the
+seconds the chip stood empty-handed before each dispatch, by what emptied
+the queue and what ended the gap, kept with no profiler session open.
 """
 
 from __future__ import annotations
@@ -140,9 +143,13 @@ class StepProfiler:
         tokens: int = 0,
         batch: int = 0,
         duration_s: float = 0.0,
+        starved_s: float = 0.0,
+        dispatches: list[str] | tuple = (),
     ) -> None:
         """Close one step's record into the ring and queue its phases for
-        histogram export. Wakes /v1/profile waiters."""
+        histogram export. Wakes /v1/profile waiters. `starved_s` and
+        `dispatches` are the step's page of the device queue's book
+        (`DeviceQueueBook.end_step`)."""
         with self._cond:
             self.steps_completed += 1
             self._ring.append(
@@ -152,6 +159,8 @@ class StepProfiler:
                     "tokens": int(tokens),
                     "batch": int(batch),
                     "duration_s": round(float(duration_s), 9),
+                    "starved_s": round(float(starved_s), 9),
+                    "dispatches": list(dispatches),
                     "phases_s": {
                         k: round(float(v), 9) for k, v in phases.items()
                     },
@@ -186,6 +195,122 @@ class StepProfiler:
                     break
                 self._cond.wait(timeout=min(remaining, 0.25))
             return self.steps_completed - start
+
+
+# Label values of the book's two series (docs list them).
+QUEUE_AFTER = (
+    "reap_admission", "reap_seq_cap", "reap_spec", "reap_external",
+    "reap_sync", "admit",
+)
+QUEUE_BEFORE = ("prefill", "decode")
+QUEUE_STATES = ("empty", "drained", "busy")
+
+
+class DeviceQueueBook:
+    """The device's queue as the thread that feeds it can know it.
+
+    The engine tells the book four things, each where it happens:
+    `dispatching(before)` the moment before it launches a program
+    (`before` = prefill | decode), `dispatched(out)` with an output of the
+    newest program launched (the tail; None for device work the book
+    cannot watch), `waited(out, after)` when it returns from a blocking
+    wait on `out`, and `idle()` when the serve loop found no work.
+
+    The queue is OBSERVED EMPTY since t when the host came back from a
+    wait on the tail itself: nothing was dispatched behind it, so the
+    device has nothing to do until the next dispatch. That dispatch
+    records `now - t` under (`after`, `before`): a lower bound of the
+    device's gap (the wake-up after the device finished and the launch,
+    an admission's staging and uploads and the jit call itself, are
+    left out), exact in what it counts. A dispatch
+    that did not observe the queue empty asks the tail `is_ready()`:
+    ready = `drained`, the device ran dry behind the host's back for an
+    unknown time of at most the time since the host last woke from a
+    device wait; not ready = `busy`, the dispatch hid behind device work.
+    Time in which the engine had no work is dropped: after `idle()` the
+    next step counts from its own start.
+
+    Plain values, mutated under the engine lock only (`idle` and `now`
+    touch nothing shared); the starved seconds wait in a list with a lock
+    of its own until `drain`, like `StepProfiler`'s phases."""
+
+    def __init__(self, clock=time.perf_counter):
+        self.now = clock
+        self._tail = None
+        self._empty: tuple[float, str] | None = None  # (since, after)
+        self._woke: float | None = None  # end of the last device wait
+        self._idle = False
+        # Cumulative, by (before, queue): EngineMetrics folds the deltas in.
+        self.dispatches = {
+            (b, q): 0 for b in QUEUE_BEFORE for q in QUEUE_STATES
+        }
+        self._lock = threading.Lock()
+        self._starved: list[tuple[str, str, float]] = []  # after, before, s
+        self._step_starved = 0.0
+        self._step_dispatches: list[str] = []
+
+    def idle(self) -> None:
+        """The serve loop found no work: what follows is no starvation."""
+        self._idle = True
+
+    def begin_step(self, started: float) -> None:
+        """Open a step's page. `started` is `now()` as the step's
+        `serve.step` span opened: after an idle spell the queue counts as
+        empty, and the host as awake, from there."""
+        if self._idle:
+            self._idle = False
+            if self._empty is not None:
+                self._empty = (started, self._empty[1])
+            self._woke = started
+        self._step_starved = 0.0
+        self._step_dispatches = []
+
+    def end_step(self) -> tuple[float, list[str]]:
+        """The step's starved seconds and its dispatches, `<before>:<queue>`
+        in order."""
+        return self._step_starved, self._step_dispatches
+
+    def waited(self, out, after: str) -> None:
+        """The host is back from a blocking wait on `out`."""
+        self._woke = self.now()
+        if out is not None and out is self._tail:
+            self._empty = (self._woke, after)
+
+    def dispatching(self, before: str) -> dict:
+        """Record the queue's state as a program is about to be launched;
+        returns it as span attributes (`queue`, and `starved_ms` or
+        `drained_bound_ms` where known)."""
+        now = self.now()
+        if self._empty is not None:
+            since, after = self._empty
+            self._empty = None
+            seconds = now - since
+            with self._lock:
+                self._starved.append((after, before, seconds))
+            self._step_starved += seconds
+            note = {"queue": "empty", "starved_ms": seconds * 1e3}
+        elif self._tail is None or self._tail.is_ready():
+            note = {"queue": "drained"}
+            if self._woke is not None:
+                note["drained_bound_ms"] = (now - self._woke) * 1e3
+        else:
+            note = {"queue": "busy"}
+        self.dispatches[before, note["queue"]] += 1
+        self._step_dispatches.append(f"{before}:{note['queue']}")
+        return note
+
+    def dispatched(self, out) -> None:
+        """`out` is an output of the newest program launched (None: device
+        work was launched that the book cannot watch)."""
+        self._tail = out
+        self._empty = None
+
+    def drain(self) -> list[tuple[str, str, float]]:
+        """Hand the pending (after, before, seconds) observations to the
+        caller (EngineMetrics' histogram); clears the list."""
+        with self._lock:
+            out, self._starved = self._starved, []
+            return out
 
 
 def phase_totals(records: list[dict]) -> dict[str, float]:

@@ -136,12 +136,14 @@ NEW_HISTOGRAMS = (
     "kubeai_engine_loop_gap_seconds",
     "kubeai_engine_emit_busy_seconds",
     "kubeai_engine_emit_lag_seconds",
+    "kubeai_engine_device_starved_seconds",
 )
 NEW_COUNTERS = (
     "kubeai_engine_admit_calls_total",
     "kubeai_engine_prefill_tokens_total",
     "kubeai_engine_decode_live_pages_total",
     "kubeai_engine_step_reaps_total",
+    "kubeai_engine_dispatches_total",
 )
 
 
