@@ -28,6 +28,7 @@ import dataclasses
 import functools
 import threading
 import time
+import types
 from typing import Any, NamedTuple
 
 import jax
@@ -270,6 +271,55 @@ class _Request:
     forward_backlog: list | None = None
 
 
+@dataclasses.dataclass
+class _Admission:
+    """One admission call whose output `head` (first tokens, a routed
+    family's expert sets) the host has not read yet. `batch` is the
+    call's entries of `_plan_admission`, `launched` what the device
+    queue's book holds for the call, `host_s` the seconds of its first
+    `admit.host`; `seated`: the admitted already ride a chunk
+    (`Engine._seat`)."""
+
+    batch: list
+    head: Any
+    launched: Any
+    cached_len: int
+    host_s: float
+    seated: bool = False
+
+
+def _call(fn, args):
+    """`fn(*args)`. As `_from_its_own_chunk` (below) it is entered from a
+    frame so large that the interpreter has to start a new chunk of its
+    frame stack for it, with room behind it for everything `fn` calls.
+
+    CPython (3.11 on) keeps a thread's Python frames in chunks of 16 KiB
+    and gives a chunk back to the system the moment the frame that opened
+    it returns. A loop whose callees' frames happen to straddle the end
+    of a chunk therefore maps, faults in and unmaps a chunk on every
+    call. JAX's tracing and lowering make some hundred thousand Python
+    calls at every depth down to a few thousand words, so the time of a
+    jitted function's FIRST call swung with the depth it was called at:
+    with the number of local variables in `Engine.step`, with who called
+    `step`. On the TPU host, where a page fault is dear, that was 22 to
+    35 s for one warm-up of 28 prefill shapes, by three words of stack
+    (PERF.md section 6, PR 42). From the large frame the depth is always
+    the same, and 64 KiB lie in one piece below it: nothing under a
+    jitted call crosses a chunk's end. The price is one mapping of
+    address space a call, microseconds beside a device program."""
+    return fn(*args)
+
+
+# Over 64 KiB of frame: the interpreter sizes the new chunk at the next
+# power of two, 128 KiB, so as many words again stay free behind the frame.
+_OWN_CHUNK_WORDS = 8200
+_from_its_own_chunk = types.FunctionType(
+    _call.__code__.replace(co_stacksize=_OWN_CHUNK_WORDS),
+    globals(), "_from_its_own_chunk",
+)
+_from_its_own_chunk.__doc__ = _call.__doc__
+
+
 class EngineDraining(RuntimeError):
     """Raised by add_request once drain has begun: the server answers
     503 + Retry-After so the LB moves the request to another replica."""
@@ -373,14 +423,18 @@ class Engine:
         # The device queue's book (same module): seconds the device had
         # nothing queued before a dispatch, always on. Fed where the work
         # happens: the dispatches in `step.decode` and `_admit_pending`,
-        # the ends of `step.overlap_idle` and `admit.wait`, and the serve
-        # loop's idle branch. EngineMetrics folds it into
+        # the ends of `step.overlap_idle` and `admit.wait` (`_collect_head`),
+        # and the serve loop's idle branch. EngineMetrics folds it into
         # kubeai_engine_device_starved_seconds / _dispatches_total.
         self.device_queue = DeviceQueueBook()
         # Admission device calls made by step(), and the prompt tokens
         # they computed against the tokens of the shapes they ran
         # (padded - useful = padding); EngineMetrics folds the deltas in.
         self.admit_stats = {"calls": 0, "useful_tokens": 0, "padded_tokens": 0}
+        # Admission calls dispatched behind the chunk in flight by the
+        # step under way, whose first tokens are still on the device, in
+        # dispatch order: empty between steps.
+        self._heads: list[_Admission] = []
         # Reaped chunks by what forced the reap (the `step.reap` span's
         # `barrier`): "none" ran behind the next dispatched chunk.
         self.step_reaps = dict.fromkeys(
@@ -932,7 +986,7 @@ class Engine:
 
         def call(*args):
             with jax.set_mesh(self.mesh):
-                return jitted(*args)
+                return _from_its_own_chunk(jitted, args)
 
         call.lower = jitted.lower
         return call
@@ -1881,7 +1935,37 @@ class Engine:
             req.t_admit_start = _now()
         return req
 
-    def _admit_pending(self) -> list[StepEvent]:
+    def _admission_rides(self) -> bool:
+        """Whether the head of the queue can be admitted BEHIND the chunk
+        in flight, with no reap first. Decided from what the engine can
+        see: a chunk is in flight (so the loop overlaps and runs no
+        speculation window, and there is device work to hide the prefill's
+        dispatch behind: an idle engine's first request and a warm-up
+        admit as they always did), a slot is free now (else the reap is
+        how one is found), and the head is a fresh prompt for the fused
+        call that its first token cannot end and whose pages the pool can
+        give now (else the reap is how they are found). A resumed
+        request's re-prefill must see its full `out_tokens`, and the
+        `prefix` and `chunked` kinds keep the barrier with their staged
+        calls."""
+        if self._inflight is None or not (len(self._sched) and self._free_slots):
+            return False
+        peeked = self._peek_admission()
+        pages = -(-peeked[2] // self.cfg.page_size)  # of its `plen` tokens
+        return self._fresh_for_queue(peeked) and pages <= self._alloc.free_pages
+
+    def _fresh_for_queue(self, peeked) -> bool:
+        """`peeked` (`_peek_admission`) is a fresh prompt for the fused
+        call, and the host does not already know that its first token
+        ends it (`_check_stop`'s two lengths; no token comes of a block
+        family's prefill): such a row is never put on a chunk."""
+        req, _seq, plen, resumed, _hashes, _hit, kind = peeked
+        ends = not self._block and (
+            req.params.max_tokens <= 1 or plen >= self.cfg.max_seq_len
+        )
+        return kind == "batch" and not resumed and not ends
+
+    def _admit_pending(self, rides: bool = False) -> list[StepEvent]:
         """Admission, BATCHED: same-bucket pending prompts prefill
         in one fused device call (up to cfg.max_admit_batch per call).
         A preempted request resumes by RECOMPUTE — re-prefill prompt +
@@ -1889,17 +1973,27 @@ class Engine:
         step writes) with its first token FORCED to the one already
         emitted.
 
-        Each pass of the loop is one `step.admit` span: `admit.host` is
-        the pops, page grants, staging, uploads and dispatch (and, once
-        the tokens are back, the bookkeeping of the admitted),
-        `admit.wait` the time blocked on the sampled first tokens."""
+        Each pass of the loop is one `step.admit` span around one device
+        call: its `admit.host` is the pops, page grants, staging, uploads
+        and dispatch; the call's first tokens are read by `_collect_head`
+        (`admit.wait`, then a second `admit.host`), here inside the
+        `step.admit` and before anything else is dispatched.
+
+        `rides` (`_admission_rides`): the chunk in flight was not reaped.
+        Only fresh prompts for the fused call are taken (the first request
+        that is anything else stays at the head of the queue for the next
+        step, which reaps first), and no call is waited for: the device
+        wrote the first tokens, positions and sampling state into `state`
+        itself, so the admitted are seated with what the host knows
+        (`_seat`) and the call joins `_heads`; `step` dispatches the next
+        chunk behind it and reads the heads after."""
         emitted: list[StepEvent] = []
         span = self.profiler.span
         C = self.cfg.prefill_chunk
         while len(self._sched) and self._free_slots:
             with span("step.admit") as call:
                 with span("admit.host") as host:
-                    plan = self._plan_admission()
+                    plan = self._plan_admission(rides)
                     if plan is None:
                         break  # defer: nothing was popped, nothing is held
                     kind, batch, bucket, cached_len = plan
@@ -1922,46 +2016,90 @@ class Engine:
                         a_pad = 1
                         padded = -(-(plen - cached_len) // C) * C
                     launched = self._launched(head)
-                blocks = [None] * len(batch)
-                with span("admit.wait") as wait:
-                    if self._block:
-                        # No token comes of a block family's prefill: the
-                        # wait is for the prompts' expert sets alone.
-                        fetched = jax.device_get(head)
-                        toks = np.zeros(a_pad, np.int64)
-                    elif self._routes:
-                        # The prompts' expert sets come back whole in the
-                        # transfer that brings the first tokens.
-                        toks, fetched = jax.device_get(head)
-                        toks = np.asarray(toks).reshape(-1)
-                    else:
-                        toks = np.asarray(head).reshape(-1)
-                self.device_queue.waited(launched, "admit")
-                if self._routes:
-                    blocks = self._admission_routes(batch, cached_len, fetched)
-                with span("admit.host") as tail:
-                    for (
-                        (req, slot, _seq, plen, resumed, hashes), tok, block
-                    ) in zip(batch, toks, blocks):
-                        if not resumed:
-                            self._note_prefix_admission(
-                                req, slot, plen, cached_len, hashes
-                            )
-                        ev = self._finish_admission(
-                            req, slot, plen, int(tok), resumed, block
-                        )
-                        if ev is not None:
-                            emitted.append(ev)
+                    if rides:
+                        for req, slot, _seq, plen, _resumed, hashes in batch:
+                            self._seat(req, slot, plen, cached_len, hashes)
                 useful = sum(entry[3] for entry in batch) - cached_len
                 call.note(
                     kind=kind, bucket=bucket, batch=len(batch), a_pad=a_pad,
                     useful_tokens=useful, padded_tokens=padded, **queue,
                 )
-            self.admit_stats["calls"] += 1
-            self.admit_stats["useful_tokens"] += useful
-            self.admit_stats["padded_tokens"] += padded
-            self._timing.append(("admit_host", host.seconds + tail.seconds))
-            self._timing.append(("admit_wait", wait.seconds))
+                self.admit_stats["calls"] += 1
+                self.admit_stats["useful_tokens"] += useful
+                self.admit_stats["padded_tokens"] += padded
+                admission = _Admission(
+                    batch, head, launched, cached_len, host.seconds, rides
+                )
+                if rides:
+                    self._heads.append(admission)
+                else:
+                    emitted.extend(self._collect_head(admission))
+        return emitted
+
+    def _seat(
+        self, req: _Request, slot: int, plen: int, cached_len: int, hashes
+    ) -> None:
+        """Put a fresh admission on the next chunk before the host has
+        read its first token: everything the chunk's dispatch asks of the
+        host (the slot, `position = plen`, the pages `_grant` gave) is
+        known, the token itself is in the device's `state`.
+        `_finish_admission` does the rest once the token is read, also
+        where the page walk has evicted the request in between (it then
+        waits in the queue with its one token, to resume by recompute)."""
+        self._note_prefix_admission(req, slot, plen, cached_len, hashes)
+        req.position = plen
+        self._active[slot] = req
+
+    def _collect_heads(self) -> list[StepEvent]:
+        """Read every admission call still in `_heads`, in dispatch
+        order."""
+        heads, self._heads = self._heads, []
+        return [ev for adm in heads for ev in self._collect_head(adm)]
+
+    def _collect_head(self, admission: _Admission) -> list[StepEvent]:
+        """The host's second half of one admission call: `admit.wait` is
+        the time blocked on its first tokens and nothing else (with a
+        chunk dispatched behind the call, what is left of the prefill
+        once the chunk it rode behind is reaped), the `admit.host` after
+        it the bookkeeping of the admitted: first tokens into
+        `out_tokens`, stops, timings, the prompts' expert sets. Returns
+        the first tokens' events."""
+        span = self.profiler.span
+        batch, head, seated = admission.batch, admission.head, admission.seated
+        cached_len = admission.cached_len
+        emitted: list[StepEvent] = []
+        blocks = [None] * len(batch)
+        with span("admit.wait") as wait:
+            if self._block:
+                # No token comes of a block family's prefill: the
+                # wait is for the prompts' expert sets alone.
+                fetched = jax.device_get(head)
+                toks = np.zeros(len(batch), np.int64)
+            elif self._routes:
+                # The prompts' expert sets come back whole in the
+                # transfer that brings the first tokens.
+                toks, fetched = jax.device_get(head)
+                toks = np.asarray(toks).reshape(-1)
+            else:
+                toks = np.asarray(head).reshape(-1)
+        self.device_queue.waited(admission.launched, "admit")
+        if self._routes:
+            blocks = self._admission_routes(batch, cached_len, fetched)
+        with span("admit.host") as tail:
+            for (
+                (req, slot, _seq, plen, resumed, hashes), tok, block
+            ) in zip(batch, toks, blocks):
+                if not resumed and not seated:
+                    self._note_prefix_admission(
+                        req, slot, plen, cached_len, hashes
+                    )
+                ev = self._finish_admission(
+                    req, slot, plen, int(tok), resumed, block, seated
+                )
+                if ev is not None:
+                    emitted.append(ev)
+        self._timing.append(("admit_host", admission.host_s + tail.seconds))
+        self._timing.append(("admit_wait", wait.seconds))
         return emitted
 
     def _launched(self, out):
@@ -2069,7 +2207,54 @@ class Engine:
         self.route_stats["rows_sent"] += sum(len(b[1]) for b in blocks)
         return blocks
 
-    def _plan_admission(self):
+    def _peek_admission(self):
+        """What the head of the queue asks of an admission, nothing popped
+        or held: (req, seq, plen, resumed, hashes, hit, kind). `seq` is
+        what its prefill computes, `hit` the cached pages of its prefix,
+        `kind` the call it takes: "prefix" with a hit, "chunked" past one
+        prefill chunk, else "batch", the fused call."""
+        C = self.cfg.prefill_chunk
+        req = self._sched.peek()
+        resumed = bool(req.out_tokens)
+        # A resumed request's last token has no K and V yet (the next
+        # decode step writes them); a block family's tokens are served
+        # once their block's K and V are written, so all are held.
+        seq = req.prompt + (
+            req.out_tokens if self._block else req.out_tokens[:-1]
+        )
+        plen = len(seq)
+        hashes = None
+        hit = ()
+        if self._prefix_cache and not resumed:
+            # Memoized per request: a head-of-line admission
+            # deferred by OutOfPages would otherwise re-hash its
+            # whole prompt every engine step. (Safe across steps:
+            # adapter swaps refuse while a pending request
+            # references the slot, so the generation in the seed
+            # cannot change under a queued request.)
+            hashes = getattr(req, "_apc_hashes", None)
+            if hashes is None:
+                hashes = self._prefix_hashes(seq, req.adapter_idx)
+                req._apc_hashes = hashes
+            # Cap the hit twice over: at least the final token
+            # must compute (its logits seed the first sample),
+            # and cached_len + prefill_chunk must fit inside the
+            # staging buffer — a padded suffix chunk starting
+            # past max_seq_len - C would have its
+            # dynamic_update_slice start CLAMPED, silently
+            # writing KV at the wrong offset and then scattering
+            # it into shared pages.
+            cap = min(
+                (plen - 1) // self.cfg.page_size,
+                max(0, (self.cfg.max_seq_len - C) // self.cfg.page_size),
+            )
+            hit = self._alloc.lookup(hashes[:cap])
+        kind = (
+            "prefix" if hit else "chunked" if C > 0 and plen > C else "batch"
+        )
+        return req, seq, plen, resumed, hashes, hit, kind
+
+    def _plan_admission(self, fresh_only: bool = False):
         """Pop the next admission off the scheduler and grant its slots
         and pages. Returns (kind, entries, bucket, cached_len): "batch"
         is same-bucket prompts for one fused call, "chunked" one long
@@ -2078,7 +2263,9 @@ class Engine:
         are one-at-a-time: the staging buffer holds one sequence, so a
         batch under construction is flushed first and they are taken by
         the next call). None when nothing can be admitted now. An entry
-        is (req, slot, seq, plen, resumed, hashes)."""
+        is (req, slot, seq, plen, resumed, hashes). `fresh_only`: take
+        what may ride the device's queue (`_fresh_for_queue`) and leave
+        the first request that may not at the head of the queue."""
         C = self.cfg.prefill_chunk
         batch: list[
             tuple[_Request, int, list[int], int, bool, list[bytes] | None]
@@ -2089,48 +2276,17 @@ class Engine:
             and self._free_slots
             and len(batch) < max(1, self.cfg.max_admit_batch)
         ):
-            req = self._sched.peek()
-            resumed = bool(req.out_tokens)
-            # A resumed request's last token has no K and V yet (the next
-            # decode step writes them); a block family's tokens are served
-            # once their block's K and V are written, so all are held.
-            seq = req.prompt + (
-                req.out_tokens if self._block else req.out_tokens[:-1]
-            )
-            plen = len(seq)
-            hashes = None
-            hit = ()
-            if self._prefix_cache and not resumed:
-                # Memoized per request: a head-of-line admission
-                # deferred by OutOfPages would otherwise re-hash its
-                # whole prompt every engine step. (Safe across steps:
-                # adapter swaps refuse while a pending request
-                # references the slot, so the generation in the seed
-                # cannot change under a queued request.)
-                hashes = getattr(req, "_apc_hashes", None)
-                if hashes is None:
-                    hashes = self._prefix_hashes(seq, req.adapter_idx)
-                    req._apc_hashes = hashes
-                # Cap the hit twice over: at least the final token
-                # must compute (its logits seed the first sample),
-                # and cached_len + prefill_chunk must fit inside the
-                # staging buffer — a padded suffix chunk starting
-                # past max_seq_len - C would have its
-                # dynamic_update_slice start CLAMPED, silently
-                # writing KV at the wrong offset and then scattering
-                # it into shared pages.
-                cap = min(
-                    (plen - 1) // self.cfg.page_size,
-                    max(0, (self.cfg.max_seq_len - C) // self.cfg.page_size),
-                )
-                hit = self._alloc.lookup(hashes[:cap])
-            if hit or (C > 0 and plen > C):
+            peeked = self._peek_admission()
+            req, seq, plen, resumed, hashes, hit, kind = peeked
+            if fresh_only and not self._fresh_for_queue(peeked):
+                break
+            if kind != "batch":
                 if batch:
                     break
                 if self._grant(req, plen, hit) is None:
                     return None
                 return (
-                    "prefix" if hit else "chunked",
+                    kind,
                     [(req, req.slot, seq, plen, resumed, hashes)],
                     C,
                     len(hit) * self.cfg.page_size,
@@ -2419,11 +2575,16 @@ class Engine:
     def _finish_admission(
         self, req: _Request, slot: int, plen: int, tok: int,
         resumed: bool = False, block: tuple | None = None,
+        seated: bool = False,
     ) -> StepEvent | None:
         """`block`: the admission's expert sets for a request that asked,
-        `(first computed position, rows)`."""
+        `(first computed position, rows)`. `seated`: the request already
+        rides a chunk (`_seat`), or was evicted since and waits in the
+        queue; its slot is not touched here unless the token ends it."""
         if self._block:
-            return self._finish_block_admission(req, slot, plen, resumed, block)
+            return self._finish_block_admission(
+                req, slot, plen, resumed, block, seated
+            )
         if resumed:
             if req.done:  # finished/cancelled while pending: don't revive
                 self._release(req)
@@ -2454,7 +2615,7 @@ class Engine:
         finished = self._check_stop(req)
         if finished:
             self._release(req)
-        else:
+        elif not seated:
             self._active[slot] = req
         return StepEvent(
             req.rid, tok, finished, req.finish_reason,
@@ -2462,7 +2623,8 @@ class Engine:
         )
 
     def _finish_block_admission(
-        self, req: _Request, slot: int, plen: int, resumed: bool, block
+        self, req: _Request, slot: int, plen: int, resumed: bool, block,
+        seated: bool = False,
     ) -> None:
         """A block family's admission serves no token: the slot's first
         block is open on the device, and its tokens come of the next
@@ -2481,9 +2643,10 @@ class Engine:
             # The prompt's forward: its whole blocks' rows, nothing
             # committed.
             req.forward_backlog.append((0, block[1], (), ()))
-        req.position = plen
-        req.last_token = int(req.out_tokens[-1]) if req.out_tokens else 0
-        self._active[slot] = req
+        if not seated:
+            req.position = plen
+            req.last_token = int(req.out_tokens[-1]) if req.out_tokens else 0
+            self._active[slot] = req
         return None
 
     @staticmethod
@@ -3340,7 +3503,30 @@ class Engine:
         compute). Conservative barriers reap first wherever overlap
         could change tokens — see _reap_inflight_locked.
 
-        Returns a list of StepEvents in emission order.
+        Three kinds of step. With nothing waiting, the next chunk goes out
+        behind the chunk in flight, which is then reaped (`barrier=none`).
+        With a prompt waiting that `_admission_rides` (a chunk in flight,
+        a slot free now, a fresh prompt for the fused call at the head of
+        the queue): plan, stage and dispatch the prefill(s) behind the
+        chunk in flight, seat the admitted, grow pages, upload the block
+        table, dispatch the next chunk behind the prefill, reap the chunk
+        that was in flight as an ordinary reap, and only then read each
+        prefill's first tokens, in dispatch order. The device runs chunk,
+        prefill, chunk as it would have; the host no longer stands between
+        them. With a prompt waiting that does not ride (no slot free, a
+        resumed request or a `prefix` / `chunked` admission at the head, a
+        request its first token is known to end, a pool too short for the
+        head's pages now), the admission barrier: reap FIRST
+        (`barrier=admission`), then admit, each call's first tokens read
+        before anything else is dispatched, then the chunk. So does every
+        admission with no chunk in flight (an idle engine, a warm-up, the
+        synchronous loop, speculation). Nothing is configured: the engine's
+        own state decides.
+
+        Returns a list of StepEvents in emission order: what an
+        out-of-step barrier reaped, a barrier's chunk and its admissions'
+        first tokens, the reaped chunk's tokens, the first tokens of the
+        admissions that rode, and (synchronous loop) this step's chunk.
         """
         span = self.profiler.span
         book = self.device_queue
@@ -3365,19 +3551,32 @@ class Engine:
                 # handoff, prefix fetch) — deliver before this step's.
                 emitted.extend(self._pending_events)
                 self._pending_events.clear()
-            # ADMISSION BARRIER: a pending prompt's slot/page grant must
-            # observe the in-flight chunk's stop-driven slot frees (and a
-            # preempted request's re-prefill must see its full out_tokens),
-            # so reap before admitting. Also reap before any speculation
-            # window: prompt-lookup proposals read out_tokens.
-            if self._inflight is not None and (len(self._sched) or self._spec):
+            # ADMISSION BARRIER, taken where the host must see the chunk
+            # in flight before it admits: no slot is free (the reap's
+            # stop-driven frees are how one is found), or the head of the
+            # queue is a preempted request (its re-prefill must see its
+            # full out_tokens), takes the staged `prefix` / `chunked`
+            # calls, is known to end with its first token, or needs pages
+            # the pool cannot give yet. Also reap before any speculation
+            # window: prompt-lookup proposals read out_tokens. Otherwise
+            # the admission RIDES the device's queue (`_admission_rides`):
+            # the slot it needs was freed by an earlier reap, and the
+            # device runs chunk, prefill, next chunk in that order whether
+            # or not the host stands between them, each call's pools,
+            # tables and `state` being the previous call's outputs.
+            rides = self._admission_rides()
+            if (
+                self._inflight is not None
+                and (len(self._sched) or self._spec)
+                and not rides
+            ):
                 emitted.extend(
                     self._reap_inflight_locked(
                         "admission" if len(self._sched) else "spec"
                     )
                 )
             with span("step.prefill"):
-                emitted.extend(self._admit_pending())
+                emitted.extend(self._admit_pending(rides))
             prev = self._inflight
             self._inflight = None
             current = None
@@ -3408,8 +3607,13 @@ class Engine:
                     )
                 if self._bt_dirty:
                     with span("step.dispatch"):
+                        # A copy: the mirror is written in place while the
+                        # chunk that reads this upload is in flight (a
+                        # release, a grant behind it), and on the CPU an
+                        # aligned host array is handed over without one.
                         self.cache.block_tables = jax.device_put(
-                            jnp.asarray(self._bt_host), self._bt_sharding
+                            jnp.asarray(self._bt_host.copy()),
+                            self._bt_sharding,
                         )
                         self._bt_dirty = False
                 self.live_kv["pages_total"] += self.live_kv["pages"]
@@ -3523,6 +3727,15 @@ class Engine:
                         decode_mode, len(evs), time.perf_counter() - t0
                     )
             step_s = time.perf_counter() - t0
+            # The first tokens of the admissions that rode (none where
+            # `current` was reaped above: the loop that rides keeps its
+            # chunk in flight), read only now: behind the chunk that
+            # carries their rows and behind the reap of the chunk they
+            # rode behind, so after its events and a step before any token
+            # of theirs that a chunk decodes. No head outlives the step
+            # that dispatched it. Like the barrier's admissions, their
+            # wait is no part of `step_s`.
+            emitted.extend(self._collect_heads())
             # Feed the scheduler's drain-rate estimator: completed
             # requests per second of engine-step wall time. Deadline
             # feasibility and the computed Retry-After both divide queue

@@ -555,6 +555,11 @@ def worker_loop(engine: Engine) -> None:
     """WORKER processes (process_id > 0): receive descriptors forever,
     mirror host 0's ops and steps. Blocks inside the broadcast collective
     while host 0 is idle."""
+    # A worker replays host 0's synchronous loop step for step
+    # (`LockstepEngine` forces it there): a worker that kept a chunk in
+    # flight would admit behind it, and grant slots and pages from a state
+    # one reap behind host 0's.
+    engine._overlap = False
     logger.info("multihost worker loop running")
     while True:
         desc = _broadcast(_control_zeros(), is_source=False)

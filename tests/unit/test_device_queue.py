@@ -106,6 +106,59 @@ def test_run_ahead_with_no_barrier(book, finished_early):
                              if finished_early else {})}
 
 
+@pytest.mark.parametrize("outran", [False, True], ids=["busy", "drained"])
+def test_an_admission_that_rides_the_queue(book, outran):
+    """The order of a step whose admission rides: chunk N+1 in flight, a
+    prompt pending, a slot free. The prefill(s) and chunk N+2 go out behind
+    it (`busy`, `busy`), N+1 is then reaped and the first tokens read.
+    Neither wait is on the tail: a `waited(..., "admit")` on a head that is
+    not the tail does not mark the queue empty, no `after="admit"` second
+    is booked, and the next dispatch asks the tail as ever."""
+    b, clock = book
+    n1 = Tail()
+    b.dispatched(n1)
+    clock.t += 0.030
+    assert b.dispatching("prefill") == {"queue": "busy"}
+    head = Tail()
+    b.dispatched(head)
+    clock.t += 0.002
+    assert b.dispatching("prefill") == {"queue": "busy"}  # a second bucket
+    head2 = Tail()
+    b.dispatched(head2)
+    clock.t += 0.002
+    assert b.dispatching("decode") == {"queue": "busy"}
+    n2 = Tail()
+    b.dispatched(n2)
+    clock.t += 0.060
+    b.waited(n1, "reap_sync")
+    clock.t += 0.015
+    b.waited(head, "admit")
+    b.waited(head2, "admit")
+    clock.t += 0.003
+    n2.ready = outran
+    note = b.dispatching("decode")
+    assert note == ({"queue": "drained",
+                     "drained_bound_ms": pytest.approx(3.0)}
+                    if outran else {"queue": "busy"})
+    assert b.drain() == [] and b.end_step() == (0.0, [
+        "prefill:busy", "prefill:busy", "decode:busy",
+        "decode:drained" if outran else "decode:busy"])
+    # The barrier's order in the same book: the wait IS on the tail.
+    b.dispatched(n2)
+    b.waited(n2, "reap_admission")
+    clock.t += 0.002
+    assert b.dispatching("prefill") == {
+        "queue": "empty", "starved_ms": pytest.approx(2.0)}
+    head3 = Tail()
+    b.dispatched(head3)
+    b.waited(head3, "admit")
+    clock.t += 0.001
+    assert b.dispatching("decode") == {
+        "queue": "empty", "starved_ms": pytest.approx(1.0)}
+    assert [(a, c) for a, c, _ in b.drain()] == [
+        ("reap_admission", "prefill"), ("admit", "decode")]
+
+
 def test_admission_step_reap_then_prefill_then_decode(book):
     b, clock = book
     chunk = Tail()
@@ -214,9 +267,12 @@ def tiny():
 
 
 def _drive(eng, metrics, idle_s=0.0):
-    """A run with admissions: two prompts at the start, one more while the
-    first two decode (an admission barrier under overlap), a last one once
-    the engine has gone idle. Returns the seconds of the two drives."""
+    """A run with admissions: two prompts at the start, three more while
+    the first two decode (two find a slot free, and under overlap ride the
+    device's queue behind the chunk in flight; the third finds none, which
+    under overlap is an admission barrier on every step until the reap it
+    forces frees one), a last one once the engine has gone idle. Returns
+    the seconds of the two drives."""
     sp = SamplingParams(temperature=0.0, max_tokens=14)
     eng.add_request([1, 2, 3], sp)
     eng.add_request(list(range(4, 24)), sp)
@@ -227,7 +283,8 @@ def _drive(eng, metrics, idle_s=0.0):
         eng.step()
         steps += 1
         if steps == 2:
-            eng.add_request([7, 8, 9, 10], sp)
+            for prompt in ([7, 8, 9, 10], [11, 12, 13], [14, 15]):
+                eng.add_request(prompt, sp)
         metrics.sync_engine(eng)
     walls.append(time.perf_counter() - t0)
     eng.device_queue.idle()  # what the serve loop does when it finds no work
@@ -334,7 +391,11 @@ def test_what_emptied_the_queue_follows_the_loop(run):
         assert after == {"reap_sync", "admit"}
         assert _sum(m.dispatches, before="decode", queue="busy") == 0
     else:
-        # The third prompt forced a reap ahead of its admission.
+        # The prompt that found no slot free forced a reap ahead of every
+        # chunk, and at last ahead of its own admission. (The two that
+        # found one rode behind the chunk in flight: `busy` on a device
+        # slower than its host, which `test_step_overlap.py` pins; these
+        # tiny CPU programs often end first, and then read `drained`.)
         assert {"reap_admission", "admit"} <= after
         assert m.device_starved.get(after="reap_admission",
                                     before="prefill") >= 1

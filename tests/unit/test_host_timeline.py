@@ -310,7 +310,8 @@ def test_phase_totals_keep_their_labels_and_stay_inside_wall_time(tiny):
     reaps = rec.named("step.reap")
     assert reaps and {r["attrs"]["barrier"] for r in reaps} <= {
         "none", "admission", "seq_cap", "spec", "external"}
-    assert "admission" in {r["attrs"]["barrier"] for r in reaps}
+    # A slot was free whenever a prompt came: no reap was forced ahead.
+    assert {r["attrs"]["barrier"] for r in reaps} == {"none"}
     assert all(r["attrs"]["chunk"] == 4 and r["attrs"]["rows"] >= 0 for r in reaps)
     for name in ("step.overlap_idle", "step.readback", "step.sample"):
         assert {s["parent"] for s in rec.named(name)} == {"step.reap"}

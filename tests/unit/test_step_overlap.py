@@ -25,7 +25,7 @@ from kubeai_tpu.engine.multihost import LockstepEngine
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.engine.server import EngineServer
 from kubeai_tpu.engine.tokenizer import ByteTokenizer
-from kubeai_tpu.fleet.profiler import PHASES, phase_totals
+from kubeai_tpu.fleet.profiler import PHASES, DeviceQueueBook, phase_totals
 from kubeai_tpu.metrics.registry import parse_prometheus_text
 from kubeai_tpu.models import llama
 from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -305,7 +305,13 @@ def test_step_reaps_counter_by_barrier(tiny, monkeypatch):
     eng.step()  # nothing waiting: reaped behind the next dispatch
     assert eng.step_reaps["none"] == 1
     eng.add_request(PROMPTS[1], sp)
-    eng.step()  # a prompt waits: reaped first
+    eng.step()  # a prompt waits and a slot is free: it rides, no barrier
+    assert eng.step_reaps["admission"] == 0 and eng.step_reaps["none"] == 2
+    for prompt in (PROMPTS[2], PROMPTS[3], PROMPTS[1]):
+        eng.add_request(prompt, sp)
+    eng.step()  # two slots are free: two of the three ride
+    assert eng.step_reaps["admission"] == 0 and len(eng._sched) == 1
+    eng.step()  # a prompt waits and NO slot is free: reaped first
     assert eng.step_reaps["admission"] == 1
     assert eng._inflight is not None
     assert eng.cancel(r0)  # outside a step
@@ -336,6 +342,698 @@ def test_step_reaps_counter_by_barrier(tiny, monkeypatch):
     }
     assert on_wire == {k: float(v) for k, v in eng.step_reaps.items()}
     assert on_wire["none"] > by_span["none"]
+
+
+# ---- an admission rides the device's queue ------------------------------------
+
+
+class _Running:
+    @staticmethod
+    def is_ready():
+        return False
+
+
+class _SlowDevice(DeviceQueueBook):
+    """The book of a device slower than any host: what was dispatched last
+    is still running when the next dispatch asks. (The tiny CPU programs
+    end in microseconds, and `is_ready()` would say what the sandbox's
+    scheduler did.) A wait on the tail still observes the queue empty."""
+
+    def dispatching(self, before):
+        tail = self._tail
+        if tail is not None:
+            self._tail = _Running()
+        try:
+            return super().dispatching(before)
+        finally:
+            self._tail = tail
+
+
+class _CallLog:
+    """The order in which an engine launches its device programs and takes
+    its waits: `prefill` (the fused admission call), `staged` (a staged
+    admission's last chunk), `decode` (the chunk, or a speculation window),
+    `reap:<why>` (`_process_chunk`), `head` (an admission's first tokens
+    read), and `pages[` .. `]pages` around the walk that grows the slots'
+    pages."""
+
+    def __init__(self, eng):
+        self.calls = calls = []
+        self.evicted = []  # tokens a victim had when it was preempted
+        self.riders = []  # a dispatched chunk's rows, by their `max_tokens`
+
+        def logged(name, label):
+            real = getattr(eng, name, None)
+            if real is None:
+                return
+
+            def call(*a, **kw):
+                calls.append(label(*a, **kw) if callable(label) else label)
+                return real(*a, **kw)
+
+            setattr(eng, name, call)
+
+        logged("_prefill_admit_jit", "prefill")
+        logged("_stage_chunk_last_jit", "staged")
+        def chunk(*a, **kw):
+            self.riders.append(
+                [r.params.max_tokens for r in eng._active.values()])
+            return "decode"
+
+        logged("_decode_jit", chunk)
+        logged("_spec_jit", "decode")
+        logged("_collect_head", "head")
+        logged("_process_chunk",
+               lambda inflight, barrier="none": f"reap:{barrier}")
+        walk, preempt = eng._ensure_decode_pages, eng._preempt
+
+        def walking(*a, **kw):
+            calls.append("pages[")
+            try:
+                return walk(*a, **kw)
+            finally:
+                calls.append("]pages")
+
+        def preempting(victim):
+            self.evicted.append(len(victim.out_tokens))
+            return preempt(victim)
+
+        eng._ensure_decode_pages, eng._preempt = walking, preempting
+
+    def take(self, *, walk=False):
+        """The calls since the last take (without the page walk's marks
+        unless asked for)."""
+        out = [c for c in self.calls if walk or "pages" not in c]
+        self.calls.clear()
+        return out
+
+    def device(self):
+        return [c for c in self.calls if c in ("prefill", "staged", "decode")]
+
+
+def _step(eng, out=None):
+    evs = eng.step()
+    if out is not None:
+        _collect(out, evs)
+    return evs
+
+
+def _barrier_forced(eng):
+    """The same engine with the admission barrier taken wherever the
+    parent took it: no admission rides."""
+    eng._admission_rides = lambda: False
+    return eng
+
+
+def test_an_admission_rides_behind_the_chunk_in_flight(tiny):
+    """A chunk in flight, a prompt pending, a slot free: the prefill and
+    the next chunk are dispatched behind the chunk in flight with no wait
+    between, the chunk in flight is then reaped as an ordinary reap, and
+    the first token is read last: no `barrier="admission"` reap, the
+    prefill booked `queue="busy"`. The device sees what the barrier's and
+    the synchronous loop's device sees, in its order."""
+    engines = {"rides": _engine(tiny, "on"),
+               "barrier": _barrier_forced(_engine(tiny, "on")),
+               "off": _engine(tiny, "off")}
+    logs, streams = {}, {}
+    for name, eng in engines.items():
+        eng.device_queue = _SlowDevice()
+        logs[name] = log = _CallLog(eng)
+        r0 = eng.add_request(PROMPTS[0], GREEDY)
+        out = {r0: []}
+        _step(eng, out)
+        _step(eng, out)
+        assert (eng._inflight is not None) == (name != "off")
+        # An idle engine's first request is admitted as it always was.
+        assert log.take() == {
+            "off": ["prefill", "head", "decode", "reap:none",
+                    "decode", "reap:none"],
+        }.get(name, ["prefill", "head", "decode", "decode", "reap:none"])
+        r1 = eng.add_request(PROMPTS[1], GREEDY)
+        out[r1] = []
+        evs = _step(eng, out)
+        last = eng.profiler.recent()[-1]
+        if name == "rides":
+            assert log.take() == ["prefill", "decode", "reap:none", "head"]
+            # The chunk's tokens, then the admitted request's first.
+            assert [ev.rid for ev in evs] == [r0] * 4 + [r1]
+            assert last["dispatches"] == ["prefill:busy", "decode:busy"]
+            assert last["starved_s"] == 0.0
+            assert eng.step_reaps["admission"] == 0
+        elif name == "barrier":
+            assert log.take() == ["reap:admission", "prefill", "head", "decode"]
+            assert [ev.rid for ev in evs] == [r0] * 4 + [r1]
+            assert last["dispatches"] == ["prefill:empty", "decode:empty"]
+            assert eng.step_reaps["admission"] == 1
+        else:
+            assert log.take() == ["prefill", "head", "decode", "reap:none"]
+            assert [ev.rid for ev in evs] == [r1] + [r0, r1] * 4
+        while eng.has_work():
+            _step(eng, out)
+        streams[name] = [out[r0], out[r1]]
+        assert eng._heads == []
+    assert streams["rides"] == streams["barrier"] == streams["off"]
+    on = engines["rides"]
+    assert on.step_reaps["admission"] == 0 and on.step_reaps["none"] >= 6
+    assert on.device_queue.dispatches["prefill", "busy"] == 1
+    assert on.device_queue.dispatches["prefill", "empty"] == 0
+    # The idle engine's admission is all that was ever observed empty.
+    assert {(a, b) for a, b, _ in on.device_queue.drain()} == {
+        ("admit", "decode")}
+    # Only the run-ahead's surplus chunk at the end sets the loops apart.
+    device = {n: log.device() for n, log in logs.items()}
+    assert device["rides"] == device["barrier"]
+    assert device["rides"][:len(device["off"])] == device["off"]
+    assert len(device["rides"]) - len(device["off"]) in (0, 1)
+
+
+def _family_engines(name, **kw):
+    """Three engines of one tiny family (admissions ride, the barrier
+    forced, the synchronous loop), and what a request asks of it besides
+    tokens."""
+    from kubeai_tpu.models import mixtral
+    from tests.unit.test_engine_paged import _family_world
+
+    if name == "block":
+        family, cfg = "SDARMoeForCausalLM", mixtral.MixtralConfig.tiny_sdar()
+        params = mixtral.init_params(cfg, jax.random.PRNGKey(3))
+    else:
+        family, cfg, params = _family_world(
+            "gemma2" if name == "gemma" else name)
+    ask = {"mixtral": {"routes": True}, "block": {"forwards": True}}.get(
+        name, {})
+    rides, barrier, off = (
+        Engine(family, cfg, params, eos_token_ids=(), cfg=EngineConfig(**{
+            "num_slots": 4, "max_seq_len": 128, "page_size": 16,
+            "decode_chunk": 4, "step_overlap": overlap, **kw}))
+        for overlap in ("on", "on", "off")
+    )
+    return (rides, _barrier_forced(barrier), off), ask, cfg.vocab_size
+
+
+def _event_key(ev):
+    """An event as plain values: the token, how it ended, and what rode
+    on it (expert sets, a block family's forwards)."""
+    def plain(x):
+        if isinstance(x, (tuple, list)):
+            return tuple(plain(v) for v in x)
+        return x.tolist() if hasattr(x, "tolist") else x
+
+    return (ev.token, ev.finished, ev.finish_reason, plain(ev.routes),
+            plain(ev.forwards))
+
+
+def _closed_loop(eng, scripts, clients, ask):
+    """`clients` streams over `scripts` [(prompt, params)]: a stream sends
+    the next script only once its request's finish has reached it, as the
+    benchmark's closed loop does, so a prompt arrives while a chunk is in
+    flight. Per script, its events in order."""
+    todo = list(enumerate(scripts))
+    live, out = {}, {i: [] for i in range(len(scripts))}
+
+    def send():
+        if todo:
+            i, (prompt, sp) = todo.pop(0)
+            live[eng.add_request(prompt, sp, **ask)] = i
+
+    for _ in range(clients):
+        send()
+    while eng.has_work():
+        for ev in eng.step():
+            out[live[ev.rid]].append(_event_key(ev))
+            if ev.finished:
+                send()
+    return out
+
+
+@pytest.mark.parametrize("clients", [3, 6], ids=["slot-free", "slots-full"])
+@pytest.mark.parametrize("family", ["llama", "mixtral", "block", "gemma"])
+def test_closed_loop_equals_the_barrier_and_the_synchronous_engine(
+        family, clients):
+    """Requests that end and are replaced while a chunk is in flight,
+    greedy and seeded, some ended by a stop id and some by their first
+    token (a stop id, `max_tokens=1`): every request's events (token,
+    finish reason, the expert sets or forwards that rode on it) are those
+    of the same engine with the barrier forced and of the synchronous
+    engine, in their order. With three streams on four slots a slot is
+    free whenever a prompt comes, and no admission that rides forces a
+    reap; with six the queue is never empty and a prompt waits for a
+    slot."""
+    (on, barrier, off), ask, vocab = _family_engines(family)
+    rng = np.random.default_rng(41)
+    prompts = [rng.integers(1, vocab - 1, n).tolist()
+               for n in (5, 11, 3, 20, 7, 2, 14, 9, 4, 17, 6, 12)]
+    # A stop id that ends one request on its first token (and others where
+    # they come to it): the first token the third prompt is served.
+    [[stop]] = off.generate(
+        [prompts[2]], SamplingParams(temperature=0.0, max_tokens=1))
+    for eng in (on, barrier, off):
+        eng.eos_token_ids = (int(stop),)
+    scripts = []
+    for i, prompt in enumerate(prompts):
+        n = (1, 9, 24, 6, 13, 18)[i % 6]
+        scripts.append((prompt, SamplingParams(temperature=0.0, max_tokens=n)
+                        if i % 2 == 0 else SamplingParams(
+                            temperature=0.9, top_k=8, seed=100 + i,
+                            max_tokens=n)))
+    log = _CallLog(on)
+    got = _closed_loop(on, scripts, clients, ask)
+    want = _closed_loop(off, scripts, clients, ask)
+    assert got == want
+    assert _closed_loop(barrier, scripts, clients, ask) == want
+    reasons = {events[-1][2] for events in want.values()}
+    assert reasons == {"stop", "length"}
+    assert want[2] == [(int(stop), True, "stop") + want[2][0][3:]]
+    assert len(want[0]) == 1 and want[0][0][1:3] == (True, "length")
+    assert all(len(events) >= 1 and events[-1][1] for events in got.values())
+    # Some admissions rode (a head read after the chunk behind its call
+    # went out), and every call was read once.
+    calls = log.calls
+    assert calls.count("prefill") == calls.count("head") >= len(scripts) // 4
+    rode = sum(
+        next(x for x in calls[i + 1:] if x in ("head", "decode")) == "decode"
+        for i, c in enumerate(calls) if c == "prefill")
+    assert rode >= 2
+    # No row known to end with its first token was ever put on a chunk.
+    assert log.riders and all(
+        family == "block" or n > 1 for chunk in log.riders for n in chunk)
+    assert barrier.step_reaps["admission"] > on.step_reaps["admission"]
+    if clients == 3:
+        # Only the `max_tokens=1` requests kept the barrier.
+        assert on.step_reaps["admission"] <= (family != "block") * 2
+        assert on.step_reaps["none"] > len(scripts)
+    else:
+        assert on.step_reaps["admission"] >= 1
+    for eng in (on, barrier):
+        assert eng._heads == []
+        assert not eng._active and len(eng._free_slots) == 4
+        assert eng._alloc.free_pages == off._alloc.free_pages
+
+
+def test_a_first_token_that_is_a_stop_id_frees_the_slot(tiny):
+    """The request's row is already in the chunk that went out behind its
+    prefill when the host reads the stop id that ends it: slot and pages
+    are released there, the chunk's rows for it are dropped as a row that
+    stops mid-chunk is, and the next prompt takes the slot behind that
+    chunk and is served what the synchronous engine serves it."""
+    on, off = _engine(tiny, "on"), _engine(tiny, "off")
+    [[first]] = off.generate(
+        [PROMPTS[1]], SamplingParams(temperature=0.0, max_tokens=1))
+    [want0, want2] = off.generate([PROMPTS[0], PROMPTS[2]], GREEDY)
+    log = _CallLog(on)
+    r0 = on.add_request(PROMPTS[0], GREEDY)
+    out = {r0: []}
+    _collect(out, _step_until_inflight(on))
+    log.take()
+    # A request keeps the stop ids it was added under.
+    eos, on.eos_token_ids = on.eos_token_ids, (int(first),)
+    r1 = on.add_request(PROMPTS[1], GREEDY)
+    on.eos_token_ids = eos
+    slot, slot0 = on._free_slots[-1], on._requests[r0].slot
+    evs = _step(on, out)
+    assert log.take() == ["prefill", "decode", "reap:none", "head"]
+    assert evs[-1] == (r1, int(first), True, "stop", None, None)
+    # Released after the chunk that carries its row went out.
+    assert r1 not in on._requests and slot not in on._active
+    assert on._free_slots[-1] == slot and on._alloc.pages_for(slot) == []
+    assert (on._bt_host[slot] == -1).all()
+    assert sorted(s for s, _ in on._inflight[1]) == sorted([slot0, slot])
+    r2 = on.add_request(PROMPTS[2], GREEDY)
+    out[r2] = []
+    evs = _step(on, out)
+    assert on._active[slot].rid == r2  # the slot, behind the chunk in flight
+    assert log.take() == ["prefill", "decode", "reap:none", "head"]
+    assert {ev.rid for ev in evs} == {r0, r2}  # the chunk's r1 rows: dropped
+    while on.has_work():
+        assert r1 not in {ev.rid for ev in _step(on, out)}
+    assert [out[r0], out[r2]] == [want0, want2]
+    assert on.step_reaps["admission"] == 0
+
+
+def _fallback(tiny, case):
+    """An engine with a chunk in flight (where its loop keeps one), a slot
+    free, and at the head of its queue the request of `case`: (engine, log,
+    rid of the request, the synchronous engine's tokens for it)."""
+    kw, draft, resume, sp = {}, None, None, GREEDY
+    prompt = PROMPTS[2]
+    if case in ("prefix", "chunked"):
+        kw = {"prefill_chunk": 8, "prefix_cache": case == "prefix"}
+        prompt = list(range(40, 61))  # 21 tokens: past one chunk, one page
+    elif case in ("spec", "draft"):
+        kw = {"speculate": 2, "spec_adaptive": False}
+        draft = tiny if case == "draft" else None
+    elif case == "one-token":
+        sp = SamplingParams(temperature=0.0, max_tokens=1)
+    cfg, params = tiny
+
+    def build(overlap):
+        return Engine("llama", cfg, params, draft=draft, cfg=EngineConfig(
+            num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4,
+            step_overlap=overlap, **kw), eos_token_ids=TOK.eos_token_ids)
+
+    eng = build("off" if case == "off" else "on")
+    ref = eng if case == "off" else build("off")
+    if case == "prefix":
+        # A first request leaves the prompt's one full page in the cache.
+        for e in {eng, ref}:
+            e.generate([prompt[:17]], SamplingParams(
+                temperature=0.0, max_tokens=2))
+    [want] = ref.generate([prompt], sp)
+    if case == "resumed":
+        resume, want = want[:5], want[5:]
+    log = _CallLog(eng)
+    eng.add_request(PROMPTS[0], GREEDY)
+    eng.step()
+    eng.step()
+    log.take()
+    rid = eng.add_request(prompt, sp, resume_tokens=resume)
+    return eng, log, rid, want
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["resumed", "prefix", "chunked", "one-token", "spec", "draft", "off"])
+def test_each_fallback_keeps_the_old_order(tiny, case):
+    """Where the host must see the chunk in flight before it admits (a
+    preempted or resumed request, whose re-prefill reads its `out_tokens`;
+    the staged `prefix` and `chunked` calls), where the request is known
+    to end with its first token (it is never put on a chunk), and where
+    the loop keeps no chunk in flight (speculation, a draft model, the
+    synchronous loop), the step goes as it went: the reap first, then the
+    admission, its first token read BEFORE the chunk is dispatched."""
+    eng, log, rid, want = _fallback(tiny, case)
+    in_flight = case in ("resumed", "prefix", "chunked", "one-token")
+    assert (eng._inflight is not None) == in_flight and eng._free_slots
+    assert not eng._admission_rides()
+    out = {rid: []}
+    _step(eng, out)
+    calls = log.take()
+    if in_flight:
+        call = "prefill" if case in ("resumed", "one-token") else "staged"
+        assert calls == ["reap:admission", call, "head", "decode"]
+        assert eng.step_reaps["admission"] == 1
+        assert (eng.prefix_stats["hit_tokens"] > 0) == (case == "prefix")
+        assert eng.profiler.recent()[-1]["dispatches"][0] in (
+            "prefill:empty", "prefill:drained")
+        if case == "one-token":
+            assert rid not in eng._requests
+            assert all(n > 1 for n in log.riders[-1])
+    else:
+        assert calls == ["prefill", "head", "decode", "reap:none"]
+        assert eng.step_reaps["admission"] == 0
+    while eng.has_work():
+        _step(eng, out)
+    assert out[rid] == want
+    assert eng._heads == []
+
+
+def test_what_does_not_ride_waits_at_the_head_of_the_queue(tiny):
+    """Behind a prompt that rides, a request that may not (here one that
+    its first token ends) stays at the head of the queue: the next step
+    takes the barrier for it. Nothing is dropped or reordered."""
+    on, off = _engine(tiny, "on"), _engine(tiny, "off")
+    one = SamplingParams(temperature=0.0, max_tokens=1)
+    want = [off.generate([PROMPTS[0]], GREEDY)[0],
+            off.generate([PROMPTS[1]], GREEDY)[0],
+            off.generate([PROMPTS[3]], one)[0],
+            off.generate([PROMPTS[2]], GREEDY)[0]]
+    log = _CallLog(on)
+    rids = [on.add_request(PROMPTS[0], GREEDY)]
+    out = {rids[0]: []}
+    _collect(out, _step_until_inflight(on))
+    log.take()
+    rids += [on.add_request(PROMPTS[1], GREEDY), on.add_request(PROMPTS[3], one),
+             on.add_request(PROMPTS[2], GREEDY)]
+    out.update({r: [] for r in rids[1:]})
+    _step(on, out)
+    assert log.take() == ["prefill", "decode", "reap:none", "head"]
+    assert [r.rid for r in (on._sched.peek(),)] == [rids[2]]
+    assert len(on._sched) == 2 and on.step_reaps["admission"] == 0
+    _step(on, out)
+    # The barrier: the one-token request, then the prompt behind it (its
+    # bucket's own call), each read before the chunk.
+    taken = log.take()
+    assert taken[0] == "reap:admission" and taken[-1] == "decode"
+    assert taken.count("prefill") == taken.count("head") >= 1
+    assert len(on._sched) == 0 and on.step_reaps["admission"] == 1
+    while on.has_work():
+        _step(on, out)
+    assert [out[r] for r in rids] == want
+
+
+def test_no_slot_free_keeps_the_barrier_as_it_was(tiny):
+    """A pending prompt with no slot free is what the admission barrier is
+    still for: the reap is how a slot is found, and with the device empty
+    behind that reap there is nothing to hide the prefill behind: its
+    first token is read before the chunk goes out, as ever."""
+    cfg, params = tiny
+    eng = Engine("llama", cfg, params, cfg=EngineConfig(
+        num_slots=2, max_seq_len=128, page_size=16, decode_chunk=4),
+        eos_token_ids=TOK.eos_token_ids)
+    eng.device_queue = _SlowDevice()
+    log = _CallLog(eng)
+    short = SamplingParams(temperature=0.0, max_tokens=6)
+    rids = [eng.add_request(PROMPTS[0], short),
+            eng.add_request(PROMPTS[1], GREEDY)]
+    out = {r: [] for r in rids}
+    _collect(out, _step_until_inflight(eng))
+    log.take()
+    rids.append(eng.add_request(PROMPTS[3], GREEDY))
+    out[rids[2]] = []
+    waited = []
+    while len(eng._sched):
+        assert not eng._admission_rides()
+        _step(eng, out)
+        waited.append(log.take())
+    # No slot: reaped ahead, nothing admitted. Then the first request's
+    # last token frees its slot in a reap that was forced ahead, and the
+    # same step admits.
+    assert waited[:-1] == [["reap:admission", "decode"]] * (len(waited) - 1)
+    assert waited[-1] == ["reap:admission", "prefill", "head", "decode"]
+    assert eng.step_reaps["admission"] == len(waited) >= 2
+    assert eng.profiler.recent()[-1]["dispatches"] == [
+        "prefill:empty", "decode:empty"]
+    assert {(a, b) for a, b, _ in eng.device_queue.drain()} >= {
+        ("reap_admission", "decode"), ("reap_admission", "prefill")}
+    while eng.has_work():
+        _step(eng, out)
+    ref = _engine(tiny, "off", num_slots=2)
+    assert [out[r] for r in rids] == [
+        ref.generate([PROMPTS[0]], short)[0],
+        *ref.generate([PROMPTS[1], PROMPTS[3]], GREEDY)]
+
+
+def test_a_pool_short_of_the_heads_pages_takes_the_barrier(tiny):
+    """A slot is free and the head is a fresh prompt, but the pool cannot
+    give its pages until the chunk in flight has given some back: nothing
+    is popped behind the chunk, and the step takes the barrier as ever."""
+    kw = dict(num_slots=2, max_seq_len=64, num_pages=1 + 4)
+    on, off = _engine(tiny, "on", **kw), _engine(tiny, "off", **kw)
+    rng = np.random.default_rng(11)
+    first, late = (rng.integers(1, TOK.vocab_size - 1, n).tolist()
+                   for n in (40, 20))
+    # Ends inside the chunk in flight when `late` comes: 40 + 7 tokens.
+    sp0 = SamplingParams(temperature=0.0, max_tokens=7)
+    want = [off.generate([first], sp0)[0], off.generate([late], GREEDY)[0]]
+    log = _CallLog(on)
+    r0 = on.add_request(first, sp0)
+    out = {r0: []}
+    _collect(out, _step_until_inflight(on))
+    _step(on, out)
+    log.take()
+    r1 = on.add_request(late, GREEDY)
+    out[r1] = []
+    assert on._free_slots and on._alloc.free_pages < 2
+    assert not on._admission_rides()
+    _step(on, out)
+    assert log.take()[:3] == ["reap:admission", "prefill", "head"]
+    assert on.step_reaps["admission"] == 1
+    while on.has_work():
+        _step(on, out)
+    assert [out[r0], out[r1]] == want
+
+
+def test_a_short_pool_may_evict_a_request_before_its_first_token_is_read(tiny):
+    """The walk that grows the slots' pages evicts the youngest request
+    when the pool runs short, and the youngest is the one this step has
+    just seated, whose first token is still on the device. It then waits
+    in the queue, is handed its first token where the others are, and
+    resumes from it by recompute behind the barrier. Over every step at
+    which the second prompt can arrive while the first one decodes, every
+    token is the synchronous engine's."""
+    kw = dict(num_slots=2, max_seq_len=64, num_pages=1 + 5)
+    on, off = _engine(tiny, "on", **kw), _engine(tiny, "off", **kw)
+    rng = np.random.default_rng(7)
+    long, late = (rng.integers(1, TOK.vocab_size - 1, n).tolist()
+                  for n in (30, 20))
+    sp = SamplingParams(temperature=0.0, max_tokens=30)
+    want = [off.generate([p], sp)[0] for p in (long, late)]
+    log = _CallLog(on)
+    rode = 0
+    for arrives in range(1, 8):
+        r0 = on.add_request(long, sp)
+        out = {r0: []}
+        for _ in range(arrives):
+            _step(on, out)
+        log.take()
+        r1 = on.add_request(late, sp)
+        out[r1] = []
+        _step(on, out)
+        seat = log.take()
+        rode += seat[:2] == ["prefill", "decode"]
+        while on.has_work():
+            _step(on, out)
+        assert [out[r0], out[r1]] == want, arrives
+        assert on._heads == [] and not on._active
+    assert rode >= 1 and log.evicted, (rode, log.evicted)
+    # A victim with no token yet was one this PR seats; it lost nothing.
+    assert min(log.evicted) == 0
+
+
+@pytest.mark.parametrize("family", ["llama", "mixtral"])
+def test_a_prefix_cache_miss_rides_and_a_hit_keeps_the_barrier(family):
+    """With the prefix cache on, a prompt that hits nothing takes the
+    fused call and rides; its full pages are published when it is seated,
+    so that a later prompt that shares them is a hit, which keeps the
+    barrier with its staged calls. Tokens are the synchronous engine's."""
+    kw = dict(prefill_chunk=32, prefix_cache=True)
+    (on, barrier, off), ask, vocab = _family_engines(family, **kw)
+    rng = np.random.default_rng(5)
+    shared = rng.integers(1, vocab - 1, 20).tolist()
+    scripts = [(p, GREEDY) for p in (
+        rng.integers(1, vocab - 1, 9).tolist(), shared + [3, 4],
+        rng.integers(1, vocab - 1, 5).tolist(), shared + [5],
+        shared[:17] + [6, 7, 8], rng.integers(1, vocab - 1, 12).tolist())]
+    log = _CallLog(on)
+    got = _closed_loop(on, scripts, 2, ask)
+    assert got == _closed_loop(off, scripts, 2, ask)
+    assert got == _closed_loop(barrier, scripts, 2, ask)
+    assert on.prefix_stats["hit_tokens"] == off.prefix_stats["hit_tokens"] > 0
+    calls = log.calls
+    nxt = [next(x for x in calls[i + 1:] if x in ("head", "decode"))
+           for i, c in enumerate(calls) if c in ("prefill", "staged")]
+    by_call = {c: {n for cc, n in zip(
+        [c for c in calls if c in ("prefill", "staged")], nxt) if cc == c}
+        for c in ("prefill", "staged")}
+    assert "decode" in by_call["prefill"] and by_call["staged"] == {"head"}
+    assert 1 <= on.step_reaps["admission"] <= barrier.step_reaps["admission"]
+
+
+# ---- the set-up guard: a warm-up pays nothing ---------------------------------
+
+
+def test_one_token_batches_on_an_idle_engine_dispatch_no_chunk(tiny):
+    """What a warm-up does for each (bucket, admit batch) shape: batches of
+    `max_tokens=1` requests on an idle engine, drained. Each is one
+    prefill call and nothing else: no chunk is dispatched, none reaped."""
+    eng = _engine(tiny, "on")
+    log = _CallLog(eng)
+    one = SamplingParams(temperature=0.0, max_tokens=1)
+    for batch in (1, 2, 4, 3):
+        for i in range(batch):
+            eng.add_request(PROMPTS[i], one)
+        while eng.has_work():
+            eng.step()
+    assert eng._steps == 0 and set(eng.step_reaps.values()) == {0}
+    assert set(log.device()) == {"prefill"} and not log.riders
+    assert eng._inflight is None and eng._heads == []
+    assert eng.device_queue.dispatches["decode", "busy"] == 0
+
+
+@pytest.mark.parametrize("family", ["llama", "block"])
+def test_the_benchmarks_warm_up_runs_the_programs_it_ran(family):
+    """`perf/run.py:warm_up` on the tiny engine: the same sequence of
+    `_prefill_admit_jit` / `_decode_jit` calls as with the barrier forced
+    wherever it used to be taken, and one run of the chunk's loop for a
+    family whose admission serves a token."""
+    from perf import run, traffic
+
+    (on, barrier, _off), _ask, vocab = _family_engines(
+        family, max_admit_batch=4)
+    mix = traffic.load_mix("tiny-closed")
+    logs = {}
+    for name, eng in (("on", on), ("barrier", barrier)):
+        logs[name] = log = _CallLog(eng)
+        sent = run.warm_up(eng, mix, vocab)
+        shapes = run.warm_shapes(eng, mix)
+        assert sent == sum(b for _, b in shapes) + eng.cfg.num_slots
+        assert eng._heads == [] and not eng.has_work()
+        device = log.device()
+        if family == "llama":
+            # One prefill a shape and no chunk, then the chunk's own run.
+            assert device[:len(shapes)] == ["prefill"] * len(shapes)
+            assert "decode" not in device[:len(shapes)]
+    assert logs["on"].calls == logs["barrier"].calls
+    assert on._steps == barrier._steps
+    assert on.step_reaps == barrier.step_reaps
+    assert on.step_reaps["admission"] == 0
+
+
+def _leaf():
+    a = b = c = None
+    return 1
+
+
+def _hot(depth):
+    """A loop of calls at the bottom of `depth` frames."""
+    if depth:
+        return _hot(depth - 1)
+    n = 0
+    for _ in range(2000):
+        n += _leaf()
+    return n
+
+
+def _under(pad, f):
+    if pad:
+        return _under(pad - 1, f)
+    return f()
+
+
+def _faults(f):
+    import resource
+
+    before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+    f()
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt - before
+
+
+def test_a_jitted_call_is_entered_from_a_chunk_of_its_own(tiny):
+    """What a warm-up's seconds hung on besides its programs: CPython gives
+    a 16 KiB chunk of its frame stack back when the frame that opened it
+    returns, so a loop whose callees straddle a chunk's end maps and faults
+    one in on every call, and which loops do is decided by the depth they
+    are called at. `Engine.jit` enters the jitted function from a frame
+    that starts a chunk of its own with room below it: the page faults of
+    what runs under it no longer depend on the caller's depth."""
+    from kubeai_tpu.engine import engine as engine_mod
+
+    own = engine_mod._from_its_own_chunk
+    assert own(lambda a, b: a + b, (1, 2)) == 3
+    assert own.__code__.co_stacksize * 8 > 64 * 1024 and own.__doc__
+    depths = range(0, 160)  # frames of some 16 words: over a whole chunk
+    straddling = [_faults(lambda: _under(p, lambda: _hot(5))) for p in depths]
+    if max(straddling) < 1000:
+        pytest.skip("this interpreter's frame stack does not thrash")
+    # At some depth every one of the 2000 calls faulted a chunk in.
+    assert max(straddling) >= 2000
+    entered = [_faults(lambda: _under(p, lambda: own(_hot, (5,))))
+               for p in depths]
+    assert max(entered) < 50, max(entered)
+    # The engine's jitted calls go through it.
+    eng = _engine(tiny, "on")
+    seen = []
+
+    def spy(fn, args):
+        seen.append(fn)
+        return own(fn, args)
+
+    engine_mod._from_its_own_chunk, real = spy, own
+    try:
+        eng.generate([PROMPTS[0]], SamplingParams(temperature=0.0, max_tokens=6))
+    finally:
+        engine_mod._from_its_own_chunk = real
+    assert len(seen) >= 3  # the prefill and the chunks
 
 
 # ---- phase vocabulary --------------------------------------------------------
