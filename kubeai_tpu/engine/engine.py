@@ -469,6 +469,8 @@ class Engine:
         )
         if self._block is not None:
             self._check_block_engine(draft)
+        if self._recurrent is not None:
+            self._check_state_engine(draft)
         if self._kv_quant and (cfg.speculate > 0 or draft is not None):
             raise ValueError(
                 "kv_dtype='int8' does not compose with speculative "
@@ -640,7 +642,7 @@ class Engine:
         # Born sharded: each device allocates only its own part of
         # the pool.
         self.cache = PagedKVCache.create(
-            model_cfg.num_layers,
+            self._page_layers,
             n_pages,
             cfg.page_size,
             cfg.num_slots,
@@ -650,6 +652,8 @@ class Engine:
             dtype="int8" if self._kv_quant else cfg.cache_dtype,
             pool_sharding=pool_sharding,
             table_sharding=self._bt_sharding,
+            state=self._recurrent,
+            state_sharding=self._state_sharding,
         )
         self._alloc = PageAllocator(
             n_pages, cfg.page_size, max_pages_per_slot=max_pages
@@ -697,7 +701,7 @@ class Engine:
                 self.mesh, (None, None, psh.KV_HEADS, None), cache_rules
             )
             stage_shape = (
-                model_cfg.num_layers,
+                self._page_layers,
                 cfg.max_seq_len,
                 model_cfg.num_kv_heads,
                 model_cfg.head_size,
@@ -849,6 +853,12 @@ class Engine:
                 "routed_layers": int(layers),
                 "routes": self._routes and not self.routes_unsupported,
             }
+            if self.family.held_experts is not None:
+                # The share of the router's experts this engine holds,
+                # global ids [first, end).
+                self.moe["held"] = [
+                    int(e) for e in self.family.held_experts(model_cfg)
+                ]
         # Cumulative, plain host values (EngineMetrics folds the deltas
         # in): token-layer assignments per global expert id, rows whose
         # tokens were kept by the forward that computed them, rows handed
@@ -865,7 +875,13 @@ class Engine:
             "touched_decode": 0,
             "passes_prefill": 0,
             "passes_decode": 0,
+            # Kept assignments that fell on experts held here and on experts
+            # of another share (all are held where the family has no share).
+            "assigned_held": 0,
+            "assigned_absent": 0,
         }
+        # Slots whose state pools an admission wrote (cumulative).
+        self.state_stats = {"admissions": 0}
         # A family that generates by blocks (cumulative, like route_stats):
         # forwards of one slot's block by kind, the tokens they committed
         # (both over the blocks that served a token), forwards of the model
@@ -907,6 +923,86 @@ class Engine:
         are the same whether or not anybody asks; a dense family's are
         what they were."""
         return self.family.routes and self._pp == 1 and not self._spec
+
+    # ---- state beside the pages --------------------------------------------------
+
+    @functools.cached_property
+    def _recurrent(self) -> dict | None:
+        """What a slot owns beside its pages, as the family says it
+        (`ModelFamily.recurrent_state`), None for a family all of whose
+        layers keep keys and values. Derived, not set."""
+        fn = self.family.recurrent_state
+        return fn(self.model_cfg) if fn else None
+
+    @property
+    def _page_layers(self) -> int:
+        """Layers the page pool is stacked over: those that own pages."""
+        rec = self._recurrent
+        return rec["page_layers"] if rec else self.model_cfg.num_layers
+
+    @property
+    def _state_sharding(self):
+        """Where the state pools live, born and returned: whole on every
+        device (a tp axis is refused). One sharding from creation on, so a
+        program meets the pools it was compiled for."""
+        return jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec()
+        )
+
+    def _state_pools(self) -> tuple:
+        """The state pools as the compiled programs take them: one more
+        donated argument after `lora`, none for a family without."""
+        return (self.cache.state,) if self._recurrent else ()
+
+    def _check_state_engine(self, draft) -> None:
+        """What a family with state beside its pages is not served with:
+        each would need a snapshot of a slot's state at a position other
+        than its last, which nothing writes yet. Preemption by recompute
+        needs none (the re-admission rebuilds the state from position 0)."""
+        cfg = self.cfg
+        refused = [
+            name for name, on in (
+                ("prefix_cache", cfg.prefix_cache),
+                ("prefill_chunk", cfg.prefill_chunk > 0),
+                ("speculate", cfg.speculate > 0 or draft is not None),
+                ("kv_dtype int8", self._kv_quant),
+                ("max_adapters", cfg.max_adapters > 0),
+                ("a pp mesh axis", self.mesh.shape.get("pp", 1) > 1),
+                ("a tp mesh axis", self.mesh.shape.get("tp", 1) > 1),
+                ("decode_kernel per_layer", self.decode_kernel != "fused"),
+            ) if on
+        ]
+        if refused:
+            self.refuse_state_snapshot(", ".join(refused))
+
+    def refuse_state_snapshot(self, what: str) -> None:
+        """Raise for `what` where it would need a snapshot of a slot's
+        state, or move a slot's keys and values without the state that
+        belongs to them (hand-off, pages served to or fetched from a peer,
+        spill). `_check_state_engine` asks it of the engine's options, the
+        server of its own, at construction."""
+        if self._recurrent is not None:
+            raise ValueError(
+                f"family {self.family.name} keeps recurrent state beside its "
+                f"pages and is not served with: {what}"
+            )
+
+    @functools.cached_property
+    def state_info(self) -> dict | None:
+        """What /v1/state says of the state beside the pages (None for a
+        family without)."""
+        rec = self._recurrent
+        if rec is None:
+            return None
+        pool_bytes = self.cache.state_nbytes()  # fixed when the engine is built
+        return {
+            "state_layers": int(rec["state_layers"]),
+            "page_layers": int(rec["page_layers"]),
+            "bytes_per_slot": {
+                name: n // self.cfg.num_slots for name, n in pool_bytes.items()
+            },
+            "pool_bytes": pool_bytes,
+        }
 
     # ---- generation by blocks --------------------------------------------------
 
@@ -1017,6 +1113,13 @@ class Engine:
         # reads back. A dense family's programs are what they were.
         routed = self._routes
         route_kw = {"routes": True} if routed else {}
+        # A family with state beside its pages: its programs take the state
+        # pools as one more donated argument and return them, its prefill
+        # returns the rows an admission writes after k and v, its decode
+        # step the pools after the pages. Every other family's programs
+        # are what they were, argument for argument.
+        stateful = self._recurrent is not None
+        state_kw = {"state": True} if stateful else {}
         if self._pp > 1:
             from functools import partial as _partial
 
@@ -1034,7 +1137,8 @@ class Engine:
             )
 
         def _prefill_admit(
-            params, tokens, ints, floats, bt_rows, kp, vp, bt, state, lora
+            params, tokens, ints, floats, bt_rows, kp, vp, bt, state, lora,
+            *pools,
         ):
             """BATCHED admission: prefill [A, S] prompts → page scatter →
             first-token sample → state update, ONE device call for up to
@@ -1058,12 +1162,24 @@ class Engine:
             temp, topp = floats[:, 0], floats[:, 1]
             if lora is None:
                 logits, k_all, v_all, *routes = prefill_fn(
-                    params, mcfg, tokens, lengths, **route_kw
+                    params, mcfg, tokens, lengths, **state_kw, **route_kw
                 )
             else:
                 logits, k_all, v_all, *routes = prefill_fn(
                     params, mcfg, tokens, lengths,
                     lora=lora, lora_idx=adapters, **route_kw,
+                )
+            if stateful:
+                # The slot's state is overwritten whole, never added to;
+                # a padding row's slot is out of range and dropped.
+                rows = routes.pop(0)
+                pools = (
+                    {
+                        name: pool.at[:, slots].set(
+                            rows[name].astype(pool.dtype), mode="drop"
+                        )
+                        for name, pool in pools[0].items()
+                    },
                 )
             # Per-row page coordinates: [A, S] ids/offsets; padded tails
             # (and padding rows) land in reserved scratch page 0.
@@ -1093,17 +1209,17 @@ class Engine:
             # Routed: the prompts' expert sets [A, S, routed layers, k]
             # ride with the first tokens the admission blocks on.
             head = (toks, routes[0]) if routed else toks
-            return head, kp, vp, bt, state
+            return (head, kp, vp, bt, state, *pools)
 
         self._prefill_admit_jit = self.jit(
             _prefill_admit,
-            donate_argnums=(5, 6),
+            donate_argnums=(5, 6) + ((10,) if stateful else ()),
             out_shardings=(
                 None, pool_sharding, pool_sharding, self._bt_sharding, None,
-            ),
+            ) + ((self._state_sharding,) if stateful else ()),
         )
 
-        def _decode_chunk(params, kp, vp, bt, state, lora):
+        def _decode_chunk(params, kp, vp, bt, state, lora, *pools):
             """`chunk` paged decode steps fused via lax.scan. The block
             tables are read-only here — page growth happens host-side
             between chunks (the host ensures pages cover position+chunk
@@ -1112,8 +1228,14 @@ class Engine:
             topk, topp = state["topk"], state["topp"]
 
             def body(carry, _):
-                tokens, positions, kp, vp = carry
-                if lora is None:
+                tokens, positions, kp, vp, *pools = carry
+                if stateful:
+                    logits, kp, vp, *routes = decode_paged(
+                        params, mcfg, tokens, positions, kp, vp, bt,
+                        state=pools[0],
+                    )
+                    pools = [routes.pop(0)]
+                elif lora is None:
                     logits, kp, vp, *routes = decode_paged(
                         params, mcfg, tokens, positions, kp, vp, bt
                     )
@@ -1127,21 +1249,22 @@ class Engine:
                 # Routed: (tokens [B], expert sets [B, routed layers, k])
                 # of each step, stacked over the chunk by the scan.
                 out = (toks, routes[0]) if routed else toks
-                return (toks, next_pos, kp, vp), out
+                return (toks, next_pos, kp, vp, *pools), out
 
-            (tokens, positions, kp, vp), toks_seq = jax.lax.scan(
+            (tokens, positions, kp, vp, *pools), toks_seq = jax.lax.scan(
                 body,
-                (state["tokens"], state["positions"], kp, vp),
+                (state["tokens"], state["positions"], kp, vp, *pools),
                 None,
                 length=chunk,
             )
             state = dict(state, tokens=tokens, positions=positions)
-            return toks_seq, kp, vp, state
+            return (toks_seq, kp, vp, state, *pools)
 
         self._decode_jit = self.jit(
             _decode_chunk,
-            donate_argnums=(1, 2),
-            out_shardings=(None, pool_sharding, pool_sharding, None),
+            donate_argnums=(1, 2) + ((6,) if stateful else ()),
+            out_shardings=(None, pool_sharding, pool_sharding, None)
+            + ((self._state_sharding,) if stateful else ()),
         )
 
         from kubeai_tpu.ops.paged_attention import (
@@ -2191,8 +2314,14 @@ class Engine:
         per_forward = np.bincount(forward, minlength=n_forwards)
         live = per_forward > 0
         # Experts that hold a row, summed over (pass, routed layer): what a
-        # sparsely computed expert layer reads.
-        self.route_stats["touched_" + kind] += int((counts[live] > 0).sum())
+        # sparsely computed expert layer reads. An engine that holds a
+        # share of the experts reads its own only.
+        first, end = self.moe.get("held", (0, experts))
+        held = counts[live][..., first:end]
+        assigned = int(held.sum())
+        self.route_stats["touched_" + kind] += int((held > 0).sum())
+        self.route_stats["assigned_held"] += assigned
+        self.route_stats["assigned_absent"] += n * layers * k - assigned
         self.route_stats["passes_" + kind] += int(live.sum()) * layers
         ratio = counts[live].max(-1) * experts / (per_forward[live, None] * k)
         self._timing.extend(
@@ -2547,6 +2676,7 @@ class Engine:
             self.cache.v_pages,
             self.cache.block_tables,
             self._state,
+            *pools,
         ) = self._prefill_admit_jit(
             self.params,
             jnp.asarray(tokens),
@@ -2558,7 +2688,11 @@ class Engine:
             self.cache.block_tables,
             self._state,
             self._lora,
+            *self._state_pools(),
         )
+        if pools:
+            (self.cache.state,) = pools
+            self.state_stats["admissions"] += A
         if self._draft:
             self._dk, self._dv = self._draft_admit_jit(
                 self._draft_params,
@@ -2864,6 +2998,8 @@ class Engine:
             "capacity_factor": factor,
             "slot_capacity": int(self.cfg.num_slots),
             "kv_layout": self.kv_layout,
+            # Layers the pool is stacked over: those that own pages.
+            "page_layers": int(self._page_layers),
             "num_pages": int(self._n_pages),
             "page_size": int(self.cfg.page_size),
             "token_capacity": int((self._n_pages - 1) * self.cfg.page_size),
@@ -2893,6 +3029,7 @@ class Engine:
         Raises EngineBusy when no slot/pages are free right now (the
         server sheds 429 and the router re-picks) and EngineDraining once
         drain has begun."""
+        self.refuse_state_snapshot("disaggregated hand-off (export)")
         from kubeai_tpu.disagg.handoff import KVHandoff
         from kubeai_tpu.engine.paged_cache import OutOfPages
 
@@ -3022,6 +3159,7 @@ class Engine:
         hold bit-identical KV bytes, the slot state resumes the same
         seeded sampler at the same position, and decode runs the same
         compiled graph."""
+        self.refuse_state_snapshot("disaggregated hand-off (import)")
         from kubeai_tpu.disagg.handoff import HandoffError
 
         mcfg = self.model_cfg
@@ -3256,6 +3394,7 @@ class Engine:
         snapshot; an empty export means "hold nothing of that chain".
         Base-model chains only — per-replica LoRA slot seeds make adapter
         chains incomparable across replicas."""
+        self.refuse_state_snapshot("prefix pages served to a peer")
         from kubeai_tpu.disagg.handoff import KVPageExport
 
         if not self._prefix_cache:
@@ -3319,6 +3458,7 @@ class Engine:
         token-identity with the no-sharing baseline. Returns the number of
         pages actually seeded (0 when the pool refuses or everything was
         already held)."""
+        self.refuse_state_snapshot("prefix pages fetched from a peer or a spill store")
         from kubeai_tpu.disagg.handoff import HandoffError
 
         if not self._prefix_cache:
@@ -3426,6 +3566,7 @@ class Engine:
         for an evicted hot prefix can FILL from the store instead of
         recomputing. The hook runs under the engine lock on the eviction
         path and must never raise (the allocator also guards it)."""
+        self.refuse_state_snapshot("a KV spill store")
         from kubeai_tpu.disagg.handoff import KVPageExport, serialize_pages
 
         def _spill(page: int, h: bytes) -> None:
@@ -3675,6 +3816,7 @@ class Engine:
                             self.cache.k_pages,
                             self.cache.v_pages,
                             self._state,
+                            *pools,
                         ) = self._decode_jit(
                             self.params,
                             self.cache.k_pages,
@@ -3682,7 +3824,10 @@ class Engine:
                             self.cache.block_tables,
                             self._state,
                             self._lora,
+                            *self._state_pools(),
                         )
+                        if pools:
+                            (self.cache.state,) = pools
                         if self._routes:
                             toks_seq, routes_seq = toks_seq
                         if self._draft:

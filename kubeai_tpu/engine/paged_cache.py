@@ -10,6 +10,10 @@ tables are fixed-size buffers; only their CONTENTS change).
 Layout:
   k_pages / v_pages: [NL, n_pages, page_size, KVH, D]
   block_tables:      [slots, max_pages_per_slot] int32 (page ids; -1 free)
+  state:             {name: [state layers, slots, ...]}: what a slot owns
+                     beside its pages in a family some of whose layers keep
+                     a state of fixed size and no keys and values (NL above
+                     is then the layers that own pages); {} for every other
   host allocator:    free-list of page ids (bookkeeping outside jit)
 
 Ops (jit-safe, tested against contiguous semantics):
@@ -44,6 +48,10 @@ class PagedKVCache:
     k_pages: jax.Array | dict  # [NL, n_pages, page, KVH, D]
     v_pages: jax.Array | dict
     block_tables: jax.Array  # [slots, max_pages] int32, -1 = unallocated
+    # Row `slot` of each pool is that slot's, as its page list is: written
+    # whole by an admission, updated in place by every decode step
+    # (docs/concepts/hybrid-state.md).
+    state: dict = dataclasses.field(default_factory=dict)
 
     @property
     def quantized(self) -> bool:
@@ -75,6 +83,10 @@ class PagedKVCache:
 
         return kv_pool_nbytes(self.k_pages) + kv_pool_nbytes(self.v_pages)
 
+    def state_nbytes(self) -> dict:
+        """Resident bytes of each state pool, by name."""
+        return {name: int(pool.nbytes) for name, pool in self.state.items()}
+
     @staticmethod
     def create(
         num_layers: int,
@@ -87,9 +99,12 @@ class PagedKVCache:
         dtype=jnp.bfloat16,
         pool_sharding=None,  # a Sharding, or {"q8", "scale"} of them (int8)
         table_sharding=None,
+        state: dict | None = None,  # ModelFamily.recurrent_state(cfg)
+        state_sharding=None,
     ) -> "PagedKVCache":
         """Buffers are created under their shardings (None = the default
-        device), never whole on one device and re-placed afterwards."""
+        device), never whole on one device and re-placed afterwards.
+        `num_layers` are the layers that own pages."""
         from kubeai_tpu.ops.kv_quant import make_quantized_pool
 
         max_pages = -(-max_seq_len // page_size)
@@ -106,11 +121,18 @@ class PagedKVCache:
             block_tables=jnp.full(
                 (num_slots, max_pages), -1, jnp.int32, device=table_sharding
             ),
+            state={
+                name: jnp.zeros(
+                    (state["state_layers"], num_slots, *shape), dt,
+                    device=state_sharding,
+                )
+                for name, (shape, dt) in (state["pools"] if state else {}).items()
+            },
         )
 
 
 jax.tree_util.register_dataclass(
-    PagedKVCache, ["k_pages", "v_pages", "block_tables"], []
+    PagedKVCache, ["k_pages", "v_pages", "block_tables", "state"], []
 )
 
 

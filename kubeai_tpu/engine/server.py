@@ -396,6 +396,31 @@ class EngineMetrics:
             "`kind`).",
             self.registry,
         )
+        self.moe_assignments = Counter(
+            "kubeai_engine_moe_assignments_total",
+            "(Kept row, routed layer, taken expert) assignments, by whether "
+            "the expert is one this engine holds (label `held`: true | "
+            "false; an engine that holds every expert counts all as true). "
+            "The share under true is what this chip's part of the routed "
+            "sum was computed from: 1 / shares at even routing.",
+            self.registry,
+        )
+        self.state_pool_bytes = Gauge(
+            "kubeai_engine_state_pool_bytes",
+            "Resident bytes of the state a family keeps beside its pages "
+            "(label `kind`: recurrent = the linear-attention layers' "
+            "states, conv = their convolutions' last inputs): a slot's "
+            "share is the same whatever its length. Absent for a family "
+            "all of whose layers keep keys and values.",
+            self.registry,
+        )
+        self.state_admissions = Counter(
+            "kubeai_engine_state_admissions_total",
+            "Slots whose state pools an admission wrote (whole: the state "
+            "after the prompt's true length, never added to what the slot "
+            "held).",
+            self.registry,
+        )
         self.route_rows_sent = Counter(
             "kubeai_engine_route_rows_sent_total",
             "Rows of expert sets handed to requests that asked for them "
@@ -713,8 +738,18 @@ class EngineMetrics:
                 ),
                 (self.route_rows_sent, rstats["rows_sent"], {}),
                 (self.route_requests, rstats["requests"], {}),
+                (self.moe_assignments, rstats["assigned_held"], {"held": "true"}),
+                (self.moe_assignments, rstats["assigned_absent"],
+                 {"held": "false"}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
+        state_info = getattr(inner, "state_info", None)
+        if state_info:
+            for kind, nbytes in state_info["pool_bytes"].items():
+                self.state_pool_bytes.set(nbytes, kind=kind)
+            total = inner.state_stats["admissions"]
+            self.state_admissions.inc(
+                max(0.0, total - self.state_admissions.get()))
         bstats = getattr(inner, "block_stats", None)
         if bstats and getattr(inner, "block_generation", None):
             for counter, total, labels in (
@@ -847,7 +882,11 @@ def engine_state_snapshot(engine) -> dict:
     dev_info = getattr(inner, "device_info", None)
     moe = getattr(inner, "moe", None)
     blocks = getattr(inner, "block_generation", None)
+    state_info = getattr(inner, "state_info", None)
     return {
+        # A family that keeps state beside its pages: the layers of each
+        # kind, a slot's bytes and each pool's, by kind.
+        **({"state": state_info} if state_info else {}),
         # A family with a router: experts, k, routed layers, and whether
         # this engine hands the routes over. A dense family has no key.
         **({"moe": dict(moe)} if moe else {}),
@@ -924,6 +963,18 @@ class EngineServer:
         # router's fallback pool.
         if role not in ("unified", "prefill", "decode"):
             raise ValueError(f"unknown engine role {role!r}")
+        # A family with recurrent state beside its pages refuses whatever
+        # would move a slot's pages without it (engine.refuse_state_snapshot).
+        refuse = getattr(
+            getattr(engine, "inner", engine), "refuse_state_snapshot", None
+        )
+        for what, on in (
+            (f"the {role} role of a disaggregated pair", role != "unified"),
+            ("kv_sharing", kv_sharing),
+            ("a KV spill store", kv_spill_store is not None),
+        ):
+            if on and refuse is not None:
+                refuse(what)
         self.role = role
         self.max_transfer_bytes = max(0, int(max_transfer_mb)) * 1024 * 1024
         self.transfer_timeout = transfer_timeout

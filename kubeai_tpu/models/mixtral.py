@@ -271,7 +271,7 @@ def _qkv(h, lp, cfg, positions, inv_freq):
     )
 
 
-def _moe_sparse(x, experts, layer, topi, probs, cfg):
+def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
     """x [N, E] through the experts each row took (`topi` [N, k], weights
     `probs` [N, k] float32): the N * k assignments sorted by expert, one
     grouped product over the experts that hold any, and each row's k
@@ -280,11 +280,21 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg):
 
     `experts` holds every layer's weights, [NL, X, ...]; the grouped
     product reads them as NL * X groups of which only `layer`'s hold rows,
-    so no layer is sliced out of the stack."""
+    so no layer is sliced out of the stack.
+
+    `first` (None: every expert the router scores is held, `topi` counts
+    from 0): the layer holds a SHARE of the router's experts, global ids
+    `first .. first + X - 1`. An assignment to an expert that lives on
+    another chip is sorted behind the held ones, belongs to no group of
+    the product and adds nothing: what comes back is this share's part of
+    the routed sum, under the weights of the whole set."""
     N, k = topi.shape
     NL, X = experts["w_gate"].shape[:2]
     with jax.named_scope("moe_dispatch"):
         flat = topi.reshape(-1)
+        if first is not None:
+            flat = flat - first
+            flat = jnp.where((flat >= 0) & (flat < X), flat, X)
         order = jnp.argsort(flat)  # stable: an expert's rows in row order
         xs = x[order // k]  # [N * k, E]
         sizes = jax.lax.dynamic_update_slice(
@@ -300,6 +310,9 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg):
         u = grouped_matmul(xs, stacked["w_up"], sizes)
         out = grouped_matmul(jax.nn.silu(g) * u, stacked["w_down"], sizes)
     with jax.named_scope("moe_combine"):
+        if first is not None:
+            # Rows behind the last group are the product's to leave unwritten.
+            out = jnp.where((flat[order] < X)[:, None], out, 0)
         back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
         return jnp.einsum(
             "nke,nk->ne", out[back].reshape(N, k, -1), probs.astype(out.dtype),

@@ -47,8 +47,28 @@ class ModelFamily:
         block_forward_paged: Callable | None = None,
         block_commit: Callable | None = None,
         block_generation: Callable | None = None,
+        held_experts: Callable | None = None,
+        recurrent_state: Callable | None = None,
     ):
         self.hidden_states = hidden_states
+        # A family some of whose layers keep no keys and values but a state
+        # of fixed size a slot says so here: recurrent_state(cfg) ->
+        # {"state_layers": layers that own state, "page_layers": layers
+        # that own pages, "pools": {name: (a slot's shape, dtype)}}. The
+        # engine then stacks its page pool over `page_layers`, keeps a pool
+        # `[state_layers, slots, *shape]` a name beside it
+        # (engine.cache.state), and calls `prefill(..., state=True)`, which
+        # returns after k and v the rows `{name: [state_layers, A, *shape]}`
+        # an admission writes whole, and `decode_step_paged(...,
+        # state=pools)`, which returns the pools after the pages. What needs
+        # a snapshot of such state (prefix cache, chunked prefill,
+        # speculation, hand-off, spill) is refused at construction.
+        self.recurrent_state = recurrent_state
+        # A routed family whose layers hold a SHARE of the experts the
+        # router scores says which: held_experts(cfg) -> (first, end) global
+        # ids, half-open. Its forwards hand over all the ids a row took;
+        # the load counters count held experts as touched.
+        self.held_experts = held_experts
         # A family that generates by diffusion over blocks says so here:
         # block_generation(cfg) -> {"block_length", "denoising_steps",
         # "confidence_threshold", "mask_token_id"}. Its decode step is
@@ -180,6 +200,6 @@ def _ensure_builtin() -> None:
         )
     )
     # Further families self-register on import.
-    from kubeai_tpu.models import gemma, mixtral  # noqa: F401
+    from kubeai_tpu.models import gemma, mixtral, qwen3_next  # noqa: F401
 
     _LOADED = True

@@ -67,3 +67,18 @@ def over_kv_heads(fn, num_kv_heads: int, head_dims: tuple):
     return jax.shard_map(
         fn, mesh=mesh, in_specs=specs, out_specs=specs[0], check_vma=False
     )
+
+
+def on_every_device(fn, n_in: int, n_out: int):
+    """`fn` (one pallas_call over whole arrays) as it must run under the
+    context mesh where nothing of it is split: inside a shard_map that is
+    manual over every mesh axis, every argument and result replicated. With
+    no context mesh, or inside a region that is already manual over every
+    axis, `fn` is returned as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or set(mesh.manual_axes) == set(mesh.axis_names):
+        return fn
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(P(),) * n_in,
+        out_specs=(P(),) * n_out if n_out > 1 else P(), check_vma=False,
+    )

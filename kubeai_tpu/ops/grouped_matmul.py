@@ -21,7 +21,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from kubeai_tpu.ops import dispatch
 
@@ -54,13 +53,10 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.nd
     mode = dispatch.kernel_mode()
     if mode == "reference":
         return jax.lax.ragged_dot(x, w, sizes)
-    call = functools.partial(_grouped_pallas, interpret=mode == "interpret")
-    mesh = jax.sharding.get_abstract_mesh()
-    if not mesh.empty and set(mesh.manual_axes) != set(mesh.axis_names):
-        # A Mosaic kernel cannot be partitioned by GSPMD: it runs whole on
-        # every device (the engine refuses a sparse family a tp axis).
-        call = jax.shard_map(
-            call, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
-            check_vma=False,
-        )
+    # A Mosaic kernel cannot be partitioned by GSPMD: it runs whole on every
+    # device (the engine refuses a sparse family a tp axis).
+    call = dispatch.on_every_device(
+        functools.partial(_grouped_pallas, interpret=mode == "interpret"),
+        n_in=3, n_out=1,
+    )
     return call(x, w, sizes)
