@@ -36,7 +36,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from kubeai_tpu.engine.sampling import SamplingParams, sample
+from kubeai_tpu.engine.sampling import (
+    SamplingParams,
+    any_samples,
+    sample,
+    sample_rows,
+)
 from kubeai_tpu.models.registry import ModelFamily, get_model_family
 from kubeai_tpu.parallel import sharding as psh
 from kubeai_tpu.parallel.mesh import single_device_mesh
@@ -288,6 +293,16 @@ class _Admission:
     seated: bool = False
 
 
+def _live_temp(state, bt):
+    """The slots' temperatures [B] as a decode chunk's sampler reads them:
+    a slot that holds no page (free, or just freed) reads as greedy. Its
+    row of `state["temp"]` keeps what the request that left had asked for
+    until an admission overwrites it; its tokens go nowhere, and it must
+    not keep the sampler's candidate pool running for live rows that are
+    all greedy."""
+    return jnp.where(bt[:, 0] >= 0, state["temp"], 0.0)
+
+
 def _call(fn, args):
     """`fn(*args)`. As `_from_its_own_chunk` (below) it is entered from a
     frame so large that the interpreter has to start a new chunk of its
@@ -440,6 +455,10 @@ class Engine:
         self.step_reaps = dict.fromkeys(
             ("none", "admission", "seq_cap", "spec", "external"), 0
         )
+        # Reaped decode chunks by what their sampler ran ON THE DEVICE (the
+        # chunk hands the conditional's predicate back beside its tokens):
+        # "argmax" = every live row greedy, the candidate pool not entered.
+        self.sampler_chunks = {"argmax": 0, "pool": 0}
         # The KV a decode chunk reads, from the walk that grows the active
         # slots' pages: slots and pages (ceil(tokens / page) each) at the
         # newest dispatch, and the pages summed over every dispatched chunk.
@@ -1223,8 +1242,9 @@ class Engine:
             """`chunk` paged decode steps fused via lax.scan. The block
             tables are read-only here — page growth happens host-side
             between chunks (the host ensures pages cover position+chunk
-            before dispatching)."""
-            seeds, temp = state["seeds"], state["temp"]
+            before dispatching). The last output says whether the
+            sampler's candidate pool ran (`_live_temp`)."""
+            seeds, temp = state["seeds"], _live_temp(state, bt)
             topk, topp = state["topk"], state["topp"]
 
             def body(carry, _):
@@ -1258,13 +1278,14 @@ class Engine:
                 length=chunk,
             )
             state = dict(state, tokens=tokens, positions=positions)
-            return (toks_seq, kp, vp, state, *pools)
+            return (toks_seq, kp, vp, state, *pools, any_samples(temp))
 
         self._decode_jit = self.jit(
             _decode_chunk,
             donate_argnums=(1, 2) + ((6,) if stateful else ()),
             out_shardings=(None, pool_sharding, pool_sharding, None)
-            + ((self._state_sharding,) if stateful else ()),
+            + ((self._state_sharding,) if stateful else ())
+            + (None,),
         )
 
         from kubeai_tpu.ops.paged_attention import (
@@ -1743,30 +1764,34 @@ class Engine:
         )
 
         def _block_chunk(params, kp, vp, bt, state, lora):
-            seeds, temp = state["seeds"], state["temp"]
+            seeds, temp = state["seeds"], _live_temp(state, bt)
             topk, topp = state["topk"], state["topp"]
             mp = bt.shape[1]
             slot_idx = jnp.arange(slots)[:, None]
+            pooled = any_samples(temp)
 
             def choose(logits, pos):
                 """Sampled tokens [slots, R]; the top ones when every slot
                 is greedy (the sampler's top-k over the vocabulary is not
-                run then)."""
+                run then). `sample`'s own conditional with the mask id
+                kept out of the greedy branch, so its rows are called
+                directly: a second conditional would nest in this one."""
                 flat = logits.reshape(slots * R, -1)
-                return jax.lax.cond(
-                    jnp.any(temp > 0),
-                    lambda: sample(
-                        flat, jnp.repeat(seeds, R), pos.reshape(-1) + 1,
-                        jnp.repeat(temp, R), jnp.repeat(topk, R),
-                        jnp.repeat(topp, R),
-                    ),
-                    lambda: jnp.argmax(
-                        jnp.where(
-                            jnp.arange(flat.shape[-1]) == mask_id,
-                            -jnp.inf, flat,
-                        ), axis=-1,
-                    ).astype(jnp.int32),
-                ).reshape(slots, R)
+                with jax.named_scope("sample"):
+                    return jax.lax.cond(
+                        pooled,
+                        lambda: sample_rows(
+                            flat, jnp.repeat(seeds, R), pos.reshape(-1) + 1,
+                            jnp.repeat(temp, R), jnp.repeat(topk, R),
+                            jnp.repeat(topp, R),
+                        ),
+                        lambda: jnp.argmax(
+                            jnp.where(
+                                jnp.arange(flat.shape[-1]) == mask_id,
+                                -jnp.inf, flat,
+                            ), axis=-1,
+                        ).astype(jnp.int32),
+                    ).reshape(slots, R)
 
             def forward(c):
                 tokens, positions, left = c["tokens"], c["positions"], c["left"]
@@ -1856,12 +1881,12 @@ class Engine:
                 c["out"].reshape(nblk * R, slots),
                 dict(c["records"], forwards=c["f"]),
             )
-            return head, c["kp"], c["vp"], state
+            return head, c["kp"], c["vp"], state, pooled
 
         self._decode_jit = self.jit(
             _block_chunk,
             donate_argnums=(1, 2),
-            out_shardings=(None, pool_sharding, pool_sharding, None),
+            out_shardings=(None, pool_sharding, pool_sharding, None, None),
         )
 
     def add_request(
@@ -3723,6 +3748,7 @@ class Engine:
             current = None
             decode_mode = None
             routes_seq = None  # routed: the chunk's expert sets, on device
+            pooled = None  # whether the chunk's sampler ran its pool, on device
             t0 = time.perf_counter()
             if self._active and prev is not None:
                 # SEQ-CAP BARRIER: dispatching chunk N+1 before reaping N
@@ -3817,6 +3843,7 @@ class Engine:
                             self.cache.v_pages,
                             self._state,
                             *pools,
+                            pooled,
                         ) = self._decode_jit(
                             self.params,
                             self.cache.k_pages,
@@ -3851,6 +3878,7 @@ class Engine:
                     chunk_len,
                     time.monotonic(),
                     routes_seq,
+                    pooled,
                 )
                 if self._overlap and not is_spec and not self._spec:
                     # Reap current NEXT call: the device computes through
@@ -3998,13 +4026,12 @@ class Engine:
                 jax.block_until_ready(toks_seq)
             self.device_queue.waited(toks_seq, after)
             with span("step.readback"):
-                if routes_seq is None:
-                    toks_seq = np.asarray(jax.device_get(toks_seq))
-                else:
-                    # The same transfer brings the chunk's expert sets.
-                    toks_seq, routes_seq = jax.device_get(
-                        (toks_seq, routes_seq)
-                    )
+                # One transfer: the tokens, a routed family's expert sets
+                # and the scalar that says what the chunk's sampler ran.
+                toks_seq, routes_seq, pooled = jax.device_get(
+                    (toks_seq, routes_seq, inflight[5])
+                )
+            self.sampler_chunks["pool" if pooled else "argmax"] += 1
             if self._block:
                 return self._emit_blocks(toks_seq, routes_seq, chunk_slots)
             asked: list[tuple] = []  # (event index, step, slot, req, position)

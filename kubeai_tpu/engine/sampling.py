@@ -1,7 +1,8 @@
 """Token sampling: greedy, temperature, top-k, top-p — jit-safe.
 
-All branches are computed with masking (no Python control flow on traced
-values). Semantics match the conventional engine behavior users calibrate
+Rows are told apart by masking (no Python control flow on traced values);
+the one branch on data is `sample`'s, over the whole batch: all greedy, or
+not. Semantics match the conventional engine behavior users calibrate
 against: top-k filters first, then top-p operates on the *renormalized*
 post-top-k distribution; the most-likely token always survives (so
 top_p=0.0 degrades to greedy, not to token 0).
@@ -47,6 +48,13 @@ class SamplingParams:
 MAX_TOP_K = 64
 
 
+def any_samples(temperature: jnp.ndarray) -> jnp.ndarray:
+    """Whether any row of `temperature` [B] asks for a draw: the scalar
+    `sample` branches on, and the one a decode chunk hands back beside its
+    tokens so the host can count what the device ran."""
+    return jnp.any(temperature > 0)
+
+
 @jax.named_scope("sample")
 def sample(
     logits: jnp.ndarray,  # [B, V] float32
@@ -56,7 +64,25 @@ def sample(
     top_k: jnp.ndarray,  # [B] int32 (0 = off; capped at MAX_TOP_K)
     top_p: jnp.ndarray,  # [B] float32 (1 = off)
 ) -> jnp.ndarray:
-    """Vectorized per-request sampling. Returns [B] int32 token ids."""
+    """Vectorized per-request sampling. Returns [B] int32 token ids.
+
+    Where no row samples, the argmax and nothing else: the candidate pool
+    (`sample_rows`: a top-k over the vocabulary, a softmax, a draw) sits in
+    the branch of a conditional that a batch of greedy rows never enters.
+    A greedy row gets `argmax(logits)` from either branch, a sampling row
+    what `sample_rows` gives it, so no token depends on its batch-mates."""
+    return jax.lax.cond(
+        any_samples(temperature),
+        sample_rows,
+        lambda logits, *_: jnp.argmax(logits, axis=-1).astype(jnp.int32),
+        logits, seeds, positions, temperature, top_k, top_p,
+    )
+
+
+def sample_rows(logits, seeds, positions, temperature, top_k, top_p):
+    """`sample`'s branch for a batch in which some row samples (arguments
+    and result as there): every row's candidate pool is computed, and a
+    greedy row takes its argmax at the end."""
     B, V = logits.shape
     K = min(MAX_TOP_K, V)
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -279,6 +279,15 @@ class EngineMetrics:
             "spec = ahead of it; external = outside a step.",
             self.registry,
         )
+        self.sampler_chunks = Counter(
+            "kubeai_engine_sampler_chunks_total",
+            "Decode chunks reaped, by what their sampler ran on the device "
+            "(label `path`; the chunk hands the scalar back beside its "
+            "tokens): argmax = every live row greedy, nothing but the "
+            "argmax; pool = some live row samples, so every step took the "
+            "top-k over the vocabulary, the softmax and the draw.",
+            self.registry,
+        )
         self.device_starved = Histogram(
             "kubeai_engine_device_starved_seconds",
             "Seconds the device had nothing queued before a dispatch, as "
@@ -701,11 +710,13 @@ class EngineMetrics:
                  {"kind": "pad"}),
             ):
                 counter.inc(max(0.0, total - counter.get(**labels)), **labels)
-        for barrier, total in getattr(inner, "step_reaps", {}).items():
-            self.step_reaps.inc(
-                max(0.0, total - self.step_reaps.get(barrier=barrier)),
-                barrier=barrier,
-            )
+        for counter, tally, label in (
+            (self.step_reaps, "step_reaps", "barrier"),
+            (self.sampler_chunks, "sampler_chunks", "path"),
+        ):
+            for value, total in getattr(inner, tally, {}).items():
+                labels = {label: value}
+                counter.inc(max(0.0, total - counter.get(**labels)), **labels)
         book = getattr(inner, "device_queue", None)
         if book is not None:
             for after, before, seconds in book.drain():

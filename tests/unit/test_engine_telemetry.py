@@ -144,6 +144,7 @@ NEW_COUNTERS = (
     "kubeai_engine_decode_live_pages_total",
     "kubeai_engine_step_reaps_total",
     "kubeai_engine_dispatches_total",
+    "kubeai_engine_sampler_chunks_total",
 )
 
 
@@ -201,6 +202,55 @@ def test_host_timeline_counters_and_names_after_one_stream(streamed):
     assert lint_registry(m.registry) == []
     names = {inst.name for inst in m.registry.metrics}
     assert set(NEW_HISTOGRAMS + NEW_COUNTERS) <= names
+
+
+def _sampler_chunks(port: int) -> dict[str, float]:
+    from kubeai_tpu.metrics.registry import parse_prometheus_text
+
+    _, body = http_get(f"127.0.0.1:{port}", "/metrics")
+    parsed = parse_prometheus_text(body.decode())
+    return {
+        path: parsed[("kubeai_engine_sampler_chunks_total", (("path", path),))]
+        for path in ("argmax", "pool")
+    }
+
+
+def test_sampler_chunks_of_a_greedy_run_are_all_argmax(streamed):
+    """What the device ran, chunk by chunk: a greedy stream never enters
+    the sampler's candidate pool."""
+    server, _events, _parsed = streamed
+    chunks = _sampler_chunks(server.port)
+    assert chunks["pool"] == 0 and chunks["argmax"] >= 2
+    assert chunks == server.engine.sampler_chunks
+    # As many as the reaps that read tokens back.
+    assert sum(chunks.values()) <= sum(server.engine.step_reaps.values())
+
+
+def test_sampler_chunks_read_pool_while_a_sampled_stream_lives(server):
+    """One sampled stream among greedy ones: `pool` while it lives, then
+    `argmax` again for the greedy stream that follows."""
+    bodies = [
+        {"model": "tiny", "prompt": "hello", "max_tokens": 24,
+         "temperature": 0},
+        {"model": "tiny", "prompt": "abc", "max_tokens": 12,
+         "temperature": 0.9, "seed": 7},
+    ]
+    threads = [
+        threading.Thread(target=_stream_completion, args=(server.port, b))
+        for b in bodies
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    mixed = _sampler_chunks(server.port)
+    # 11 of its tokens come of decode chunks of 4: three at the least.
+    assert mixed["pool"] >= 3
+    _stream_completion(server.port, bodies[0])
+    after = _sampler_chunks(server.port)
+    assert after["pool"] == mixed["pool"]
+    assert after["argmax"] >= mixed["argmax"] + 5
 
 
 def test_event_queue_stamps_each_hand_over():

@@ -447,11 +447,14 @@ def test_a_dense_familys_programs_have_the_outputs_they_had(dense):
     out = _lowered_outputs(
         eng, eng._decode_jit, eng.params, pool, eng.cache.v_pages,
         eng.cache.block_tables, eng._state, None)
-    toks, kp, vp, state = out
+    # Since PR 44 a chunk's last output is the scalar its sampler branched
+    # on (did the candidate pool run), whatever the family.
+    toks, kp, vp, state, pooled = out
     assert (toks.shape, toks.dtype) == ((chunk, B), jnp.int32)
+    assert (pooled.shape, pooled.dtype) == ((), jnp.bool_)
     assert kp.shape == vp.shape == pool.shape
     assert jax.tree.structure(state) == jax.tree.structure(eng._state)
-    assert len(jax.tree.leaves(out)) == 3 + len(eng._state)
+    assert len(jax.tree.leaves(out)) == 4 + len(eng._state)
     mp = eng._bt_host.shape[1]
     out = _lowered_outputs(
         eng, eng._prefill_admit_jit, eng.params, jnp.zeros((2, 16), jnp.int32),
@@ -472,10 +475,11 @@ def test_a_routed_familys_programs_gain_one_small_output_each(tiny):
     out = _lowered_outputs(
         eng, eng._decode_jit, eng.params, pool, eng.cache.v_pages,
         eng.cache.block_tables, eng._state, None)
-    (toks, routes), *_ = out
+    (toks, routes), *_, pooled = out
     assert (toks.shape, routes.shape, routes.dtype) == (
         (4, 4), (4, 4, 2, 2), jnp.uint8)  # [chunk, slots, routed layers, k]
-    assert len(jax.tree.leaves(out)) == 4 + len(eng._state)
+    assert (pooled.shape, pooled.dtype) == ((), jnp.bool_)
+    assert len(jax.tree.leaves(out)) == 5 + len(eng._state)
     out = _lowered_outputs(
         eng, eng._prefill_admit_jit, eng.params, jnp.zeros((2, 16), jnp.int32),
         jnp.zeros((2, 6), jnp.int32), jnp.zeros((2, 2), jnp.float32),
@@ -486,7 +490,9 @@ def test_a_routed_familys_programs_gain_one_small_output_each(tiny):
         (2,), (2, 16, 2, 2), jnp.uint8)
 
 
-def test_a_dense_family_reads_back_one_bare_array_a_reap(dense, monkeypatch):
+def test_a_dense_family_reads_back_no_expert_sets_a_reap(dense, monkeypatch):
+    """One transfer a reap: the tokens, no expert sets, and the scalar that
+    says what the chunk's sampler ran (PR 44)."""
     eng = dense
     got = []
     real = jax.device_get
@@ -496,7 +502,10 @@ def test_a_dense_family_reads_back_one_bare_array_a_reap(dense, monkeypatch):
     (toks, blocks), = _run(eng, [[1, 2, 3]], ask=True)
     assert len(toks) == 6 and blocks == []
     reaps = [r for r in rec.named("step.reap") if r["attrs"]["rows"]]
-    assert len(got) == len(reaps) and not any(isinstance(x, tuple) for x in got)
+    assert len(got) == len(reaps)
+    for tokens, routes, pooled in got:
+        assert tokens.shape == (eng.cfg.decode_chunk, eng.cfg.num_slots)
+        assert routes is None and pooled.shape == ()
     assert not rec.named("step.routes")
     assert "routes" not in {p for p, _ in eng.profiler.drain()}
 

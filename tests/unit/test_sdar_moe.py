@@ -488,8 +488,14 @@ def test_a_sampled_request_commits_its_sampled_tokens_and_repeats_with_its_seed(
     sampled = SamplingParams(temperature=0.8, top_k=20, seed=3, max_tokens=9)
     first = eng.generate([PROMPTS[1]], sampled)
     assert first == eng.generate([PROMPTS[1]], sampled)
+    # What the block chunk says its sampler ran (PR 44): the candidate pool
+    # while a sampled request lives, the argmax alone for a greedy one.
+    drawn = dict(eng.sampler_chunks)
+    assert drawn["pool"] >= 2 and drawn["argmax"] == 0
     assert first != eng.generate(
         [PROMPTS[1]], SamplingParams(temperature=0.0, max_tokens=9))
+    assert eng.sampler_chunks["pool"] == drawn["pool"]
+    assert eng.sampler_chunks["argmax"] >= 1
     assert len(first[0]) == 9 and MASK not in first[0]
 
 

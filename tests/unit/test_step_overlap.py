@@ -205,7 +205,9 @@ class _ReapWatch:
         def dispatching(*args):
             out = decode(*args)
             self.dispatched += 1
-            for leaf in jax.tree_util.tree_leaves(out[0]):
+            # Its tokens (a routed family's expert sets with them) and the
+            # scalar that says what its sampler ran (PR 44).
+            for leaf in jax.tree_util.tree_leaves((out[0], out[-1])):
                 self.born[id(leaf)] = (self.dispatched, leaf)
             return out
 
@@ -266,10 +268,14 @@ def test_reap_launches_no_device_program(tiny, overlap, monkeypatch):
     assert late == [], f"{len(late)} programs compiled after the first reap"
     shape = (eng.cfg.decode_chunk, eng.cfg.num_slots)
     assert watch.touched and all(
-        made is not None and got == shape
+        made is not None and got in (shape, ())
         for _, made, _, got in watch.touched), watch.touched
-    # One wait and one read a reap, on the same array.
-    assert watch.touched[0::2] == watch.touched[1::2]
+    # One wait and one read a reap, on the same array; the read brings the
+    # same chunk's scalar with it.
+    tokens = [t for t in watch.touched if t[3] == shape]
+    scalars = [t[:3] for t in watch.touched if t[3] == ()]
+    assert tokens[0::2] == tokens[1::2]
+    assert scalars == [t[:3] for t in tokens[1::2]]
 
 
 def test_reap_of_chunk_n_precedes_the_end_of_chunk_n_plus_1(tiny, monkeypatch):
