@@ -70,7 +70,10 @@ class EngineConfig:
     # of pages + the reserved scratch page): every slot can reach
     # max_seq_len, no preemption possible. Set smaller to oversubscribe slots —
     # admission defers on pool exhaustion and decode preempts (recompute)
-    # the youngest request when it can't grow.
+    # the youngest request when it can't grow. Of a family with window
+    # layers this is the GLOBAL pool, the one a slot takes pages of by its
+    # length and the only one that can run out; the window pool's size
+    # follows from num_slots (a fixed ring a slot) and is no setting.
     num_pages: int = 0
     # Batched admission: up to this many same-bucket pending
     # prompts prefill in ONE device call — each dispatch costs a full
@@ -185,6 +188,9 @@ class EngineConfig:
         return tuple(out)
 
     def effective_num_pages(self) -> int:
+        """Pages of the pool that slots take pages of by their length (the
+        global pool of a family that also keeps a window pool), the
+        reserved scratch page included."""
         if self.num_pages > 0:
             return self.num_pages
         per_slot = -(-self.max_seq_len // self.page_size)
@@ -463,6 +469,9 @@ class Engine:
         # slots' pages: slots and pages (ceil(tokens / page) each) at the
         # newest dispatch, and the pages summed over every dispatched chunk.
         self.live_kv = {"slots": 0, "pages": 0, "pages_total": 0}
+        # Of a family with window layers, the ring pages a window layer's
+        # attention reads (at most a ring a slot), the same way.
+        self.live_window = {"pages": 0, "pages_total": 0}
 
         self._spec = 0  # resolved speculation window (see below)
         if (
@@ -488,7 +497,7 @@ class Engine:
         )
         if self._block is not None:
             self._check_block_engine(draft)
-        if self._recurrent is not None:
+        if self._recurrent is not None or self._window is not None:
             self._check_state_engine(draft)
         if self._kv_quant and (cfg.speculate > 0 or draft is not None):
             raise ValueError(
@@ -673,6 +682,7 @@ class Engine:
             table_sharding=self._bt_sharding,
             state=self._recurrent,
             state_sharding=self._state_sharding,
+            window=self._window,
         )
         self._alloc = PageAllocator(
             n_pages, cfg.page_size, max_pages_per_slot=max_pages
@@ -953,10 +963,28 @@ class Engine:
         fn = self.family.recurrent_state
         return fn(self.model_cfg) if fn else None
 
+    @functools.cached_property
+    def _window(self) -> dict | None:
+        """The layers that keep a ring of fixed size a slot in a pool of
+        their own, as the family says them (`ModelFamily.kv_layers`:
+        `global_layers`, `window_layers`, `window`) with `ring`, the pages
+        of a slot's ring at this engine's page size; None for a family all
+        of whose KV layers are of one kind. Derived, not set."""
+        fn = self.family.kv_layers
+        if fn is None:
+            return None
+        from kubeai_tpu.ops.paged_attention import ring_pages
+
+        layers = fn(self.model_cfg)
+        return {**layers, "ring": ring_pages(layers["window"], self.cfg.page_size)}
+
     @property
     def _page_layers(self) -> int:
-        """Layers the page pool is stacked over: those that own pages."""
-        rec = self._recurrent
+        """Layers the page pool is stacked over: those that own pages by
+        the sequence's length."""
+        rec, win = self._recurrent, self._window
+        if win:
+            return win["global_layers"]
         return rec["page_layers"] if rec else self.model_cfg.num_layers
 
     @property
@@ -969,15 +997,26 @@ class Engine:
         )
 
     def _state_pools(self) -> tuple:
-        """The state pools as the compiled programs take them: one more
-        donated argument after `lora`, none for a family without."""
-        return (self.cache.state,) if self._recurrent else ()
+        """The pools beside the page pool (recurrent state, or a window
+        pool) as the compiled programs take them: one more donated argument
+        after `lora`, none for a family without."""
+        return (self.cache.state,) if self._beside_pages else ()
+
+    @property
+    def _beside_pages(self) -> str | None:
+        """What a slot owns beside its pages of the page pool, in words
+        (None: nothing; the programs then take no further argument)."""
+        if self._recurrent is not None:
+            return "recurrent state"
+        return "a window ring" if self._window is not None else None
 
     def _check_state_engine(self, draft) -> None:
         """What a family with state beside its pages is not served with:
         each would need a snapshot of a slot's state at a position other
-        than its last, which nothing writes yet. Preemption by recompute
-        needs none (the re-admission rebuilds the state from position 0)."""
+        than its last, which nothing writes yet, or, of a window ring, a
+        rule for positions the ring has already forgotten. Preemption by
+        recompute needs none (the re-admission rebuilds the state, or both
+        pools, from position 0)."""
         cfg = self.cfg
         refused = [
             name for name, on in (
@@ -1000,11 +1039,42 @@ class Engine:
         belongs to them (hand-off, pages served to or fetched from a peer,
         spill). `_check_state_engine` asks it of the engine's options, the
         server of its own, at construction."""
-        if self._recurrent is not None:
+        if self._beside_pages is not None:
             raise ValueError(
-                f"family {self.family.name} keeps recurrent state beside its "
-                f"pages and is not served with: {what}"
+                f"family {self.family.name} keeps {self._beside_pages} beside "
+                f"its pages and is not served with: {what}"
             )
+
+    def kv_pools(self) -> list[dict] | None:
+        """What /v1/state says of the pools of a family with two kinds of
+        KV layer (None for any other): kind, layers, pages (scratch page
+        left out), the pages a slot can own, the pages in use now, bytes;
+        the window pool's `window`."""
+        win = self._window
+        if win is None:
+            return None
+        per_page = (
+            2 * self.cfg.page_size * self.model_cfg.num_kv_heads
+            * self.model_cfg.head_size * np.dtype(self.cfg.cache_dtype).itemsize
+        )
+        total = self._n_pages - 1
+        ring_total = self.cfg.num_slots * win["ring"]
+        return [
+            {
+                "kind": "global", "layers": int(win["global_layers"]),
+                "pages": int(total),
+                "pages_per_slot": int(self._bt_host.shape[1]),
+                "pages_used": int(total - self._alloc.free_pages),
+                "bytes": int(win["global_layers"] * (total + 1) * per_page),
+            },
+            {
+                "kind": "window", "layers": int(win["window_layers"]),
+                "pages": int(ring_total), "pages_per_slot": int(win["ring"]),
+                "pages_used": int(len(self._active) * win["ring"]),
+                "bytes": int(win["window_layers"] * (ring_total + 1) * per_page),
+                "window": int(win["window"]),
+            },
+        ]
 
     @functools.cached_property
     def state_info(self) -> dict | None:
@@ -1137,8 +1207,9 @@ class Engine:
         # returns the rows an admission writes after k and v, its decode
         # step the pools after the pages. Every other family's programs
         # are what they were, argument for argument.
-        stateful = self._recurrent is not None
+        stateful = self._beside_pages is not None
         state_kw = {"state": True} if stateful else {}
+        window = self._window
         if self._pp > 1:
             from functools import partial as _partial
 
@@ -1188,7 +1259,20 @@ class Engine:
                     params, mcfg, tokens, lengths,
                     lora=lora, lora_idx=adapters, **route_kw,
                 )
-            if stateful:
+            if window:
+                # The window layers' keys and values: the positions that
+                # stay in each slot's ring, and no other, go to its pages.
+                from kubeai_tpu.ops.paged_attention import ring_scatter_sequence
+
+                rows = routes.pop(0)
+                pools = (
+                    dict(zip(("k_window", "v_window"), ring_scatter_sequence(
+                        pools[0]["k_window"], pools[0]["v_window"],
+                        rows["k_window"], rows["v_window"], slots, lengths,
+                        window["ring"],
+                    ))),
+                )
+            elif stateful:
                 # The slot's state is overwritten whole, never added to;
                 # a padding row's slot is out of range and dropped.
                 rows = routes.pop(0)
@@ -2059,8 +2143,11 @@ class Engine:
 
     def kv_utilization(self) -> float:
         """Fraction of KV-cache capacity in use: allocated pages over the
-        pool. Pages parked idle in the prefix cache count
-        as free — they are reclaimable by any admission."""
+        pool that slots take pages of by their length (of a family with
+        window layers the GLOBAL pool: a slot's ring in the window pool is
+        its own from construction, so that pool is never short). Pages
+        parked idle in the prefix cache count as free — they are
+        reclaimable by any admission."""
         total = self._n_pages - 1  # page 0 is reserved scratch
         if total <= 0:
             return 0.0
@@ -2898,6 +2985,13 @@ class Engine:
                 self._set_bt_row(slot, pages)
         self.live_kv["slots"] = len(live)
         self.live_kv["pages"] = sum(live.values())
+        if self._window:
+            # Pages from the first that holds an in-window position on.
+            win = self._window["window"]
+            self.live_window["pages"] = sum(
+                n - max(self._active[slot].position + 1 - win, 0) // page
+                for slot, n in live.items()
+            )
 
     def _set_bt_row(self, slot: int, pages: list[int]) -> None:
         """Update the host block-table mirror for one slot and mark the
@@ -3784,6 +3878,7 @@ class Engine:
                         )
                         self._bt_dirty = False
                 self.live_kv["pages_total"] += self.live_kv["pages"]
+                self.live_window["pages_total"] += self.live_window["pages"]
                 with span(
                     "step.decode", kv_layout=self.kv_layout,
                     live_slots=self.live_kv["slots"],

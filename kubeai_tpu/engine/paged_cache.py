@@ -13,7 +13,13 @@ Layout:
   state:             {name: [state layers, slots, ...]}: what a slot owns
                      beside its pages in a family some of whose layers keep
                      a state of fixed size and no keys and values (NL above
-                     is then the layers that own pages); {} for every other
+                     is then the layers that own pages); or, in a family
+                     some of whose layers attend a sliding window, the
+                     WINDOW pool {"k_window", "v_window": [window layers, 1 +
+                     slots * ring, page, KVH, D]} in which slot s owns pages
+                     1 + s * ring .. as a ring, however long its sequence
+                     (NL above is then the global layers; docs/concepts/
+                     window-cache.md); {} for every other
   host allocator:    free-list of page ids (bookkeeping outside jit)
 
 Ops (jit-safe, tested against contiguous semantics):
@@ -101,11 +107,20 @@ class PagedKVCache:
         table_sharding=None,
         state: dict | None = None,  # ModelFamily.recurrent_state(cfg)
         state_sharding=None,
+        window: dict | None = None,  # ModelFamily.kv_layers(cfg) + "ring"
     ) -> "PagedKVCache":
         """Buffers are created under their shardings (None = the default
         device), never whole on one device and re-placed afterwards.
-        `num_layers` are the layers that own pages."""
+        `num_layers` are the layers that own pages by the sequence's length
+        (`num_pages` of them: the pool that can run out). With `window` a
+        second pool over its `window_layers` holds every slot's ring of
+        `ring` pages and a scratch page: no allocator, no growth."""
         from kubeai_tpu.ops.kv_quant import make_quantized_pool
+
+        if window and (state or dtype in (jnp.int8, "int8")):
+            raise ValueError(
+                "a window pool goes with a bf16 page pool and no recurrent state"
+            )
 
         max_pages = -(-max_seq_len // page_size)
         shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
@@ -127,6 +142,12 @@ class PagedKVCache:
                     device=state_sharding,
                 )
                 for name, (shape, dt) in (state["pools"] if state else {}).items()
+            } or {
+                name: jnp.zeros(
+                    (window["window_layers"], 1 + num_slots * window["ring"],
+                     page_size, kv_heads, head_dim), dtype, device=state_sharding,
+                )
+                for name in (("k_window", "v_window") if window else ())
             },
         )
 

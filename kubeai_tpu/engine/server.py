@@ -322,7 +322,11 @@ class EngineMetrics:
             "KV pages that hold the active slots' tokens (ceil(tokens / "
             "page) a slot), added up once per dispatched decode chunk: "
             "its delta over the chunks of a window is the mean live "
-            "pages the decode attention kernel reads a layer.",
+            "pages the decode attention kernel reads a layer. A family "
+            "with two kinds of KV layer has a series a pool (label `pool`: "
+            "global = ceil(tokens / page) a slot, window = the ring pages "
+            "from the first in-window position on, at most a ring a "
+            "slot); every other family the one series without a label.",
             self.registry,
         )
         self.prefill_tokens = Counter(
@@ -421,6 +425,15 @@ class EngineMetrics:
             "states, conv = their convolutions' last inputs): a slot's "
             "share is the same whatever its length. Absent for a family "
             "all of whose layers keep keys and values.",
+            self.registry,
+        )
+        self.kv_pool_pages = Gauge(
+            "kubeai_engine_kv_pool_pages",
+            "Pages of each KV pool of a family that keeps two (label `pool`: "
+            "global = the layers whose pages a slot takes by its length, "
+            "window = the layers that keep a ring of fixed size a slot; "
+            "label `state`: used, free; the scratch pages left out). Absent "
+            "for a family with one kind of KV layer.",
             self.registry,
         )
         self.state_admissions = Counter(
@@ -729,7 +742,19 @@ class EngineMetrics:
                     max(0.0, total - self.dispatches.get(**labels)), **labels
                 )
         live = getattr(inner, "live_kv", None)
-        if live:
+        pools = getattr(inner, "kv_pools", lambda: None)()
+        if live and pools:
+            for pool, book in (("global", live), ("window", inner.live_window)):
+                self.decode_live_pages.inc(max(
+                    0.0,
+                    book["pages_total"] - self.decode_live_pages.get(pool=pool),
+                ), pool=pool)
+            for pool in pools:
+                used = pool["pages_used"]
+                self.kv_pool_pages.set(used, pool=pool["kind"], state="used")
+                self.kv_pool_pages.set(
+                    pool["pages"] - used, pool=pool["kind"], state="free")
+        elif live:
             self.decode_live_pages.inc(max(
                 0.0, live["pages_total"] - self.decode_live_pages.get()))
         rstats = getattr(inner, "route_stats", None)
@@ -894,7 +919,11 @@ def engine_state_snapshot(engine) -> dict:
     moe = getattr(inner, "moe", None)
     blocks = getattr(inner, "block_generation", None)
     state_info = getattr(inner, "state_info", None)
+    kv_pools = getattr(inner, "kv_pools", lambda: None)()
     return {
+        # A family with two kinds of KV layer: each pool's kind, layers,
+        # pages, pages a slot, pages in use, bytes, and the window.
+        **({"kv_pools": kv_pools} if kv_pools else {}),
         # A family that keeps state beside its pages: the layers of each
         # kind, a slot's bytes and each pool's, by kind.
         **({"state": state_info} if state_info else {}),
@@ -974,8 +1003,9 @@ class EngineServer:
         # router's fallback pool.
         if role not in ("unified", "prefill", "decode"):
             raise ValueError(f"unknown engine role {role!r}")
-        # A family with recurrent state beside its pages refuses whatever
-        # would move a slot's pages without it (engine.refuse_state_snapshot).
+        # A family with recurrent state or a window ring beside its pages
+        # refuses whatever would move a slot's pages without it
+        # (engine.refuse_state_snapshot).
         refuse = getattr(
             getattr(engine, "inner", engine), "refuse_state_snapshot", None
         )

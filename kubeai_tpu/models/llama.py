@@ -43,18 +43,23 @@ from kubeai_tpu.parallel import sharding as sh
 
 
 @jax.named_scope("prefill_attention")
-def _prefill_attention(q, k, v, mask_block: int = 1):
+def _prefill_attention(q, k, v, mask_block: int = 1, window: int = 0):
     """Aligned buckets of 256 tokens and up take the Pallas flash kernel
     wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
     interpreter); the short and unaligned buckets keep the jnp path.
     `mask_block` > 1 is a block-diffusion family's mask: causal between
-    blocks of that many positions, full inside one."""
+    blocks of that many positions, full inside one. `window` > 0 is a
+    window layer's: a query sees that many positions, its own the last."""
     S = q.shape[1]
     if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
         from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
-        return flash_causal_prefill(q, k, v, mask_block=mask_block)
-    return causal_prefill_attention(q, k, v, mask_block=mask_block)
+        return flash_causal_prefill(
+            q, k, v, mask_block=mask_block, window=window
+        )
+    return causal_prefill_attention(
+        q, k, v, mask_block=mask_block, window=window or None
+    )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -49,8 +49,27 @@ class ModelFamily:
         block_generation: Callable | None = None,
         held_experts: Callable | None = None,
         recurrent_state: Callable | None = None,
+        kv_layers: Callable | None = None,
     ):
         self.hidden_states = hidden_states
+        # A family some of whose layers attend a sliding window and the
+        # others every earlier position says so here: kv_layers(cfg) ->
+        # {"global_layers": layers that own pages by the sequence's length,
+        # "window_layers": layers that own a ring of fixed size a slot,
+        # "window": positions a window layer sees}. The engine then stacks
+        # its page pool over `global_layers` and keeps a window pool
+        # `[window_layers, 1 + slots * ring, page, KVH, D]` beside it
+        # (engine.cache.state: "k_window", "v_window"; a slot's ring is
+        # pages `1 + slot * ring ..`, fixed when the cache is built), and
+        # calls `prefill(..., state=True)`, which returns after k and v (the
+        # global layers') the window layers' `{"k_window", "v_window"}:
+        # [window_layers, A, S, KVH, D]`, of which the admission writes the
+        # ring's positions, and `decode_step_paged(..., state=pools)`,
+        # which returns the pools after the pages. What needs a rule for a
+        # ring that has already forgotten (prefix cache, chunked prefill,
+        # speculation, hand-off, spill) is refused at construction
+        # (docs/concepts/window-cache.md).
+        self.kv_layers = kv_layers
         # A family some of whose layers keep no keys and values but a state
         # of fixed size a slot says so here: recurrent_state(cfg) ->
         # {"state_layers": layers that own state, "page_layers": layers
@@ -200,6 +219,8 @@ def _ensure_builtin() -> None:
         )
     )
     # Further families self-register on import.
-    from kubeai_tpu.models import gemma, mixtral, qwen3_next  # noqa: F401
+    from kubeai_tpu.models import (  # noqa: F401
+        exaone_moe, gemma, mixtral, qwen3_next,
+    )
 
     _LOADED = True

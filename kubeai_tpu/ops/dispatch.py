@@ -15,8 +15,12 @@ prefill) asks `kernel_mode()`:
 Three cases keep the `jnp` path on every backend, by design: an int8
 {"q8", "scale"} pool (the kernels read bf16 pages; ROADMAP A4), a prefill
 whose padded length is under 256 or not a multiple of 128 (the short
-buckets), and a Gemma-2 prefill, whose softcap and sliding window the
-flash kernel does not carry.
+buckets), and a Gemma-2 prefill, whose logit softcap the flash kernel does
+not carry (its window rides the layer scan as a traced value beside the
+softcap, so it stays with it). A sliding window alone is no such case: the
+flash kernel takes a static `window` and skips the blocks wholly outside
+it (`ops/pallas_attention.py`), which is how a family that mixes window
+and global layers prefills both kinds.
 
 `kernel_mode()` is the only way to choose: no dispatch and no kernel takes
 a mode or an `interpret` argument.

@@ -8,11 +8,22 @@ costs nothing: its matrix is not read.
 
 Where kernels run (`ops/dispatch.py`) it is the Pallas grouped matmul that
 ships with JAX (`jax.experimental.pallas.ops.tpu.megablox.gmm`), with tiles
-that take a whole [k, n] matrix of an expert in one or two steps: at 8 rows an
-expert the work is streaming each expert's weights once, and small tiles
-leave the DMA engine waiting on the grid (0.63 ms a product of 128 experts of
-2048 x 768 on a v5e, 78% of the HBM roofline, against 1.9 ms for
-`jax.lax.ragged_dot`, the reference path here; my chip run, PR 36).
+that take a [k, n] matrix of an expert in as few steps as fit: at a few rows
+an expert the work is streaming each expert's weights once, and small tiles
+leave the DMA engine waiting on the grid.
+
+**The tile rule** (`weight_tile`): the weight tile is `[min(k, TILE_MAX),
+min(n, TILE_MAX)]`, then halved along k (rows stay whole and contiguous),
+and along n once k is down to `TILE_ROWS`, until it is at most `TILE_BYTES`: the kernel
+holds two of them (double-buffered) beside its row, output and accumulator
+tiles, inside Mosaic's default 16 MiB of scoped VMEM on a v5e. Measured at
+two shapes: 128 narrow experts of 2048 x 768 (SDAR-30B-A3B; tile `[2048,
+768]`, 3 MiB, what the rule leaves as it was: 0.63 ms a product at 8 rows an
+expert on a v5e, 78% of the HBM roofline, against 1.9 ms for
+`jax.lax.ragged_dot`, the reference path here; my chip run, PR 36), and 16
+wide experts of 6144 x 2048 (K-EXAONE-236B-A23B's share; `[2048, 2048]`
+would be 8 MiB a buffer, the whole scoped VMEM for two, so the rule gives
+`[1024, 2048]`, 4 MiB; PERF.md section 6, PR 46, has the chip's reading).
 """
 
 from __future__ import annotations
@@ -26,6 +37,18 @@ from kubeai_tpu.ops import dispatch
 
 TILE_ROWS = 128  # rows a tile; sorted rows are padded to a multiple of it
 TILE_MAX = 2048  # widest k or n tile: [2048, 768] bf16 is 3 MiB a buffer
+TILE_BYTES = 4 << 20  # most bytes of one weight tile; the kernel holds two
+
+
+def weight_tile(k: int, n: int, itemsize: int) -> tuple[int, int]:
+    """(tk, tn) of the weight tile for a [k, n] matrix an expert."""
+    tk, tn = min(k, TILE_MAX), min(n, TILE_MAX)
+    while tk * tn * itemsize > TILE_BYTES:
+        if tk > TILE_ROWS:
+            tk //= 2
+        else:
+            tn //= 2
+    return tk, tn
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -43,7 +66,7 @@ def _grouped_pallas(x, w, sizes, *, interpret=False):
         out = gmm(
             jnp.pad(x, ((0, -m % TILE_ROWS), (0, 0))), w, sizes,
             preferred_element_type=x.dtype,
-            tiling=(TILE_ROWS, min(k, TILE_MAX), min(n, TILE_MAX)),
+            tiling=(TILE_ROWS, *weight_tile(k, n, w.dtype.itemsize)),
             interpret=interpret,
         )
     return out[:m]

@@ -1330,6 +1330,73 @@ def batched_scatter_sequence(
     )
 
 
+# ---- a window layer's ring ----------------------------------------------------
+#
+# A layer that attends the last `window` positions keeps, a slot, a ring of
+# `ring_pages` pages in a pool of its own, however long the sequence: the
+# page that holds positions `[p * page, (p + 1) * page)` is ring page `p %
+# ring`, pool page `1 + slot * ring + p % ring` (page 0 is scratch, as in the
+# global pool). `ring` is the most pages a window can touch, so the page a
+# new token opens holds only positions that no later query sees. A slot's
+# ring is fixed when the pool is built: nothing allocates on the step's path,
+# and the block table below is a function of the slot alone.
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages of a slot's ring: the most that `window` consecutive positions
+    touch (`window / page_size + 1` where the page divides the window)."""
+    return -(-(window - 1) // page_size) + 1
+
+
+def ring_block_tables(block_tables: jnp.ndarray, ring: int) -> jnp.ndarray:
+    """The window pool's block tables, in the global pool's form ([B, MP],
+    entry p = the pool page that holds positions `p * page ..`, wrapped), so
+    the kernels read a ring as they read a page list: with the window as
+    their argument they start at the first in-window page and read at most
+    `ring` pages a slot. A slot that holds no global page (its row starts
+    with -1: freed, or never admitted) holds no ring page either."""
+    b, mp = block_tables.shape
+    pages = (
+        1 + jnp.arange(b, dtype=jnp.int32)[:, None] * ring
+        + jnp.arange(mp, dtype=jnp.int32)[None, :] % ring
+    )
+    return jnp.where(block_tables[:, :1] >= 0, pages, -1)
+
+
+def ring_scatter_sequence(
+    k_ring: jnp.ndarray,  # [NL, 1 + slots * ring, page, KVH, D]
+    v_ring: jnp.ndarray,
+    k_seq: jnp.ndarray,  # [NL, A, S, KVH, D] prefilled (padded) sequences
+    v_seq: jnp.ndarray,
+    slots: jnp.ndarray,  # [A] the rows' slots (>= slots: a padding row)
+    lengths: jnp.ndarray,  # [A] true lengths
+    ring: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Write the positions of A prefilled sequences that stay in their
+    slots' rings: the last `ring` pages of each (whole pages, so the tail is
+    `ring * page` positions of the S computed, and its live targets are
+    distinct). Positions at or past a row's length, and padding rows, go to
+    scratch page 0."""
+    page = k_ring.shape[2]
+    num_slots = (k_ring.shape[1] - 1) // ring
+    S = k_seq.shape[2]
+    T = min(S, ring * page)
+    last = (jnp.maximum(lengths, 1) - 1) // page  # the page of the last token
+    start = jnp.maximum(last - ring + 1, 0) * page  # [A]
+    pos = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]  # [A, T]
+    live = (pos < lengths[:, None]) & (slots[:, None] < num_slots)
+    at = jnp.minimum(pos, S - 1)[None, :, :, None, None]
+    page_ids = jnp.where(
+        live, 1 + slots[:, None] * ring + (pos // page) % ring, 0
+    )
+    return batched_scatter_sequence(
+        k_ring, v_ring,
+        jnp.take_along_axis(k_seq, at, axis=2),
+        jnp.take_along_axis(v_seq, at, axis=2),
+        page_ids, pos % page,
+    )
+
+
 def sequence_page_coords(
     bt_row: jnp.ndarray,  # [MP] the slot's block-table row
     length: jnp.ndarray,  # scalar true length

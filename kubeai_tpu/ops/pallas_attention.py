@@ -3,7 +3,11 @@
 Online-softmax attention computed block-by-block so the [S, S] logits
 matrix never materializes in HBM — the prefill hot op for long context.
 Grid: (batch, q-head, q-block); the kernel loops over k-blocks up to the
-causal frontier (skipping fully-masked blocks entirely).
+causal frontier (skipping fully-masked blocks entirely). With a sliding
+`window` (a window layer of a family that mixes window and global
+attention) a query sees that many positions, its own the last, and the loop
+starts at the first k-block that holds any of them: blocks wholly outside
+the window are skipped like those past the frontier.
 
 GQA: the q-head grid axis maps each q head onto its kv head (h // group).
 
@@ -42,6 +46,7 @@ def _flash_kernel(
     seq_len: int,
     scale: float,
     mask_block: int,
+    window: int,
 ):
     qi = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, D]
@@ -69,6 +74,8 @@ def _flash_kernel(
             seen = k_pos < (q_pos // mask_block + 1) * mask_block
         else:
             seen = q_pos >= k_pos
+        if window > 0:
+            seen = seen & (q_pos - k_pos < window)
         s = jnp.where(seen, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -81,7 +88,13 @@ def _flash_kernel(
 
     # Causal frontier: k blocks strictly after this q block are all masked.
     num_k = (qi + 1) * block_q // block_k
-    m, l, acc = jax.lax.fori_loop(0, num_k, body, (m, l, acc))
+    # Window: the first k block that holds a position the block's first
+    # query still sees (0 with no window: the loop is what it was).
+    first_k = (
+        jnp.maximum(qi * block_q - window + 1, 0) // block_k if window > 0
+        else 0
+    )
+    m, l, acc = jax.lax.fori_loop(first_k, num_k, body, (m, l, acc))
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -89,6 +102,7 @@ def _flash_kernel(
     jax.jit,
     static_argnames=(
         "block_q", "block_k", "interpret", "scale", "group", "mask_block",
+        "window",
     ),
 )
 def _flash_bhsd(
@@ -101,6 +115,7 @@ def _flash_bhsd(
     scale: float = 1.0,
     group: int = 1,
     mask_block: int = 1,
+    window: int = 0,
 ) -> jnp.ndarray:
     B, H, S, D = q.shape
     grid = (B, H, S // block_q)
@@ -111,6 +126,7 @@ def _flash_bhsd(
         seq_len=S,
         scale=scale,
         mask_block=mask_block,
+        window=window,
     )
     return pl.pallas_call(
         kernel,
@@ -137,9 +153,11 @@ def flash_causal_prefill(
     *,
     block: int = 128,
     mask_block: int = 1,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Flash attention with the causal_prefill_attention contract
-    (`mask_block` > 1: its block mask, for a divisor of `block`)."""
+    (`mask_block` > 1: its block mask, for a divisor of `block`; `window` >
+    0: its sliding window, not with a block mask)."""
     B, S, H, D = q.shape
     KVH = k.shape[2]
     if block % mask_block:
@@ -151,6 +169,8 @@ def flash_causal_prefill(
             f"flash prefill needs a sequence that is a multiple of {block}, "
             f"got {S}"
         )
+    if window > 0 and mask_block > 1:
+        raise ValueError("flash prefill takes a window or a block mask, not both")
 
     group = H // KVH
     # [B, S, H, D] -> [B, H, S, D]. K/V keep their KVH heads — the kernel's
@@ -171,6 +191,7 @@ def flash_causal_prefill(
             _flash_bhsd, block_q=block, block_k=block,
             interpret=dispatch.kernel_mode() == "interpret",
             scale=D ** -0.5, group=group, mask_block=mask_block,
+            window=window,
         ),
         KVH, (1, 1, 1),
     )(qt, kt, vt)

