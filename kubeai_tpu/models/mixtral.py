@@ -27,6 +27,7 @@ no row dropped). It generates a block of `block_length` positions at a time
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -39,7 +40,7 @@ from kubeai_tpu.models.registry import (
     route_dtype,
 )
 from kubeai_tpu.ops.attention import decode_attention
-from kubeai_tpu.ops.grouped_matmul import grouped_matmul
+from kubeai_tpu.ops.grouped_matmul import grouped_matmul, tile_plan
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -279,7 +280,7 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
     load of an expert; one that gets no row costs nothing.
 
     `experts` holds every layer's weights, [NL, X, ...]; the grouped
-    product reads them as NL * X groups of which only `layer`'s hold rows,
+    product reads them as NL * X groups and is told which X are `layer`'s,
     so no layer is sliced out of the stack.
 
     `first` (None: every expert the router scores is held, `topi` counts
@@ -297,18 +298,19 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
             flat = jnp.where((flat >= 0) & (flat < X), flat, X)
         order = jnp.argsort(flat)  # stable: an expert's rows in row order
         xs = x[order // k]  # [N * k, E]
-        sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros((NL * X,), jnp.int32),
-            jnp.bincount(flat, length=X).astype(jnp.int32),
-            (layer * X,),
-        )
+        counts = jnp.bincount(flat, length=X).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
+        # One tile map for the three products, over this layer's X groups;
+        # only the weights are NL * X groups wide.
+        product = functools.partial(
+            grouped_matmul, sizes=counts, layer=layer,
+            plan=tile_plan(counts, N * k))
         stacked = {
             name: w.reshape(NL * X, *w.shape[2:]) for name, w in experts.items()
         }
-        g = grouped_matmul(xs, stacked["w_gate"], sizes)
-        u = grouped_matmul(xs, stacked["w_up"], sizes)
-        out = grouped_matmul(jax.nn.silu(g) * u, stacked["w_down"], sizes)
+        g = product(xs, stacked["w_gate"])
+        u = product(xs, stacked["w_up"])
+        out = product(jax.nn.silu(g) * u, stacked["w_down"])
     with jax.named_scope("moe_combine"):
         if first is not None:
             # Rows behind the last group are the product's to leave unwritten.

@@ -6,11 +6,25 @@ group's matrix. What a sparsely computed expert layer is made of
 `sum(sizes[:g]) .. sum(sizes[:g + 1])` of `x` by `w[g]`. A group without rows
 costs nothing: its matrix is not read.
 
-Where kernels run (`ops/dispatch.py`) it is the Pallas grouped matmul that
-ships with JAX (`jax.experimental.pallas.ops.tpu.megablox.gmm`), with tiles
-that take a [k, n] matrix of an expert in as few steps as fit: at a few rows
-an expert the work is streaming each expert's weights once, and small tiles
+Where kernels run (`ops/dispatch.py`) it is a Pallas kernel, `gmm`: the
+grid, index maps, accumulator and store mask of the grouped matmul that
+ships with JAX (`jax.experimental.pallas.ops.tpu.megablox`), with tiles that
+take a [k, n] matrix of an expert in as few steps as fit: at a few rows an
+expert the work is streaming each expert's weights once, and small tiles
 leave the DMA engine waiting on the grid.
+
+**Who builds the tile map, and why the stack is NL x X groups wide on the
+weight side only.** The kernel walks a map from grid step to (group, row
+tile). `_moe_sparse` builds it ONCE a layer from the layer's own X counts
+(`tile_plan`: seven small dense fusions) and hands it to the layer's three
+products with the layer's number. The weights stay the whole stack,
+`[NL * X, k, n]`, read in place: the kernel's right-hand index map adds
+`layer * X` to the map's group, so no layer's experts are sliced out (a
+kernel's sliced operand is copied, 1.2 GB a layer at SDAR's sizes). Until
+PR 47 the stack was NL x X groups wide on the row side too: the library's
+`gmm` built its own metadata over all NL * X sizes, of which X held rows,
+with two scatter-adds and a scatter of a thousand updates and a `while`
+loop, some sixty instructions a routed layer (PERF.md section 6, PR 47).
 
 **The tile rule** (`weight_tile`): the weight tile is `[min(k, TILE_MAX),
 min(n, TILE_MAX)]`, then halved along k (rows stay whole and contiguous),
@@ -29,9 +43,12 @@ would be 8 MiB a buffer, the whole scoped VMEM for two, so the rule gives
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from kubeai_tpu.ops import dispatch
 
@@ -51,35 +68,175 @@ def weight_tile(k: int, n: int, itemsize: int) -> tuple[int, int]:
     return tk, tn
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _grouped_pallas(x, w, sizes, *, interpret=False):
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+class TilePlan(NamedTuple):
+    """What the kernel's grid walks, for one set of groups over `m` sorted
+    rows (megablox's `GroupMetadata` and its `num_tiles`)."""
 
+    group_offsets: jnp.ndarray  # [X + 1]: group g holds rows offsets[g] .. offsets[g + 1]
+    group_ids: jnp.ndarray  # [tiles_m + X - 1]: the group of grid step v
+    m_tile_ids: jnp.ndarray  # [tiles_m + X - 1]: the row tile of grid step v
+    num_tiles: jnp.ndarray  # []: grid steps that do any work
+
+
+def tile_plan(counts: jnp.ndarray, rows: int) -> TilePlan:
+    """The map from grid step to (group, row tile) for `counts` [X] rows a
+    group over `rows` sorted rows (padded up to whole tiles of TILE_ROWS).
+    A group without rows is visited by no step; a row tile that two groups
+    share is visited once a group, in group order; rows behind the last
+    group are visited by none. Entries from `num_tiles` on are padding.
+
+    Four comparisons against an iota, each summed over its groups, and
+    nothing else: running sums as `[X, X]` triangles, a visit's group as the
+    number of groups whose visits have all passed, its row tile as the
+    visit's number less the tiles revisited by then (a group that starts
+    inside a tile visits again the tile the group before it ended in). A
+    TPU runs the whole as seven small fusions. megablox's
+    `make_group_metadata` gives the same arrays through `cumsum`, `repeat`,
+    `histogram`, `take` and `roll`: nine `reduce-window`s, two scatter-adds,
+    a scatter and a `while` loop, each of the latter walking its groups one
+    by one."""
+    (X,) = counts.shape
+    tiles_m = -(-rows // TILE_ROWS)
+
+    def grid(n):  # ([n, X] of i, [n, X] of g)
+        return (jax.lax.broadcasted_iota(jnp.int32, (n, X), 0),
+                jax.lax.broadcasted_iota(jnp.int32, (n, X), 1))
+
+    i, g = grid(X + 1)
+    offsets = jnp.where(g < i, counts, 0).sum(1)
+    starts, ends = offsets[:-1], offsets[1:]
+    tiles = jnp.where(
+        counts == 0, 0, (ends + TILE_ROWS - 1) // TILE_ROWS - starts // TILE_ROWS)
+    i, g = grid(X)
+    tile_ends = jnp.where(g <= i, tiles, 0).sum(1)
+    revisits = (counts > 0) & (starts % TILE_ROWS != 0)
+    visit, g = grid(tiles_m + X - 1)
+    # Of groups 0 .. X - 2, those whose visits have all passed: at most
+    # X - 1, so the padding names the last group.
+    passed = ((tile_ends <= visit) & (g < X - 1)).sum(1, dtype=jnp.int32)
+    revisited = (revisits & (tile_ends <= visit + tiles)).sum(1, dtype=jnp.int32)
+    return TilePlan(
+        offsets, passed,
+        jnp.minimum(visit[:, 0] - revisited, tiles_m - 1),
+        tile_ends[-1],
+    )
+
+
+# The kernel below follows `gmm` of jax/experimental/pallas/ops/tpu/megablox/
+# gmm.py (Copyright 2024 The JAX Authors, Apache License 2.0): its grid, index
+# maps, accumulator and store mask, without the paths this repo never takes
+# (an existing output, a transposed right-hand side, a shard of the groups).
+# What is new is where the tile map comes from (`tile_plan`, handed in) and
+# `layer`: the map speaks of one layer's X groups, the right-hand index map
+# adds `layer * X`, and the stack of every layer's groups is read in place.
+# In HLO and in a device trace the kernel bears this function's name, as the
+# library's did: `gmm.N`.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gmm(x, w, group_offsets, group_ids, m_tile_ids, num_tiles, layer, *, interpret=False):
     m, k = x.shape
     n = w.shape[-1]
-    # In HLO and in a device trace the kernel bears the library function's
-    # name: `gmm.N`. bf16 products are exact in the kernel's f32 accumulator
-    # in one pass, and Mosaic takes no other precision for bf16 operands
-    # (the tests' process-wide "float32" would reach the kernel's dot).
+    X = group_offsets.shape[0] - 1
+    tm = TILE_ROWS
+    tk, tn = weight_tile(k, n, w.dtype.itemsize)
+    tiles_k, k_rem = -(-k // tk), k % tk
+    tiles_n = -(-n // tn)
+    x = jnp.pad(x, ((0, -m % tm), (0, 0)))
+    rows = x.shape[0]
+
+    def kernel(offsets, groups, m_tiles, layer, x_ref, w_ref, out_ref, acc):
+        del layer
+        step, k_i = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(k_i == 0)
+        def _zero():
+            acc[...] = jnp.zeros_like(acc)
+
+        def masked(tile, dim):
+            # The last k tile of a k that is no multiple of tk reads past it.
+            if not k_rem:
+                return tile
+            inside = jax.lax.broadcasted_iota(jnp.int32, tile.shape, dim) < k_rem
+            keep = (k_i < tiles_k - 1) | inside
+            return jnp.where(keep, tile.astype(jnp.float32), 0).astype(tile.dtype)
+
+        acc[...] += jax.lax.dot_general(
+            masked(x_ref[...], 1), masked(w_ref[...], 0),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+        @pl.when(k_i == tiles_k - 1)
+        def _store():
+            # Only the rows of this step's group: a row tile that two groups
+            # share keeps what the group before wrote.
+            group = groups[step]
+            row = m_tiles[step] * tm + jax.lax.broadcasted_iota(
+                jnp.int32, (tm, tn), 0)
+            mine = (row >= offsets[group]) & (row < offsets[group + 1])
+            out_ref[...] = jax.lax.select(
+                mine, acc[...], out_ref[...].astype(jnp.float32)
+            ).astype(out_ref.dtype)
+
+    def x_index(n_i, step, k_i, offsets, groups, m_tiles, layer):
+        return m_tiles[step], k_i
+
+    def w_index(n_i, step, k_i, offsets, groups, m_tiles, layer):
+        return layer[0] * X + groups[step], k_i, n_i
+
+    def out_index(n_i, step, k_i, offsets, groups, m_tiles, layer):
+        return m_tiles[step], n_i
+
+    # bf16 products are exact in the kernel's f32 accumulator in one pass,
+    # and Mosaic takes no other precision for bf16 operands (the tests'
+    # process-wide "float32" would reach the kernel's dot).
     exact = "default" if x.dtype == jnp.bfloat16 else "highest"
     with jax.default_matmul_precision(exact):
-        out = gmm(
-            jnp.pad(x, ((0, -m % TILE_ROWS), (0, 0))), w, sizes,
-            preferred_element_type=x.dtype,
-            tiling=(TILE_ROWS, *weight_tile(k, n, w.dtype.itemsize)),
+        out = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                in_specs=[
+                    pl.BlockSpec((tm, tk), x_index),
+                    pl.BlockSpec((None, tk, tn), w_index),
+                ],
+                out_specs=pl.BlockSpec((tm, tn), out_index),
+                grid=(tiles_n, num_tiles, tiles_k),
+                scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * rows * k * n, transcendentals=0,
+                bytes_accessed=(
+                    x.size * x.dtype.itemsize * tiles_n
+                    + k * n * w.dtype.itemsize * group_ids.shape[0]
+                    + rows * n * x.dtype.itemsize)),
             interpret=interpret,
-        )
+        )(group_offsets, group_ids, m_tile_ids, jnp.reshape(layer, (1,)), x, w)
     return out[:m]
 
 
-def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+def grouped_matmul(
+    x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray, *,
+    layer=0, plan: TilePlan | None = None,
+) -> jnp.ndarray:
+    """`sizes` [X] rows a group; `w` holds X groups, or a stack of them
+    (`[NL * X, k, n]`) of which `sizes` speaks of those of `layer`. `plan`:
+    `tile_plan(sizes, rows of x)`, from a caller that has several products
+    over the same rows."""
+    X = sizes.shape[0]
     mode = dispatch.kernel_mode()
     if mode == "reference":
+        if w.shape[0] != X:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((w.shape[0],), sizes.dtype), sizes, (layer * X,))
         return jax.lax.ragged_dot(x, w, sizes)
+    if plan is None:
+        plan = tile_plan(sizes, x.shape[0])
     # A Mosaic kernel cannot be partitioned by GSPMD: it runs whole on every
     # device (the engine refuses a sparse family a tp axis).
     call = dispatch.on_every_device(
-        functools.partial(_grouped_pallas, interpret=mode == "interpret"),
-        n_in=3, n_out=1,
+        functools.partial(gmm, interpret=mode == "interpret"), n_in=7, n_out=1,
     )
-    return call(x, w, sizes)
+    return call(x, w, *plan, jnp.asarray(layer, jnp.int32))

@@ -299,26 +299,72 @@ def test_the_sparse_expert_layer_equals_the_dense_sum(routing):
         assert float(jnp.abs(dropped - want).max()) > 1e-3
 
 
+# rows of x, groups of w (NL * X), the layer's X counts, the layer, k: what
+# the product is called with. "stack": sizes over all of w's groups, as the
+# function is called without a layer. "middle-layer": only the groups of
+# layer 1 of 3 hold rows. "share": 21 rows lie behind the last group.
+# "k-steps": two steps along k, the second over a remainder of 52 columns.
+GROUPED = {
+    "stack": (74, 24, [0] * 8 + [20, 0, 1, 9, 0, 30, 11, 3] + [0] * 8, None, 64),
+    "middle-layer": (74, 24, [20, 0, 1, 9, 0, 30, 11, 3], 1, 64),
+    "share": (95, 24, [20, 0, 1, 9, 0, 30, 11, 3], 2, 64),
+    "two-row-tiles": (300, 12, [0, 130, 126, 44], 1, 64),
+    "share-nothing-held": (40, 8, [0, 0, 0, 0], 1, 64),
+    "k-steps": (150, 6, [100, 0, 30], 1, 2100),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED))
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 0.0)],
                          ids=["f32", "bf16"])
-def test_the_grouped_matmul_kernel_equals_the_grouped_product(interpreted, dtype, tol):
-    """The Pallas grouped matmul (interpreted) against `jax.lax.ragged_dot`:
-    74 rows (no multiple of the 128-row tile) over groups 8..15 of 24 (one
-    layer of a stack), two of them empty. One k step, so bf16 is exact."""
-    from kubeai_tpu.ops.grouped_matmul import grouped_matmul
+def test_the_grouped_matmul_kernel_equals_the_grouped_product(
+        interpreted, dtype, tol, case):
+    """The Pallas grouped matmul (interpreted) against `jax.lax.ragged_dot`
+    within the tolerance, and BIT FOR BIT against the installed library's
+    `gmm` (interpreted) over every group of the stack: rows that are no
+    multiple of the 128-row tile, empty groups, one layer of a stack told by
+    its number. With one k step bf16 is exact; with two, the sum is taken in
+    another order than the plain product's."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm as library_gmm
 
+    from kubeai_tpu.ops.grouped_matmul import (
+        TILE_ROWS, grouped_matmul, tile_plan, weight_tile)
+
+    rows, G, counts, layer, k = GROUPED[case]
+    if k > 64:
+        tol = 1e-3 if dtype == jnp.float32 else 0.05
     rng = np.random.default_rng(3)
-    x = jnp.asarray(rng.standard_normal((74, 64)), dtype)
-    w = jnp.asarray(rng.standard_normal((24, 64, 96)) * 0.1, dtype)
-    sizes = np.zeros(24, np.int32)
-    sizes[8:16] = [20, 0, 1, 9, 0, 30, 11, 3]
-    got = grouped_matmul(x, w, jnp.asarray(sizes))
-    want = jax.lax.ragged_dot(x, w, jnp.asarray(sizes))
-    assert got.shape == (74, 96) and got.dtype == dtype
+    x = jnp.asarray(rng.standard_normal((rows, k)), dtype)
+    w = jnp.asarray(rng.standard_normal((G, k, 96)) * 0.1, dtype)
+    counts = jnp.asarray(counts, jnp.int32)
+    X = counts.shape[0]
+    held = int(counts.sum())
+    whole = np.zeros(G, np.int32)  # the sizes the library is called with
+    whole[(layer or 0) * X:][:X] = counts
+    if layer is None:
+        got = grouped_matmul(x, w, counts)
+    else:
+        got = grouped_matmul(
+            x, w, counts, layer=jnp.int32(layer), plan=tile_plan(counts, rows))
+    assert got.shape == (rows, 96) and got.dtype == dtype
+    want = jax.lax.ragged_dot(x, w, jnp.asarray(whole))
     np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol)
-    moved = jax.lax.ragged_dot(x, w, jnp.asarray(np.roll(sizes, 1)))
-    assert float(jnp.abs(moved.astype(jnp.float32) - want.astype(jnp.float32)).max()) > 0.1
+        np.asarray(got[:held], np.float32), np.asarray(want[:held], np.float32),
+        atol=tol)
+    with jax.default_matmul_precision(
+            "default" if dtype == jnp.bfloat16 else "highest"):
+        library = library_gmm(
+            jnp.pad(x, ((0, -rows % TILE_ROWS), (0, 0))), w, jnp.asarray(whole),
+            preferred_element_type=dtype,
+            tiling=(TILE_ROWS, *weight_tile(k, 96, w.dtype.itemsize)),
+            interpret=True)
+    # Rows behind the last group are written by nobody, in either.
+    np.testing.assert_array_equal(
+        np.asarray(got[:held], np.float32), np.asarray(library[:held], np.float32))
+    if held:
+        moved = jax.lax.ragged_dot(x, w, jnp.asarray(np.roll(whole, 1)))
+        gap = jnp.abs(moved.astype(jnp.float32) - want.astype(jnp.float32))
+        assert float(gap[:held].max()) > 0.1
 
 
 def test_a_sparse_family_refuses_a_forward_that_computes_experts_densely():
