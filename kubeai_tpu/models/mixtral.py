@@ -272,12 +272,87 @@ def _qkv(h, lp, cfg, positions, inv_freq):
     )
 
 
+# Most assignments (rows x k) whose order `_dispatch` finds by comparison;
+# above it they are sorted. The comparison is [N * k, N * k] and the two
+# one-hot products [N * k, N] x [N, E], so their cost grows with the square
+# where a sort and a gather grow with N * k. Read on a v5e (my chip run, PR
+# 48: us a layer of `_dispatch` + `_combine` at bf16, the wall clock of a scan
+# of 64 layers whose products are one elementwise pass, less the same scan
+# without the books; median of 30): by comparison / sorted with the inverse
+# scattered / sorted with the inverse as a second `argsort`:
+#     512 (64 x 8, 16 held of 128, E 6,144)     11.0 /  57.7 /  60.3
+#     640 (64 x 10, 64 held of 512, E 2,048)     5.7 /  44.1 /  42.6
+#   1,024 (128 x 8, 128 held, E 2,048)          16.2 /  56.0 /  54.9
+#   1,280 (128 x 10, 64 held of 512)            14.8 /  71.1 /  70.2
+#   2,048 (256 x 8, 128 held)                   79.1 /  84.7 /  80.5
+#   5,120 (512 x 10, 64 held of 512)           195.3 / 217.9 / 201.9
+#  16,384 (2,048 x 8, 128 held)                    - / 489.8 / 418.8
+# Four to eight times cheaper up to 1,280, level from 2,048 on, where the
+# products' square would soon lead (4 GFLOP at 2,048, 275 at 16,384): the
+# largest size read at which the comparison clearly wins. The second
+# `argsort` beats the scatter at every size read above it.
+RANK_BY_COMPARISON_MAX = 1280
+
+
+def _exact(dtype):
+    """The precision at which a product adds up its operands' products as
+    they are: bf16 operands are exact in one pass into a float32 sum,
+    float32 operands (the tests') need every pass."""
+    return None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+
+
+def _dispatch(x, flat, k, X):
+    """Rows `x` [N, E] and their assignments `flat` [N * k] (the local expert
+    of assignment n * k + j, X for one that is not held) -> (xs [N * k, E]:
+    the rows in the order of their experts, an expert's in row order;
+    counts [X]: assignments an expert; dest [N, k]: where in `xs` each
+    assignment's row went, the inverse of the stable `argsort(flat)`).
+
+    Few assignments (a decode step, a block) are put in order where they
+    lie: `dest` is ONE comparison of every assignment's key with every
+    other's, summed, and `xs` a product with the [N * k, N] matrix of zeros
+    and ones that says which row goes where (one term a row, so exact).
+    Nothing is sorted, gathered or scattered; on a v5e the `argsort`, the
+    row gather and `bincount`'s scatter-add cost 4.5, 9.7 and 6.8 us a layer
+    at 640 assignments (PERF.md section 5). Many (an admission) are sorted."""
+    (NK,) = flat.shape
+    counts = (flat[:, None] == jnp.arange(X, dtype=flat.dtype)).sum(
+        0, dtype=jnp.int32)
+    if NK > RANK_BY_COMPARISON_MAX:
+        order = jnp.argsort(flat)  # stable
+        return x[order // k], counts, jnp.argsort(order).reshape(-1, k)
+    key = flat * NK + jnp.arange(NK, dtype=flat.dtype)  # distinct; ordered as the stable sort orders
+    dest = (key[None, :] < key[:, None]).sum(1, dtype=jnp.int32).reshape(-1, k)
+    goes = (dest[None] == jnp.arange(NK, dtype=jnp.int32)[:, None, None]).any(-1)
+    xs = jnp.dot(goes.astype(x.dtype), x, precision=_exact(x.dtype),
+                 preferred_element_type=x.dtype)
+    return xs, counts, dest
+
+
+def _combine(out, dest, weights):
+    """out [N * k, E] in `_dispatch`'s order -> [N, E] float32: row n's k
+    results `out[dest[n, j]]` under `weights` [N, k] (of `out`'s dtype),
+    summed in float32. Few assignments: a product with the [N, N * k] matrix
+    that holds each weight at its assignment's place (at most one term an
+    entry, so exact), the same products summed in another order than the
+    gather-then-sum that many assignments keep."""
+    N, k = dest.shape
+    if N * k > RANK_BY_COMPARISON_MAX:
+        return jnp.einsum("nke,nk->ne", out[dest], weights,
+                          preferred_element_type=jnp.float32)
+    at = dest[:, :, None] == jnp.arange(N * k, dtype=jnp.int32)  # [N, k, N * k]
+    return jnp.dot(
+        jnp.where(at, weights[:, :, None], 0).sum(1), out,
+        precision=_exact(out.dtype), preferred_element_type=jnp.float32)
+
+
 def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
     """x [N, E] through the experts each row took (`topi` [N, k], weights
-    `probs` [N, k] float32): the N * k assignments sorted by expert, one
-    grouped product over the experts that hold any, and each row's k
-    results added back weighted. No assignment is dropped, whatever the
-    load of an expert; one that gets no row costs nothing.
+    `probs` [N, k] float32): the N * k assignments in the order of their
+    experts (`_dispatch`), one grouped product over the experts that hold
+    any, and each row's k results added back weighted (`_combine`). No
+    assignment is dropped, whatever the load of an expert; one that gets no
+    row costs nothing.
 
     `experts` holds every layer's weights, [NL, X, ...]; the grouped
     product reads them as NL * X groups and is told which X are `layer`'s,
@@ -286,7 +361,7 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
     `first` (None: every expert the router scores is held, `topi` counts
     from 0): the layer holds a SHARE of the router's experts, global ids
     `first .. first + X - 1`. An assignment to an expert that lives on
-    another chip is sorted behind the held ones, belongs to no group of
+    another chip is ordered behind the held ones, belongs to no group of
     the product and adds nothing: what comes back is this share's part of
     the routed sum, under the weights of the whole set."""
     N, k = topi.shape
@@ -296,9 +371,7 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
         if first is not None:
             flat = flat - first
             flat = jnp.where((flat >= 0) & (flat < X), flat, X)
-        order = jnp.argsort(flat)  # stable: an expert's rows in row order
-        xs = x[order // k]  # [N * k, E]
-        counts = jnp.bincount(flat, length=X).astype(jnp.int32)
+        xs, counts, dest = _dispatch(x, flat, k, X)
     with jax.named_scope("moe_experts"):
         # One tile map for the three products, over this layer's X groups;
         # only the weights are NL * X groups wide.
@@ -313,13 +386,11 @@ def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
         out = product(jax.nn.silu(g) * u, stacked["w_down"])
     with jax.named_scope("moe_combine"):
         if first is not None:
-            # Rows behind the last group are the product's to leave unwritten.
-            out = jnp.where((flat[order] < X)[:, None], out, 0)
-        back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
-        return jnp.einsum(
-            "nke,nk->ne", out[back].reshape(N, k, -1), probs.astype(out.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(x.dtype)
+            # Rows behind the last group are the product's to leave unwritten
+            # (a zero weight does not do: 0 x NaN is NaN).
+            held = jnp.arange(N * k) < counts.sum()
+            out = jnp.where(held[:, None], out, 0)
+        return _combine(out, dest, probs.astype(out.dtype)).astype(x.dtype)
 
 
 @jax.named_scope("moe_ffn")

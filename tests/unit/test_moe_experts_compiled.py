@@ -1,20 +1,28 @@
-"""Guard on the COMPILED expert layer of `qwen3-next-80b-a3b.decode-sat`'s
-decode chunk: beside its three grouped products a routed layer spends a few
-dense instructions on their tile map (`ops/grouped_matmul.py:tile_plan`), not
-the sixty of the library's group metadata over `NL x X` groups with their
-scatters and their `while` (PR 47), and the products are still the kernel
-`gmm` that the per-layer metrics find by that name.
+"""Guards on the COMPILED routed and recurrent layers of
+`qwen3-next-80b-a3b.decode-sat`'s decode chunk. Beside its three grouped
+products a routed layer spends a few dense instructions on their tile map
+(`ops/grouped_matmul.py:tile_plan`), not the sixty of the library's group
+metadata over `NL x X` groups with their scatters and their `while` (PR 47),
+and the products are still the kernel `gmm` that the per-layer metrics find by
+that name. Its 640 assignments are put in order and summed back by a
+comparison and two small products, with no sort, scatter or gather
+(`models/mixtral.py:_dispatch`, `_combine`), and the convolution of a
+recurrent layer reads its taps as slices of the pool, with no relayout of the
+window (`models/qwen3_next.py:_conv_step`) (PR 48). An admission's
+assignments are still sorted.
 
 One ahead-of-time compile of the decode program alone, for a described v5e
-(nothing runs; a compile that passes is not a chip run): 17 s. The family's
-programs take the state pools as one more argument than `tests/perf/aot.py`
-passes, so the lowering is written out here as
-`tests/perf/test_aot_qwen3_next.py:compile_hybrid_cell` writes it, less the
+(nothing runs; a compile that passes is not a chip run): 17 s, shared by the
+tests of this file. The family's programs take the state pools as one more
+argument than `tests/perf/aot.py` passes, so the lowering is written out here
+as `tests/perf/test_aot_qwen3_next.py:compile_hybrid_cell` writes it, less the
 admission and the weights' program, which would make it 80 s."""
 
 import os
 import re
 import sys
+
+import pytest
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -126,9 +134,27 @@ def instructions_in_scope(text: str, scope: str) -> dict[str, list[tuple[str, st
     return found
 
 
-def test_a_routed_layer_spends_a_few_dense_instructions_beside_its_products(topo):  # noqa: F811
+def lines_in_scope(text: str, scope: str) -> list[str]:
+    """Every instruction's line, inside fusions too, whose innermost named
+    scope is `scope`."""
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        scopes = SCOPE.findall(name.group(1)) if name else []
+        if scopes and scopes[-1] == scope:
+            found.append(line)
+    return found
+
+
+@pytest.fixture(scope="module")
+def decode_chunk(topo):  # noqa: F811
+    """(the cell's configuration, its decode chunk's compiled text)."""
     cfg = aot.load_config("qwen3-next-80b-a3b-v5e1")
-    text = compiled_decode_chunk(topo, cfg)
+    return cfg, compiled_decode_chunk(topo, cfg)
+
+
+def test_a_routed_layer_spends_a_few_dense_instructions_beside_its_products(decode_chunk):
+    cfg, text = decode_chunk
     found = instructions_in_scope(text, "moe_experts")
     # The layer loop's body holds a period of 4 layers, each of them routed.
     period = cfg["full_attention_interval"]
@@ -144,3 +170,81 @@ def test_a_routed_layer_spends_a_few_dense_instructions_beside_its_products(topo
     # its groups one by one.
     everywhere = {op for insts in found.values() for _, op in insts}
     assert not everywhere & {"while", "scatter", "sort", "gather"}, everywhere
+
+
+# Instructions a routed layer, read at PR 48: 5 and 7. Until then 9 and 11,
+# among them a sort, two scatters (`bincount`, the inverse permutation) and
+# the two row gathers, 4.5 to 11.2 us each on the chip (PERF.md section 5).
+BOOKS_A_LAYER = {"moe_dispatch": 8, "moe_combine": 10}
+
+
+@pytest.mark.parametrize("scope", list(BOOKS_A_LAYER))
+def test_a_decode_steps_assignments_are_ordered_and_summed_where_they_lie(
+        decode_chunk, scope):
+    """640 assignments a layer: ranked by one comparison, gathered and
+    summed by two small products (`models/mixtral.py:_dispatch`,
+    `_combine`)."""
+    cfg, text = decode_chunk
+    found = instructions_in_scope(text, scope)
+    body = max(found.values(), key=len)
+    period = cfg["full_attention_interval"]
+    assert period <= len(body) <= BOOKS_A_LAYER[scope] * period, (len(body), body)
+    # Anywhere in the program, inside fusions too.
+    inside = " ".join(lines_in_scope(text, scope))
+    assert not re.search(r" (sort|scatter|gather|while)\(", inside)
+
+
+def test_the_convolution_reads_its_taps_where_they_lie(decode_chunk):
+    """`models/qwen3_next.py:_conv_step`: no copy or transpose of the K - 1
+    inputs a slot keeps (they were re-laid-out as a `[B, K, C]` window three
+    times a layer), and no sum over a 4-row sublane axis."""
+    cfg, text = decode_chunk
+    C = (2 * cfg["linear_num_key_heads"] * cfg["linear_key_head_dim"]
+         + cfg["linear_num_value_heads"] * cfg["linear_value_head_dim"])
+    lines = lines_in_scope(text, "gdn_conv")
+    assert lines
+    moved = [
+        line for line in lines
+        if re.search(r" (copy|transpose)\(", line)
+        and re.search(r"\[[\d,]*\b(%d|%d)\b[\d,]*\]" % (C, 3 * C), line)]
+    assert not moved, moved
+    assert not [line for line in lines if "T(4,128)" in line]
+
+
+def test_an_admissions_assignments_are_still_sorted(topo):  # noqa: F811
+    """Over `RANK_BY_COMPARISON_MAX` (two 128-token prompts of this cell are
+    2,560 assignments a layer) the comparison's square costs what the sort
+    it replaces costs, and soon more: the same function, traced with more
+    rows, keeps the sort."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from kubeai_tpu.models import mixtral
+    from kubeai_tpu.ops import dispatch
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def compiled(rows):
+        shapes = [
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+            for shape, dtype in (
+                ((rows, 2048), jnp.bfloat16), ((rows, 10), jnp.int32),
+                ((rows, 10), jnp.float32), ((2, 64, 2048, 512), jnp.bfloat16),
+                ((2, 64, 512, 2048), jnp.bfloat16))]
+
+        def layer(x, topi, probs, w_in, w_out):
+            experts = {"w_gate": w_in, "w_up": w_in, "w_down": w_out}
+            return mixtral._moe_sparse(x, experts, jnp.int32(1), topi, probs, None, 0)
+
+        return jax.jit(layer).lower(*shapes).compile().as_text()
+
+    saved = dispatch.kernel_mode
+    dispatch.kernel_mode = lambda: "compiled"
+    try:
+        assert 64 * 10 <= mixtral.RANK_BY_COMPARISON_MAX < 256 * 10
+        admission, step = compiled(256), compiled(64)
+    finally:
+        dispatch.kernel_mode = saved
+    assert re.search(r" sort\(", admission) and "gmm" in admission
+    assert not re.search(r" (sort|scatter|gather)\(", step) and "gmm" in step

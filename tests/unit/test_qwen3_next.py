@@ -174,6 +174,56 @@ def test_a_prompt_padded_into_a_larger_bucket_leaves_the_state_of_the_unpadded_o
                           np.asarray(state["conv"][:, SLOT]))
 
 
+def window_step(conv, li, u, w):
+    """A position of the convolution as `decode_step_paged` took it until PR
+    48: the row as a `[B, K, C]` window, summed over the taps' axis."""
+    B, C = u.shape
+    K = w.shape[0]
+    tail = jax.lax.dynamic_index_in_dim(conv, li, 0, keepdims=False)
+    window = jnp.concatenate(
+        [tail.reshape(B, K - 1, C), u[:, None].astype(tail.dtype)], axis=1)
+    y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None], axis=1)
+    return y, jax.lax.dynamic_update_index_in_dim(
+        conv, window[:, 1:].reshape(B, -1), li, 0)
+
+
+def test_the_decode_convolution_reads_its_taps_where_they_lie():
+    """`_conv_step` against the three lines it replaces, over K - 1
+    consecutive positions from an admission's row (after which no input of
+    the prompt is left in it): the new row bit for bit, the sum within one
+    float32 rounding of its terms (the same four products, in tap order)."""
+    cfg, params = served(jnp.bfloat16)
+    K, C, NS = cfg.linear_conv_kernel_dim, cfg.conv_dim, cfg.state_layers
+    tokens = np.zeros((1, BUCKET), np.int32)
+    tokens[0, :PROMPT] = TOKENS[:PROMPT]
+    rows = jax.jit(lambda p, t, l: qn.prefill(p, cfg, t, l, state=True)[3])(
+        params, jnp.asarray(tokens), jnp.array([PROMPT]))["conv"]
+    assert rows.shape == (NS, 1, (K - 1) * C)
+    rng = np.random.default_rng(48)
+    pool = jnp.asarray(rng.standard_normal((NS, SLOTS, (K - 1) * C)), cfg.dtype)
+    pool = pool.at[:, SLOT].set(rows[:, 0].astype(cfg.dtype))
+    new, old = jax.jit(qn._conv_step), jax.jit(window_step)
+    w = params["layers"]["gdn"]["conv_w"]
+    assert w.shape == (NS, K, C)
+    theirs = pool
+    for step in range(K - 1):
+        for li in range(NS):
+            u = jnp.asarray(rng.standard_normal((SLOTS, C)), jnp.float32)
+            y, pool = new(pool, jnp.int32(li), u, w[li])
+            want, theirs = old(theirs, jnp.int32(li), u, w[li])
+            np.testing.assert_array_equal(
+                np.asarray(pool, np.float32), np.asarray(theirs, np.float32))
+            terms = np.abs(np.asarray(w[li], np.float32)).max() * (
+                np.abs(np.asarray(theirs[li], np.float32)).max() + 1) * K
+            assert y.dtype == jnp.float32 and y.shape == (SLOTS, C)
+            assert np.abs(np.asarray(y) - np.asarray(want)).max() <= 2.0 ** -23 * terms
+            assert np.abs(np.asarray(want)).max() > 1e-3
+    # The newest input is the row's last.
+    np.testing.assert_array_equal(
+        np.asarray(pool[NS - 1, :, -C:], np.float32),
+        np.asarray(u.astype(cfg.dtype), np.float32))
+
+
 @pytest.mark.parametrize("length,padded", [(150, 192), (64, 64), (37, 64), (9, 16)])
 def test_the_chunked_scan_is_the_rule_position_by_position(length, padded):
     H, DK, DV = 4, 16, 8

@@ -299,6 +299,110 @@ def test_the_sparse_expert_layer_equals_the_dense_sum(routing):
         assert float(jnp.abs(dropped - want).max()) > 1e-3
 
 
+def sorted_moe(x, experts, layer, topi, probs, first=None):
+    """The expert layer as it kept its books until PR 48, the reference of
+    the test below: the assignments sorted, the rows gathered, `bincount`,
+    the inverse permutation scattered, `out[back]` summed under the weights.
+    Returns (xs, counts, back, out, y)."""
+    from kubeai_tpu.ops.grouped_matmul import grouped_matmul
+
+    N, k = topi.shape
+    NL, X = experts["w_gate"].shape[:2]
+    flat = topi.reshape(-1)
+    if first is not None:
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < X), flat, X)
+    order = jnp.argsort(flat)
+    xs = x[order // k]
+    counts = jnp.bincount(flat, length=X).astype(jnp.int32)
+    stacked = {n: w.reshape(NL * X, *w.shape[2:]) for n, w in experts.items()}
+    product = functools.partial(grouped_matmul, sizes=counts, layer=layer)
+    g, u = product(xs, stacked["w_gate"]), product(xs, stacked["w_up"])
+    out = product(jax.nn.silu(g) * u, stacked["w_down"])
+    out = jnp.where((flat[order] < X)[:, None], out, 0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
+    y = jnp.einsum(
+        "nke,nk->ne", out[back].reshape(N, k, -1), probs.astype(out.dtype),
+        preferred_element_type=jnp.float32)
+    return xs, counts, back, out, y
+
+
+# rows, k, experts held, experts the router scores, `first`, how rows are
+# routed. The first three are the cells' decode and block shapes; the last is
+# over `RANK_BY_COMPARISON_MAX`, where the assignments are still sorted.
+BOOKS = {
+    "qwen3-next-640": (64, 10, 64, 512, 0, "top_k"),
+    "sdar-1024": (128, 8, 128, 128, None, "top_k"),
+    "k-exaone-512": (64, 8, 16, 128, 0, "top_k"),
+    "every-row-to-one-expert": (24, 4, 8, 8, None, "one"),
+    "no-assignment-held": (24, 4, 8, 32, 24, "none_held"),
+    "a-share-in-the-middle": (40, 6, 16, 64, 32, "top_k"),
+    "over-the-crossover": (192, 8, 16, 32, 16, "top_k"),
+}
+
+
+@pytest.mark.parametrize("mode", ["reference", "interpret"])
+@pytest.mark.parametrize("case", list(BOOKS))
+def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, case):
+    """`_dispatch` and `_combine` against the sort, gather and scatter they
+    replace: `dest` is the inverse of the stable `argsort`, `counts` is
+    `bincount`, the rows handed to the products are bit for bit the gathered
+    ones, the combined rows are the same products summed in another order
+    (one float32 rounding of the terms' size) and the layer's output is the
+    same bf16 but where that rounding straddles a bf16 step."""
+    N, k, X, R, first, routing = BOOKS[case]
+    monkeypatch.setattr(dispatch, "FORCE_INTERPRET", mode == "interpret")
+    assert dispatch.kernel_mode() == mode
+    assert (N * k > mixtral.RANK_BY_COMPARISON_MAX) == (case == "over-the-crossover")
+    E, M, NL = 128, 64, 2
+    rng = np.random.default_rng(N * k)
+    x = jnp.asarray(rng.standard_normal((N, E)), jnp.bfloat16)
+    scores = rng.standard_normal((N, R)).astype(np.float32)
+    topi = np.argsort(-scores, -1)[:, :k]
+    if routing == "one":
+        topi = np.full((N, k), 5)
+    elif routing == "none_held":
+        topi = topi % first  # every id under the share's first
+    probs = jnp.asarray(rng.dirichlet(np.ones(k), N), jnp.float32)
+    topi = jnp.asarray(topi, jnp.int32)
+    experts = {
+        name: jnp.asarray(rng.standard_normal((NL, X, *shape)) * 0.2, jnp.bfloat16)
+        for name, shape in (("w_gate", (E, M)), ("w_up", (E, M)), ("w_down", (M, E)))}
+    layer = jnp.int32(1)
+    xs_s, counts_s, back, out, y_s = sorted_moe(x, experts, layer, topi, probs, first)
+
+    flat = topi.reshape(-1)
+    if first is not None:
+        flat = jnp.where((flat >= first) & (flat < first + X), flat - first, X)
+    xs, counts, dest = mixtral._dispatch(x, flat, k, X)
+    np.testing.assert_array_equal(np.asarray(dest).reshape(-1), np.asarray(back))
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_s))
+    assert xs.dtype == x.dtype
+    np.testing.assert_array_equal(
+        np.asarray(xs, np.float32), np.asarray(xs_s, np.float32))
+    if routing == "none_held":
+        assert int(counts.sum()) == 0
+    elif first is not None:
+        assert 0 < int(counts.sum()) < N * k
+
+    weights = probs.astype(out.dtype)
+    y = mixtral._combine(out, dest, weights)
+    assert y.dtype == jnp.float32
+    terms = jnp.abs(out[back].reshape(N, k, -1).astype(jnp.float32)
+                    * weights.astype(jnp.float32)[:, :, None]).sum(1)
+    assert np.all(np.abs(np.asarray(y) - np.asarray(y_s))
+                  <= 2.0 ** -23 * np.asarray(terms))
+
+    got = mixtral._moe_sparse(x, experts, layer, topi, probs, None, first)
+    want = y_s.astype(x.dtype)
+    differ = np.asarray(got, np.float32) != np.asarray(want, np.float32)
+    assert differ.mean() <= 1e-3, differ.mean()
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=2.0 ** -7)
+    if routing != "none_held":
+        assert float(jnp.abs(want.astype(jnp.float32)).max()) > 0.05
+
+
 # rows of x, groups of w (NL * X), the layer's X counts, the layer, k: what
 # the product is called with. "stack": sizes over all of w's groups, as the
 # function is called without a layer. "middle-layer": only the groups of
