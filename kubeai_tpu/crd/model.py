@@ -575,34 +575,6 @@ class KVCacheSpec:
             )
 
 
-STEP_OVERLAP_MODES = ("auto", "on", "off")
-
-
-@dataclasses.dataclass
-class EngineStep:
-    """Engine step-loop tuning (in-tree engine only). `overlap` drives
-    the overlapped step pipeline (engine flag --step-overlap): dispatch
-    decode chunk N+1 before reaping chunk N so readback, admission,
-    detokenize and SSE fan-out hide behind device compute —
-    token-identical to the synchronous loop. "auto" (the engine default)
-    overlaps wherever the topology allows and degrades to synchronous
-    for lockstep multihost and pipeline parallelism; "on" requires it
-    (the engine refuses with a typed error where unsupported); "off"
-    forces the synchronous loop."""
-
-    overlap: str = ""  # "" = engine default (auto)
-
-    def enabled(self) -> bool:
-        return bool(self.overlap)
-
-    def validate(self) -> None:
-        if self.overlap and self.overlap not in STEP_OVERLAP_MODES:
-            raise ValidationError(
-                "engineStep.overlap must be one of "
-                f"{list(STEP_OVERLAP_MODES)}"
-            )
-
-
 # Logical mesh axes a sharding block may size (SpecLayout vocabulary:
 # data-parallel replicas, FSDP weight shards, tensor-parallel shards).
 MESH_AXES = ("data", "fsdp", "tp")
@@ -698,8 +670,6 @@ class ModelSpec:
     kv_cache: KVCacheSpec = dataclasses.field(default_factory=KVCacheSpec)
     # Engine snapshot/restore cold-start path (in-tree engine only).
     cold_start: ColdStart = dataclasses.field(default_factory=ColdStart)
-    # Engine step-loop tuning (overlapped step pipeline; in-tree only).
-    engine_step: EngineStep = dataclasses.field(default_factory=EngineStep)
     # Multi-host slice-group serving (in-tree engine only).
     sharding: Sharding = dataclasses.field(default_factory=Sharding)
     # Graceful-drain budget: seconds an engine waits for in-flight
@@ -810,11 +780,6 @@ class ModelSpec:
         if self.cold_start.enabled and self.engine != ENGINE_KUBEAI_TPU:
             raise ValidationError(
                 "spec.coldStart requires the KubeAITPU engine"
-            )
-        self.engine_step.validate()
-        if self.engine_step.enabled() and self.engine != ENGINE_KUBEAI_TPU:
-            raise ValidationError(
-                "spec.engineStep requires the KubeAITPU engine"
             )
         self.sharding.validate()
         if self.sharding.enabled() and self.engine != ENGINE_KUBEAI_TPU:
@@ -989,7 +954,6 @@ class Model:
         kvs = spec.get("kvSharing", {}) or {}
         kvc = spec.get("kvCache", {}) or {}
         cold = spec.get("coldStart", {}) or {}
-        estep = spec.get("engineStep", {}) or {}
         shd = spec.get("sharding", {}) or {}
         ten = spec.get("tenancy", {}) or {}
         slo = spec.get("slo", {}) or {}
@@ -1156,9 +1120,6 @@ class Model:
                     snapshot_url=cold.get("snapshotURL", ""),
                     publish=bool(cold.get("publish", True)),
                     prewarm=bool(cold.get("prewarm", True)),
-                ),
-                engine_step=EngineStep(
-                    overlap=estep.get("overlap", "") or "",
                 ),
                 sharding=Sharding(
                     hosts=int(shd.get("hosts", 0) or 0),
@@ -1353,8 +1314,6 @@ def _spec_to_dict(s: ModelSpec) -> dict:
         }
     if s.kv_cache.enabled():
         d["kvCache"] = {"dtype": s.kv_cache.dtype}
-    if s.engine_step.enabled():
-        d["engineStep"] = {"overlap": s.engine_step.overlap}
     if s.sharding.enabled():
         shd: dict[str, Any] = {}
         if s.sharding.hosts:

@@ -146,28 +146,10 @@ class EngineConfig:
     # Weight-only quantization: "" (bf16) or "int8" (per-channel symmetric;
     # halves HBM weight traffic on the memory-bound decode path).
     quantization: str = ""
-    # Paged decode layout: "" = decided by the pool ("fused", the stacked
-    # pool read and written in place, for bf16; "per_layer",
-    # scatter-then-attend inside the layer scan, for int8). An explicit
-    # value is honoured: for tests and A/B runs, not for deployments
-    # (PERF.md section 6, PR 25; the field goes when ROADMAP C3 does).
-    decode_kernel: str = ""
     # LoRA hot-swap: number of simultaneously loaded adapters (0 disables
     # the LoRA path entirely — no extra compute in the compiled graphs).
     max_adapters: int = 0
     max_lora_rank: int = 16
-    # Overlapped step pipeline: "auto" (default — overlap ON wherever the
-    # topology allows it), "on" (require overlap; typed
-    # StepOverlapUnsupported where it can't run), "off" (synchronous
-    # loop). When on, step() dispatches decode chunk N+1 BEFORE reaping
-    # chunk N's tokens, so readback, scheduler admission, detokenize and
-    # SSE fan-out for chunk N run concurrently with chunk N+1's device
-    # compute. Conservative barriers (pending admissions, cancel/release,
-    # drain, handoff export/import, prefix-page export/import, and any
-    # speculation window) force a reap before state mutates, so greedy
-    # AND seeded streams are token-identical to the synchronous loop.
-    # Auto-off for pipeline parallelism (pp > 1) and lockstep multihost.
-    step_overlap: str = "auto"
     # Pipeline parallelism (mesh pp axis > 1): decode microbatch count for
     # the GPipe schedule. 0 = the pp stage count (steady-state utilization
     # M/(M+P-1); raise toward num_slots for higher utilization at smaller
@@ -195,14 +177,6 @@ class EngineConfig:
             return self.num_pages
         per_slot = -(-self.max_seq_len // self.page_size)
         return 1 + self.num_slots * per_slot  # +1: reserved scratch page 0
-
-
-class StepOverlapUnsupported(ValueError):
-    """step_overlap='on' requested in a topology that cannot overlap
-    (pipeline parallelism, lockstep multihost): a second in-flight
-    program would race the GPipe stage handoffs / desynchronize the
-    per-step cross-host broadcast. 'auto' degrades to the synchronous
-    loop instead of raising."""
 
 
 class StepEvent(NamedTuple):
@@ -486,19 +460,14 @@ class Engine:
         # KV quantization: validated here, materialized with the pool
         # below ({"q8", "scale"} pool leaves; ops/kv_quant.py).
         from kubeai_tpu.ops.kv_quant import resolve_kv_dtype
-        from kubeai_tpu.ops.paged_attention import resolve_decode_kernel
+        from kubeai_tpu.ops.paged_attention import decode_layout
 
         self.kv_dtype = resolve_kv_dtype(cfg.kv_dtype)
         self._kv_quant = self.kv_dtype == "int8"
-        # Paged decode layout: decided by the pool's kind unless the
-        # config names one (int8 + "fused" is refused there).
-        self.decode_kernel = resolve_decode_kernel(
-            cfg.decode_kernel, quantized=self._kv_quant
-        )
-        if self._block is not None:
-            self._check_block_engine(draft)
-        if self._recurrent is not None or self._window is not None:
-            self._check_state_engine(draft)
+        # Paged decode layout: the pool's kind decides, nothing else.
+        self.decode_kernel = decode_layout(quantized=self._kv_quant)
+        if self._block is not None or self._beside_pages is not None:
+            self._check_family_engine(draft)
         if self._kv_quant and (cfg.speculate > 0 or draft is not None):
             raise ValueError(
                 "kv_dtype='int8' does not compose with speculative "
@@ -555,34 +524,19 @@ class Engine:
             else "per_layer"
         )
 
-        # Overlapped stepping: resolve the tri-state knob against the
-        # topology. pp > 1 already fills the device with microbatch ticks
+        # Overlapped stepping: step() dispatches decode chunk N+1 BEFORE
+        # reaping chunk N's tokens, so readback, scheduler admission,
+        # detokenize and SSE fan-out for chunk N run concurrently with
+        # chunk N+1's device compute. Conservative barriers (cancel /
+        # release, drain, handoff export/import, prefix-page export/import,
+        # and any speculation window) force a reap before state mutates, so
+        # greedy AND seeded streams are token-identical to the synchronous
+        # loop. pp > 1 already fills the device with microbatch ticks
         # inside ONE call and a second in-flight donated-buffer program
-        # would race the stage handoffs, so explicit "on" is a typed
-        # refusal and "auto" stays synchronous. (Lockstep multihost is
-        # enforced one level up — LockstepEngine / server main — because
-        # the engine cannot see its wrapper.)
-        overlap = cfg.step_overlap
-        if isinstance(overlap, bool):
-            overlap = "on" if overlap else "off"
-        overlap = (overlap or "auto").strip().lower()
-        if overlap not in ("auto", "on", "off"):
-            raise ValueError(
-                f"unknown step_overlap {cfg.step_overlap!r} "
-                "(expected 'auto' | 'on' | 'off')"
-            )
-        if self._pp > 1:
-            if overlap == "on":
-                raise StepOverlapUnsupported(
-                    "step_overlap='on' does not compose with pipeline "
-                    "parallelism (pp>1): the GPipe decode schedule already "
-                    "keeps the device busy with microbatch ticks and a "
-                    "second in-flight program would race the stage "
-                    "handoffs; use step_overlap='auto' or 'off'"
-                )
-            overlap = "off"
-        # Resolved: the step loop overlaps unless something said no.
-        self._overlap = overlap != "off"
+        # would race the stage handoffs, so it keeps the synchronous loop.
+        # (Lockstep multihost clears the flag one level up, LockstepEngine,
+        # because the engine cannot see its wrapper.)
+        self._overlap = self._pp == 1
         # Events reaped OUTSIDE step() (barrier reaps in cancel/drain/
         # handoff/prefix paths): queued here, prepended to the next
         # step()'s return so no token is ever dropped.
@@ -1010,13 +964,15 @@ class Engine:
             return "recurrent state"
         return "a window ring" if self._window is not None else None
 
-    def _check_state_engine(self, draft) -> None:
-        """What a family with state beside its pages is not served with:
-        each would need a snapshot of a slot's state at a position other
-        than its last, which nothing writes yet, or, of a window ring, a
-        rule for positions the ring has already forgotten. Preemption by
-        recompute needs none (the re-admission rebuilds the state, or both
-        pools, from position 0)."""
+    def _check_family_engine(self, draft) -> None:
+        """What a family that keeps something beside its pages, or generates
+        by blocks, is not served with: the one table. Beside the pages, each
+        row would need a snapshot of a slot's state at a position other than
+        its last, which nothing writes yet, or, of a window ring, a rule for
+        positions the ring has already forgotten (preemption by recompute
+        needs none: the re-admission rebuilds the state, or both pools, from
+        position 0); by blocks, each is a composition the one-token step has
+        and the block step does not yet (ROADMAP B10)."""
         cfg = self.cfg
         refused = [
             name for name, on in (
@@ -1026,24 +982,41 @@ class Engine:
                 ("kv_dtype int8", self._kv_quant),
                 ("max_adapters", cfg.max_adapters > 0),
                 ("a pp mesh axis", self.mesh.shape.get("pp", 1) > 1),
+                # Sparsely computed experts are one kernel on every device:
+                # an expert layer that holds a share is ROADMAP B2.
                 ("a tp mesh axis", self.mesh.shape.get("tp", 1) > 1),
-                ("decode_kernel per_layer", self.decode_kernel != "fused"),
             ) if on
         ]
         if refused:
-            self.refuse_state_snapshot(", ".join(refused))
+            raise self._not_served_with(", ".join(refused))
+        if self._block is not None:
+            R = self._block["block_length"]
+            if cfg.max_seq_len % R or any(b % R for b in cfg.buckets()):
+                raise ValueError(
+                    f"max_seq_len and the prefill buckets must be multiples "
+                    f"of the block length {R}"
+                )
+
+    def _not_served_with(self, what: str) -> ValueError:
+        """The one sentence a family's refusals are raised in; the family's
+        kind picks its middle."""
+        beside = self._beside_pages
+        kind = (
+            f"keeps {beside} beside its pages" if beside
+            else "generates by blocks"
+        )
+        return ValueError(
+            f"family {self.family.name} {kind} and is not served with: {what}"
+        )
 
     def refuse_state_snapshot(self, what: str) -> None:
         """Raise for `what` where it would need a snapshot of a slot's
         state, or move a slot's keys and values without the state that
         belongs to them (hand-off, pages served to or fetched from a peer,
-        spill). `_check_state_engine` asks it of the engine's options, the
-        server of its own, at construction."""
+        spill). The engine asks it of its own calls, the server of its
+        options, at construction."""
         if self._beside_pages is not None:
-            raise ValueError(
-                f"family {self.family.name} keeps {self._beside_pages} beside "
-                f"its pages and is not served with: {what}"
-            )
+            raise self._not_served_with(what)
 
     def kv_pools(self) -> list[dict] | None:
         """What /v1/state says of the pools of a family with two kinds of
@@ -1115,34 +1088,6 @@ class Engine:
         worth, so a chunk reads back `decode_chunk` tokens a slot as any
         family's does."""
         return max(1, self.cfg.decode_chunk // self._block["block_length"])
-
-    def _check_block_engine(self, draft) -> None:
-        """What a family that generates by blocks is not served with."""
-        cfg, R = self.cfg, self._block["block_length"]
-        refused = [
-            name for name, on in (
-                ("prefill_chunk", cfg.prefill_chunk > 0),
-                ("prefix_cache", cfg.prefix_cache),
-                ("speculate", cfg.speculate > 0 or draft is not None),
-                ("kv_dtype int8", self._kv_quant),
-                ("max_adapters", cfg.max_adapters > 0),
-                ("a pp mesh axis", self.mesh.shape.get("pp", 1) > 1),
-                # Its sparsely computed experts are one kernel on every
-                # device: an expert layer that holds a share is ROADMAP B2.
-                ("a tp mesh axis", self.mesh.shape.get("tp", 1) > 1),
-                ("decode_kernel per_layer", self.decode_kernel != "fused"),
-            ) if on
-        ]
-        if refused:
-            raise ValueError(
-                f"family {self.family.name} generates by blocks and is not "
-                f"served with: {', '.join(refused)}"
-            )
-        if cfg.max_seq_len % R or any(b % R for b in cfg.buckets()):
-            raise ValueError(
-                f"max_seq_len and the prefill buckets must be multiples of "
-                f"the block length {R}"
-            )
 
     # ---- compiled functions -------------------------------------------------
 
@@ -1932,7 +1877,7 @@ class Engine:
                 )
 
             experts, k, layers = fam.route_dims(mcfg)
-            from kubeai_tpu.models.registry import route_dtype
+            from kubeai_tpu.ops.experts import route_dtype
 
             init = dict(
                 f=jnp.int32(0),
@@ -3756,7 +3701,7 @@ class Engine:
         """Admit pending prefills, then run one fused decode chunk
         (cfg.decode_chunk model steps in a single device call).
 
-        With step_overlap resolved on, the chunk dispatched this call is
+        Where the loop overlaps (`_overlap`), the chunk dispatched this call is
         reaped on the NEXT call: the device computes chunk N+1 while the
         host reads back and processes chunk N's tokens (readback,
         admission, detokenize, SSE fan-out all hide behind device

@@ -140,25 +140,9 @@ class LockstepEngine:
     is_lockstep = True  # server gates non-lockstep paths (embeddings)
 
     def __init__(self, inner: Engine):
-        from kubeai_tpu.engine.engine import StepOverlapUnsupported
-
         # Overlapped stepping cannot run under lockstep: every host must
         # replay the SAME op/step sequence, and an unreaped chunk on host
         # 0 would reorder its broadcast schedule relative to the workers'.
-        # Explicit "on" is a typed refusal; "auto" silently degrades to
-        # the synchronous loop.
-        # Defense in depth — server main() resolves this before the
-        # worker engines are even built.
-        explicit = inner.cfg.step_overlap
-        if (
-            explicit is True
-            or str(explicit).strip().lower() == "on"
-        ):
-            raise StepOverlapUnsupported(
-                "step_overlap='on' does not compose with lockstep "
-                "multihost: the overlapped reap would desynchronize the "
-                "per-step cross-host broadcast; use 'auto' or 'off'"
-            )
         inner._overlap = False
         self.inner = inner
         self._lock = threading.Lock()

@@ -3056,15 +3056,6 @@ def main(argv=None) -> int:
         "(CRD kvCache.dtype)",
     )
     ap.add_argument(
-        "--step-overlap", choices=["auto", "on", "off"], default="auto",
-        help="overlapped step pipeline: dispatch decode chunk N+1 before "
-        "reaping chunk N so readback/admission/detokenize/SSE hide "
-        "behind device compute (token-identical to the synchronous "
-        "loop). auto = on wherever the topology allows (off for "
-        "lockstep multihost and pipeline parallelism); on = require it "
-        "(typed error where unsupported) (CRD engineStep.overlap)",
-    )
-    ap.add_argument(
         "--speculate", type=int, default=0,
         help="speculative-decoding window (0 = off); prompt-lookup "
         "proposals unless --draft-url provides a draft model",
@@ -3196,23 +3187,6 @@ def main(argv=None) -> int:
         args.prefix_cache = True
     if args.prefix_cache and args.prefill_chunk <= 0:
         args.prefill_chunk = max(32, min(512, args.max_seq_len // 4))
-    if args.num_processes > 1:
-        # Lockstep multihost: every host must replay the SAME op/step
-        # sequence; an overlapped reap would reorder host 0's broadcast
-        # schedule relative to the workers'. Refuse an explicit "on"
-        # (typed — the operator asked for something this topology cannot
-        # do), auto-off otherwise — BEFORE EngineConfig is built, so the
-        # worker hosts' engines resolve identically to host 0's.
-        from kubeai_tpu.engine.engine import StepOverlapUnsupported
-
-        if args.step_overlap == "on":
-            raise StepOverlapUnsupported(
-                "--step-overlap on does not compose with lockstep "
-                "multihost (--num-processes > 1): the overlapped reap "
-                "would desynchronize the per-step cross-host broadcast; "
-                "use --step-overlap auto or off"
-            )
-        args.step_overlap = "off"
 
     logging.basicConfig(level=logging.INFO)
     log = logging.getLogger("kubeai-tpu-engine")
@@ -3302,7 +3276,6 @@ def main(argv=None) -> int:
         # weights to every process (engine/multihost.py).
         max_adapters=args.max_adapters,
         decode_chunk=args.decode_chunk,
-        step_overlap=args.step_overlap,
         quantization=args.quantization,
         kv_dtype=args.kv_dtype,
         speculate=args.speculate,

@@ -47,13 +47,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from kubeai_tpu.models.llama import _prefill_attention
-from kubeai_tpu.models.mixtral import EXPERT_LEAVES, _moe_sparse
-from kubeai_tpu.models.qwen3_next import _at
-from kubeai_tpu.models.registry import (
-    ModelFamily,
-    register_model_family,
-    route_dtype,
+from kubeai_tpu.models.registry import ModelFamily, register_model_family
+from kubeai_tpu.ops.attention import prefill_attention
+from kubeai_tpu.ops.experts import (
+    EXPERT_LEAVES,
+    at,
+    moe_sparse,
+    shared_expert,
+    stack_routes,
 )
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -358,13 +359,9 @@ def _moe_parts(x, mp, experts, layer, cfg):
     float32, and topi [N, k])."""
     topi, probs = _route(x, mp, cfg)
     with jax.named_scope("moe_shared"):
-        mid = jax.nn.silu(x @ mp["shared_gate"]) * (x @ mp["shared_up"])
-        shared = jnp.einsum(
-            "nm,me->ne", mid, mp["shared_down"],
-            preferred_element_type=jnp.float32,
-        )
-    routed = _moe_sparse(
-        x, experts, layer, topi, probs, cfg, first=cfg.first_expert
+        shared = shared_expert(x, mp)
+    routed = moe_sparse(
+        x, experts, layer, topi, probs, first=cfg.first_expert
     )
     return routed.astype(jnp.float32), shared, topi
 
@@ -377,14 +374,14 @@ def _ffn(x, layers, layer, slot, cfg):
     k = cfg.num_experts_per_tok
 
     def dense(x):
-        dp = _at(layers["dense"], jnp.minimum(layer, cfg.first_k_dense - 1))
+        dp = at(layers["dense"], jnp.minimum(layer, cfg.first_k_dense - 1))
         h = rms_norm(x, dp["post_norm"], cfg.rms_norm_eps)
         return x + _dense(h, dp, cfg), jnp.zeros((x.shape[0], k), jnp.int32)
 
     @jax.named_scope("moe_ffn")
     def moe(x):
         r = jnp.maximum(layer - cfg.first_k_dense, 0)
-        mp = _at(layers["moe"], r)
+        mp = at(layers["moe"], r)
         h = rms_norm(x, mp["post_norm"], cfg.rms_norm_eps)
         routed, shared, topi = _moe_parts(h, mp, layers["experts"], r, cfg)
         return x + (routed + shared).astype(x.dtype), topi
@@ -392,13 +389,6 @@ def _ffn(x, layers, layer, slot, cfg):
     if slot >= cfg.first_k_dense:
         return moe(x)
     return jax.lax.cond(layer < cfg.first_k_dense, dense, moe, x)
-
-
-def _stack_routes(topi, cfg):
-    """[periods, layers a period, *rows, k] -> [*rows, routed layers, k]:
-    the leading dense layers have no row."""
-    topi = topi.reshape(cfg.num_layers, *topi.shape[2:])[cfg.first_k_dense:]
-    return jnp.moveaxis(topi, 0, -2).astype(route_dtype(cfg.router_experts))
 
 
 def _stack_kind(per_period):
@@ -425,7 +415,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         with jax.named_scope("attn_window" if kind == WINDOW else "attn_global"):
             h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
             q, k, v = _qkv(h, lp, cfg, positions, rotate=kind == WINDOW)
-            attn = _prefill_attention(
+            attn = prefill_attention(
                 q, k, v, window=cfg.sliding_window if kind == WINDOW else 0
             ).reshape(A, S, -1)
             return x + jnp.einsum("bsh,he->bse", attn, lp["wo"]), k, v
@@ -435,7 +425,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         topis = []
         for slot, kind in enumerate(cfg.period_types):
             layer = pi * P + slot
-            x, k, v = attention(x, _at(layers["attn"], layer), kind)
+            x, k, v = attention(x, at(layers["attn"], layer), kind)
             kv[kind][0].append(k), kv[kind][1].append(v)
             flat, topi = _ffn(x.reshape(A * S, -1), layers, layer, slot, cfg)
             x = flat.reshape(A, S, -1)
@@ -456,7 +446,11 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
     if state:
         out.append({"k_window": _stack_kind(kw), "v_window": _stack_kind(vw)})
     if routes:
-        out.append(_stack_routes(topi_all, cfg))
+        # [periods, layers a period, *rows, k] -> layers first; the leading
+        # dense layers have no row.
+        topi_all = topi_all.reshape(cfg.num_layers, *topi_all.shape[2:])
+        out.append(stack_routes(
+            topi_all[cfg.first_k_dense:], cfg.router_experts))
     return tuple(out)
 
 
@@ -520,7 +514,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
         for slot, kind in enumerate(cfg.period_types):
             layer = pi * P + slot
             li = pi * per[kind] + len(kv[kind][0])
-            x, k, v = attention(x, _at(layers["attn"], layer), kind, li)
+            x, k, v = attention(x, at(layers["attn"], layer), kind, li)
             kv[kind][0].append(k), kv[kind][1].append(v)
             x, topi = _ffn(x, layers, layer, slot, cfg)
             topis.append(topi)
@@ -545,7 +539,9 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
         )
     state = dict(zip(("k_window", "v_window"), pools[WINDOW]))
     if routes:
-        return logits, *pools[GLOBAL], state, _stack_routes(topi_all, cfg)
+        topi_all = topi_all.reshape(cfg.num_layers, *topi_all.shape[2:])
+        return logits, *pools[GLOBAL], state, stack_routes(
+            topi_all[cfg.first_k_dense:], cfg.router_experts)
     return logits, *pools[GLOBAL], state
 
 

@@ -29,8 +29,8 @@ from kubeai_tpu.ops.attention import (
     causal_prefill_attention,
     chunked_prefill_attention,
     decode_attention,
+    prefill_attention,
 )
-from kubeai_tpu.models.llama import _prefill_attention
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -233,7 +233,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None):
                 window=win if cfg.sliding_window else None,
             )
         else:
-            attn = _prefill_attention(qs, k, v)
+            attn = prefill_attention(qs, k, v)
         a_out = jnp.einsum(
             "bsh,he->bse", attn.reshape(B, S, H * D), lp["wo"]
         )
@@ -330,17 +330,17 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     graph."""
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
+        decode_layout,
         paged_decode_attention,
         paged_decode_attention_fused,
-        resolve_decode_kernel,
         scatter_decode_token,
         token_page_coords,
     )
 
     from kubeai_tpu.ops.kv_quant import is_quantized_kv, kv_pages_shape
 
-    attn_kernel = resolve_decode_kernel(
-        attn_kernel, quantized=is_quantized_kv(k_pages)
+    attn_kernel = attn_kernel or decode_layout(
+        quantized=is_quantized_kv(k_pages)
     )
     B = tokens.shape[0]
     H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_size

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeai_tpu.ops import dispatch
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import (
@@ -34,32 +33,9 @@ from kubeai_tpu.ops.rope import (
     rope_attention_scaling,
     rope_frequencies,
 )
-from kubeai_tpu.ops.attention import (
-    causal_prefill_attention,
-    decode_attention,
-)
+from kubeai_tpu.ops.attention import decode_attention, prefill_attention
 from kubeai_tpu.engine.quantization import dequantize as _w
 from kubeai_tpu.parallel import sharding as sh
-
-
-@jax.named_scope("prefill_attention")
-def _prefill_attention(q, k, v, mask_block: int = 1, window: int = 0):
-    """Aligned buckets of 256 tokens and up take the Pallas flash kernel
-    wherever kernels run (ops/dispatch.py: a TPU, or tests forcing the
-    interpreter); the short and unaligned buckets keep the jnp path.
-    `mask_block` > 1 is a block-diffusion family's mask: causal between
-    blocks of that many positions, full inside one. `window` > 0 is a
-    window layer's: a query sees that many positions, its own the last."""
-    S = q.shape[1]
-    if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
-        from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
-
-        return flash_causal_prefill(
-            q, k, v, mask_block=mask_block, window=window
-        )
-    return causal_prefill_attention(
-        q, k, v, mask_block=mask_block, window=window or None
-    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -305,7 +281,7 @@ def prefill(
         def attend(q, k, v):
             return ring_attention_sharded(q, k, v, mesh)
     else:
-        attend = _prefill_attention
+        attend = prefill_attention
     inv_freq = jnp.asarray(
         rope_frequencies(
             D, cfg.rope_theta, cfg.rope_scaling,
@@ -518,8 +494,8 @@ def decode_step_paged(
     attn_kernel: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Decode step against the PAGED cache. The layout follows the pool
-    (ops.paged_attention.resolve_decode_kernel; `attn_kernel` names one
-    explicitly, for tests and A/B runs):
+    (ops.paged_attention.decode_layout; `attn_kernel` names one
+    explicitly, as a test's reference: no engine option does):
 
     "fused", every bf16 pool — the stacked [NL, ...] page pools stay
     OUTSIDE the layer scan and are never sliced, copied or re-stacked:
@@ -541,13 +517,13 @@ def decode_step_paged(
     from kubeai_tpu.ops.kv_quant import is_quantized_kv, kv_pages_shape
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
+        decode_layout,
         paged_decode_attention_fused,
-        resolve_decode_kernel,
         token_page_coords,
     )
 
-    attn_kernel = resolve_decode_kernel(
-        attn_kernel, quantized=is_quantized_kv(k_pages)
+    attn_kernel = attn_kernel or decode_layout(
+        quantized=is_quantized_kv(k_pages)
     )
     inv_freq = jnp.asarray(
         rope_frequencies(
@@ -811,7 +787,7 @@ def trunk_layer(x: jnp.ndarray, lp: dict, cfg: LlamaConfig) -> jnp.ndarray:
         v = v + lp["bv"]
     q = apply_rope(q.reshape(B, S, H, D), positions, inv_freq, msc)
     k = apply_rope(k.reshape(B, S, KVH, D), positions, inv_freq, msc)
-    attn = _prefill_attention(q, k, v.reshape(B, S, KVH, D))
+    attn = prefill_attention(q, k, v.reshape(B, S, KVH, D))
     x = x + jnp.einsum("bsh,he->bse", attn.reshape(B, S, H * D), _w(lp["wo"]))
     h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
     return x + _mlp(h2, lp["w_gate"], lp["w_up"], lp["w_down"])

@@ -27,20 +27,14 @@ no row dropped). It generates a block of `block_length` positions at a time
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from kubeai_tpu.models.llama import _prefill_attention
-from kubeai_tpu.models.registry import (
-    ModelFamily,
-    register_model_family,
-    route_dtype,
-)
-from kubeai_tpu.ops.attention import decode_attention
-from kubeai_tpu.ops.grouped_matmul import grouped_matmul, tile_plan
+from kubeai_tpu.models.registry import ModelFamily, register_model_family
+from kubeai_tpu.ops.attention import decode_attention, prefill_attention
+from kubeai_tpu.ops.experts import EXPERT_LEAVES, moe_sparse, stack_routes
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -230,15 +224,12 @@ def init_params(cfg: MixtralConfig, key: jax.Array | None = None) -> dict:
     }
 
 
-EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
-
-
 def _layer_xs(params, cfg):
     """(what the layer scan slices a layer at a time, what it does not).
     A sparse family's expert weights stay whole outside the scan: the
     grouped product is a kernel, and a kernel's sliced operand would be
     copied out of the stack, 1.2 GB a layer at SDAR's sizes. It takes the
-    stack and the layer's number instead (`_moe_sparse`)."""
+    stack and the layer's number instead (`ops.experts.moe_sparse`)."""
     layers = params["layers"]
     if not cfg.sparse_experts:
         return layers, None
@@ -272,127 +263,6 @@ def _qkv(h, lp, cfg, positions, inv_freq):
     )
 
 
-# Most assignments (rows x k) whose order `_dispatch` finds by comparison;
-# above it they are sorted. The comparison is [N * k, N * k] and the two
-# one-hot products [N * k, N] x [N, E], so their cost grows with the square
-# where a sort and a gather grow with N * k. Read on a v5e (my chip run, PR
-# 48: us a layer of `_dispatch` + `_combine` at bf16, the wall clock of a scan
-# of 64 layers whose products are one elementwise pass, less the same scan
-# without the books; median of 30): by comparison / sorted with the inverse
-# scattered / sorted with the inverse as a second `argsort`:
-#     512 (64 x 8, 16 held of 128, E 6,144)     11.0 /  57.7 /  60.3
-#     640 (64 x 10, 64 held of 512, E 2,048)     5.7 /  44.1 /  42.6
-#   1,024 (128 x 8, 128 held, E 2,048)          16.2 /  56.0 /  54.9
-#   1,280 (128 x 10, 64 held of 512)            14.8 /  71.1 /  70.2
-#   2,048 (256 x 8, 128 held)                   79.1 /  84.7 /  80.5
-#   5,120 (512 x 10, 64 held of 512)           195.3 / 217.9 / 201.9
-#  16,384 (2,048 x 8, 128 held)                    - / 489.8 / 418.8
-# Four to eight times cheaper up to 1,280, level from 2,048 on, where the
-# products' square would soon lead (4 GFLOP at 2,048, 275 at 16,384): the
-# largest size read at which the comparison clearly wins. The second
-# `argsort` beats the scatter at every size read above it.
-RANK_BY_COMPARISON_MAX = 1280
-
-
-def _exact(dtype):
-    """The precision at which a product adds up its operands' products as
-    they are: bf16 operands are exact in one pass into a float32 sum,
-    float32 operands (the tests') need every pass."""
-    return None if dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
-
-
-def _dispatch(x, flat, k, X):
-    """Rows `x` [N, E] and their assignments `flat` [N * k] (the local expert
-    of assignment n * k + j, X for one that is not held) -> (xs [N * k, E]:
-    the rows in the order of their experts, an expert's in row order;
-    counts [X]: assignments an expert; dest [N, k]: where in `xs` each
-    assignment's row went, the inverse of the stable `argsort(flat)`).
-
-    Few assignments (a decode step, a block) are put in order where they
-    lie: `dest` is ONE comparison of every assignment's key with every
-    other's, summed, and `xs` a product with the [N * k, N] matrix of zeros
-    and ones that says which row goes where (one term a row, so exact).
-    Nothing is sorted, gathered or scattered; on a v5e the `argsort`, the
-    row gather and `bincount`'s scatter-add cost 4.5, 9.7 and 6.8 us a layer
-    at 640 assignments (PERF.md section 5). Many (an admission) are sorted."""
-    (NK,) = flat.shape
-    counts = (flat[:, None] == jnp.arange(X, dtype=flat.dtype)).sum(
-        0, dtype=jnp.int32)
-    if NK > RANK_BY_COMPARISON_MAX:
-        order = jnp.argsort(flat)  # stable
-        return x[order // k], counts, jnp.argsort(order).reshape(-1, k)
-    key = flat * NK + jnp.arange(NK, dtype=flat.dtype)  # distinct; ordered as the stable sort orders
-    dest = (key[None, :] < key[:, None]).sum(1, dtype=jnp.int32).reshape(-1, k)
-    goes = (dest[None] == jnp.arange(NK, dtype=jnp.int32)[:, None, None]).any(-1)
-    xs = jnp.dot(goes.astype(x.dtype), x, precision=_exact(x.dtype),
-                 preferred_element_type=x.dtype)
-    return xs, counts, dest
-
-
-def _combine(out, dest, weights):
-    """out [N * k, E] in `_dispatch`'s order -> [N, E] float32: row n's k
-    results `out[dest[n, j]]` under `weights` [N, k] (of `out`'s dtype),
-    summed in float32. Few assignments: a product with the [N, N * k] matrix
-    that holds each weight at its assignment's place (at most one term an
-    entry, so exact), the same products summed in another order than the
-    gather-then-sum that many assignments keep."""
-    N, k = dest.shape
-    if N * k > RANK_BY_COMPARISON_MAX:
-        return jnp.einsum("nke,nk->ne", out[dest], weights,
-                          preferred_element_type=jnp.float32)
-    at = dest[:, :, None] == jnp.arange(N * k, dtype=jnp.int32)  # [N, k, N * k]
-    return jnp.dot(
-        jnp.where(at, weights[:, :, None], 0).sum(1), out,
-        precision=_exact(out.dtype), preferred_element_type=jnp.float32)
-
-
-def _moe_sparse(x, experts, layer, topi, probs, cfg, first=None):
-    """x [N, E] through the experts each row took (`topi` [N, k], weights
-    `probs` [N, k] float32): the N * k assignments in the order of their
-    experts (`_dispatch`), one grouped product over the experts that hold
-    any, and each row's k results added back weighted (`_combine`). No
-    assignment is dropped, whatever the load of an expert; one that gets no
-    row costs nothing.
-
-    `experts` holds every layer's weights, [NL, X, ...]; the grouped
-    product reads them as NL * X groups and is told which X are `layer`'s,
-    so no layer is sliced out of the stack.
-
-    `first` (None: every expert the router scores is held, `topi` counts
-    from 0): the layer holds a SHARE of the router's experts, global ids
-    `first .. first + X - 1`. An assignment to an expert that lives on
-    another chip is ordered behind the held ones, belongs to no group of
-    the product and adds nothing: what comes back is this share's part of
-    the routed sum, under the weights of the whole set."""
-    N, k = topi.shape
-    NL, X = experts["w_gate"].shape[:2]
-    with jax.named_scope("moe_dispatch"):
-        flat = topi.reshape(-1)
-        if first is not None:
-            flat = flat - first
-            flat = jnp.where((flat >= 0) & (flat < X), flat, X)
-        xs, counts, dest = _dispatch(x, flat, k, X)
-    with jax.named_scope("moe_experts"):
-        # One tile map for the three products, over this layer's X groups;
-        # only the weights are NL * X groups wide.
-        product = functools.partial(
-            grouped_matmul, sizes=counts, layer=layer,
-            plan=tile_plan(counts, N * k))
-        stacked = {
-            name: w.reshape(NL * X, *w.shape[2:]) for name, w in experts.items()
-        }
-        g = product(xs, stacked["w_gate"])
-        u = product(xs, stacked["w_up"])
-        out = product(jax.nn.silu(g) * u, stacked["w_down"])
-    with jax.named_scope("moe_combine"):
-        if first is not None:
-            # Rows behind the last group are the product's to leave unwritten
-            # (a zero weight does not do: 0 x NaN is NaN).
-            held = jnp.arange(N * k) < counts.sum()
-            out = jnp.where(held[:, None], out, 0)
-        return _combine(out, dest, probs.astype(out.dtype)).astype(x.dtype)
-
-
 @jax.named_scope("moe_ffn")
 def _moe_ffn(x, lp, cfg, experts=None, layer=None):
     """x: [B, S, E] (or [B, E] for decode via S=1 squeeze by caller).
@@ -400,7 +270,7 @@ def _moe_ffn(x, lp, cfg, experts=None, layer=None):
     Top-k MoE: softmax over the selected experts' router logits; then
     either all experts computed batched over the (sharded) expert axis and
     combined weighted by the routing probabilities (dense dispatch), or,
-    for a `sparse_experts` family, `_moe_sparse` on the stack `experts`
+    for a `sparse_experts` family, `moe_sparse` on the stack `experts`
     at `layer`.
 
     Returns (y [B, S, E], topi [B, S, k]): the output and the global ids
@@ -421,9 +291,9 @@ def _moe_ffn(x, lp, cfg, experts=None, layer=None):
                 "family's layers take the expert stack and their number"
             )
         B, S, E = x.shape
-        y = _moe_sparse(
+        y = moe_sparse(
             x.reshape(B * S, E), experts, layer,
-            topi.reshape(B * S, -1), probs.reshape(B * S, -1), cfg,
+            topi.reshape(B * S, -1), probs.reshape(B * S, -1),
         )
         return y.reshape(B, S, E), topi
     with jax.named_scope("moe_router"):
@@ -444,19 +314,12 @@ def _moe_ffn(x, lp, cfg, experts=None, layer=None):
     ), topi
 
 
-def _stack_routes(topi_all, cfg):
-    """The layer scan's stacked `topi` [routed layers, *rows, k] as the
-    forwards hand it over: [*rows, routed layers, k], in the smallest
-    unsigned integer type that holds an expert id."""
-    return jnp.moveaxis(topi_all, 0, -2).astype(route_dtype(cfg.num_experts))
-
-
 def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
             routes=False):
     """Whole-prompt prefill. `routes=True` appends the expert sets taken,
-    [B, S, routed layers, k] (see `_stack_routes`); the engine always asks
-    for them, a caller that does not gets the three outputs it always
-    got. The same holds for the other three forwards below."""
+    [B, S, routed layers, k] (see `ops.experts.stack_routes`); the engine
+    always asks for them, a caller that does not gets the three outputs it
+    always got. The same holds for the other three forwards below."""
     B, S = tokens.shape
     H, D = cfg.num_heads, cfg.head_size
     inv_freq = jnp.asarray(rope_frequencies(D, cfg.rope_theta))
@@ -470,7 +333,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         q, k, v = _qkv(h, lp, cfg, positions, inv_freq)
         # A block-diffusion family's prompt is causal between blocks and
         # full inside one.
-        attn = _prefill_attention(q, k, v, mask_block=max(1, cfg.block_length))
+        attn = prefill_attention(q, k, v, mask_block=max(1, cfg.block_length))
         x = x + jnp.einsum("bsh,he->bse", attn.reshape(B, S, H * D), lp["wo"])
         h2 = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
         y, topi = _moe_ffn(h2, lp, cfg, experts, xs["li"])
@@ -488,7 +351,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         preferred_element_type=jnp.float32,
     )
     if routes:
-        return logits, k_all, v_all, _stack_routes(topi_all, cfg)
+        return logits, k_all, v_all, stack_routes(topi_all, cfg.num_experts)
     return logits, k_all, v_all
 
 
@@ -523,7 +386,7 @@ def decode_step(params, cfg, tokens, positions, k_cache, v_cache,
         "be,ve->bv", x, params["lm_head"], preferred_element_type=jnp.float32
     )
     if routes:
-        return logits, k_cache, v_cache, _stack_routes(topi_all, cfg)
+        return logits, k_cache, v_cache, stack_routes(topi_all, cfg.num_experts)
     return logits, k_cache, v_cache
 
 
@@ -539,17 +402,17 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     expert sets of the B rows, [B, routed layers, k]."""
     from kubeai_tpu.ops.paged_attention import (
         batched_scatter_sequence,
+        decode_layout,
         paged_decode_attention,
         paged_decode_attention_fused,
-        resolve_decode_kernel,
         scatter_decode_token,
         token_page_coords,
     )
 
     from kubeai_tpu.ops.kv_quant import is_quantized_kv, kv_pages_shape
 
-    attn_kernel = resolve_decode_kernel(
-        attn_kernel, quantized=is_quantized_kv(k_pages)
+    attn_kernel = attn_kernel or decode_layout(
+        quantized=is_quantized_kv(k_pages)
     )
     B = tokens.shape[0]
     H, D = cfg.num_heads, cfg.head_size
@@ -623,7 +486,7 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
             preferred_element_type=jnp.float32,
         )
     if routes:
-        return logits, k_pages, v_pages, _stack_routes(topi_all, cfg)
+        return logits, k_pages, v_pages, stack_routes(topi_all, cfg.num_experts)
     return logits, k_pages, v_pages
 
 
@@ -676,7 +539,7 @@ def prefill_chunk(
         layer, x, {"p": params["layers"], "kc": k_slot, "vc": v_slot}
     )
     k_slot, v_slot = outs["kc"], outs["vc"]
-    tail = (_stack_routes(outs["topi"], cfg),) if routes else ()
+    tail = (stack_routes(outs["topi"], cfg.num_experts),) if routes else ()
     if not want_logits:
         return (None, k_slot, v_slot, *tail)
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -740,7 +603,7 @@ def block_forward_paged(params, cfg, tokens, positions, k_pages, v_pages,
             preferred_element_type=jnp.float32,
         )
     if routes:
-        return logits, k_new, v_new, _stack_routes(topi_all, cfg)
+        return logits, k_new, v_new, stack_routes(topi_all, cfg.num_experts)
     return logits, k_new, v_new
 
 

@@ -25,7 +25,7 @@ state=pools)` updates the pools in place (`ops/gated_delta.py`).
 share this is: global ids `first_expert .. first_expert + num_experts - 1`.
 The router scores all, takes `num_experts_per_tok` on a softmax over all,
 renormalised over the taken; the rows routed to held experts go through the
-grouped product (`models/mixtral.py:_moe_sparse`), the others add nothing
+grouped product (`ops/experts.py:moe_sparse`), the others add nothing
 here, and this partial sum plus the shared expert (which every chip computes
 alike) goes on to the next layer. No code stands in for the absent chips.
 The hand-over carries all the global ids a row took.
@@ -43,12 +43,14 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from kubeai_tpu.models.llama import _prefill_attention
-from kubeai_tpu.models.mixtral import EXPERT_LEAVES, _moe_sparse
-from kubeai_tpu.models.registry import (
-    ModelFamily,
-    register_model_family,
-    route_dtype,
+from kubeai_tpu.models.registry import ModelFamily, register_model_family
+from kubeai_tpu.ops.attention import prefill_attention
+from kubeai_tpu.ops.experts import (
+    EXPERT_LEAVES,
+    at,
+    moe_sparse,
+    shared_expert,
+    stack_routes,
 )
 from kubeai_tpu.ops.gated_delta import gdn_chunk_scan, gdn_update
 from kubeai_tpu.ops.norms import rms_norm
@@ -331,13 +333,9 @@ def _moe_parts(x, mp, experts, layer, cfg):
             "ne,e->n", x, mp["shared_router"],
             preferred_element_type=jnp.float32,
         ))
-        mid = jax.nn.silu(x @ mp["shared_gate"]) * (x @ mp["shared_up"])
-        shared = jnp.einsum(
-            "nm,me->ne", mid, mp["shared_down"],
-            preferred_element_type=jnp.float32,
-        ) * gate[:, None]
-    routed = _moe_sparse(
-        x, experts, layer, topi, probs, cfg, first=cfg.first_expert
+        shared = shared_expert(x, mp) * gate[:, None]
+    routed = moe_sparse(
+        x, experts, layer, topi, probs, first=cfg.first_expert
     )
     return routed.astype(jnp.float32), shared, topi
 
@@ -410,21 +408,14 @@ def _period_xs(params, cfg):
     """What the scan over periods slices a period at a time: the attention
     layer's weights and the period's number. The DeltaNet layers' and the
     mixtures' weights stay whole outside it and are read at their layer's
-    number (`_at`): sliced a period at a time, the three DeltaNet layers of
-    a period came out of the stack as one copy (150 MB of `in_qkvz` a period
-    a decode step in the compiled chunk), where a layer read at its own
-    number is read by the product that uses it."""
+    number (`ops.experts.at`): sliced a period at a time, the three DeltaNet
+    layers of a period came out of the stack as one copy (150 MB of `in_qkvz`
+    a period a decode step in the compiled chunk), where a layer read at its
+    own number is read by the product that uses it."""
     return {
         "attn": params["layers"]["attn"],
         "pi": jnp.arange(cfg.periods, dtype=jnp.int32),
     }
-
-
-def _at(tree, i):
-    """Layer `i` (traced) of weights stacked over layers."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree
-    )
 
 
 def _conv_step(conv, li, u, w):
@@ -454,12 +445,6 @@ def _conv_step(conv, li, u, w):
         conv, jnp.concatenate(taps[1:], axis=-1), li, 0)
 
 
-def _stack_routes(topi, cfg):
-    """[periods, layers a period, *rows, k] -> [*rows, routed layers, k]."""
-    topi = topi.reshape(cfg.num_layers, *topi.shape[2:])
-    return jnp.moveaxis(topi, 0, -2).astype(route_dtype(cfg.router_experts))
-
-
 def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
             routes=False, state=False):
     """Whole-prompt prefill of [A, S] prompts. Returns (logits at
@@ -478,7 +463,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
     x = params["embed"][tokens]
 
     def moe(x, layer):
-        mp = _at(layers["moe"], layer)
+        mp = at(layers["moe"], layer)
         h = _norm0(x, mp["post_norm"], cfg.rms_norm_eps)
         y, topi = _moe(h.reshape(A * S, -1), mp, experts, layer, cfg)
         return x + y.reshape(A, S, -1), topi.reshape(A, S, -1)
@@ -495,8 +480,8 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
             )
             q, k, v = _gdn_heads(jax.nn.silu(y), cfg)
             # The inputs at lengths - K + 1 .. lengths - 1 (zeros before 0).
-            at = lengths[:, None] + jnp.arange(K - 1)[None, :]  # into `padded`
-            tail = jnp.take_along_axis(padded, at[:, :, None], axis=1)
+            idx = lengths[:, None] + jnp.arange(K - 1)[None, :]  # into `padded`
+            tail = jnp.take_along_axis(padded, idx[:, :, None], axis=1)
         with jax.named_scope("gdn_scan"):
             # A pad position neither decays nor writes.
             o, s = gdn_chunk_scan(
@@ -509,7 +494,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         with jax.named_scope("gated_attention"):
             h = _norm0(x, lp["input_norm"], cfg.rms_norm_eps)
             q, k, v, gate = _attn_project(h, lp, cfg, positions)
-            attn = _prefill_attention(q, k, v).reshape(A, S, -1)
+            attn = prefill_attention(q, k, v).reshape(A, S, -1)
             attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
             return x + jnp.einsum("bsh,he->bse", attn, lp["wo"]), k, v
 
@@ -517,7 +502,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         first = xs["pi"] * (G + 1)
         rec, conv, topis = [], [], []
         for j in range(G):
-            x, s, tail = gdn(x, _at(layers["gdn"], xs["pi"] * G + j))
+            x, s, tail = gdn(x, at(layers["gdn"], xs["pi"] * G + j))
             x, topi = moe(x, first + j)
             rec.append(s), conv.append(tail), topis.append(topi)
         x, k, v = attention(x, xs["attn"])
@@ -542,7 +527,9 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
             "conv": conv.reshape(cfg.state_layers, *conv.shape[2:]),
         })
     if routes:
-        out.append(_stack_routes(topi_all, cfg))
+        # [periods, layers a period, *rows, k] -> layers first.
+        topi_all = topi_all.reshape(cfg.num_layers, *topi_all.shape[2:])
+        out.append(stack_routes(topi_all, cfg.router_experts))
     return tuple(out)
 
 
@@ -576,13 +563,13 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
     x = params["embed"][tokens]
 
     def moe(x, layer):
-        mp = _at(layers["moe"], layer)
+        mp = at(layers["moe"], layer)
         h = _norm0(x, mp["post_norm"], cfg.rms_norm_eps)
         y, topi = _moe(h, mp, experts, layer, cfg)
         return x + y, topi
 
     def gdn(x, rec, conv, li):
-        lp = _at(layers["gdn"], li)
+        lp = at(layers["gdn"], li)
         h = _norm0(x, lp["input_norm"], cfg.rms_norm_eps)
         u, z, beta, g = _gdn_project(h, lp, cfg)
         with jax.named_scope("gdn_conv"):
@@ -634,7 +621,9 @@ def decode_step_paged(params, cfg, tokens, positions, k_pages, v_pages,
         )
     state = {"recurrent": rec, "conv": conv}
     if routes:
-        return logits, k_pages, v_pages, state, _stack_routes(topi_all, cfg)
+        topi_all = topi_all.reshape(cfg.num_layers, *topi_all.shape[2:])
+        return logits, k_pages, v_pages, state, stack_routes(
+            topi_all, cfg.router_experts)
     return logits, k_pages, v_pages, state
 
 

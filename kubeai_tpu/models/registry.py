@@ -14,14 +14,6 @@ from typing import Callable
 _FAMILIES: dict[str, "ModelFamily"] = {}
 
 
-def route_dtype(num_experts: int) -> str:
-    """The smallest unsigned integer type that holds a global expert id:
-    what a routed family's forwards hand their expert sets over in."""
-    return "uint8" if num_experts <= 256 else (
-        "uint16" if num_experts <= 65536 else "uint32"
-    )
-
-
 class ModelFamily:
     """A family bundle: config parser, param init, prefill/decode fns."""
 
@@ -102,7 +94,8 @@ class ModelFamily:
         # route_dims(cfg) -> (experts, experts per token, routed layers).
         # Its prefill / decode_step / decode_step_paged / prefill_chunk
         # then take `routes=True` and append the expert sets they took,
-        # [*rows, routed layers, k] of global expert ids (route_dtype).
+        # [*rows, routed layers, k] of global expert ids
+        # (ops.experts.route_dtype).
         # The pp stage forwards and the verify forwards hand none over.
         self.route_dims = route_dims
         self.name = name

@@ -122,12 +122,6 @@ def kubeai_tpu_pod(
     # KV bytes (~2x slot capacity at equal HBM) and every KV transfer.
     if model.spec.kv_cache.enabled():
         args += ["--kv-dtype", model.spec.kv_cache.dtype]
-    # Overlapped step pipeline (CRD engineStep: block): dispatch chunk
-    # N+1 before reaping chunk N so host work hides behind device
-    # compute. Unset = engine default (auto: on where the topology
-    # allows, synchronous for lockstep multihost / pipeline parallelism).
-    if model.spec.engine_step.enabled():
-        args += ["--step-overlap", model.spec.engine_step.overlap]
     # Engine snapshot/restore (CRD coldStart: block): boot restores the
     # post-conversion param tree + compilation cache from the snapshot
     # store instead of re-running HF conversion and XLA compilation.

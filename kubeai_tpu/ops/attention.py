@@ -22,6 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from kubeai_tpu.ops import dispatch
+
 NEG_INF = -1e30
 
 
@@ -75,6 +77,27 @@ def causal_prefill_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32))
     return out.reshape(b, s, h, d).astype(q.dtype)
+
+
+@jax.named_scope("prefill_attention")
+def prefill_attention(q, k, v, mask_block: int = 1, window: int = 0):
+    """The prefill kernel, chosen by shape: aligned buckets of 256 tokens
+    and up take the Pallas flash kernel wherever kernels run
+    (ops/dispatch.py: a TPU, or tests forcing the interpreter); the short
+    and unaligned buckets keep `causal_prefill_attention`.
+    `mask_block` > 1 is a block-diffusion family's mask: causal between
+    blocks of that many positions, full inside one. `window` > 0 is a
+    window layer's: a query sees that many positions, its own the last."""
+    S = q.shape[1]
+    if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
+        from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
+
+        return flash_causal_prefill(
+            q, k, v, mask_block=mask_block, window=window
+        )
+    return causal_prefill_attention(
+        q, k, v, mask_block=mask_block, window=window or None
+    )
 
 
 def chunked_prefill_attention(

@@ -1,6 +1,6 @@
 """Grouped matrix product: rows sorted by group, each group's rows times that
 group's matrix. What a sparsely computed expert layer is made of
-(`models/mixtral.py:_moe_sparse`).
+(`ops/experts.py:moe_sparse`).
 
 `grouped_matmul(x [m, k], w [groups, k, n], sizes [groups])` multiplies rows
 `sum(sizes[:g]) .. sum(sizes[:g + 1])` of `x` by `w[g]`. A group without rows
@@ -15,7 +15,7 @@ leave the DMA engine waiting on the grid.
 
 **Who builds the tile map, and why the stack is NL x X groups wide on the
 weight side only.** The kernel walks a map from grid step to (group, row
-tile). `_moe_sparse` builds it ONCE a layer from the layer's own X counts
+tile). `moe_sparse` builds it ONCE a layer from the layer's own X counts
 (`tile_plan`: seven small dense fusions) and hands it to the layer's three
 products with the layer's number. The weights stay the whole stack,
 `[NL * X, k, n]`, read in place: the kernel's right-hand index map adds

@@ -60,29 +60,12 @@ NEG_INF = -1e30
 # scan (paged_decode_attention_fused); what every bf16 pool takes.
 # "per_layer": scatter-then-attend on one layer's pool
 # (paged_decode_attention); what a quantized pool takes, since the Pallas
-# kernels read bf16 only.
-_DECODE_KERNELS = ("per_layer", "fused")
-
-
-def resolve_decode_kernel(
-    requested: str | None, *, quantized: bool = False
-) -> str:
-    """The decode layout for a pool: None/"" decides by the pool's kind
-    (`quantized` = a {"q8", "scale"} pool), an explicit choice is
-    validated and honoured."""
-    if not requested:
-        return "per_layer" if quantized else "fused"
-    if requested not in _DECODE_KERNELS:
-        raise ValueError(
-            f"decode kernel {requested!r} not in {_DECODE_KERNELS}"
-        )
-    if quantized and requested == "fused":
-        raise ValueError(
-            "a quantized (int8) KV pool does not compose with "
-            "decode_kernel='fused' (the stacked kernel reads a raw bf16 "
-            "pool); leave it unset or use per_layer"
-        )
-    return requested
+# kernels read bf16 only. The pool's kind is the only input: no option names
+# a layout (a test names one at a forward, `attn_kernel=`).
+def decode_layout(*, quantized: bool) -> str:
+    """The decode layout a pool takes, by its kind (`quantized` = a
+    {"q8", "scale"} pool)."""
+    return "per_layer" if quantized else "fused"
 
 
 def _accum_head(

@@ -13,7 +13,7 @@ GQA: the q-head grid axis maps each q head onto its kv head (h // group).
 
 Numerics: fp32 accumulation in VMEM scratch; bf16 in/out. head_dim is
 padded to 128 lanes; q/k blocks are 128 rows, so the sequence must be a
-multiple of 128 (anything else raises — models.llama._prefill_attention
+multiple of 128 (anything else raises — ops.attention.prefill_attention
 routes the short and unaligned buckets to the jnp reference first).
 
 Usage: flash_causal_prefill(q, k, v) — same contract as the jnp reference.

@@ -287,3 +287,25 @@ def popen_logged(cmd, **kw) -> subprocess.Popen:
 
     proc.output = output
     return proc
+
+
+def synchronous(engine):
+    """`engine` on the synchronous step loop, the way lockstep puts it
+    there (`engine/multihost.py`: `_overlap = False` before the first
+    step). No option selects the loop: an engine overlaps unless its mesh
+    has a pp axis, so a case that wants the other loop says so here."""
+    assert engine._inflight is None, "set before the first step"
+    engine._overlap = False
+    return engine
+
+
+def per_layer_forward(family):
+    """`family.decode_step_paged` held to the per-layer layout through the
+    forwards' own `attn_kernel=` keyword: the reference of the stacked
+    layout, named at the forward since no engine option names a layout."""
+    forward = family.decode_step_paged
+
+    def held(*args, **kw):
+        return forward(*args, **{**kw, "attn_kernel": "per_layer"})
+
+    return held

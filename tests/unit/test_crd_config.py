@@ -224,32 +224,14 @@ def test_queue_pressure_config_parses_and_validates():
 
 
 @pytest.mark.stepperf
-def test_engine_step_block_valid_and_roundtrip():
-    from kubeai_tpu.crd.model import EngineStep
-
-    for mode in ("auto", "on", "off"):
-        m = valid_model(engine_step=EngineStep(overlap=mode))
-        m.validate()
-        d = m.to_dict()
-        assert d["spec"]["engineStep"] == {"overlap": mode}
-        back = Model.from_dict(d)
-        assert back.spec.engine_step == m.spec.engine_step
-    # Default (unset) engineStep is omitted from the manifest.
-    assert "engineStep" not in valid_model().to_dict()["spec"]
-    assert Model.from_dict(
-        valid_model().to_dict()
-    ).spec.engine_step.enabled() is False
-
-
-@pytest.mark.stepperf
-def test_engine_step_block_invalid():
-    from kubeai_tpu.crd.model import EngineStep
-
-    with pytest.raises(ValidationError, match="engineStep.overlap"):
-        valid_model(engine_step=EngineStep(overlap="sometimes")).validate()
-    # engineStep is an in-tree engine feature (like speculation).
-    with pytest.raises(ValidationError, match="KubeAITPU"):
-        valid_model(
-            engine_step=EngineStep(overlap="on"), engine="VLLM",
-            resource_profile="",
-        ).validate()
+def test_a_manifest_that_still_carries_engine_step_parses_as_without_it():
+    """`spec.engineStep` is no field any more (the engine's loop follows its
+    topology): the parser does with it what it does with any block it does
+    not know, and what is written back does not carry it."""
+    d = valid_model().to_dict()
+    carried = {**d, "spec": {**d["spec"], "engineStep": {"overlap": "off"}}}
+    back = Model.from_dict(carried)
+    back.validate()
+    assert back.spec == Model.from_dict(d).spec
+    assert "engineStep" not in back.to_dict()["spec"]
+    assert not hasattr(back.spec, "engine_step")

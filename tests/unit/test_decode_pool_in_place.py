@@ -224,12 +224,19 @@ def test_weight_movers_sees_the_folded_projections(topo, cell, monkeypatch):
     assert out["decode"].temp_size_in_bytes > 4 * smallest
 
 
-def test_pool_movers_sees_the_per_layer_layout(topo, cell):
+def test_pool_movers_sees_the_per_layer_layout(topo, cell, monkeypatch):
     """The guard above is not blind: scatter-then-attend inside the layer
-    scan is full of what it looks for."""
+    scan is full of what it looks for. No engine option names a layout, so
+    the family's forward is held to it (`attn_kernel="per_layer"`)."""
+    from testutil import per_layer_forward
+
+    from kubeai_tpu.models.registry import get_model_family
+
     cfg, stacked, layer = cell
-    out = aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",),
-                           engine_overrides={"decode_kernel": "per_layer"})
+    family = get_model_family(cfg["architectures"][0])
+    monkeypatch.setattr(
+        family, "decode_step_paged", per_layer_forward(family))
+    out = aot.compile_cell(topo, cfg, admit=1, bucket=128, what=("decode",))
     moved = pool_movers(out["decode_text"], (stacked, layer))
     assert any("copy" in m for m in moved), moved
     assert any("dynamic-update-slice" in m for m in moved), moved

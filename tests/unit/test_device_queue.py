@@ -10,6 +10,7 @@ import jax
 import pytest
 
 from test_host_timeline import Recorder
+from testutil import synchronous
 
 from kubeai_tpu.engine import Engine, EngineConfig
 from kubeai_tpu.engine.sampling import SamplingParams
@@ -307,8 +308,9 @@ def _run(tiny, overlap):
     it, and its seconds."""
     cfg, params = tiny
     eng = Engine("llama", cfg, params, cfg=EngineConfig(
-        num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4,
-        step_overlap=overlap))
+        num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4))
+    if overlap == "off":
+        synchronous(eng)
     _drive(eng, EngineMetrics())
     rec = Recorder()
     eng.profiler._annotate = rec
@@ -474,11 +476,10 @@ def test_the_serve_loops_idle_spell_is_no_starvation(tiny):
 
     tok = ByteTokenizer()
     cfg = llama.LlamaConfig.tiny(vocab_size=tok.vocab_size)
-    eng = Engine(
+    eng = synchronous(Engine(
         "llama", cfg, llama.init_params(cfg, jax.random.PRNGKey(0)),
-        cfg=EngineConfig(num_slots=2, max_seq_len=64, decode_chunk=4,
-                         step_overlap="off"),
-        eos_token_ids=tok.eos_token_ids)
+        cfg=EngineConfig(num_slots=2, max_seq_len=64, decode_chunk=4),
+        eos_token_ids=tok.eos_token_ids))
     srv = EngineServer(eng, tok, "tiny", host="127.0.0.1", port=0)
     srv.start()
     try:

@@ -131,20 +131,21 @@ def test_sharded_engine_tp_matches_single(devices8):
 
 @pytest.mark.slow
 def test_pipelined_stepping_equivalent():
-    """step_overlap="on" must emit the identical token stream, one chunk
-    late."""
+    """The overlapped loop must emit the synchronous loop's token stream,
+    one chunk late."""
+    from testutil import synchronous
+
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    base = Engine(
+    base = synchronous(Engine(
         "llama", cfg, params,
-        cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4,
-                         step_overlap="off"),
-    )
+        cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4),
+    ))
     piped = Engine(
         "llama", cfg, params,
-        cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4,
-                         step_overlap="on"),
+        cfg=EngineConfig(num_slots=3, max_seq_len=64, decode_chunk=4),
     )
+    assert piped._overlap and not base._overlap
     prompts = [[1, 2, 3], [4, 5], [6, 7, 8, 9], [2, 2]]  # > slots: queueing
     want = base.generate(prompts, GREEDY)
     got = piped.generate(prompts, GREEDY)
@@ -239,4 +240,4 @@ def test_engine_config_field_count():
     has to keep working)."""
     import dataclasses
 
-    assert len(dataclasses.fields(EngineConfig)) <= 20
+    assert len(dataclasses.fields(EngineConfig)) <= 18

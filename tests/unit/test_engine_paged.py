@@ -143,31 +143,38 @@ def test_paged_equals_the_cache_free_forward_long(sp):
 
 @pytest.fixture(scope="module", params=["llama", "mixtral", "gemma2"])
 def layouts(request):
-    """One family's engines over the same weights: nothing set (the pool's
-    kind decides), and each layout named."""
-    family, cfg, params = _family_world(request.param)
+    """One family's engines over the same weights: the two kinds of pool
+    (the pool's kind decides the layout), and a bf16 pool under the family
+    with its decode forward held to the per-layer layout, the reference."""
+    import copy
 
-    def mk(**kw):
+    from testutil import per_layer_forward
+
+    from kubeai_tpu.models.registry import get_model_family
+
+    family, cfg, params = _family_world(request.param)
+    held = copy.copy(get_model_family(family))
+    held.decode_step_paged = per_layer_forward(held)
+
+    def mk(family=family, **kw):
         return Engine(family, cfg, params, cfg=EngineConfig(
             num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4, **kw))
 
-    return {"unset": mk(), "per_layer": mk(decode_kernel="per_layer"),
-            "int8": mk(kv_dtype="int8"), "mk": mk,
+    return {"bf16": mk(), "per_layer": mk(held), "int8": mk(kv_dtype="int8"),
             "vocab": cfg.vocab_size}
 
 
 def test_layout_follows_the_pool(layouts):
-    """An unset option resolves by the pool's kind: a bf16 pool is read
-    and written in place (`stacked`), an int8 pool takes
+    """The pool's kind decides, with no field to say otherwise: a bf16 pool
+    is read and written in place (`stacked`), an int8 pool takes
     scatter-then-attend; /v1/state's `kv_cache` block names it."""
-    unset, int8 = layouts["unset"], layouts["int8"]
-    assert (unset.decode_kernel, unset.kv_layout) == ("fused", "stacked")
+    bf16, int8 = layouts["bf16"], layouts["int8"]
+    assert (bf16.decode_kernel, bf16.kv_layout) == ("fused", "stacked")
     assert (int8.decode_kernel, int8.kv_layout) == ("per_layer", "per_layer")
-    assert layouts["per_layer"].kv_layout == "per_layer"
-    assert unset.kv_cache_info()["kv_layout"] == "stacked"
+    assert bf16.kv_cache_info()["kv_layout"] == "stacked"
     assert int8.kv_cache_info()["kv_layout"] == "per_layer"
-    with pytest.raises(ValueError):
-        layouts["mk"](decode_kernel="bogus")
+    assert "decode_kernel" not in {
+        f.name for f in dataclasses.fields(EngineConfig)}
 
 
 @pytest.mark.parametrize(
@@ -182,7 +189,7 @@ def test_stacked_layout_equals_scatter_then_attend(layouts, sp):
     """Token for token, across chunk boundaries (decode_chunk=4) and, for
     gemma-2, past the sliding window of 8."""
     prompts = _prompts(5, vocab=layouts["vocab"])
-    assert layouts["unset"].generate(prompts, sp) == (
+    assert layouts["bf16"].generate(prompts, sp) == (
         layouts["per_layer"].generate(prompts, sp))
 
 

@@ -257,14 +257,14 @@ def test_kubeai_tpu_renderer_no_coldstart_keeps_slow_budget(cfg):
 
 
 @pytest.mark.stepperf
-def test_kubeai_tpu_renderer_step_overlap_flag(cfg):
-    from kubeai_tpu.crd.model import EngineStep
-
-    for mode in ("on", "off", "auto"):
-        m = mk("KubeAITPU", "hf://org/model",
-               engine_step=EngineStep(overlap=mode))
-        args = container(render(cfg, m))["args"]
-        assert args[args.index("--step-overlap") + 1] == mode
-    # No engineStep block -> no flag (the engine default, auto, applies).
-    plain = container(render(cfg, mk("KubeAITPU", "hf://org/model")))["args"]
-    assert "--step-overlap" not in plain
+def test_kubeai_tpu_renderer_names_no_step_loop(cfg):
+    """The engine takes its step loop from its topology and has no flag for
+    it: a manifest that still carries `engineStep` renders the arguments of
+    one that does not."""
+    plain = mk("KubeAITPU", "hf://org/model")
+    d = plain.to_dict()
+    carried = Model.from_dict(
+        {**d, "spec": {**d["spec"], "engineStep": {"overlap": "off"}}})
+    args = container(render(cfg, carried))["args"]
+    assert args == container(render(cfg, plain))["args"]
+    assert not any("overlap" in a for a in args)

@@ -27,6 +27,7 @@ from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.models import exaone_moe as em
 from kubeai_tpu.models.registry import get_model_family
 from kubeai_tpu.ops import paged_attention as pa
+from kubeai_tpu.ops.experts import at
 from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
 from perf.reference import exaone_moe as reference
 
@@ -248,7 +249,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
         cfg, params = served(jnp.float32, hf=hf)
         layers = params["layers"]
         routed, shared, topi = em._moe_parts(
-            h, em._at(layers["moe"], layer - 1), layers["experts"], layer - 1, cfg)
+            h, at(layers["moe"], layer - 1), layers["experts"], layer - 1, cfg)
         assert np.array_equal(np.sort(np.asarray(topi), -1), np.sort(own, -1))
         total = total + routed
     # Float32 sums in another order: 3e-8 read, the layer's output reaches 0.05.
@@ -259,7 +260,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
 
 def test_the_bias_moves_a_selection_and_never_a_weight():
     cfg, params = served(jnp.float32)
-    mp = em._at(params["layers"]["moe"], 2)
+    mp = at(params["layers"]["moe"], 2)
     x = jax.random.normal(jax.random.PRNGKey(12), (64, cfg.hidden_size))
     topi, probs = em._route(x, mp, cfg)
     s = jax.nn.sigmoid(x @ mp["router"])
@@ -405,7 +406,6 @@ def test_the_family_refuses_what_needs_a_rule_for_a_forgotten_ring(family, devic
         ("max_adapters", dict(max_adapters=2)),
         ("a pp mesh axis", dict(mesh=build_mesh(MeshConfig(pp=2), devices=devices8[:2]))),
         ("a tp mesh axis", dict(mesh=build_mesh(MeshConfig(tp=2), devices=devices8[:2]))),
-        ("decode_kernel per_layer", dict(decode_kernel="per_layer")),
     ):
         with pytest.raises(ValueError, match=f"exaone_moe keeps a window ring.*{name}"):
             build(**kw)

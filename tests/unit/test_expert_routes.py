@@ -24,7 +24,8 @@ from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.engine.server import EngineServer
 from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.models import gemma, llama, mixtral
-from kubeai_tpu.models.registry import get_model_family, route_dtype
+from kubeai_tpu.models.registry import get_model_family
+from kubeai_tpu.ops.experts import route_dtype
 from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
 from tests.unit.test_host_timeline import Recorder
 

@@ -342,12 +342,7 @@ def raw(tiny):
 
 
 @pytest.mark.parametrize(
-    "kw,msg",
-    [
-        (dict(speculate=2), "speculative"),
-        (dict(decode_kernel="fused"), "fused"),
-    ],
-    ids=["speculation", "fused-kernel"],
+    "kw,msg", [(dict(speculate=2), "speculative")], ids=["speculation"],
 )
 def test_int8_engine_config_refusals(tiny, kw, msg):
     cfg, params = tiny
@@ -359,6 +354,25 @@ def test_int8_engine_config_refusals(tiny, kw, msg):
             ),
             eos_token_ids=TOK.eos_token_ids,
         )
+
+
+@pytest.mark.parametrize(
+    "kv_dtype,layout",
+    [("", "fused"), ("bfloat16", "fused"), ("int8", "per_layer")],
+    ids=["unset", "bfloat16", "int8"],
+)
+def test_the_pool_decides_the_decode_layout(tiny, kv_dtype, layout):
+    """The only input is the pool's kind: the Pallas kernels read bf16
+    pages, so an int8 pool takes scatter-then-attend and every other the
+    stacked pool in place."""
+    cfg, params = tiny
+    eng = Engine(
+        "llama", cfg, params,
+        cfg=EngineConfig(num_slots=2, max_seq_len=64, kv_dtype=kv_dtype),
+        eos_token_ids=TOK.eos_token_ids,
+    )
+    assert eng.decode_kernel == layout
+    assert eng.kv_layout == {"fused": "stacked"}.get(layout, layout)
 
 
 def _greedy(eng, prompts, max_tokens=8):

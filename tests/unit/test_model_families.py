@@ -397,3 +397,37 @@ def test_mixtral_engine_chunked_and_prefix_cache():
     )
     assert apc.generate(prompts, sp) == want
     assert apc.prefix_stats["hit_tokens"] > 0
+
+
+def test_no_family_file_imports_from_another_family():
+    """What families share lives under `kubeai_tpu/ops/` under public names
+    (the routed expert layer in `ops/experts.py`, the choice of the prefill
+    kernel in `ops/attention.py`): a file under `models/` imports no other
+    file there but the registry, and the registry imports the families only
+    where it loads them."""
+    import ast
+    import pathlib
+
+    import kubeai_tpu.models as models
+
+    root = pathlib.Path(models.__file__).parent
+    reached = {}
+    for path in sorted(root.glob("*.py")):
+        if path.stem in ("registry", "__init__"):
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom) and node.module:
+                names = [node.module] + [
+                    f"{node.module}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            into = {
+                n.split(".")[2] for n in names
+                if n.startswith("kubeai_tpu.models.")
+            } - {"registry", path.stem}
+            if into:
+                reached.setdefault(path.stem, set()).update(into)
+    assert reached == {}
+    assert len(list(root.glob("*.py"))) >= 8  # the walk saw the families

@@ -6,7 +6,7 @@ metadata over `NL x X` groups with their scatters and their `while` (PR 47),
 and the products are still the kernel `gmm` that the per-layer metrics find by
 that name. Its 640 assignments are put in order and summed back by a
 comparison and two small products, with no sort, scatter or gather
-(`models/mixtral.py:_dispatch`, `_combine`), and the convolution of a
+(`ops/experts.py:dispatch`, `combine`), and the convolution of a
 recurrent layer reads its taps as slices of the pool, with no relayout of the
 window (`models/qwen3_next.py:_conv_step`) (PR 48). An admission's
 assignments are still sorted.
@@ -182,8 +182,8 @@ BOOKS_A_LAYER = {"moe_dispatch": 8, "moe_combine": 10}
 def test_a_decode_steps_assignments_are_ordered_and_summed_where_they_lie(
         decode_chunk, scope):
     """640 assignments a layer: ranked by one comparison, gathered and
-    summed by two small products (`models/mixtral.py:_dispatch`,
-    `_combine`)."""
+    summed by two small products (`ops/experts.py:dispatch`,
+    `combine`)."""
     cfg, text = decode_chunk
     found = instructions_in_scope(text, scope)
     body = max(found.values(), key=len)
@@ -220,8 +220,8 @@ def test_an_admissions_assignments_are_still_sorted(topo):  # noqa: F811
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from kubeai_tpu.models import mixtral
     from kubeai_tpu.ops import dispatch
+    from kubeai_tpu.ops import experts as experts_ops
 
     one = SingleDeviceSharding(topo.devices[0])
 
@@ -235,14 +235,14 @@ def test_an_admissions_assignments_are_still_sorted(topo):  # noqa: F811
 
         def layer(x, topi, probs, w_in, w_out):
             experts = {"w_gate": w_in, "w_up": w_in, "w_down": w_out}
-            return mixtral._moe_sparse(x, experts, jnp.int32(1), topi, probs, None, 0)
+            return experts_ops.moe_sparse(x, experts, jnp.int32(1), topi, probs, 0)
 
         return jax.jit(layer).lower(*shapes).compile().as_text()
 
     saved = dispatch.kernel_mode
     dispatch.kernel_mode = lambda: "compiled"
     try:
-        assert 64 * 10 <= mixtral.RANK_BY_COMPARISON_MAX < 256 * 10
+        assert 64 * 10 <= experts_ops.RANK_BY_COMPARISON_MAX < 256 * 10
         admission, step = compiled(256), compiled(64)
     finally:
         dispatch.kernel_mode = saved

@@ -50,15 +50,15 @@ def test_flash_gqa_and_padded_head_dim(kernels_interpreted):
 
 
 def test_flash_refuses_unaligned_seq_and_dispatch_routes_it():
-    """The kernel raises on a sequence it cannot tile; the model-side
-    dispatch sends the short and unaligned buckets to the jnp path."""
-    from kubeai_tpu.models.llama import _prefill_attention
+    """The kernel raises on a sequence it cannot tile; the choice of the
+    prefill kernel sends the short and unaligned buckets to the jnp path."""
+    from kubeai_tpu.ops.attention import prefill_attention
 
     q, k, v = _mk(S=100)  # 100 % 128 != 0
     with pytest.raises(ValueError, match="multiple of 128"):
         flash_causal_prefill(q, k, v)
     want = causal_prefill_attention(q, k, v)
-    got = _prefill_attention(q, k, v)
+    got = prefill_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
 
 

@@ -23,9 +23,10 @@ from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.engine.server import EngineServer
 from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.models import qwen3_next as qn
-from kubeai_tpu.models.registry import get_model_family, route_dtype
+from kubeai_tpu.models.registry import get_model_family
 from kubeai_tpu.ops import dispatch
 from kubeai_tpu.ops import gated_delta as gd
+from kubeai_tpu.ops.experts import at, route_dtype, stack_routes
 from kubeai_tpu.ops.paged_attention import (
     batched_scatter_sequence,
     batched_sequence_page_coords,
@@ -286,7 +287,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
         params = jax.jit(lambda k, hf=hf: reference.served_params(hf, k))(KEY)
         layers = jax.tree.map(lambda a: a.astype(jnp.float32), params["layers"])
         routed, shared, topi = qn._moe_parts(
-            h, qn._at(layers["moe"], layer), layers["experts"], layer, cfg)
+            h, at(layers["moe"], layer), layers["experts"], layer, cfg)
         assert np.array_equal(np.sort(np.asarray(topi), -1), np.sort(own, -1))
         total = total + routed
     # Float32 sums in another order: 2e-8 read, the layer's output reaches 0.02.
@@ -300,7 +301,7 @@ def test_route_ids_past_255_are_handed_over_in_sixteen_bits():
     cfg = dataclasses.replace(qn.Qwen3NextConfig.tiny(), router_experts=512,
                               num_experts=64, expert_share_index=7)
     assert cfg.first_expert == 448
-    routes = qn._stack_routes(jnp.full((2, 4, 5, 3), 511), cfg)
+    routes = stack_routes(jnp.full((8, 5, 3), 511), cfg.router_experts)
     assert routes.dtype == jnp.uint16 and routes.shape == (5, 8, 3)
     assert int(routes.max()) == 511
 
@@ -375,7 +376,6 @@ def test_the_family_refuses_what_needs_a_snapshot_of_state(family, devices8):
         ("max_adapters", dict(max_adapters=2)),
         ("a pp mesh axis", dict(mesh=build_mesh(MeshConfig(pp=2), devices=devices8[:2]))),
         ("a tp mesh axis", dict(mesh=build_mesh(MeshConfig(tp=2), devices=devices8[:2]))),
-        ("decode_kernel per_layer", dict(decode_kernel="per_layer")),
     ):
         with pytest.raises(ValueError, match=f"qwen3_next keeps recurrent state.*{name}"):
             build(**kw)

@@ -27,7 +27,8 @@ from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.models import llama, mixtral
 from kubeai_tpu.models.registry import get_model_family
 from kubeai_tpu.ops import dispatch
-from kubeai_tpu.ops.attention import causal_prefill_attention
+from kubeai_tpu.ops import experts as experts_ops
+from kubeai_tpu.ops.attention import causal_prefill_attention, prefill_attention
 from kubeai_tpu.ops.paged_attention import (
     batched_scatter_sequence,
     batched_sequence_page_coords,
@@ -289,9 +290,9 @@ def test_the_sparse_expert_layer_equals_the_dense_sum(routing):
         assert not np.isin([1, 6], topi).any()
     probs = jnp.asarray(rng.dirichlet(np.ones(k), n), jnp.float32)
     topi = jnp.asarray(topi, jnp.int32)
-    experts = {name: layers[name] for name in mixtral.EXPERT_LEAVES}
+    experts = {name: layers[name] for name in experts_ops.EXPERT_LEAVES}
     for layer in range(cfg.num_layers):
-        got = mixtral._moe_sparse(x, experts, jnp.int32(layer), topi, probs, cfg)
+        got = experts_ops.moe_sparse(x, experts, jnp.int32(layer), topi, probs)
         lp = {name: w[layer] for name, w in experts.items()}
         want = dense_moe(x, lp, topi, probs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
@@ -344,7 +345,7 @@ BOOKS = {
 @pytest.mark.parametrize("mode", ["reference", "interpret"])
 @pytest.mark.parametrize("case", list(BOOKS))
 def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, case):
-    """`_dispatch` and `_combine` against the sort, gather and scatter they
+    """`dispatch` and `combine` against the sort, gather and scatter they
     replace: `dest` is the inverse of the stable `argsort`, `counts` is
     `bincount`, the rows handed to the products are bit for bit the gathered
     ones, the combined rows are the same products summed in another order
@@ -353,7 +354,7 @@ def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, ca
     N, k, X, R, first, routing = BOOKS[case]
     monkeypatch.setattr(dispatch, "FORCE_INTERPRET", mode == "interpret")
     assert dispatch.kernel_mode() == mode
-    assert (N * k > mixtral.RANK_BY_COMPARISON_MAX) == (case == "over-the-crossover")
+    assert (N * k > experts_ops.RANK_BY_COMPARISON_MAX) == (case == "over-the-crossover")
     E, M, NL = 128, 64, 2
     rng = np.random.default_rng(N * k)
     x = jnp.asarray(rng.standard_normal((N, E)), jnp.bfloat16)
@@ -374,7 +375,7 @@ def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, ca
     flat = topi.reshape(-1)
     if first is not None:
         flat = jnp.where((flat >= first) & (flat < first + X), flat - first, X)
-    xs, counts, dest = mixtral._dispatch(x, flat, k, X)
+    xs, counts, dest = experts_ops.dispatch(x, flat, k, X)
     np.testing.assert_array_equal(np.asarray(dest).reshape(-1), np.asarray(back))
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_s))
     assert xs.dtype == x.dtype
@@ -386,14 +387,14 @@ def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, ca
         assert 0 < int(counts.sum()) < N * k
 
     weights = probs.astype(out.dtype)
-    y = mixtral._combine(out, dest, weights)
+    y = experts_ops.combine(out, dest, weights)
     assert y.dtype == jnp.float32
     terms = jnp.abs(out[back].reshape(N, k, -1).astype(jnp.float32)
                     * weights.astype(jnp.float32)[:, :, None]).sum(1)
     assert np.all(np.abs(np.asarray(y) - np.asarray(y_s))
                   <= 2.0 ** -23 * np.asarray(terms))
 
-    got = mixtral._moe_sparse(x, experts, layer, topi, probs, None, first)
+    got = experts_ops.moe_sparse(x, experts, layer, topi, probs, first)
     want = y_s.astype(x.dtype)
     differ = np.asarray(got, np.float32) != np.asarray(want, np.float32)
     assert differ.mean() <= 1e-3, differ.mean()
@@ -558,10 +559,10 @@ def test_prefill_attention_under_the_block_mask(monkeypatch, path):
     v = jnp.asarray(rng.standard_normal((2, s, 2, 32)), jnp.float32)
     if path == "flash":
         monkeypatch.setattr(dispatch, "FORCE_INTERPRET", True)
-    got = llama._prefill_attention(q, k, v, mask_block=4)
+    got = prefill_attention(q, k, v, mask_block=4)
     want = _dense_block_attention(q, k, v, 4)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=2e-4)
-    causal = llama._prefill_attention(q, k, v)
+    causal = prefill_attention(q, k, v)
     assert float(jnp.abs(causal - want).max()) > 0.05
     np.testing.assert_allclose(
         np.asarray(causal), np.asarray(causal_prefill_attention(q, k, v)),

@@ -2,8 +2,8 @@
 greedy AND seeded, across paged/slot/chunked-prefill), the conservative
 barriers (cancel, drain, handoff export/import) over REAL engines and
 real HTTP, the reap that launches no device program, the watchdog/overlap
-interaction, topology refusals (pp, lockstep), and the new
-dispatch/readback/overlap_idle phase vocabulary."""
+interaction, which loop each topology runs (pp, lockstep: synchronous),
+and the new dispatch/readback/overlap_idle phase vocabulary."""
 
 import dataclasses as _dc
 import threading
@@ -16,11 +16,11 @@ import jax
 import numpy as np
 import pytest
 
-from testutil import http_get, http_post
+from testutil import http_get, http_post, synchronous
 from tests.unit.test_host_timeline import Recorder
 
 from kubeai_tpu.engine import Engine, EngineConfig
-from kubeai_tpu.engine.engine import EngineDraining, StepOverlapUnsupported
+from kubeai_tpu.engine.engine import EngineDraining
 from kubeai_tpu.engine.multihost import LockstepEngine
 from kubeai_tpu.engine.sampling import SamplingParams
 from kubeai_tpu.engine.server import EngineServer
@@ -52,16 +52,23 @@ def tiny():
     return cfg, params
 
 
+def _loop(eng, overlap):
+    """`eng` on the loop a case names: "on" is what every engine off a pp
+    mesh runs, "off" the synchronous loop as lockstep builds it."""
+    assert overlap in ("on", "off")
+    return eng if overlap == "on" else synchronous(eng)
+
+
 def _engine(tiny, overlap, **overrides):
     cfg, params = tiny
     ecfg = EngineConfig(
         **{
             "num_slots": 4, "max_seq_len": 128, "page_size": 16,
-            "decode_chunk": 4, "step_overlap": overlap, **overrides,
+            "decode_chunk": 4, **overrides,
         }
     )
-    return Engine("llama", cfg, params, cfg=ecfg,
-                  eos_token_ids=TOK.eos_token_ids)
+    return _loop(Engine("llama", cfg, params, cfg=ecfg,
+                        eos_token_ids=TOK.eos_token_ids), overlap)
 
 
 @pytest.fixture(scope="module")
@@ -529,9 +536,9 @@ def _family_engines(name, **kw):
     ask = {"mixtral": {"routes": True}, "block": {"forwards": True}}.get(
         name, {})
     rides, barrier, off = (
-        Engine(family, cfg, params, eos_token_ids=(), cfg=EngineConfig(**{
+        _loop(Engine(family, cfg, params, eos_token_ids=(), cfg=EngineConfig(**{
             "num_slots": 4, "max_seq_len": 128, "page_size": 16,
-            "decode_chunk": 4, "step_overlap": overlap, **kw}))
+            "decode_chunk": 4, **kw})), overlap)
         for overlap in ("on", "on", "off")
     )
     return (rides, _barrier_forced(barrier), off), ask, cfg.vocab_size
@@ -693,9 +700,9 @@ def _fallback(tiny, case):
     cfg, params = tiny
 
     def build(overlap):
-        return Engine("llama", cfg, params, draft=draft, cfg=EngineConfig(
+        return _loop(Engine("llama", cfg, params, draft=draft, cfg=EngineConfig(
             num_slots=4, max_seq_len=128, page_size=16, decode_chunk=4,
-            step_overlap=overlap, **kw), eos_token_ids=TOK.eos_token_ids)
+            **kw), eos_token_ids=TOK.eos_token_ids), overlap)
 
     eng = build("off" if case == "off" else "on")
     ref = eng if case == "off" else build("off")
@@ -1137,36 +1144,44 @@ def test_watchdog_fires_when_inflight_reap_is_overdue():
         srv.stop()
 
 
-# ---- topology refusals + knob parsing ----------------------------------------
+# ---- which loop a topology runs ----------------------------------------------
 
 
-def test_pp_refuses_explicit_overlap(devices8):
+@pytest.mark.parametrize(
+    "topology", ["one-device", "dp2", "tp2", "pp2", "lockstep", "worker"])
+def test_the_loop_follows_the_topology(devices8, monkeypatch, topology):
+    """Nothing is set: an engine overlaps unless a second program in flight
+    would race its pp stages' hand-offs, and lockstep (host 0's wrapper and
+    a worker's loop alike) clears the flag before the first step, since
+    every host replays one sequence of steps."""
+    from kubeai_tpu.engine import multihost
+
     cfg = _dc.replace(llama.LlamaConfig.tiny(), num_layers=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    mesh = build_mesh(MeshConfig(pp=2), devices=devices8[:2])
-    ecfg = EngineConfig(num_slots=4, max_seq_len=96, decode_chunk=4,
-                        step_overlap="on")
-    with pytest.raises(StepOverlapUnsupported, match="pipeline parallelism"):
-        Engine("llama", cfg, params, mesh=mesh, cfg=ecfg)
-    # 'auto' silently degrades to the synchronous loop.
-    eng = Engine("llama", cfg, params, mesh=mesh,
-                 cfg=_dc.replace(ecfg, step_overlap="auto"))
-    assert eng._overlap is False
+    mesh = None
+    if topology in ("dp2", "tp2", "pp2"):
+        mesh = build_mesh(MeshConfig(**{topology[:2]: 2}), devices=devices8[:2])
+    eng = Engine("llama", cfg, params, mesh=mesh, cfg=EngineConfig(
+        num_slots=4, max_seq_len=96, decode_chunk=4))
+    if topology == "lockstep":
+        assert eng._overlap is True
+        eng = LockstepEngine(eng).inner
+    elif topology == "worker":
+        # The first descriptor says shut down: the loop sets up and returns.
+        desc = multihost._control_zeros()
+        desc["header"][3] = 1
+        monkeypatch.setattr(multihost, "_broadcast", lambda *a, **kw: desc)
+        multihost.worker_loop(eng)
+    assert eng._overlap is (topology not in ("pp2", "lockstep", "worker"))
 
 
-def test_lockstep_refuses_explicit_overlap(tiny):
-    with pytest.raises(StepOverlapUnsupported, match="lockstep"):
-        LockstepEngine(_engine(tiny, "on"))
-    ls = LockstepEngine(_engine(tiny, "auto"))
-    assert ls.inner._overlap is False
-
-
-def test_step_overlap_knob_parsing(tiny):
-    with pytest.raises(ValueError, match="step_overlap"):
-        _engine(tiny, "sometimes")
-    assert _engine(tiny, "auto")._overlap is True  # default-on
-    assert _engine(tiny, True)._overlap is True    # bool accepted
-    assert _engine(tiny, False)._overlap is False
+@pytest.mark.parametrize("field", ["step_overlap", "decode_kernel"])
+def test_no_option_names_a_loop_or_a_layout(field):
+    """Both choices are the engine's, from what it observes (the mesh, the
+    pool's kind): the fields are gone, not ignored."""
+    assert field not in {f.name for f in _dc.fields(EngineConfig)}
+    with pytest.raises(TypeError, match=field):
+        EngineConfig(**{field: "auto"})
 
 
 # ---- over real HTTP ----------------------------------------------------------
