@@ -466,7 +466,7 @@ class Engine:
         self._kv_quant = self.kv_dtype == "int8"
         # Paged decode layout: the pool's kind decides, nothing else.
         self.decode_kernel = decode_layout(quantized=self._kv_quant)
-        if self._block is not None or self._beside_pages is not None:
+        if self._block is not None or self._kept_apart is not None:
             self._check_family_engine(draft)
         if self._kv_quant and (cfg.speculate > 0 or draft is not None):
             raise ValueError(
@@ -599,6 +599,10 @@ class Engine:
             (psh.LAYERS, None, None, psh.KV_HEADS, None),
             cache_rules,
         )
+        if self._latent:
+            # One pool of rows without heads: whole on every device (a tp
+            # axis is refused), as the state pools beside it.
+            pool_sharding = self._state_sharding
         if self._kv_quant:
             # Dict pool leaves: int8 pages shard like bf16 pages; the
             # [NL, pages, page, KVH] scale leaf drops the head_dim
@@ -637,6 +641,7 @@ class Engine:
             state=self._recurrent,
             state_sharding=self._state_sharding,
             window=self._window,
+            latent=self._latent,
         )
         self._alloc = PageAllocator(
             n_pages, cfg.page_size, max_pages_per_slot=max_pages
@@ -932,6 +937,16 @@ class Engine:
         layers = fn(self.model_cfg)
         return {**layers, "ring": ring_pages(layers["window"], self.cfg.page_size)}
 
+    @functools.cached_property
+    def _latent(self) -> dict | None:
+        """What a token leaves in a page layer of a family whose page layers
+        keep ONE latent row a token and no keys and values, as the family
+        says it (`ModelFamily.latent_pages`: `row`, `dtype`); None for
+        every other. The page pool is then that one pool (`cache.k_pages`;
+        `cache.v_pages` is None). Derived, not set."""
+        fn = self.family.latent_pages
+        return fn(self.model_cfg) if fn else None
+
     @property
     def _page_layers(self) -> int:
         """Layers the page pool is stacked over: those that own pages by
@@ -964,15 +979,29 @@ class Engine:
             return "recurrent state"
         return "a window ring" if self._window is not None else None
 
+    @property
+    def _kept_apart(self) -> str | None:
+        """What of a slot's cache is not keys and values in pages of the one
+        pool, in words: what it keeps beside its pages, a latent pool, or
+        both (None: nothing; `_check_family_engine` is then not asked)."""
+        kinds = [
+            k for k in (self._beside_pages, self._latent and "a latent pool")
+            if k
+        ]
+        return " and ".join(kinds) or None
+
     def _check_family_engine(self, draft) -> None:
-        """What a family that keeps something beside its pages, or generates
-        by blocks, is not served with: the one table. Beside the pages, each
-        row would need a snapshot of a slot's state at a position other than
-        its last, which nothing writes yet, or, of a window ring, a rule for
-        positions the ring has already forgotten (preemption by recompute
-        needs none: the re-admission rebuilds the state, or both pools, from
-        position 0); by blocks, each is a composition the one-token step has
-        and the block step does not yet (ROADMAP B10)."""
+        """What a family that keeps something beside its pages, or one latent
+        pool in place of keys and values, or generates by blocks, is not
+        served with: the one table. Beside the pages, each row would need a
+        snapshot of a slot's state at a position other than its last, which
+        nothing writes yet, or, of a window ring, a rule for positions the
+        ring has already forgotten (preemption by recompute needs none: the
+        re-admission rebuilds the state, or both pools, from position 0); of
+        a latent pool, a chunk graph, a verify forward, a quantizer and a
+        wire format that know a row without heads (ROADMAP B4); by blocks,
+        each is a composition the one-token step has and the block step does
+        not yet (ROADMAP B10)."""
         cfg = self.cfg
         refused = [
             name for name, on in (
@@ -1000,10 +1029,10 @@ class Engine:
     def _not_served_with(self, what: str) -> ValueError:
         """The one sentence a family's refusals are raised in; the family's
         kind picks its middle."""
-        beside = self._beside_pages
+        apart = self._kept_apart
         kind = (
-            f"keeps {beside} beside its pages" if beside
-            else "generates by blocks"
+            f"keeps {apart} beside its pages" if self._beside_pages
+            else f"keeps {apart}" if apart else "generates by blocks"
         )
         return ValueError(
             f"family {self.family.name} {kind} and is not served with: {what}"
@@ -1015,22 +1044,32 @@ class Engine:
         belongs to them (hand-off, pages served to or fetched from a peer,
         spill). The engine asks it of its own calls, the server of its
         options, at construction."""
-        if self._beside_pages is not None:
+        if self._kept_apart is not None:
             raise self._not_served_with(what)
 
     def kv_pools(self) -> list[dict] | None:
         """What /v1/state says of the pools of a family with two kinds of
-        KV layer (None for any other): kind, layers, pages (scratch page
-        left out), the pages a slot can own, the pages in use now, bytes;
-        the window pool's `window`."""
+        KV layer, or with one latent pool (None for any other): kind,
+        layers, pages (scratch page left out), the pages a slot can own, the
+        pages in use now, bytes; the window pool's `window`, the latent
+        pool's `row` (numbers a token leaves a layer)."""
         win = self._window
+        total = self._n_pages - 1
+        if self._latent:
+            return [{
+                "kind": "latent", "layers": int(self._page_layers),
+                "pages": int(total),
+                "pages_per_slot": int(self._bt_host.shape[1]),
+                "pages_used": int(total - self._alloc.free_pages),
+                "bytes": int(self.cache.nbytes()),
+                "row": int(self._latent["row"][0]),
+            }]
         if win is None:
             return None
         per_page = (
             2 * self.cfg.page_size * self.model_cfg.num_kv_heads
             * self.model_cfg.head_size * np.dtype(self.cfg.cache_dtype).itemsize
         )
-        total = self._n_pages - 1
         ring_total = self.cfg.num_slots * win["ring"]
         return [
             {
@@ -1049,6 +1088,16 @@ class Engine:
             },
         ]
 
+    def live_pages(self) -> dict | None:
+        """The books of the pages a decode chunk reads, a pool of
+        `kv_pools()` (None where that is): `pages` at the newest dispatch,
+        `pages_total` over every dispatched chunk."""
+        if self._latent:
+            return {"latent": self.live_kv}
+        if self._window:
+            return {"global": self.live_kv, "window": self.live_window}
+        return None
+
     @functools.cached_property
     def state_info(self) -> dict | None:
         """What /v1/state says of the state beside the pages (None for a
@@ -1057,11 +1106,16 @@ class Engine:
         if rec is None:
             return None
         pool_bytes = self.cache.state_nbytes()  # fixed when the engine is built
+        if self._latent:
+            # The latent pool beside them, where a reader of the state
+            # pools' bytes looks (`kubeai_engine_state_pool_bytes{kind}`).
+            pool_bytes = {**pool_bytes, "latent": int(self.cache.nbytes())}
         return {
             "state_layers": int(rec["state_layers"]),
             "page_layers": int(rec["page_layers"]),
             "bytes_per_slot": {
-                name: n // self.cfg.num_slots for name, n in pool_bytes.items()
+                name: n // self.cfg.num_slots
+                for name, n in self.cache.state_nbytes().items()
             },
             "pool_bytes": pool_bytes,
         }
@@ -1155,6 +1209,7 @@ class Engine:
         stateful = self._beside_pages is not None
         state_kw = {"state": True} if stateful else {}
         window = self._window
+        latent = self._latent is not None
         if self._pp > 1:
             from functools import partial as _partial
 
@@ -1239,9 +1294,17 @@ class Engine:
             page_ids, offsets = batched_sequence_page_coords(
                 bt_rows, lengths, tokens.shape[1], page
             )
-            kp, vp = batched_scatter_sequence(
-                kp, vp, k_all, v_all, page_ids, offsets
-            )
+            if latent:
+                # One pool of rows: `k_all` [page layers, A, S, row], no
+                # second pool (`vp` and `v_all` are None).
+                from kubeai_tpu.ops.latent_attention import write_latent_rows
+
+                with jax.named_scope("latent_page_write"):
+                    kp = write_latent_rows(kp, k_all, page_ids, offsets)
+            else:
+                kp, vp = batched_scatter_sequence(
+                    kp, vp, k_all, v_all, page_ids, offsets
+                )
             bt = bt.at[slots].set(bt_rows)
             toks = sample(logits, seeds, lengths, temp, topk, topp)  # [A]
             toks = jnp.where(forced >= 0, forced, toks)

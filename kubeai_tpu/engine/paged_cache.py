@@ -20,6 +20,12 @@ Layout:
                      1 + s * ring .. as a ring, however long its sequence
                      (NL above is then the global layers; docs/concepts/
                      window-cache.md); {} for every other
+  latent:            a family whose page layers keep ONE row a token from
+                     which keys and values are both read (multi-head latent
+                     attention) has one pool, `k_pages` [NL, n_pages, page,
+                     row], and no second: `v_pages` is None (docs/concepts/
+                     latent-cache.md). Block tables, allocator and `state`
+                     are what they are for any other
   host allocator:    free-list of page ids (bookkeeping outside jit)
 
 Ops (jit-safe, tested against contiguous semantics):
@@ -52,7 +58,7 @@ class PagedKVCache:
     # weight quantizer uses, so jit plumbing and layer scans carry both
     # unchanged.
     k_pages: jax.Array | dict  # [NL, n_pages, page, KVH, D]
-    v_pages: jax.Array | dict
+    v_pages: jax.Array | dict | None  # None: `k_pages` is a latent pool
     block_tables: jax.Array  # [slots, max_pages] int32, -1 = unallocated
     # Row `slot` of each pool is that slot's, as its page list is: written
     # whole by an admission, updated in place by every decode step
@@ -87,7 +93,8 @@ class PagedKVCache:
         """Resident pool bytes (pages + scales when quantized)."""
         from kubeai_tpu.ops.kv_quant import kv_pool_nbytes
 
-        return kv_pool_nbytes(self.k_pages) + kv_pool_nbytes(self.v_pages)
+        return kv_pool_nbytes(self.k_pages) + (
+            0 if self.v_pages is None else kv_pool_nbytes(self.v_pages))
 
     def state_nbytes(self) -> dict:
         """Resident bytes of each state pool, by name."""
@@ -108,13 +115,16 @@ class PagedKVCache:
         state: dict | None = None,  # ModelFamily.recurrent_state(cfg)
         state_sharding=None,
         window: dict | None = None,  # ModelFamily.kv_layers(cfg) + "ring"
+        latent: dict | None = None,  # ModelFamily.latent_pages(cfg)
     ) -> "PagedKVCache":
         """Buffers are created under their shardings (None = the default
         device), never whole on one device and re-placed afterwards.
         `num_layers` are the layers that own pages by the sequence's length
         (`num_pages` of them: the pool that can run out). With `window` a
         second pool over its `window_layers` holds every slot's ring of
-        `ring` pages and a scratch page: no allocator, no growth."""
+        `ring` pages and a scratch page: no allocator, no growth. With
+        `latent` the page pool is ONE pool of its rows (`kv_heads` and
+        `head_dim` are not asked), and there is no second."""
         from kubeai_tpu.ops.kv_quant import make_quantized_pool
 
         if window and (state or dtype in (jnp.int8, "int8")):
@@ -122,9 +132,20 @@ class PagedKVCache:
                 "a window pool goes with a bf16 page pool and no recurrent state"
             )
 
+        if latent and (window or dtype in (jnp.int8, "int8")):
+            raise ValueError(
+                "a latent pool goes with no window pool and is not quantized"
+            )
+
         max_pages = -(-max_seq_len // page_size)
         shape = (num_layers, num_pages, page_size, kv_heads, head_dim)
-        if dtype in (jnp.int8, "int8"):
+        if latent:
+            k_pages = jnp.zeros(
+                (*shape[:3], *latent["row"]), latent["dtype"],
+                device=pool_sharding,
+            )
+            v_pages = None
+        elif dtype in (jnp.int8, "int8"):
             k_pages = make_quantized_pool(shape, sharding=pool_sharding)
             v_pages = make_quantized_pool(shape, sharding=pool_sharding)
         else:

@@ -74,6 +74,10 @@ ITL_BUCKETS_S = (
 )
 
 
+# `_collect` decodes a stream's last 32 to 64 tokens at an event, not all.
+DECODE_TAIL_TOKENS = 32
+
+
 class _EventQueue(queue.Queue):
     """A stream's queue of StepEvents that remembers when each was handed
     over: after `get()` returns an event, `handed_at` is the
@@ -326,7 +330,8 @@ class EngineMetrics:
             "with two kinds of KV layer has a series a pool (label `pool`: "
             "global = ceil(tokens / page) a slot, window = the ring pages "
             "from the first in-window position on, at most a ring a "
-            "slot); every other family the one series without a label.",
+            "slot), a family with one latent pool the one series "
+            "pool=latent; every other family the one series without a label.",
             self.registry,
         )
         self.prefill_tokens = Counter(
@@ -422,16 +427,20 @@ class EngineMetrics:
             "kubeai_engine_state_pool_bytes",
             "Resident bytes of the state a family keeps beside its pages "
             "(label `kind`: recurrent = the linear-attention layers' "
-            "states, conv = their convolutions' last inputs): a slot's "
-            "share is the same whatever its length. Absent for a family "
-            "all of whose layers keep keys and values.",
+            "states, conv = their convolutions' last inputs: a slot's "
+            "share is the same whatever its length; latent = the one page "
+            "pool of a family with latent attention, beside the state it "
+            "lives with). Absent for a family all of whose layers keep keys "
+            "and values.",
             self.registry,
         )
         self.kv_pool_pages = Gauge(
             "kubeai_engine_kv_pool_pages",
-            "Pages of each KV pool of a family that keeps two (label `pool`: "
+            "Pages of each KV pool of a family that keeps two, or one latent "
+            "pool (label `pool`: "
             "global = the layers whose pages a slot takes by its length, "
-            "window = the layers that keep a ring of fixed size a slot; "
+            "window = the layers that keep a ring of fixed size a slot, "
+            "latent = the layers that keep one latent row a token; "
             "label `state`: used, free; the scratch pages left out). Absent "
             "for a family with one kind of KV layer.",
             self.registry,
@@ -744,7 +753,7 @@ class EngineMetrics:
         live = getattr(inner, "live_kv", None)
         pools = getattr(inner, "kv_pools", lambda: None)()
         if live and pools:
-            for pool, book in (("global", live), ("window", inner.live_window)):
+            for pool, book in inner.live_pages().items():
                 self.decode_live_pages.inc(max(
                     0.0,
                     book["pages_total"] - self.decode_live_pages.get(pool=pool),
@@ -1318,7 +1327,10 @@ class EngineServer:
                 # next dispatch waits. `events` is the tokens the step
                 # emitted, on the trace's clock.
                 with self._span("serve.fanout", events=len(events)) as fan:
-                    for ev in events:
+                    # A request's tokens side by side (the sort is stable:
+                    # each keeps its order), so its handler wakes to all
+                    # of them and not to the first of a chunk.
+                    for ev in sorted(events, key=lambda ev: ev.rid):
                         with self._sub_lock:
                             q = self._subscribers.get(ev.rid)
                         if q is not None:
@@ -2580,6 +2592,11 @@ class EngineServer:
             )
         else:
             emitted_len = 0
+        # The text of the stream so far is `head` + the decoded
+        # `tokens[head_n:]`: every event needs the whole text (stop
+        # strings, the held-back tail), and decoding all of it at every
+        # token costs the square of an answer's length.
+        head, head_n = "", 0
         finish = "length"
         stopped = None  # the result, once a stop string ended the request
         if deadline is None:
@@ -2620,7 +2637,13 @@ class EngineServer:
                     for flag, blocks in (handed or {}).items():
                         blocks.extend(getattr(ev, HANDED_OVER[flag]) or ())
                     self.metrics.generated_tokens.inc()
-                    text = self.tokenizer.decode(tokens)
+                    text = head + self.tokenizer.decode(tokens[head_n:])
+                    if len(tokens) - head_n >= 2 * DECODE_TAIL_TOKENS:
+                        # Move the split up, if the text splits there too.
+                        k = len(tokens) - DECODE_TAIL_TOKENS
+                        tail = self.tokenizer.decode(tokens[k:])
+                        if tail and text.endswith(tail):
+                            head, head_n = text[: len(text) - len(tail)], k
                     # Stop strings act on detokenized text (engine core is
                     # token-space only; see sampling.SamplingParams
                     # docstring).

@@ -52,8 +52,7 @@ from kubeai_tpu.ops.attention import prefill_attention
 from kubeai_tpu.ops.experts import (
     EXPERT_LEAVES,
     at,
-    moe_sparse,
-    shared_expert,
+    ffn_behind_dense as _ffn,
     stack_routes,
 )
 from kubeai_tpu.ops.norms import rms_norm
@@ -328,67 +327,6 @@ def _qkv(h, lp, cfg, positions, rotate: bool):
         inv_freq = jnp.asarray(rope_frequencies(cfg.head_dim, cfg.rope_theta))
         q, k = apply_rope(q, positions, inv_freq), apply_rope(k, positions, inv_freq)
     return q, k, v
-
-
-@jax.named_scope("dense_ffn")
-def _dense(x, dp, cfg):
-    """Rows x [N, E] (already normed) through a leading dense layer's SwiGLU."""
-    mid = jax.nn.silu(x @ dp["w_gate"]) * (x @ dp["w_up"])
-    return mid @ dp["w_down"]
-
-
-def _route(x, mp, cfg):
-    """(topi [N, k]: the global ids taken, best first; their weights [N, k]
-    float32). Selected on `sigmoid + bias`, weighted by the sigmoid alone,
-    renormalised over the taken and scaled."""
-    with jax.named_scope("moe_router"):
-        s = jax.nn.sigmoid(jnp.einsum(
-            "ne,ex->nx", x, mp["router"], preferred_element_type=jnp.float32
-        ))
-        _, topi = jax.lax.top_k(s + mp["router_bias"], cfg.num_experts_per_tok)
-        taken = jnp.take_along_axis(s, topi, axis=-1)
-        probs = cfg.routed_scaling_factor * taken / jnp.sum(
-            taken, axis=-1, keepdims=True
-        )
-    return topi, probs
-
-
-def _moe_parts(x, mp, experts, layer, cfg):
-    """Rows x [N, E] (already normed) through routed layer `layer`: (this
-    share's part of the routed sum, the shared expert's output, both
-    float32, and topi [N, k])."""
-    topi, probs = _route(x, mp, cfg)
-    with jax.named_scope("moe_shared"):
-        shared = shared_expert(x, mp)
-    routed = moe_sparse(
-        x, experts, layer, topi, probs, first=cfg.first_expert
-    )
-    return routed.astype(jnp.float32), shared, topi
-
-
-def _ffn(x, layers, layer, slot, cfg):
-    """x [N, E] after attention through the FFN of `layer` (traced), which
-    stands at `slot` (static) of its period: x + ffn(rms(x)) and the expert
-    sets topi [N, k] it took (zeros for a dense layer, which has no row in
-    the hand-over). Only period 0's first `first_k_dense` slots can be dense."""
-    k = cfg.num_experts_per_tok
-
-    def dense(x):
-        dp = at(layers["dense"], jnp.minimum(layer, cfg.first_k_dense - 1))
-        h = rms_norm(x, dp["post_norm"], cfg.rms_norm_eps)
-        return x + _dense(h, dp, cfg), jnp.zeros((x.shape[0], k), jnp.int32)
-
-    @jax.named_scope("moe_ffn")
-    def moe(x):
-        r = jnp.maximum(layer - cfg.first_k_dense, 0)
-        mp = at(layers["moe"], r)
-        h = rms_norm(x, mp["post_norm"], cfg.rms_norm_eps)
-        routed, shared, topi = _moe_parts(h, mp, layers["experts"], r, cfg)
-        return x + (routed + shared).astype(x.dtype), topi
-
-    if slot >= cfg.first_k_dense:
-        return moe(x)
-    return jax.lax.cond(layer < cfg.first_k_dense, dense, moe, x)
 
 
 def _stack_kind(per_period):

@@ -52,7 +52,12 @@ from kubeai_tpu.ops.experts import (
     shared_expert,
     stack_routes,
 )
-from kubeai_tpu.ops.gated_delta import gdn_chunk_scan, gdn_update
+from kubeai_tpu.ops.gated_delta import (
+    conv_prefill,
+    conv_step as _conv_step,
+    gdn_chunk_scan,
+    gdn_update,
+)
 from kubeai_tpu.ops.norms import rms_norm
 from kubeai_tpu.ops.projections import split_heads
 from kubeai_tpu.ops.rope import apply_rope, rope_frequencies
@@ -418,33 +423,6 @@ def _period_xs(params, cfg):
     }
 
 
-def _conv_step(conv, li, u, w):
-    """One position of layer `li`'s causal convolution, every slot: the pool
-    `conv` [state layers, B, (K - 1) * C] (a slot's last K - 1 inputs, the
-    oldest first), the position's input `u` [B, C] and the taps `w` [K, C]
-    -> (y [B, C] float32, the pool with the layer's rows moved on by one).
-
-    Tap t of every slot is read where it lies: C is a multiple of the 128
-    lanes, so `[li, :, t * C:(t + 1) * C]` is a slice in the tiling the pool
-    has, and the K products are summed as they are read. As a `[B, K, C]`
-    window the row was re-laid-out three times a layer to be read once
-    (PERF.md section 5: 11.6-11.9 us each on a v5e, beside a 27.5 us sum
-    over a 4-row sublane axis)."""
-    B, C = u.shape
-    K = w.shape[0]
-    u = u.astype(conv.dtype)
-    taps = [
-        jax.lax.dynamic_slice(conv, (li, 0, t * C), (1, B, C))[0]
-        for t in range(K - 1)
-    ] + [u]
-    y = sum(
-        tap.astype(jnp.float32) * w[t].astype(jnp.float32)
-        for t, tap in enumerate(taps)
-    )
-    return y, jax.lax.dynamic_update_index_in_dim(
-        conv, jnp.concatenate(taps[1:], axis=-1), li, 0)
-
-
 def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
             routes=False, state=False):
     """Whole-prompt prefill of [A, S] prompts. Returns (logits at
@@ -455,7 +433,7 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
     convolution's last K - 1 real inputs) and, with `routes`, the expert
     sets [A, S, routed layers, k]."""
     A, S = tokens.shape
-    G, K = cfg.full_attention_interval - 1, cfg.linear_conv_kernel_dim
+    G = cfg.full_attention_interval - 1
     positions = jnp.arange(S)[None, :].repeat(A, axis=0)
     real = positions < lengths[:, None]  # [A, S]
     layers = params["layers"]
@@ -472,23 +450,15 @@ def prefill(params, cfg, tokens, lengths, lora=None, lora_idx=None, *,
         h = _norm0(x, lp["input_norm"], cfg.rms_norm_eps)
         u, z, beta, g = _gdn_project(h, lp, cfg)
         with jax.named_scope("gdn_conv"):
-            padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
-            y = sum(
-                padded[:, j : j + S].astype(jnp.float32)
-                * lp["conv_w"][j].astype(jnp.float32)
-                for j in range(K)
-            )
+            y, tail = conv_prefill(u, lp["conv_w"], lengths)
             q, k, v = _gdn_heads(jax.nn.silu(y), cfg)
-            # The inputs at lengths - K + 1 .. lengths - 1 (zeros before 0).
-            idx = lengths[:, None] + jnp.arange(K - 1)[None, :]  # into `padded`
-            tail = jnp.take_along_axis(padded, idx[:, :, None], axis=1)
         with jax.named_scope("gdn_scan"):
             # A pad position neither decays nor writes.
             o, s = gdn_chunk_scan(
                 q, k, v, jnp.where(real[..., None], g, 0.0),
                 jnp.where(real[..., None], beta, 0.0),
             )
-        return x + _gdn_out(o, z, lp, cfg), s, tail.reshape(A, -1)
+        return x + _gdn_out(o, z, lp, cfg), s, tail
 
     def attention(x, lp):
         with jax.named_scope("gated_attention"):

@@ -42,8 +42,22 @@ class ModelFamily:
         held_experts: Callable | None = None,
         recurrent_state: Callable | None = None,
         kv_layers: Callable | None = None,
+        latent_pages: Callable | None = None,
     ):
         self.hidden_states = hidden_states
+        # A family whose page layers keep ONE latent row a token, from which
+        # keys and values are both read (multi-head latent attention), says
+        # so here: latent_pages(cfg) -> {"row": a token's shape a layer,
+        # "dtype"}. The engine then holds ONE pool `[page layers, pages,
+        # page, *row]` in `cache.k_pages` and none in `cache.v_pages`
+        # (None): `prefill` returns (logits, the rows [page layers, A, S,
+        # *row], None, ...) and `decode_step_paged` takes and returns the
+        # pool and None. Allocator, block tables, reservation and
+        # preemption by recompute are every family's; what would need a
+        # chunk graph, a verify forward, a quantizer or a wire format for
+        # such rows is refused at construction
+        # (docs/concepts/latent-cache.md).
+        self.latent_pages = latent_pages
         # A family some of whose layers attend a sliding window and the
         # others every earlier position says so here: kv_layers(cfg) ->
         # {"global_layers": layers that own pages by the sequence's length,
@@ -213,7 +227,7 @@ def _ensure_builtin() -> None:
     )
     # Further families self-register on import.
     from kubeai_tpu.models import (  # noqa: F401
-        exaone_moe, gemma, mixtral, qwen3_next,
+        exaone_moe, gemma, kimi_linear, mixtral, qwen3_next,
     )
 
     _LOADED = True

@@ -87,17 +87,24 @@ def prefill_attention(q, k, v, mask_block: int = 1, window: int = 0):
     and unaligned buckets keep `causal_prefill_attention`.
     `mask_block` > 1 is a block-diffusion family's mask: causal between
     blocks of that many positions, full inside one. `window` > 0 is a
-    window layer's: a query sees that many positions, its own the last."""
-    S = q.shape[1]
+    window layer's: a query sees that many positions, its own the last.
+    Values may be narrower than queries and keys (latent attention expanded:
+    keys of 192, values of 128): both kernels take one width, so the values
+    ride in zero-padded to the keys' and what comes back is cut to theirs."""
+    S, d, dv = q.shape[1], q.shape[-1], v.shape[-1]
+    if dv != d:
+        v = jnp.pad(v, ((0, 0),) * (v.ndim - 1) + ((0, d - dv),))
     if dispatch.kernel_mode() != "reference" and S >= 256 and S % 128 == 0:
         from kubeai_tpu.ops.pallas_attention import flash_causal_prefill
 
-        return flash_causal_prefill(
+        out = flash_causal_prefill(
             q, k, v, mask_block=mask_block, window=window
         )
-    return causal_prefill_attention(
-        q, k, v, mask_block=mask_block, window=window or None
-    )
+    else:
+        out = causal_prefill_attention(
+            q, k, v, mask_block=mask_block, window=window or None
+        )
+    return out if dv == d else out[..., :dv]
 
 
 def chunked_prefill_attention(

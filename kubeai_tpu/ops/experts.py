@@ -12,7 +12,10 @@ row also takes (`shared_expert`), a layer of stacked weights read at its
 number (`at`), and the form in which a forward hands its expert sets over
 (`route_dtype`, `stack_routes`). `tests/unit/test_moe_experts_compiled.py`
 counts the compiled operations under the three scopes `moe_dispatch`,
-`moe_experts` and `moe_combine`.
+`moe_experts` and `moe_combine`. At the end, the one router two families
+share whole: sigmoid scores selected under a bias, behind leading dense layers
+(`route_sigmoid_bias`, `moe_parts_sigmoid_bias`, `dense_ffn`,
+`ffn_behind_dense`).
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeai_tpu.ops.grouped_matmul import grouped_matmul, tile_plan
+from kubeai_tpu.ops.norms import rms_norm
 
 # The leaves of a sparse family's expert stack, each [layers, experts, ...].
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
@@ -181,3 +185,74 @@ def stack_routes(topi, num_experts: int):
     [*rows, routed layers, k], in the smallest unsigned integer type that
     holds one of the router's `num_experts` ids."""
     return jnp.moveaxis(topi, 0, -2).astype(route_dtype(num_experts))
+
+# ---- a sigmoid router behind leading dense layers ------------------------------
+#
+# What the families share whose router scores by a sigmoid, selects on the
+# score plus a bias and weights by the score alone, with one ungated shared
+# expert and `first_k_dense` leading layers that have a dense SwiGLU instead
+# (models/exaone_moe.py, models/kimi_linear.py). `cfg` is the family's own
+# configuration; read of it: `num_experts_per_tok`, `routed_scaling_factor`,
+# `first_expert`, `first_k_dense`, `rms_norm_eps`.
+
+
+@jax.named_scope("dense_ffn")
+def dense_ffn(x, dp):
+    """Rows x [N, E] (already normed) through a leading dense layer's SwiGLU."""
+    mid = jax.nn.silu(x @ dp["w_gate"]) * (x @ dp["w_up"])
+    return mid @ dp["w_down"]
+
+
+def route_sigmoid_bias(x, mp, cfg):
+    """(topi [N, k]: the global ids taken, best first; their weights [N, k]
+    float32). Selected on `sigmoid + bias`, weighted by the sigmoid alone,
+    renormalised over the taken and scaled."""
+    with jax.named_scope("moe_router"):
+        s = jax.nn.sigmoid(jnp.einsum(
+            "ne,ex->nx", x, mp["router"], preferred_element_type=jnp.float32
+        ))
+        _, topi = jax.lax.top_k(s + mp["router_bias"], cfg.num_experts_per_tok)
+        taken = jnp.take_along_axis(s, topi, axis=-1)
+        probs = cfg.routed_scaling_factor * taken / jnp.sum(
+            taken, axis=-1, keepdims=True
+        )
+    return topi, probs
+
+
+def moe_parts_sigmoid_bias(x, mp, experts, layer, cfg):
+    """Rows x [N, E] (already normed) through routed layer `layer`: (this
+    share's part of the routed sum, the shared expert's output, both
+    float32, and topi [N, k])."""
+    topi, probs = route_sigmoid_bias(x, mp, cfg)
+    with jax.named_scope("moe_shared"):
+        shared = shared_expert(x, mp)
+    routed = moe_sparse(
+        x, experts, layer, topi, probs, first=cfg.first_expert
+    )
+    return routed.astype(jnp.float32), shared, topi
+
+
+def ffn_behind_dense(x, layers, layer, slot, cfg):
+    """x [N, E] after attention through the FFN of `layer` (traced), which
+    stands at `slot` (static) of its period: x + ffn(rms(x)) and the expert
+    sets topi [N, k] it took (zeros for a dense layer, which has no row in
+    the hand-over). Only period 0's first `first_k_dense` slots can be dense."""
+    k = cfg.num_experts_per_tok
+
+    def dense(x):
+        dp = at(layers["dense"], jnp.minimum(layer, cfg.first_k_dense - 1))
+        h = rms_norm(x, dp["post_norm"], cfg.rms_norm_eps)
+        return x + dense_ffn(h, dp), jnp.zeros((x.shape[0], k), jnp.int32)
+
+    @jax.named_scope("moe_ffn")
+    def moe(x):
+        r = jnp.maximum(layer - cfg.first_k_dense, 0)
+        mp = at(layers["moe"], r)
+        h = rms_norm(x, mp["post_norm"], cfg.rms_norm_eps)
+        routed, shared, topi = moe_parts_sigmoid_bias(
+            h, mp, layers["experts"], r, cfg)
+        return x + (routed + shared).astype(x.dtype), topi
+
+    if slot >= cfg.first_k_dense:
+        return moe(x)
+    return jax.lax.cond(layer < cfg.first_k_dense, dense, moe, x)

@@ -656,6 +656,32 @@ def _live_page_range(bt_ref, pos_ref, win_ref, b, *, page_size, max_pages):
     return first, n_pages
 
 
+# A cursor is (slot, page): it walks the live pages of the slots that have
+# any, in order; slot == nb means past the end (rows are read at min(slot, nb
+# - 1), so a finished cursor reads nothing out of bounds). `live(b)` is slot
+# b's (first, n_pages). The latent pool's kernel (ops/latent_attention.py)
+# walks with the same two.
+
+
+def _first_live(live, nb, b):
+    """The first slot >= b that has a live page, at its first one."""
+    def dead(b):
+        first, n_pages = live(jnp.minimum(b, nb - 1))
+        return (b < nb) & (first >= n_pages)
+
+    b = jax.lax.while_loop(dead, lambda b: b + 1, b)
+    return b, live(jnp.minimum(b, nb - 1))[0]
+
+
+def _advance(live, nb, b, i):
+    _, n_pages = live(jnp.minimum(b, nb - 1))
+    # Dead slots are walked over only when a slot is left.
+    return jax.lax.cond(
+        i + 1 < n_pages, lambda: (b, i + 1),
+        lambda: _first_live(live, nb, b + 1),
+    )
+
+
 def _split_bf16(p):
     """f32 p as three bf16 pieces whose sum is p (8 + 8 + 8 mantissa bits):
     p goes to the MXU against a bf16 V at full precision, in bf16 passes,
@@ -788,24 +814,8 @@ def _paged_fused_kernel(
         out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
         o_ref[b] = out.astype(o_ref.dtype)
 
-    # A cursor is (slot, page): it walks the live pages of the slots that
-    # have any, in order; slot == nb means past the end (rows are read at
-    # min(slot, nb - 1), so a finished cursor reads nothing out of bounds).
-    def first_live(b):
-        """The first slot >= b that has a live page, at its first one."""
-        def dead(b):
-            first, n_pages = live(jnp.minimum(b, nb - 1))
-            return (b < nb) & (first >= n_pages)
-
-        b = jax.lax.while_loop(dead, lambda b: b + 1, b)
-        return b, live(jnp.minimum(b, nb - 1))[0]
-
-    def advance(b, i):
-        _, n_pages = live(jnp.minimum(b, nb - 1))
-        # Dead slots are walked over only when a slot is left.
-        return jax.lax.cond(
-            i + 1 < n_pages, lambda: (b, i + 1), lambda: first_live(b + 1)
-        )
+    first_live = functools.partial(_first_live, live, nb)
+    advance = functools.partial(_advance, live, nb)
 
     def copies(b, i, buf):
         page_id = jnp.maximum(bt_ref[jnp.minimum(b, nb - 1), i], 0)

@@ -31,6 +31,8 @@ import sys
 SCOPES = (
     "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "moe_shared",
     "moe_ffn", "gdn_proj", "gdn_conv", "gdn_update", "gdn_out",
+    "kda_proj", "kda_conv", "kda_update", "kda_scan", "kda_out", "mla_proj",
+    "mla_decode", "mla_prefill", "latent_page_write",
     "gated_attention", "attn_window", "attn_global", "paged_attention",
     "kv_page_write", "qk_norm", "qkv", "layer_finish", "dense_ffn", "mlp",
     "lm_head", "sample", "block_commit",

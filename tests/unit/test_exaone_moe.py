@@ -27,6 +27,7 @@ from kubeai_tpu.engine.tokenizer import ByteTokenizer
 from kubeai_tpu.models import exaone_moe as em
 from kubeai_tpu.models.registry import get_model_family
 from kubeai_tpu.ops import paged_attention as pa
+from kubeai_tpu.ops import experts
 from kubeai_tpu.ops.experts import at
 from kubeai_tpu.parallel.mesh import MeshConfig, build_mesh
 from perf.reference import exaone_moe as reference
@@ -248,7 +249,7 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
         hf = {**HF, "expert_share_index": share}
         cfg, params = served(jnp.float32, hf=hf)
         layers = params["layers"]
-        routed, shared, topi = em._moe_parts(
+        routed, shared, topi = experts.moe_parts_sigmoid_bias(
             h, at(layers["moe"], layer - 1), layers["experts"], layer - 1, cfg)
         assert np.array_equal(np.sort(np.asarray(topi), -1), np.sort(own, -1))
         total = total + routed
@@ -262,7 +263,7 @@ def test_the_bias_moves_a_selection_and_never_a_weight():
     cfg, params = served(jnp.float32)
     mp = at(params["layers"]["moe"], 2)
     x = jax.random.normal(jax.random.PRNGKey(12), (64, cfg.hidden_size))
-    topi, probs = em._route(x, mp, cfg)
+    topi, probs = experts.route_sigmoid_bias(x, mp, cfg)
     s = jax.nn.sigmoid(x @ mp["router"])
     # The weights are the sigmoid scores of the taken, renormalised and scaled
     # by 2.5: the bias is no part of them.
@@ -272,7 +273,7 @@ def test_the_bias_moves_a_selection_and_never_a_weight():
     # Without the bias other experts are taken in some rows (uniform in +-0.05
     # against scores 0.01 apart), and the rows that take the same set weigh
     # it the same.
-    bare_i, bare_p = em._route(x, dict(mp, router_bias=jnp.zeros_like(mp["router_bias"])), cfg)
+    bare_i, bare_p = experts.route_sigmoid_bias(x, dict(mp, router_bias=jnp.zeros_like(mp["router_bias"])), cfg)
     same = (np.sort(np.asarray(topi), -1) == np.sort(np.asarray(bare_i), -1)).all(-1)
     assert 0 < same.sum() < len(same)
     order = np.argsort(np.asarray(topi), -1), np.argsort(np.asarray(bare_i), -1)
