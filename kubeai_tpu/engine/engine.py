@@ -446,6 +446,9 @@ class Engine:
         # Of a family with window layers, the ring pages a window layer's
         # attention reads (at most a ring a slot), the same way.
         self.live_window = {"pages": 0, "pages_total": 0}
+        # Of a family with a latent pool, the blocks of several pages its
+        # decode kernel attends those pages in, the same way.
+        self.live_blocks = {"blocks": 0, "blocks_total": 0}
 
         self._spec = 0  # resolved speculation window (see below)
         if (
@@ -946,6 +949,17 @@ class Engine:
         `cache.v_pages` is None). Derived, not set."""
         fn = self.family.latent_pages
         return fn(self.model_cfg) if fn else None
+
+    @functools.cached_property
+    def _latent_block(self) -> int:
+        """Pages of a slot the latent decode kernel attends as one block, as
+        the kernel's module derives it from the pool's shapes."""
+        from kubeai_tpu.ops.latent_attention import block_pages
+
+        return block_pages(
+            self.cfg.page_size, self._latent["row"][0],
+            jnp.dtype(self._latent["dtype"]).itemsize, self._bt_host.shape[1],
+        )
 
     @property
     def _page_layers(self) -> int:
@@ -2993,6 +3007,9 @@ class Engine:
                 self._set_bt_row(slot, pages)
         self.live_kv["slots"] = len(live)
         self.live_kv["pages"] = sum(live.values())
+        if self._latent:
+            self.live_blocks["blocks"] = sum(
+                -(-n // self._latent_block) for n in live.values())
         if self._window:
             # Pages from the first that holds an in-window position on.
             win = self._window["window"]
@@ -3886,6 +3903,7 @@ class Engine:
                         )
                         self._bt_dirty = False
                 self.live_kv["pages_total"] += self.live_kv["pages"]
+                self.live_blocks["blocks_total"] += self.live_blocks["blocks"]
                 self.live_window["pages_total"] += self.live_window["pages"]
                 with span(
                     "step.decode", kv_layout=self.kv_layout,

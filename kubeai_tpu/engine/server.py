@@ -334,6 +334,17 @@ class EngineMetrics:
             "pool=latent; every other family the one series without a label.",
             self.registry,
         )
+        self.decode_page_blocks = Counter(
+            "kubeai_engine_decode_page_blocks_total",
+            "Blocks of pages the latent decode kernel attends (ceil(live "
+            "pages / G) a slot, G pages of one slot one block: "
+            "ops/latent_attention.py:block_pages), added up once per "
+            "dispatched decode chunk beside decode_live_pages_total: live "
+            "pages over G x blocks is the share of a block's columns that "
+            "hold a fetched row. The one series pool=latent, of a family "
+            "with a latent pool; no series for any other.",
+            self.registry,
+        )
         self.prefill_tokens = Counter(
             "kubeai_engine_prefill_tokens_total",
             "Token positions computed by admission calls (label `kind`: "
@@ -758,6 +769,12 @@ class EngineMetrics:
                     0.0,
                     book["pages_total"] - self.decode_live_pages.get(pool=pool),
                 ), pool=pool)
+                if pool == "latent":
+                    self.decode_page_blocks.inc(max(
+                        0.0,
+                        inner.live_blocks["blocks_total"]
+                        - self.decode_page_blocks.get(pool=pool),
+                    ), pool=pool)
             for pool in pools:
                 used = pool["pages_used"]
                 self.kv_pool_pages.set(used, pool=pool["kind"], state="used")

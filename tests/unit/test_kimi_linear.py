@@ -280,11 +280,23 @@ def test_the_pallas_update_interpreted_is_the_jnp_update(monkeypatch, a_channel)
         assert float(jnp.abs(mean_s - want_s).max()) > 1e-2
 
 
-def _latent_case(dtype, seed=1):
-    """5 slots of 4 heads against rows of 32 + 16 in 128: lengths 0 (a new
-    sequence), 17, 33, 64 (a whole number of pages) and a freed slot whose
-    position still says 5."""
-    B, H, W, page, MP, NL, P = 5, 4, 128, 16, 4, 2, 24
+def _latent_case(dtype, seed=1, dead=(4,), past=None):
+    """9 slots of 4 heads against rows of 32 + 16 in 128, pages of 32 rows.
+    With G the pages the kernel attends as one block, the slots hold 0 pages
+    (a new sequence), 1, G - 1, G (whole pages: the block is full to its last
+    row), none (slot 4, freed between two live ones: its position still says
+    5), G + 1, 2G + 1 whole, 2G + 1 with ONE row in the last, and one page
+    with one row. The slots in `dead` have no block-table row; `past` is
+    written to every pool row past its slot's length, pages nobody holds
+    included."""
+    H, W, page, NL = 4, 128, 32, 2
+    G = la.block_pages(page, W, jnp.dtype(dtype).itemsize, 1 << 20)
+    MP = 2 * G + 1
+    assert la.block_pages(page, W, jnp.dtype(dtype).itemsize, MP) == G > 1
+    pos = np.array([
+        0, 17, (G - 1) * page - 3, G * page, 5, (G + 1) * page - 7,
+        (2 * G + 1) * page, 2 * G * page + 1, 1], np.int32)
+    B, P = len(pos), 1 + int(sum(-(-int(n) // page) for n in pos))
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     live = jnp.arange(W) < RANK + SHARED  # pad lanes are zero
 
@@ -292,22 +304,33 @@ def _latent_case(dtype, seed=1):
         return (jax.random.normal(key, shape) * live).astype(dtype)
 
     q, pool, new = rows(ks[0], (B, H, W)), rows(ks[1], (NL, P, page, W)), rows(ks[2], (B, W))
-    bt, pos = np.full((B, MP), -1, np.int32), np.array([0, 17, 33, 64, 5], np.int32)
+    bt = np.full((B, MP), -1, np.int32)
+    held = np.zeros((P, page), bool)  # rows that hold a slot's old token
     ids = iter(range(1, P))
-    for b in range(B - 1):
+    for b in range(B):
         for i in range(-(-int(pos[b]) // page)):
-            bt[b, i] = next(ids)
+            page_id = next(ids)
+            held[page_id, : int(pos[b]) - i * page] = True
+            if b not in dead:
+                bt[b, i] = page_id
+    if past is not None:
+        pool = jnp.where(jnp.asarray(held)[None, :, :, None], pool, jnp.asarray(past, dtype))
     return q, pool, new, jnp.asarray(bt), jnp.asarray(pos)
 
 
+@pytest.mark.parametrize("past", [None, 1e30], ids=["", "past-the-length-1e30"])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 2e-2)])
-def test_the_latent_kernel_interpreted_is_the_jnp_attention(monkeypatch, dtype, tol):
-    args = _latent_case(dtype)
+def test_the_latent_kernel_interpreted_is_the_jnp_attention(monkeypatch, dtype, tol, past):
+    """Page counts on both sides of a block's edge; with `past`, a block that
+    attended a row it should have masked reads 1e30 at once (the reference
+    is given the pool as drawn: it must not matter what lies past a length)."""
+    args = _latent_case(dtype, past=past)
+    drawn = _latent_case(dtype)
     want = la.ref_latent_decode_attention(
-        *args, jnp.int32(1), scale=0.2, rank=RANK).astype(jnp.float32)
+        *drawn, jnp.int32(1), scale=0.2, rank=RANK).astype(jnp.float32)
     monkeypatch.setattr(dispatch, "FORCE_INTERPRET", True)
     got = la.latent_decode_attention(*args, 1, scale=0.2, rank=RANK)
-    assert got.shape == (5, 4, RANK) and got.dtype == dtype
+    assert got.shape == (9, 4, RANK) and got.dtype == dtype
     # float32: sums in another order (6e-7 read, the outputs reach 2.9); in
     # bfloat16 both round their result to 8 bits of it.
     assert float(jnp.abs(got.astype(jnp.float32) - want).max()) < tol
@@ -317,6 +340,25 @@ def test_the_latent_kernel_interpreted_is_the_jnp_attention(monkeypatch, dtype, 
         np.testing.assert_array_equal(
             np.asarray(got[b].astype(jnp.float32)),
             np.broadcast_to(np.asarray(new[b, :RANK]), (4, RANK)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_live_slots_output_is_the_same_to_the_bit_beside_dead_slots(monkeypatch, dtype):
+    """Slots 2 and 6 freed as well (their positions still say G - 1 and 2G +
+    1 pages): the walk steps over them and the ring's entries fall to other
+    blocks, and no live slot's output moves by a bit."""
+    monkeypatch.setattr(dispatch, "FORCE_INTERPRET", True)
+    full = la.latent_decode_attention(*_latent_case(dtype), 1, scale=0.2, rank=RANK)
+    args = _latent_case(dtype, dead=(2, 4, 6))
+    got = la.latent_decode_attention(*args, 1, scale=0.2, rank=RANK)
+    for b in (0, 1, 3, 5, 7, 8):
+        np.testing.assert_array_equal(np.asarray(got[b].astype(jnp.float32)),
+                                      np.asarray(full[b].astype(jnp.float32)))
+    for b in (2, 6):
+        np.testing.assert_array_equal(
+            np.asarray(got[b].astype(jnp.float32)),
+            np.broadcast_to(np.asarray(args[2][b, :RANK].astype(jnp.float32)), (4, RANK)))
+        assert float(jnp.abs(got[b].astype(jnp.float32) - full[b].astype(jnp.float32)).max()) > 1e-2
 
 
 def test_the_absorbed_decode_is_the_expanded_attention():
@@ -563,7 +605,12 @@ def test_the_pools_on_v1_state_and_the_counters():
         assert value('kubeai_engine_state_pool_bytes{kind="recurrent"}') == (
             2 * 6 * 4 * 16 * 16 * 4)
         assert value('kubeai_engine_state_pool_bytes{kind="latent"}') == latent_bytes
-        assert value('kubeai_engine_decode_live_pages_total{pool="latent"}') > 0
+        pages = value('kubeai_engine_decode_live_pages_total{pool="latent"}')
+        # The blocks the decode kernel attended them in: G pages a block at
+        # most, and a block holds one live page at least.
+        blocks = value('kubeai_engine_decode_page_blocks_total{pool="latent"}')
+        assert engine._latent_block == la.block_pages(16, 128, 4, 8) > 1
+        assert 0 < pages / engine._latent_block <= blocks <= pages
         assert not any(l.startswith("kubeai_engine_decode_live_pages_total ")
                        for l in metrics.splitlines())
         assert value('kubeai_engine_kv_pool_pages{pool="latent",state="free"}') == 16
