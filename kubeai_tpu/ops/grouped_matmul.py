@@ -26,18 +26,36 @@ PR 47 the stack was NL x X groups wide on the row side too: the library's
 with two scatter-adds and a scatter of a thousand updates and a `while`
 loop, some sixty instructions a routed layer (PERF.md section 6, PR 47).
 
-**The tile rule** (`weight_tile`): the weight tile is `[min(k, TILE_MAX),
-min(n, TILE_MAX)]`, then halved along k (rows stay whole and contiguous),
-and along n once k is down to `TILE_ROWS`, until it is at most `TILE_BYTES`: the kernel
-holds two of them (double-buffered) beside its row, output and accumulator
-tiles, inside Mosaic's default 16 MiB of scoped VMEM on a v5e. Measured at
-two shapes: 128 narrow experts of 2048 x 768 (SDAR-30B-A3B; tile `[2048,
-768]`, 3 MiB, what the rule leaves as it was: 0.63 ms a product at 8 rows an
-expert on a v5e, 78% of the HBM roofline, against 1.9 ms for
-`jax.lax.ragged_dot`, the reference path here; my chip run, PR 36), and 16
-wide experts of 6144 x 2048 (K-EXAONE-236B-A23B's share; `[2048, 2048]`
-would be 8 MiB a buffer, the whole scoped VMEM for two, so the rule gives
-`[1024, 2048]`, 4 MiB; PERF.md section 6, PR 46, has the chip's reading).
+**The tile rule** (`weight_tile`): n is cut into EQUAL tiles of whole 128
+strips no wider than `TILE_MAX` (the output's and the accumulator's width),
+k into as few equal tiles as keep what the kernel holds (`tile_bytes`: two
+buffers each of the row, weight and output tiles and a float32 accumulator)
+within `VMEM_BYTES`, 12 of Mosaic's default 16 MiB of scoped VMEM on a v5e;
+the whole of k is one tile where that fits, and n takes more tiles only once
+k is down to one strip. A count that would leave a shorter last tile gives
+way to the next that divides the strips, if one under twice it does; where
+none does (17 strips in two tiles, a k that is no multiple of 128 in more
+than one) the last tile is shorter, and along k the kernel then masks both
+operands at every step. Measured at three shapes: 128 narrow experts of
+2048 x 768 (SDAR-30B-A3B; one tile `[2048, 768]`, 3 MiB: 0.63 ms a product at
+8 rows an expert on a v5e, 78% of the HBM roofline, against 1.9 ms for
+`jax.lax.ragged_dot`, the reference path here; my chip run, PR 36), 16 wide
+experts of 6144 x 2048 (K-EXAONE-236B-A23B's share; five tiles `[1280,
+2048]` would hold 12.7 MiB, so six of `[1024, 2048]`, 4 MiB a buffer; PERF.md
+section 6, PR 46, has the chip's reading), and 32 experts of 2304 x 1024
+(Kimi-Linear-48B-A3B's share), the one width on file that 2,048 does not
+divide: until PR 52 a tile was at most 2,048 each way and 4 MiB, which gave
+it `[2048, 1024]` and a remainder of 256 rows that cost a whole tile's
+product, masked, behind a copy an eighth the size. The chip's three
+readings there (my chip runs, PR 52; the kernel alone at 128 held rows over
+29 of 32 experts, us a touched expert, gate/up and down): that rule 10.6 and
+9.3 (55% and 62% of the time the bytes take); two equal tiles, `[1152, 1024]`
+and `[1024, 1152]`, 7.7 and 7.4; the whole matrix as one tile, 7.0 and 7.2.
+Alone, one k step reads 5-9% under two (15% where experts are visited for
+several row tiles: the weight block is then fetched once) and two n passes
+read like one: so k whole, n in halves. Inside the decode chunk of
+`kimi-linear-48b-a3b.gen-sat` one k step and two read the same: the products
+went from 8.88 to 6.3 ms a step under either (PERF.md section 6, PR 52).
 """
 
 from __future__ import annotations
@@ -53,19 +71,47 @@ from jax.experimental.pallas import tpu as pltpu
 from kubeai_tpu.ops import dispatch
 
 TILE_ROWS = 128  # rows a tile; sorted rows are padded to a multiple of it
-TILE_MAX = 2048  # widest k or n tile: [2048, 768] bf16 is 3 MiB a buffer
-TILE_BYTES = 4 << 20  # most bytes of one weight tile; the kernel holds two
+TILE_MAX = 2048  # widest n tile: the output's and the accumulator's width
+# Of Mosaic's default 16 MiB of scoped VMEM on a v5e, what the kernel's own
+# tiles may take; the rest is the compiler's (a product before it is added).
+VMEM_BYTES = 12 << 20
+
+
+def tile_bytes(tk: int, tn: int, itemsize: int) -> int:
+    """What the kernel holds in VMEM at a weight tile [tk, tn]: two buffers
+    each of the row, weight and output tiles, and a float32 accumulator."""
+    return (2 * itemsize * (TILE_ROWS * tk + tk * tn + TILE_ROWS * tn)
+            + 4 * TILE_ROWS * tn)
 
 
 def weight_tile(k: int, n: int, itemsize: int) -> tuple[int, int]:
-    """(tk, tn) of the weight tile for a [k, n] matrix an expert."""
-    tk, tn = min(k, TILE_MAX), min(n, TILE_MAX)
-    while tk * tn * itemsize > TILE_BYTES:
+    """(tk, tn) of the weight tile for a [k, n] matrix an expert: n in equal
+    tiles of whole strips of TILE_ROWS within TILE_MAX, k in as few equal
+    tiles as keep `tile_bytes` within VMEM_BYTES (one, the whole of k, where
+    that fits); more tiles along n only once k is down to a strip. Where that
+    count leaves a shorter last tile and a count under twice it divides the
+    dimension's strips, that count is taken."""
+
+    def even(d: int, count: int) -> int:
+        tile = -(-d // count)
+        return tile if count == 1 else -(-tile // TILE_ROWS) * TILE_ROWS
+
+    def no_remainder(d: int, count: int) -> int:
+        for c in range(count, 2 * count):
+            if d % (c * TILE_ROWS) == 0:
+                return c
+        return count
+
+    tiles_k, tiles_n = 1, -(-n // TILE_MAX)
+    while True:
+        tk, tn = even(k, tiles_k), even(n, tiles_n)
+        if tile_bytes(tk, tn, itemsize) <= VMEM_BYTES:
+            return (even(k, no_remainder(k, tiles_k)),
+                    even(n, no_remainder(n, tiles_n)))
         if tk > TILE_ROWS:
-            tk //= 2
+            tiles_k += 1
         else:
-            tn //= 2
-    return tk, tn
+            tiles_n += 1
 
 
 class TilePlan(NamedTuple):

@@ -1,16 +1,21 @@
 """`ops/grouped_matmul.py:tile_plan`, the map from grid step to (group, row
 tile) that a layer's grouped products walk, against what it replaces on the
 kernel path: the installed megablox's `make_group_metadata` on the same
-groups. CPU only, nothing of the kernel runs."""
+groups; and `weight_tile`, the rule that cuts an expert's matrix into the
+kernel's weight tiles, at the widths of the configurations on file. CPU only,
+nothing of the kernel runs."""
 
 import importlib
+import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from kubeai_tpu.ops.grouped_matmul import TILE_ROWS, tile_plan
+from kubeai_tpu.ops.grouped_matmul import (
+    TILE_MAX, TILE_ROWS, VMEM_BYTES, gmm, tile_bytes, tile_plan, weight_tile)
 
 
 def spread(total: int, X: int, seed: int) -> np.ndarray:
@@ -95,3 +100,93 @@ def test_the_plan_lowers_to_dense_operations_only(X, rows):
     for op in ("sort", "scatter", "gather", "while"):
         assert op not in text, op
     assert f"{rows // TILE_ROWS + X - 1}x{X}" in text  # the one comparison
+
+
+# ---- the weight tile ----------------------------------------------------------
+
+CONFIGS = pathlib.Path(__file__).parents[2] / "perf" / "configs"
+SPARSE = ["sdar-30b-a3b-v5e1", "qwen3-next-80b-a3b-v5e1",
+          "k-exaone-236b-a23b-v5e1", "kimi-linear-48b-a3b-v5e1"]
+# The tiles of the configurations whose widths the rule before PR 52 divided
+# already (Kimi-Linear's 2304 it did not): with these tiles their compiled
+# programs are what they were.
+PINNED = {
+    "sdar-30b-a3b-v5e1": {"up": (2048, 768), "down": (768, 2048)},
+    "qwen3-next-80b-a3b-v5e1": {"up": (2048, 512), "down": (512, 2048)},
+    "k-exaone-236b-a23b-v5e1": {"up": (1024, 2048), "down": (1024, 2048)},
+}
+
+
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("config", SPARSE)
+def test_the_weight_tile_at_the_widths_on_file(config, product):
+    cfg = json.loads((CONFIGS / f"{config}.json").read_text())
+    hidden, mid = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    k, n = (hidden, mid) if product == "up" else (mid, hidden)
+    tk, tn = weight_tile(k, n, 2)
+    assert tile_bytes(tk, tn, 2) <= VMEM_BYTES
+    if config in PINNED:
+        assert (tk, tn) == PINNED[config][product]
+    # No remainder tile: its product costs a whole tile's and, along k, the
+    # kernel's mask of both operands at every step.
+    assert k % tk == 0 and n % tn == 0, (k, n, tk, tn)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_the_weight_tile_is_whole_strips_within_the_bytes_and_even_where_it_can_be(
+        itemsize):
+    widths = range(TILE_ROWS, 8192 + 1, TILE_ROWS)
+    for k in widths:
+        for n in widths:
+            tk, tn = weight_tile(k, n, itemsize)
+            assert tile_bytes(tk, tn, itemsize) <= VMEM_BYTES, (k, n)
+            assert tn <= TILE_MAX, (k, n)
+            for d, t in ((k, tk), (n, tn)):
+                assert t % TILE_ROWS == 0 and 0 < t <= d, (k, n)
+                # As many tiles as it takes, and equal wherever that many
+                # equal tiles are whole strips.
+                if (d / -(-d // t)) % TILE_ROWS == 0:
+                    assert d % t == 0, (k, n, tk, tn)
+
+
+def pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from pallas_calls(inner)
+
+
+@pytest.mark.parametrize("k, n, tile, selects", [
+    (2304, 1024, (2304, 1024), 1),  # gate, up: the whole matrix, one k step
+    (1024, 2304, (1024, 1152), 1),  # down: two even passes over n
+    (2304, 2048, (1152, 2048), 1),  # too large whole: two even k steps, no mask
+    (2100, 2048, (1152, 2048), 3),  # a k of no whole strips is still masked
+], ids=["kimi-up", "kimi-down", "even-k-steps", "k-remainder"])
+def test_the_traced_kernel_walks_the_tile_and_masks_only_a_remainder(
+        k, n, tile, selects):
+    """The kernel as traced at Kimi-Linear's shapes and beside them (abstract
+    operands, nothing lowered for a device): its block shapes are the tile,
+    its grid the even count, and the one `select` left is the store's row
+    mask; `masked` adds one an operand only where k leaves a remainder."""
+    X, rows = 32, 1024
+    steps = rows // TILE_ROWS + X - 1
+
+    def abstract(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    traced = gmm.trace(
+        abstract((rows, k), jnp.bfloat16), abstract((X, k, n), jnp.bfloat16),
+        abstract((X + 1,)), abstract((steps,)), abstract((steps,)),
+        abstract(()), abstract(()))
+    (call,) = pallas_calls(traced.jaxpr.jaxpr)
+    grid = call.params["grid_mapping"]
+    blocks = [
+        tuple(getattr(b, "block_size", None) for b in m.block_shape)
+        for m in grid.block_mappings]
+    tk, tn = tile
+    assert blocks == [(TILE_ROWS, tk), (None, tk, tn), (TILE_ROWS, tn)]
+    assert (grid.grid[0], grid.grid[2]) == (-(-n // tn), -(-k // tk))
+    assert str(call.params["jaxpr"]).count("select_n") == selects

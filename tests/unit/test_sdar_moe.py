@@ -404,18 +404,23 @@ def test_the_books_kept_by_comparison_are_the_sorted_books(monkeypatch, mode, ca
         assert float(jnp.abs(want.astype(jnp.float32)).max()) > 0.05
 
 
-# rows of x, groups of w (NL * X), the layer's X counts, the layer, k: what
+# rows of x, groups of w (NL * X), the layer's X counts, the layer, k, n: what
 # the product is called with. "stack": sizes over all of w's groups, as the
 # function is called without a layer. "middle-layer": only the groups of
 # layer 1 of 3 hold rows. "share": 21 rows lie behind the last group.
-# "k-steps": two steps along k, the second over a remainder of 52 columns.
+# "k-steps": two steps along k, the second over a remainder (948 of 1152
+# columns, masked; in float32 five of 512, the last over 52). "even-k-steps",
+# "even-n-passes": a width of 18 strips in two tiles of 9, along k (nothing
+# to mask) and along n. At a narrow n the whole of k is one tile.
 GROUPED = {
-    "stack": (74, 24, [0] * 8 + [20, 0, 1, 9, 0, 30, 11, 3] + [0] * 8, None, 64),
-    "middle-layer": (74, 24, [20, 0, 1, 9, 0, 30, 11, 3], 1, 64),
-    "share": (95, 24, [20, 0, 1, 9, 0, 30, 11, 3], 2, 64),
-    "two-row-tiles": (300, 12, [0, 130, 126, 44], 1, 64),
-    "share-nothing-held": (40, 8, [0, 0, 0, 0], 1, 64),
-    "k-steps": (150, 6, [100, 0, 30], 1, 2100),
+    "stack": (74, 24, [0] * 8 + [20, 0, 1, 9, 0, 30, 11, 3] + [0] * 8, None, 64, 96),
+    "middle-layer": (74, 24, [20, 0, 1, 9, 0, 30, 11, 3], 1, 64, 96),
+    "share": (95, 24, [20, 0, 1, 9, 0, 30, 11, 3], 2, 64, 96),
+    "two-row-tiles": (300, 12, [0, 130, 126, 44], 1, 64, 96),
+    "share-nothing-held": (40, 8, [0, 0, 0, 0], 1, 64, 96),
+    "k-steps": (150, 6, [100, 0, 30], 1, 2100, 2048),
+    "even-k-steps": (150, 6, [100, 0, 30], 1, 2304, 2048),
+    "even-n-passes": (150, 6, [100, 0, 30], 1, 64, 2304),
 }
 
 
@@ -428,19 +433,20 @@ def test_the_grouped_matmul_kernel_equals_the_grouped_product(
     within the tolerance, and BIT FOR BIT against the installed library's
     `gmm` (interpreted) over every group of the stack: rows that are no
     multiple of the 128-row tile, empty groups, one layer of a stack told by
-    its number. With one k step bf16 is exact; with two, the sum is taken in
+    its number. With one k step bf16 is exact over a few columns (over
+    2,304 a dozen sums round the other way); with two, the sum is taken in
     another order than the plain product's."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm as library_gmm
 
     from kubeai_tpu.ops.grouped_matmul import (
         TILE_ROWS, grouped_matmul, tile_plan, weight_tile)
 
-    rows, G, counts, layer, k = GROUPED[case]
-    if k > 64:
+    rows, G, counts, layer, k, n = GROUPED[case]
+    if k > 64 or n > 96:
         tol = 1e-3 if dtype == jnp.float32 else 0.05
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((rows, k)), dtype)
-    w = jnp.asarray(rng.standard_normal((G, k, 96)) * 0.1, dtype)
+    w = jnp.asarray(rng.standard_normal((G, k, n)) * 0.1, dtype)
     counts = jnp.asarray(counts, jnp.int32)
     X = counts.shape[0]
     held = int(counts.sum())
@@ -451,7 +457,7 @@ def test_the_grouped_matmul_kernel_equals_the_grouped_product(
     else:
         got = grouped_matmul(
             x, w, counts, layer=jnp.int32(layer), plan=tile_plan(counts, rows))
-    assert got.shape == (rows, 96) and got.dtype == dtype
+    assert got.shape == (rows, n) and got.dtype == dtype
     want = jax.lax.ragged_dot(x, w, jnp.asarray(whole))
     np.testing.assert_allclose(
         np.asarray(got[:held], np.float32), np.asarray(want[:held], np.float32),
@@ -461,7 +467,7 @@ def test_the_grouped_matmul_kernel_equals_the_grouped_product(
         library = library_gmm(
             jnp.pad(x, ((0, -rows % TILE_ROWS), (0, 0))), w, jnp.asarray(whole),
             preferred_element_type=dtype,
-            tiling=(TILE_ROWS, *weight_tile(k, 96, w.dtype.itemsize)),
+            tiling=(TILE_ROWS, *weight_tile(k, n, w.dtype.itemsize)),
             interpret=True)
     # Rows behind the last group are written by nobody, in either.
     np.testing.assert_array_equal(
